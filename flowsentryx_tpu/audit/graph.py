@@ -44,9 +44,10 @@ from typing import Any, Callable, Iterator
 
 #: Primitives that round-trip through the host mid-graph.  Any of these
 #: in a serving step turns the "one D2H wire per batch" budget into an
-#: unbounded sync point (and wedges donation on tunneled runtimes).
+#: unbounded sync point.
 CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "outside_call", "host_callback_call", "infeed", "outfeed",
 })
 
@@ -516,7 +517,11 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
                 elif c == ")":
                     depth -= 1
                 k += 1
-            if pat_re.search(hlo_text, mc.end(), k):
+            # from the start of the instruction: the result type sits
+            # before the op name, and newer XLA prints operands by name
+            # only, so a carried table shows there and nowhere else
+            line_start = hlo_text.rfind("\n", 0, mc.start()) + 1
+            if pat_re.search(hlo_text, line_start, k):
                 n_cond += 1
         census["conditionals"] = n_cond
         if n_cond:

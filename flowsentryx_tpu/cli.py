@@ -310,7 +310,7 @@ def _cmd_audit(args: argparse.Namespace) -> int:
     sharded step's collectives are exactly the designed set."""
     import dataclasses as _dc
 
-    _honor_jax_platform()
+    _place_compile_cache()
     from flowsentryx_tpu.audit import run_audit, runner
 
     # Flag validation BEFORE any JAX/mesh boot (the fsx serve
@@ -616,7 +616,7 @@ def _cmd_chaos(args: argparse.Namespace) -> int:
         for name, (cls, desc) in chaos_faults.FAULTS.items():
             print(f"{name:20s} [{cls}]\n    {desc}")
         return 0
-    _honor_jax_platform()
+    _place_compile_cache()
     from flowsentryx_tpu.chaos import run_campaign
 
     rep = run_campaign(seed=args.seed, quick=args.quick,
@@ -669,7 +669,7 @@ def _cmd_ranges(args: argparse.Namespace) -> int:
     interval-containment bridge."""
     import dataclasses as _dc
 
-    _honor_jax_platform()
+    _place_compile_cache()
     from flowsentryx_tpu.ranges import runner as ranges_runner
 
     if args.device_loop < 0:
@@ -758,7 +758,7 @@ def _cmd_distill(args: argparse.Namespace) -> int:
         print(f"fsx distill: --thresholds wants LO,HI in [0,1], got "
               f"{args.thresholds!r}", file=sys.stderr)
         return 1
-    _honor_jax_platform()
+    _place_compile_cache()
     from flowsentryx_tpu.distill import plan as dplan
     from flowsentryx_tpu.models.registry import (
         load_artifact,
@@ -1061,17 +1061,14 @@ def _cmd_rules(args: argparse.Namespace) -> int:
     return 0
 
 
-def _honor_jax_platform() -> None:
-    """Some TPU plugins force-register themselves regardless of
-    JAX_PLATFORMS; honor an explicit env request through the config API
-    (the route tests/conftest.py uses for the virtual CPU mesh).  Called
-    by the jax-using subcommands before any backend initializes — the
-    others stay free of the multi-second jax import."""
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
+def _place_compile_cache() -> None:
+    """JAX's persistent compilation cache goes where the environment
+    says, else ``<checkout>/.jax_cache`` (core/runtime.py).  Called by
+    the jax-using subcommands before their first compile — the others
+    stay free of the multi-second jax import."""
+    from flowsentryx_tpu.core import runtime
 
-        jax.config.update("jax_platforms", plat)
+    runtime.place_compile_cache()
 
 
 def _load_cfg(args: argparse.Namespace):
@@ -1416,7 +1413,10 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     from flowsentryx_tpu.engine.traffic import Scenario, TrafficSpec
 
     import_s = _time.perf_counter() - _t_imp
-    _honor_jax_platform()
+    from flowsentryx_tpu.core import runtime
+
+    runtime.require_platform("fsx serve")
+    jax_compiles = runtime.CompileCounters(runtime.place_compile_cache())
     if args.feature_ring:
         from flowsentryx_tpu.engine.shm import ShmRingSource, ShmVerdictSink
 
@@ -1571,6 +1571,7 @@ def _cmd_serve(args: argparse.Namespace) -> int:
                  watchdog_s=args.watchdog_s,
                  compile_cache=args.compile_cache)
     eng.boot_import_s = round(import_s, 4)
+    eng.boot_jax_compiles = jax_compiles
     if args.restore:
         from flowsentryx_tpu.engine.checkpoint import CheckpointCorrupt
 
@@ -1912,7 +1913,7 @@ def _cmd_cluster(args: argparse.Namespace) -> int:
     if args.mega:
         # mirror the serve-side compact16 probe: refuse a model the
         # engines would refuse, once, here — not N times in N children
-        _honor_jax_platform()
+        _place_compile_cache()
         from flowsentryx_tpu.models import get_model
 
         if args.artifact:
@@ -2204,6 +2205,22 @@ def _merged_boot(reports: list) -> dict | None:
     }
 
 
+def _merged_device(reports: list) -> dict | None:
+    """Merge the ``device`` blocks of engine-report JSONs (where each
+    engine placed its table) via :func:`health.fleet_devices` — the
+    fold the cluster supervisor's ``aggregate()`` applies.  Jax-free."""
+    from flowsentryx_tpu.engine.health import fleet_devices
+
+    per_engine = {}
+    for path, doc, err in reports:
+        if err is not None:
+            continue
+        rep = doc.get("report") if isinstance(doc.get("report"),
+                                              dict) else doc
+        per_engine[path] = rep.get("device")
+    return fleet_devices(per_engine)
+
+
 def _cmd_status(args: argparse.Namespace) -> int:
     """Inspect the shm transport: ring cursors and backlog."""
     import numpy as np
@@ -2255,6 +2272,9 @@ def _cmd_status(args: argparse.Namespace) -> int:
         boot = _merged_boot(reports)
         if boot is not None:
             out["boot"] = boot
+        device = _merged_device(reports)
+        if device is not None:
+            out["device"] = device
     print(json.dumps(out, indent=2))
     return 0
 
@@ -2619,7 +2639,7 @@ def _cmd_train(args: argparse.Namespace) -> int:
 
     from flowsentryx_tpu.train import data, evaluate, qat
 
-    _honor_jax_platform()
+    _place_compile_cache()
     if args.epochs < 1:
         raise SystemExit("--epochs must be >= 1")
     # Recipe flags are family-specific: reject silently-ignored combos
@@ -2730,7 +2750,10 @@ def _cmd_bench(args: argparse.Namespace) -> int:
     import sys as _sys
 
     if args.scenarios or args.scaling:
-        _honor_jax_platform()
+        from flowsentryx_tpu.core import runtime
+
+        runtime.require_platform("fsx bench")
+        runtime.place_compile_cache()
 
     if args.scenarios:
         from flowsentryx_tpu import benchmarks
@@ -3102,8 +3125,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="serve sharded over an N-device mesh (N>1)")
     s.add_argument("--mega", type=_mega_arg, default=0,
                    help="group N backlogged batches into one lax.scan "
-                        "dispatch (amortizes per-dispatch cost on "
-                        "tunneled/high-rate links; compact16 wire; "
+                        "dispatch (amortizes per-dispatch cost at "
+                        "high rates; compact16 wire; "
                         "composes with --mesh via the sharded mega-step)."
                         " 'auto' = adaptive coalescing: stage every "
                         "power-of-two group size up to 8 and dispatch "

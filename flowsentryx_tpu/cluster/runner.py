@@ -98,8 +98,6 @@ def engine_main(spec: dict) -> int:
     plain JSON-able dict assembled by the supervisor/CLI — see
     ``supervisor.py::engine_spec`` for the fields."""
     _own_process_group()
-    os.environ.setdefault("JAX_PLATFORMS",
-                          spec.get("jax_platform", "cpu"))
     if spec.get("pin_core") is not None:
         pin_to_core(spec["pin_core"])
     net = None
@@ -142,6 +140,13 @@ def _serve(spec: dict, plane: GossipPlane) -> None:
     from flowsentryx_tpu.ingest import ShardedIngest
 
     import_s = time.perf_counter() - _t_imp
+    # the platform comes from the environment this child inherited: a
+    # rank that cannot get its device dies here (the supervisor's
+    # ladder shows it FAILED), it does not carry on on the CPU
+    from flowsentryx_tpu.core import runtime
+
+    runtime.require_platform(f"fsx cluster rank {spec['rank']}")
+    runtime.place_compile_cache()
 
     rank, n = spec["rank"], spec["n_engines"]
     w = spec["workers"]
@@ -323,8 +328,6 @@ def prewarm_main(spec: dict) -> int:
     never waits on it, and any failure just means the spare compiles
     (fail-open, like every cache path)."""
     _own_process_group()
-    os.environ.setdefault("JAX_PLATFORMS",
-                          spec.get("jax_platform", "cpu"))
     try:
         import numpy as np
 
@@ -333,6 +336,10 @@ def prewarm_main(spec: dict) -> int:
         from flowsentryx_tpu.engine import Engine, NullSink
         from flowsentryx_tpu.engine.sources import ArraySource
 
+        from flowsentryx_tpu.core import runtime
+
+        runtime.require_platform("fsx cluster prewarm")
+        runtime.place_compile_cache()
         cfg = FsxConfig.from_json(spec["cfg_json"])
         params = None
         if spec.get("artifact"):

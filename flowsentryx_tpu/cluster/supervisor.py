@@ -1323,6 +1323,12 @@ class ClusterSupervisor:
                      for b in boots.values()), default=0.0), 4),
                 "prewarm_spawned": self.prewarm_spawned,
             }
+        # where each rank placed its table (EngineReport.device)
+        device_block = health_mod.fleet_devices({
+            r: rep["report"].get("device")
+            for r, rep in latest.items()
+            if isinstance(rep.get("report"), dict)
+        })
         elastic_block = None
         if self._elastic is not None:
             elastic_block = {
@@ -1356,5 +1362,6 @@ class ClusterSupervisor:
             "latency": latency,
             "predict": predict_block,
             "boot": boot_block,
+            "device": device_block,
             "reports": reports,
         }

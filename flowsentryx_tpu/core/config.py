@@ -130,9 +130,10 @@ class ModelConfig:
     threshold: float = 0.5              # sigmoid cutoff (model.py:205-208)
     quantized: bool = True
     ml_block_s: float = 10.0            # blacklist TTL for ML-flagged sources
-    #: Young-flow vote (SERVE_r04 finding: a flow's first records carry
-    #: no variance/IAT mass and can score malicious, so without a vote
-    #: EVERY benign source eventually gets ML-blacklisted).  A flow's
+    #: Young-flow vote (found serving the kernel path: a flow's first
+    #: records carry no variance/IAT mass and can score malicious, so
+    #: without a vote EVERY benign source eventually gets
+    #: ML-blacklisted).  A flow's
     #: malicious-scored records count as votes only once the engine has
     #: seen ``vote_k`` records from it (the kernel emits every packet
     #: while a flow is young, fsx_kern.c:163-165, so maturity arrives

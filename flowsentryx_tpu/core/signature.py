@@ -66,10 +66,9 @@ def staging_signature(
 
     Pure data (JSON-able, deterministic ordering via
     :func:`signature_digest`): callers hash it, tuple it, or embed it
-    in artifacts.  ``donate=None`` means "backend default" and is kept
-    distinct from an explicit bool — the caller that resolved the
-    default should pass the resolved value (the compile cache does;
-    the audit key never resolved it and keeps ``None``)."""
+    in artifacts.  ``donate=None`` means the caller does not key on it
+    (the audit boot cache) and is kept distinct from a bool (the
+    compile cache passes the engine's)."""
     return {
         "cfg": cfg.to_json(),
         "wire": wire,

@@ -89,15 +89,18 @@ class MicroBatcher:
 
     # -- triggers -----------------------------------------------------------
 
-    def add_precompact(self, records: np.ndarray) -> list[np.ndarray]:
+    def add_precompact(self, records: np.ndarray,
+                       on_seal=None) -> list[np.ndarray]:
         """Append KERNEL-quantized compact records
         (``schema.COMPACT_RECORD_DTYPE``, from a compact-emit data
         plane): features pass through untouched; only word 3's wrapped
         µs stamp is unwrapped against the host clock and rebased to the
-        batch base.  Requires ``wire="compact16"``."""
+        batch base.  Requires ``wire="compact16"``.  ``on_seal`` as in
+        :meth:`add`."""
         if self.wire != schema.WIRE_COMPACT16:
             raise ValueError("add_precompact requires the compact16 wire")
         out: list[np.ndarray] = []
+        sealed = on_seal or out.append
         if not len(records):
             return out
         # Staleness heuristic (unwrap_kernel_ts16 aliases silently): the
@@ -128,7 +131,7 @@ class MicroBatcher:
             if not span_ok.all():
                 take = max(int(span_ok.argmin()), 0)
                 if take == 0:
-                    out.append(self._seal())
+                    sealed(self._seal())
                     continue
             chunk = records[pos : pos + take]
             dt_us = np.clip(
@@ -144,13 +147,23 @@ class MicroBatcher:
             self.fill += take
             pos += take
             if self.fill == b:
-                out.append(self._seal())
+                sealed(self._seal())
         return out
 
-    def add(self, records: np.ndarray) -> list[np.ndarray]:
+    def add(self, records: np.ndarray,
+            on_seal=None) -> list[np.ndarray]:
         """Append records; returns the (possibly several) wire buffers
-        completed by this addition."""
+        completed by this addition.
+
+        One call can seal MORE buffers than ``n_buffers``: compact16
+        seals at every 65 ms of record time, so a backlog of slow
+        traffic seals once per few records.  The returned list would
+        then name buffers a later seal of the same call already reused.
+        A holder of few buffers passes ``on_seal`` instead: it is
+        handed each buffer the moment it seals (and must be done with
+        it on return), and the list stays empty."""
         out: list[np.ndarray] = []
+        sealed = on_seal or out.append
         pos = 0
         b = self.cfg.max_batch
         compact = self.wire == schema.WIRE_COMPACT16
@@ -173,7 +186,7 @@ class MicroBatcher:
                 if not span_ok.all():
                     take = max(int(span_ok.argmin()), 0)
                     if take == 0:
-                        out.append(self._seal())
+                        sealed(self._seal())
                         continue
             chunk = records[pos : pos + take]
             buf = self._bufs[self._cur]
@@ -188,7 +201,7 @@ class MicroBatcher:
             self.fill += take
             pos += take
             if self.fill == b:
-                out.append(self._seal())
+                sealed(self._seal())
         return out
 
     def note_poll(self) -> float:

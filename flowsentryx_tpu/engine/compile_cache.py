@@ -32,7 +32,8 @@ Entry format (one file per (signature, variant))::
 
     b"FSXAOT1\\n"                      magic
     <u32 little-endian header length>
-    <header JSON: sig digest, variant, jax/jaxlib/backend versions>
+    <header JSON: sig digest, variant, jax/jaxlib/backend versions,
+                  ids of the devices it was compiled for>
     <u32 little-endian CRC32 of the blob>
     <blob: pickle of (payload, in_tree, out_tree) from serialize()>
 
@@ -159,8 +160,15 @@ class CompileCache:
                   file=sys.stderr)
             return None
         try:
+            # load onto the devices the executable was compiled for
+            # (recorded at store): left to its default, JAX loads over
+            # EVERY local device and a one-device step then fails its
+            # first call on any host that has more than one
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in header["device_ids"]]
             payload, in_tree, out_tree = pickle.loads(blob)
-            exe = deserialize_and_load(payload, in_tree, out_tree)
+            exe = deserialize_and_load(payload, in_tree, out_tree,
+                                       execution_devices=devices)
         except Exception as e:
             self.corrupt += 1
             print(f"fsx compile-cache: entry {p.name} failed to "
@@ -183,6 +191,9 @@ class CompileCache:
                 "sig_digest": self.digest,
                 "variant": variant,
                 "versions": self.versions,
+                "device_ids": [
+                    d.id for d in
+                    compiled.runtime_executable().local_devices()],
                 "created_s": round(time.time(), 3),
             }).encode()
             buf = io.BytesIO()

@@ -191,3 +191,21 @@ def cluster_health(per_rank: dict, failed_ranks: list,
         "reasons": reasons,
         "per_rank": {str(r): h for r, h in sorted(per_rank.items())},
     }
+
+
+def fleet_devices(per_engine: dict) -> dict | None:
+    """Fold the ``EngineReport.device`` blocks of several engines (keyed
+    by rank or report path) into one view: the platforms and device
+    kinds seen and the device count summed — one query answers "did
+    every engine run on the chip?".  The one fold behind both the
+    supervisor's ``aggregate()`` and ``fsx status --engine-report``.
+    None when no engine reported a device block."""
+    blocks = {str(k): b for k, b in sorted(per_engine.items()) if b}
+    if not blocks:
+        return None
+    return {
+        "platforms": sorted({b.get("platform") for b in blocks.values()}),
+        "kinds": sorted({b.get("kind") for b in blocks.values()}),
+        "count": sum(int(b.get("count") or 0) for b in blocks.values()),
+        "per_engine": blocks,
+    }

@@ -1,11 +1,10 @@
 """Sharded parallel host ingest: break the single-threaded drain ceiling.
 
 The inline serving loop tops out where one Python thread tops out: the
-r5 stress run (``SHMSTRESS_r05.json``) measured the bare ring-drain
-path at 6.3 Mpps but the full drain → decode → batch-assembly → dispatch
-loop at ~0.9 Mpps — the decode/seal stage between ``ShmRingSource.poll``
-and the dispatch is the system bottleneck, not the device (~265 Mpps
-resident).  The fix is the standard per-packet-ML answer (Taurus, FENXI):
+decode/seal stage between ``ShmRingSource.poll`` and the dispatch costs
+per RECORD, the dispatch per batch (the split on the chip host: not
+measured yet).  The fix is the standard per-packet-ML answer (Taurus,
+FENXI):
 shard the host ingest stage and pipeline it away from the accelerator
 dispatch loop.
 

@@ -191,11 +191,12 @@ def worker_main(spec: dict) -> None:
         q.ctl_set("wstate", schema.WSTATE_RUNNING)
 
         def add(batcher, records):
-            return (
-                batcher.add_precompact(records)
-                if src.precompact
-                else batcher.add(records)
-            )
+            # on_seal, not the returned list: this batcher has TWO
+            # buffers and one chunk of slow record time can seal many
+            # (MicroBatcher.add) — a sealed buffer must be in the queue
+            # before the next seal can reuse it
+            pack = batcher.add_precompact if src.precompact else batcher.add
+            pack(records, lambda buf: emitter.emit(buf, stopping))
 
         while True:
             q.ctl_set("hbeat", _monotonic_ns())
@@ -248,28 +249,26 @@ def worker_main(spec: dict) -> None:
                     q, batcher, schema.wire_id_of(wire), cfg.max_batch
                 )
                 for r in pending:
-                    for buf in add(batcher, r):
-                        emitter.emit(buf, stopping)
+                    add(batcher, r)
                 pending = []
             else:
                 batcher = emitter.batcher
 
-            sealed = []
+            sealed_before = batcher.batches_emitted
             if n_polled:
                 for c in chunks:
-                    sealed += add(batcher, c)
-                # add() packed every record into wire buffers; the ring
-                # slots are dead — release BEFORE emit, which may block
-                # on queue backpressure.
+                    add(batcher, c)
+                # every record is packed into wire buffers and every
+                # sealed buffer is in the queue: the ring slots are dead
                 src.ring.advance(n_polled)
             else:
                 if src.precompact:
                     batcher.note_poll()
                 if batcher.flush_due():
                     took = batcher.take()
-                    sealed = [took] if took is not None else []
-            for buf in sealed:
-                emitter.emit(buf, stopping)
+                    if took is not None:
+                        emitter.emit(took, stopping)
+            sealed = batcher.batches_emitted != sealed_before
             if stopping and not n_polled and src.ring.readable() == 0:
                 # drain-on-shutdown: ring empty, flush the partial batch
                 tail = batcher.take()

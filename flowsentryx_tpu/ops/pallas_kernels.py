@@ -236,7 +236,8 @@ def table_summary(
     # table needs the scalar replicated over its mesh up front, or the
     # jit reshards it (an implicit D2D hop) on entry.
     sh = getattr(table.key, "sharding", None)
-    if isinstance(sh, jax.sharding.NamedSharding):
+    sharded = isinstance(sh, jax.sharding.NamedSharding)
+    if sharded:
         dst = jax.sharding.NamedSharding(sh.mesh,
                                          jax.sharding.PartitionSpec())
         now_dev = jax.device_put(np.float32(now), dst)
@@ -245,13 +246,17 @@ def table_summary(
     # Pallas only on a REAL TPU: interpret-mode emulation walks the
     # grid step by step, which at production capacities turns a
     # per-report scan into tens of seconds (measured ~100 s at 4M rows
-    # on CPU — it silently dominated every engine run's report).  The
-    # XLA twin is the same answer at memory-bandwidth speed everywhere
-    # else.
+    # on CPU — it silently dominated every engine run's report).  And
+    # only for a table on ONE device: the TPU compiler refuses to
+    # partition a Mosaic kernel over a mesh ("wrap the call in a
+    # shard_map"; tests/test_chip_compile.py keeps that on record).
+    # The XLA twin is the same answer at memory-bandwidth speed
+    # everywhere else.
     counts, newest = _table_summary(
         table.key, table.state, now_dev,
         float(stale_s),
-        use_pallas=(not table.capacity % _CHUNK and not _interpret()),
+        use_pallas=(not table.capacity % _CHUNK and not sharded
+                    and not _interpret()),
     )
     counts = jax.device_get(counts)
     return {

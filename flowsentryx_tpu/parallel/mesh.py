@@ -15,27 +15,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-# ``jax.shard_map`` graduated from jax.experimental across jax releases
-# (and renamed its replication-check kwarg check_rep → check_vma on the
-# way); resolve whichever spelling this runtime has ONCE so every caller
-# (parallel/step.py, train/qat.py) stays version-agnostic.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pre-graduation releases (e.g. 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = frozenset(__import__("inspect").signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """Version-portable ``shard_map`` (modern kwarg names)."""
-    if check_vma is not None:
-        kw["check_vma" if "check_vma" in _SM_PARAMS else "check_rep"] = (
-            check_vma
-        )
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
 
 def make_mesh(
     n_devices: int | None = None, axis_name: str = "ip"

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flowsentryx_tpu.core.config import FsxConfig
-from flowsentryx_tpu.parallel import layout, mesh as mesh_lib
+from flowsentryx_tpu.parallel import layout
 from flowsentryx_tpu.core.schema import (
     IpTableState, Verdict, make_table,
 )
@@ -67,7 +67,7 @@ def make_sharded_step(
     cfg: FsxConfig,
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
     mesh: Mesh,
-    donate: bool | None = None,
+    donate: bool = True,
     emit_score: bool = False,
 ):
     """Build the jitted multi-device step.
@@ -78,8 +78,6 @@ def make_sharded_step(
     engine swaps one for the other based on mesh size.  ``table`` must
     be sharded with :func:`shard_table`; batch/params/stats replicated.
     """
-    if donate is None:
-        donate = fused.donation_supported()
     axis = mesh.axis_names[0]
     n_dev = int(mesh.devices.size)
     k_bits = n_dev.bit_length() - 1  # n_dev = 2**k_bits (validated by make_mesh)
@@ -289,7 +287,7 @@ def make_sharded_step(
         wire=P() if cfg.batch.verdict_k else None,
     )
 
-    sharded = mesh_lib.shard_map(
+    sharded = jax.shard_map(
         device_step,
         mesh=mesh,
         in_specs=(table_specs, stats_specs, P(), P()),
@@ -305,8 +303,6 @@ def _make_sharded_wire_step(cfg, classify_batch, mesh, donate, decode,
     the shard-mapped step.  The wire enters as ONE contiguous H2D
     transfer (tiny next to the sharded state); all field extraction
     fuses into the jit."""
-    if donate is None:
-        donate = fused.donation_supported()
     base = make_sharded_step(cfg, classify_batch, mesh, donate=False,
                              emit_score=emit_score)
 
@@ -320,7 +316,7 @@ def make_sharded_raw_step(
     cfg: FsxConfig,
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
     mesh: Mesh,
-    donate: bool | None = None,
+    donate: bool = True,
     emit_score: bool = False,
 ):
     """Sharded step over the RAW ring wire format — the multi-device
@@ -339,7 +335,7 @@ def make_sharded_compact_step(
     cfg: FsxConfig,
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
     mesh: Mesh,
-    donate: bool | None = None,
+    donate: bool = True,
     emit_score: bool = False,
     **quant,
 ):
@@ -365,7 +361,7 @@ def make_sharded_compact_megastep(
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
     mesh: Mesh,
     n_chunks: int,
-    donate: bool | None = None,
+    donate: bool = True,
     **quant,
 ):
     """N micro-batches in ONE dispatch over the device mesh — the
@@ -382,8 +378,6 @@ def make_sharded_compact_megastep(
     Donation matches the module's table-only policy (the replicated
     stats output cannot alias a single-device input buffer anyway).
     """
-    if donate is None:
-        donate = fused.donation_supported()
     base = make_sharded_compact_step(cfg, classify_batch, mesh,
                                      donate=False, **quant)
     return fused.wrap_megastep(base, n_chunks, (0,) if donate else ())
@@ -394,7 +388,7 @@ def make_sharded_compact_megastep_family(
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
     mesh: Mesh,
     sizes: tuple[int, ...],
-    donate: bool | None = None,
+    donate: bool = True,
     **quant,
 ) -> dict:
     """One jitted sharded megastep per group size over ONE shard-mapped
@@ -404,8 +398,6 @@ def make_sharded_compact_megastep_family(
     every rung carries the full owner-routed collective pipeline per
     chunk, so per-rung parity with sequential sharded dispatches holds
     exactly as for the single fixed size."""
-    if donate is None:
-        donate = fused.donation_supported()
     base = make_sharded_compact_step(cfg, classify_batch, mesh,
                                      donate=False, **quant)
     return {

@@ -46,7 +46,7 @@ def eqn_frames(eqn: Any) -> list[tuple[str, str]]:
         from jax._src import source_info_util as siu
 
         return [(f.file_name, f.function_name)
-                for f in siu.user_frames(eqn.source_info)]
+                for f in siu.user_frames(eqn.source_info.traceback)]
     except Exception:
         return []
 
@@ -206,7 +206,7 @@ class _Prover:
         fdt = dtype is not None and not iv.is_int_dtype(dtype)
 
         # ---- control / call structure ----
-        if name == "pjit":
+        if name == "jit":
             sub = p["jaxpr"]
             return self.run_closed(sub, ins, f"{where}/jaxpr/",
                                    axis_env, record)

@@ -331,9 +331,7 @@ def train_logreg_qat_dp(
                                is_leaf=lambda x: x is None)
     epochs_jit = {}
     for quantize in (False, True):
-        from flowsentryx_tpu.parallel.mesh import shard_map
-
-        epochs_jit[quantize] = jax.jit(shard_map(
+        epochs_jit[quantize] = jax.jit(jax.shard_map(
             partial(device_epoch, quantize=quantize),
             mesh=mesh,
             in_specs=(state_specs, P(axis), P(axis), P(axis)),

@@ -1,4 +1,4 @@
-"""Open-loop paced latency/throughput curve (VERDICT r4 #2 evidence).
+"""Open-loop paced latency/throughput curve.
 
 Drives the real Engine with PacedSource at a grid of offered loads and
 prints ONE JSON line per config with achieved rate and per-record
@@ -13,9 +13,10 @@ build measures both sides of the threaded-sink/compact-wire change.
 separated) to find where achieved≈offered stops holding.
 
 The engine compiles OUTSIDE the paced clock (reset_stream reuse).
-Run on CPU (FSX_FORCE_CPU=1) or the live backend.
+Runs on the TPU; ``JAX_PLATFORMS=cpu`` runs it on the CPU on purpose
+(every row names its backend).
 
-Usage: [FSX_FORCE_CPU=1] python scripts/paced_profile.py
+Usage: [JAX_PLATFORMS=cpu] python scripts/paced_profile.py
            [--baseline] [--loads=0.8,1.0,1.5] [out.json]
 """
 from __future__ import annotations
@@ -42,9 +43,10 @@ GRID = (
 def main() -> int:
     import jax
 
-    from _probe_common import setup_backend
+    from flowsentryx_tpu.core import runtime
 
-    setup_backend()
+    runtime.require_platform("paced_profile")
+    runtime.place_compile_cache()
 
     from flowsentryx_tpu.core import schema
     from flowsentryx_tpu.core.config import BatchConfig, FsxConfig, TableConfig
@@ -90,7 +92,7 @@ def main() -> int:
         key = (bsz, dl)
         eng = engines.get(key)
         if eng is None:
-            eng = Engine(cfg, src, NullSink(), donate=None,
+            eng = Engine(cfg, src, NullSink(),
                          readback_depth=depth, wire=schema.WIRE_COMPACT16,
                          sink_thread=False if baseline else None)
             quant = schema.wire_quant_for(eng.params)

@@ -444,3 +444,63 @@ class TestOperatorSurface:
         sup3 = ClusterSupervisor(tmp_path / "c3", [{"a": 1}, {"a": 1}])
         sup3._maybe_prewarm()  # not elastic: skip
         assert sup3._prewarm_proc is None
+
+
+class TestRuntime:
+    """core/runtime.py: no silent CPU, the JAX cache placed from
+    outside, and JAX's own cache events counted per boot."""
+
+    @pytest.mark.parametrize("asked,ok", [
+        ("cpu", True), ("tpu,cpu", True), ("", False), ("tpu", False)])
+    def test_no_silent_cpu(self, monkeypatch, asked, ok):
+        from flowsentryx_tpu.core import runtime
+
+        # the backend here IS the cpu (conftest); what varies is
+        # whether the environment asked for it
+        monkeypatch.setenv("JAX_PLATFORMS", asked)
+        if ok:
+            assert runtime.require_platform("fsx serve") == "cpu"
+        else:
+            with pytest.raises(SystemExit, match="fsx serve: JAX found "
+                                                 "no TPU"):
+                runtime.require_platform("fsx serve")
+
+    def test_cache_dir_comes_from_the_environment_first(self, monkeypatch,
+                                                        tmp_path):
+        from flowsentryx_tpu.core import runtime
+
+        set_in_code = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: set_in_code.append((k, v)))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert runtime.place_compile_cache() == str(tmp_path)
+        assert set_in_code == []  # JAX reads the variable itself
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(runtime.CHECKOUT / ".jax_cache")
+        assert runtime.place_compile_cache() == want
+        assert set_in_code == [("jax_compilation_cache_dir", want)]
+        assert (runtime.CHECKOUT / "flowsentryx_tpu").is_dir()
+
+    def test_compile_counters_count_from_their_own_start(self):
+        from flowsentryx_tpu.core import runtime
+
+        first = runtime.CompileCounters("somewhere")
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
+        second = runtime.CompileCounters("somewhere")
+        a, b = first.report(), second.report()
+        assert a["dir"] == "somewhere"
+        assert a["backend_compile_s"] > 0 and b["backend_compile_s"] == 0
+        assert set(a) == {"dir", "requests", "hits", "stores",
+                          "backend_compile_s"}
+
+    def test_serve_report_carries_the_counters(self):
+        from flowsentryx_tpu.core import runtime
+
+        cfg = small_cfg()
+        eng = Engine(cfg, ArraySource(flood_records(cfg, 2)), CollectSink(),
+                     mega_n=2, sink_thread=False)
+        eng.boot_jax_compiles = runtime.CompileCounters("d")
+        eng.warm()
+        boot = eng.run().boot
+        assert boot["jax_cache"]["dir"] == "d"
+        assert boot["jax_cache"]["backend_compile_s"] > 0

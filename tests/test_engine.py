@@ -631,6 +631,29 @@ class TestEngineLoop:
                      NullSink(), mesh=make_mesh(1))
         assert eng.mesh is None  # 1-device mesh -> plain fused step
 
+    @pytest.mark.parametrize("n_mesh,count", [(0, 1), (8, 8)])
+    def test_report_names_the_devices_the_table_is_on(self, n_mesh, count):
+        """EngineReport.device is read off the table's own shards: one
+        device single-device, all eight under mesh=8 — and it survives
+        the jax-free merge `fsx status --engine-report` and the
+        supervisor's aggregate() both apply."""
+        from flowsentryx_tpu.engine.health import fleet_devices
+        from flowsentryx_tpu.parallel import make_mesh
+
+        cfg = small_cfg(batch=128, cap=1 << 12)
+        eng = Engine(cfg, TrafficSource(TrafficSpec(seed=9), total=256),
+                     NullSink(), mesh=make_mesh(n_mesh) if n_mesh else None)
+        rep = eng.run()
+        assert rep.device == {"platform": "cpu", "kind": "cpu",
+                              "count": count}
+        shards = {s.device for s in eng.table.key.addressable_shards}
+        assert len(shards) == count
+        merged = fleet_devices({0: rep.device, 1: rep.device, 2: None})
+        assert merged["platforms"] == ["cpu"]
+        assert merged["count"] == 2 * count
+        assert sorted(merged["per_engine"]) == ["0", "1"]
+        assert fleet_devices({0: None}) is None
+
     def test_max_batches_bound(self):
         cfg = small_cfg(batch=128)
         src = TrafficSource(TrafficSpec(seed=9))  # unbounded

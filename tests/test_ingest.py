@@ -409,6 +409,47 @@ class TestWorkerFleet:
             assert not w["dead"]
         assert stats["dropped_tail_batches"] == 0
 
+    def test_backlog_of_slow_record_time_is_conserved(self, tmp_path):
+        """compact16 seals at every 65 ms of RECORD time, so a backlog
+        of slow traffic (here 1 record/ms: 65 per seal) seals dozens of
+        batches out of one drained chunk — far more than the worker's
+        two wire buffers.  Each must reach the queue before its buffer
+        is reused: every record comes back exactly once, in order
+        (the first chip run lost 6,951 of 7.7 M records here, silently,
+        with as many duplicated)."""
+        base = str(tmp_path / "fring")
+        (ring,) = _make_shard_rings(base, 1)
+        rec = make_records(3000)
+        rec["ts_ns"] = (1_000_000_000
+                        + np.arange(len(rec), dtype=np.uint64) * 1_000_000)
+        rec["pkt_len"] = np.arange(len(rec)) % 1400 + 60  # tell rows apart
+        assert ring.produce(rec) == len(rec)
+        ing = ShardedIngest(base, 1, queue_slots=4, precompact=False,
+                            t0_grace_s=0.2)
+        ing.start(BatchConfig(max_batch=256, deadline_us=10_000),
+                  schema.WIRE_COMPACT16, dict(feat_mode="minifloat"))
+        try:
+            ing.wait_ready()
+            deadline = time.monotonic() + 20
+            while ing.t0_ns is None:
+                ing.poll_batches(0)
+                assert time.monotonic() < deadline
+                time.sleep(0.01)
+            ing.request_stop()
+            batches = _drain(ing)
+        finally:
+            ing.close()
+        assert len(batches) > 40  # one seal per 65 ms span, not per 256
+        assert sum(sb.n_records for sb in batches) == len(rec)
+        rows = np.concatenate([sb.raw[:sb.n_records] for sb in batches])
+        want = np.concatenate([
+            schema.compact_pack(rec[i:i + 65], int(rec["ts_ns"][i]),
+                                feat_mode="minifloat")
+            for i in range(0, len(rec), 65)])
+        np.testing.assert_array_equal(rows, want)
+        w = ing.ingest_stats()["workers"]["0"]
+        assert w["seq_gaps"] == 0 and w["seq_missing"] == 0
+
     def test_external_t0_imposed_before_handshake(self, tmp_path):
         """A restored checkpoint's epoch (Engine.restore → _run_sealed →
         set_t0) must reach the workers instead of their min-first_ts

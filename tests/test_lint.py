@@ -76,8 +76,8 @@ class TestLocalImportStage:
         assert len(out) == 1  # not duplicated by the nested-def walk
 
     def test_module_level_conditional_import_not_flagged(self, tmp_path):
-        # mesh.py's version-portability idiom: module-level try/if
-        # imports are module-level, not function-local
+        # module-level try/if imports are module-level, not
+        # function-local
         out = _findings(tmp_path, (
             "import jax\n"
             "if hasattr(jax, 'shard_map'):\n"
