@@ -1,0 +1,11 @@
+"""Percentile 50 over every record of the window of first record in
+its batch -> verdict sunk (``harness.window_percentile``)."""
+
+NAME = "verdict_p50_ms"
+UNIT = "ms"
+LAYER = "end to end"
+MOVES = ""
+
+
+def read(ctx):
+    return ctx.harness.window_percentile(ctx, 50.0)
