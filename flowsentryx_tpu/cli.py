@@ -2025,6 +2025,13 @@ def _iter_engine_reports(globs: list):
                 yield path, None, str(e)
 
 
+def _report_body(doc: dict) -> dict:
+    """The engine report inside a parsed report file: ``fsx serve``
+    prints it bare, the cluster runner wraps it as ``{"report": ...}``."""
+    rep = doc.get("report")
+    return rep if isinstance(rep, dict) else doc
+
+
 def _merged_latency(globs: list[str], reports: list | None = None) -> dict:
     """Merge the ``latency`` blocks of engine-report JSONs (``fsx
     serve`` output, or a cluster dir's per-rank ``report_r*_g*.json``
@@ -2094,8 +2101,7 @@ def _merged_engine_health(globs: list, reports: list | None = None) -> dict:
             }
             states.append(health_mod.DEGRADED)
             continue
-        rep = doc.get("report") if isinstance(doc.get("report"),
-                                              dict) else doc
+        rep = _report_body(doc)
         h = rep.get("health") or {}
         g = rep.get("cluster") or {}
         entry: dict = {
@@ -2158,8 +2164,7 @@ def _merged_predict(reports: list) -> dict | None:
     for _path, doc, err in reports:
         if err is not None:
             continue
-        rep = doc.get("report") if isinstance(doc.get("report"),
-                                              dict) else doc
+        rep = _report_body(doc)
         if rep.get("predict"):
             blocks.append(rep["predict"])
     if not blocks:
@@ -2182,8 +2187,7 @@ def _merged_boot(reports: list) -> dict | None:
     for path, doc, err in reports:
         if err is not None:
             continue
-        rep = doc.get("report") if isinstance(doc.get("report"),
-                                              dict) else doc
+        rep = _report_body(doc)
         boot = rep.get("boot")
         if not boot:
             continue
@@ -2215,10 +2219,33 @@ def _merged_device(reports: list) -> dict | None:
     for path, doc, err in reports:
         if err is not None:
             continue
-        rep = doc.get("report") if isinstance(doc.get("report"),
-                                              dict) else doc
+        rep = _report_body(doc)
         per_engine[path] = rep.get("device")
     return fleet_devices(per_engine)
+
+
+def _merged_spans(reports: list) -> dict | None:
+    """Merge the ``spans`` blocks of engine-report JSONs (the span
+    store, engine/metrics.py) name by name: counts, sums and buckets
+    add across engines.  The result has the reports' own schema and is
+    cumulative like them, so two ``fsx status --engine-report`` reads
+    subtracted are the window between them.  Jax-free."""
+    from flowsentryx_tpu.engine.metrics import LatencyHist, span_store
+
+    merged: dict = {}
+    for _path, doc, err in reports:
+        if err is not None:
+            continue
+        rep = _report_body(doc)
+        for name, entry in (rep.get("spans") or {}).items():
+            try:
+                h = LatencyHist.from_counts(entry["hist"])
+            except (KeyError, ValueError):
+                continue  # a foreign or torn block: skipped, not merged
+            # the entry's own sum is exact; the hist's copy is rounded
+            h.sum_us = float(entry.get("sum_us", h.sum_us))
+            merged.setdefault(name, LatencyHist()).merge(h)
+    return span_store(merged) if merged else None
 
 
 def _cmd_status(args: argparse.Namespace) -> int:
@@ -2275,6 +2302,9 @@ def _cmd_status(args: argparse.Namespace) -> int:
         device = _merged_device(reports)
         if device is not None:
             out["device"] = device
+        spans = _merged_spans(reports)
+        if spans is not None:
+            out["spans"] = spans
     print(json.dumps(out, indent=2))
     return 0
 

@@ -16,7 +16,13 @@ one:
   here sets another; where it is not, JAX's persistent compilation
   cache goes to ``<checkout>/.jax_cache`` (ignored by git).  The path is
   part of the cache key's stability — never a temporary name, pid or
-  time — :func:`place_compile_cache`.
+  time — :func:`place_compile_cache`.  The key covers each program's
+  metadata too (operation names with their ``jax.named_scope`` path,
+  source lines): by JAX's default it does not, and a boot after a
+  change of scopes alone then loads the executable compiled before it,
+  whose device trace carries the OLD names (measured, PR 29: the
+  ``fsx.*`` stage scopes were absent from a traced run until the cache
+  was cold).  A trace has to name what the source says.
 
 :class:`CompileCounters` reads JAX's own cache events so a boot can
 show what it compiled and what it loaded (``EngineReport.boot
@@ -51,11 +57,12 @@ def require_platform(who: str) -> str:
 def place_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its one place (module
     docstring) before the first compile; returns the directory."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     path = str(CHECKOUT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -59,11 +59,13 @@ from flowsentryx_tpu.core.config import FsxConfig
 from flowsentryx_tpu.engine.arena import DispatchArena
 from flowsentryx_tpu.engine.batcher import MicroBatcher
 from flowsentryx_tpu.engine import health
-from flowsentryx_tpu.engine.metrics import LatencyRecorder, PipelineMetrics
+from flowsentryx_tpu.engine.metrics import (
+    LatencyRecorder, PipelineMetrics, span_store,
+)
 from flowsentryx_tpu.engine.sources import RecordSource
 from flowsentryx_tpu.engine.watchdog import DispatchWatchdog
 from flowsentryx_tpu.engine.writeback import (
-    VerdictSink, decode_verdict_wire, extract_updates,
+    VerdictSink, decode_verdict_wire, extract_updates, wire_overflowed,
 )
 from flowsentryx_tpu.models import get_model
 from flowsentryx_tpu.ops import fused, pallas_kernels
@@ -170,11 +172,48 @@ class EngineReport(NamedTuple):
     #: shards at construction, not off ``jax.devices()``) — what lets a
     #: printed report show it served on the chip and not on the CPU.
     device: dict | None = None
+    #: The span store (engine/metrics.py): ``{name: {"n", "sum_us",
+    #: "max_us", "hist"}}`` for every span of the serving path
+    #: (``fsx.dispatch.*``, ``fsx.sink.*``, ``fsx.report``, the ingest
+    #: workers' ``fsx.ingest.w<k>.*``) and every histogram of the
+    #: latency plane (``latency.seal_to_verdict``, ``latency.<stage>``)
+    #: — CUMULATIVE since boot or ``reset_stream``, so two reports
+    #: subtracted are a window (docs/ENGINE.md §Observability).
+    #: ``stages_ms``, ``latency`` and the ingest blocks' ``fill_ms`` /
+    #: ``queue_ms`` are percentile views of the same histograms.  The
+    #: ``fsx.report`` span of THIS report closes after it is built: it
+    #: is in the next one.
+    spans: dict | None = None
+
+
+class _Stamps(NamedTuple):
+    """The host stamps of one sealed batch (perf_counter domain): the
+    latency plane charges an in-flight entry from its OLDEST member
+    batch, so that member's three stamps ride with the entry."""
+
+    t_enqueue: float    # when the batch's first record arrived
+    t_seal: float       # when its batcher (worker or inline) sealed it
+    t_dequeue: float    # when the engine took it off the sealed queue
+    #                     (the inline path has no queue: == t_seal)
+
+
+def _inline_stamps(t_first: float) -> _Stamps:
+    """Stamps of a batch the engine's own batcher just sealed: the
+    caller pops it the moment it seals, so seal and dequeue are now."""
+    now = time.perf_counter()
+    return _Stamps(t_first, now, now)
+
+
+def _sealed_stamps(sb) -> _Stamps:
+    """Stamps of a batch taken off the sealed queue; a source that does
+    not stamp the dequeue (a stand-in fleet) dequeued it just now."""
+    return _Stamps(sb.t_enqueue, sb.t_seal,
+                   sb.t_dequeue or time.perf_counter())
 
 
 class _InFlight(NamedTuple):
     out: Any            # StepOutput of device futures
-    t_enqueue: float    # when the batch's first record entered the batcher
+    stamps: _Stamps     # of the entry's oldest member batch
     n_records: int      # valid records in the batch (wire meta row)
     n_chunks: int = 1   # batches in this entry (mega_n for a mega dispatch)
     # latency-plane stamps (engine/metrics.py LatencyRecorder): when
@@ -185,13 +224,17 @@ class _InFlight(NamedTuple):
     t_launch: float = 0.0
     put_s: float = 0.0
     launch_s: float = 0.0
+    t_launched: float = 0.0   # when the step call returned
+    #: dispatch ordinal of the entry: the ``seq`` its spans carry
+    #: (upload, launch, fetch, decode, apply), across threads
+    seq: int = 0
 
 
 class _Uploaded(NamedTuple):
     """One staged-and-uploaded ring slot awaiting its round."""
 
     dev: Any            # device buffer ([chunks, B+1, words])
-    t_enqueue: float    # oldest member batch's first-record arrival
+    stamps: _Stamps     # of the slot's oldest member batch
     n_records: int
     put_s: float        # the slot's explicit H2D wall
 
@@ -583,8 +626,8 @@ class Engine:
                        mega_sizes=self._mega_sizes or None,
                        device_loop=self.ring,
                        params=self.params)
-        #: Sealed-but-undispatched (raw, t_seal) group candidates.
-        self._pending: list[tuple[np.ndarray, float]] = []
+        #: Sealed-but-undispatched (raw, stamps) group candidates.
+        self._pending: list[tuple[np.ndarray, _Stamps]] = []
         # Sealed-batch sources (flowsentryx_tpu/ingest/ShardedIngest)
         # deliver finished wire buffers instead of raw records: the run
         # loop switches to dequeue → dispatch → reap, and the worker
@@ -918,36 +961,39 @@ class Engine:
         self._rung_ewma_s[key] = max(
             prev + tuning.SLO_EWMA_ALPHA * (dt - prev), floor)
 
-    def _launch_single(self, raw: Any, t_enqueue: float,
+    def _launch_single(self, raw: Any, stamps: _Stamps,
                        n_records: int) -> _InFlight:
         """The step call + accounting of a single-batch dispatch (runs
         on the dispatch thread directly, or on the device-pipeline
         worker in device-loop mode)."""
-        with self.metrics.dispatch.time():
-            t_l = time.perf_counter()
+        self._dispatch_calls += 1
+        seq = self._dispatch_calls
+        with self.metrics.upload(seq) as up:
             dev = self._put(raw)
-            t_p = time.perf_counter()
+        with self.metrics.launch(seq) as la:
             self.table, self.stats, out = self.step(
                 self.table, self.stats, self.params, dev
             )
-            t_d = time.perf_counter()
-        self._dispatch_calls += 1
         self._dispatched_chunks += 1
         self._group_hist[1] = self._group_hist.get(1, 0) + 1
-        self._note_step_s(1, t_d - t_p, out)
-        return _InFlight(out, t_enqueue, n_records,
-                         t_launch=t_l, put_s=t_p - t_l,
-                         launch_s=t_d - t_p)
+        self._note_step_s(1, la.seconds, out)
+        return _InFlight(out, stamps, n_records,
+                         t_launch=up.t0, put_s=la.t0 - up.t0,
+                         launch_s=la.seconds, t_launched=la.t1, seq=seq)
 
-    def _dispatch(self, raw: np.ndarray, t_enqueue: float) -> None:
+    def _pop_stamps(self) -> _Stamps:
+        """Stamps of the batch the engine's own batcher sealed next."""
+        return _inline_stamps(self.batcher.pop_seal_time())
+
+    def _dispatch(self, raw: np.ndarray, stamps: _Stamps) -> None:
         n_records = int(raw[self.cfg.batch.max_batch, 0])
         if self._pipe_active:
-            self._submit("single", raw, t_enqueue, n_records, 1)
+            self._submit("single", raw, stamps, n_records, 1)
             return
-        self._inflight.append(self._launch_single(raw, t_enqueue,
+        self._inflight.append(self._launch_single(raw, stamps,
                                                   n_records))
 
-    def _launch_group(self, raws: Any, t_enqueue: float, n_records: int,
+    def _launch_group(self, raws: Any, stamps: _Stamps, n_records: int,
                       on_device: bool = False,
                       put_s: float = 0.0) -> _InFlight:
         """The megastep call + accounting of a group dispatch.
@@ -955,26 +1001,29 @@ class Engine:
         already-uploaded ring slot (``put_s`` then carries the upload
         wall :meth:`_upload_slot` already paid for it)."""
         g = int(raws.shape[0])
-        with self.metrics.dispatch.time():
-            t_l = time.perf_counter()
-            dev = raws if on_device else self._put(raws)
-            t_p = time.perf_counter()
+        self._dispatch_calls += 1
+        seq = self._dispatch_calls
+        if on_device:
+            dev, t_l = raws, time.perf_counter()
+        else:
+            with self.metrics.upload(seq) as up:
+                dev = self._put(raws)
+            t_l = up.t0
+        with self.metrics.launch(seq) as la:
             self.table, self.stats, out = self.megasteps[g](
                 self.table, self.stats, self.params, dev
             )
-            t_d = time.perf_counter()
-        self._dispatch_calls += 1
         self._dispatched_chunks += g
         self._group_hist[g] = self._group_hist.get(g, 0) + 1
         if on_device:
             self._ring_partial_slots += 1
-        self._note_step_s(g, t_d - t_p, out)
-        return _InFlight(out, t_enqueue, n_records, n_chunks=g,
+        self._note_step_s(g, la.seconds, out)
+        return _InFlight(out, stamps, n_records, n_chunks=g,
                          t_launch=t_l,
-                         put_s=put_s if on_device else t_p - t_l,
-                         launch_s=t_d - t_p)
+                         put_s=put_s if on_device else la.t0 - t_l,
+                         launch_s=la.seconds, t_launched=la.t1, seq=seq)
 
-    def _dispatch_group(self, raws: np.ndarray, t_enqueue: float,
+    def _dispatch_group(self, raws: np.ndarray, stamps: _Stamps,
                         n_records: int) -> None:
         """One lax.scan dispatch over a CONTIGUOUS ``[g, B+1, words]``
         staged wire group (a dispatch-arena slice — no np.stack copy).
@@ -982,16 +1031,17 @@ class Engine:
         Queued as ONE in-flight entry whose StepOutput fields are
         stacked ``[g, B]`` (``now``/``route_drop``: ``[g]``) —
         :meth:`_sink_group` ravels, so verdict extraction is unchanged.
-        e2e is anchored at the OLDEST member's first-record arrival (the
-        honest group latency: earlier members waited for the group)."""
+        Latency is anchored at the OLDEST member's stamps (the honest
+        group latency: earlier members waited for the group)."""
         if self._pipe_active:
-            self._submit("group", raws, t_enqueue, n_records,
+            self._submit("group", raws, stamps, n_records,
                          int(raws.shape[0]))
             return
-        self._inflight.append(self._launch_group(raws, t_enqueue,
+        self._inflight.append(self._launch_group(raws, stamps,
                                                  n_records))
 
-    def _dispatch_mega(self, group: list[tuple[np.ndarray, float]]) -> None:
+    def _dispatch_mega(self,
+                       group: list[tuple[np.ndarray, _Stamps]]) -> None:
         """Group dispatch of INLINE-path pending buffers: stage the
         group's wire buffers into one arena slot (replacing the old
         per-group ``np.stack`` allocation with the arena's reusable
@@ -999,7 +1049,7 @@ class Engine:
         b = self.cfg.batch.max_batch
         g = len(group)
         rows = self._arena.rows(self._arena.claim())
-        with self.metrics.stage.time():
+        with self.metrics.stage:
             for i, (raw, _) in enumerate(group):
                 rows[i][...] = raw
         self._staged_batches += g
@@ -1009,7 +1059,7 @@ class Engine:
 
     # -- device-loop (drain ring) dispatch ----------------------------------
 
-    def _upload_slot(self, rows: np.ndarray, t_enqueue: float,
+    def _upload_slot(self, rows: np.ndarray, stamps: _Stamps,
                      n_records: int) -> _Uploaded:
         """EXPLICIT H2D of one staged ring slice — issued the moment
         the slot fills, so the transfer overlaps whatever round is
@@ -1020,27 +1070,28 @@ class Engine:
         overlapped — that is the "device never waits on the host"
         claim, measured rather than asserted."""
         busy = self._busy_depth() > 0
-        t0 = time.perf_counter()
-        buf = self._put(rows)
-        dt = time.perf_counter() - t0
+        # a slot uploads before its round has a dispatch ordinal: the
+        # span carries the slot's own upload ordinal
+        with self.metrics.upload(self._h2d_puts + 1) as up:
+            buf = self._put(rows)
+        dt = up.seconds
         self._h2d_put_s += dt
         self._h2d_puts += 1
         if busy:
             self._h2d_overlap_s += dt
             self._h2d_puts_overlapped += 1
-        return _Uploaded(buf, t_enqueue, n_records, dt)
+        return _Uploaded(buf, stamps, n_records, dt)
 
-    def _launch_ring(self, devs: list, t_enqueue: float,
+    def _launch_ring(self, devs: list, stamps: _Stamps,
                      n_records: int, put_s: float = 0.0) -> _InFlight:
         """The deep-scan call + accounting of a full ring round."""
         g = self.ring * self._ring_chunks
-        with self.metrics.dispatch.time():
-            t_l = time.perf_counter()
+        self._dispatch_calls += 1
+        seq = self._dispatch_calls
+        with self.metrics.launch(seq) as la:
             self.table, self.stats, out = self.ring_step(
                 self.table, self.stats, self.params, *devs
             )
-            t_d = time.perf_counter()
-        self._dispatch_calls += 1
         self._dispatched_chunks += g
         self._group_hist[g] = self._group_hist.get(g, 0) + 1
         self._ring_rounds += 1
@@ -1053,9 +1104,10 @@ class Engine:
         # round cost but can never decay below the seed and let
         # _slo_round_fits keep waiting for rounds that land past the
         # budget (the decaying-optimistic-estimate hazard).
-        self._note_round_s(-g, (t_d - t_l) + put_s, out)
-        return _InFlight(out, t_enqueue, n_records, n_chunks=g,
-                         t_launch=t_l, put_s=put_s, launch_s=t_d - t_l)
+        self._note_round_s(-g, la.seconds + put_s, out)
+        return _InFlight(out, stamps, n_records, n_chunks=g,
+                         t_launch=la.t0, put_s=put_s,
+                         launch_s=la.seconds, t_launched=la.t1, seq=seq)
 
     def _dispatch_ring(self, uploaded: list[_Uploaded]) -> None:
         """ONE deep-scan dispatch over a full ring round (R uploaded
@@ -1064,27 +1116,27 @@ class Engine:
         verdict wire PER SLOT — the sink harvests the round as a
         single ``[R, 2K+4]`` fetch."""
         devs = [u.dev for u in uploaded]
-        t_enqueue = min(u.t_enqueue for u in uploaded)
+        stamps = min(u.stamps for u in uploaded)
         n_records = sum(u.n_records for u in uploaded)
         put_s = sum(u.put_s for u in uploaded)
         if self._pipe_active:
-            self._submit("ring", devs, t_enqueue, n_records,
+            self._submit("ring", devs, stamps, n_records,
                          self.ring * self._ring_chunks, put_s)
             return
-        self._inflight.append(self._launch_ring(devs, t_enqueue,
+        self._inflight.append(self._launch_ring(devs, stamps,
                                                 n_records, put_s))
 
-    def _dispatch_group_dev(self, dev: Any, t_enqueue: float,
+    def _dispatch_group_dev(self, dev: Any, stamps: _Stamps,
                             n_records: int, put_s: float = 0.0) -> None:
         """Megastep dispatch of an ALREADY-UPLOADED ring slot (a short
         backlog left the round partial: the uploaded slices flush
         through the ordinary top-rung megastep, byte-identical by
         construction — the ring's slot body IS that megastep)."""
         if self._pipe_active:
-            self._submit("group_dev", dev, t_enqueue, n_records,
+            self._submit("group_dev", dev, stamps, n_records,
                          self._ring_chunks, put_s)
             return
-        self._inflight.append(self._launch_group(dev, t_enqueue,
+        self._inflight.append(self._launch_group(dev, stamps,
                                                  n_records,
                                                  on_device=True,
                                                  put_s=put_s))
@@ -1101,7 +1153,7 @@ class Engine:
             rows = self._arena.rows(self._arena.claim())
             group = self._pending[:c]
             del self._pending[:c]
-            with self.metrics.stage.time():
+            with self.metrics.stage:
                 for i, (raw, _) in enumerate(group):
                     rows[i][...] = raw
             self._staged_batches += c
@@ -1147,11 +1199,11 @@ class Engine:
                      else schema.RECORD_WORDS)
             self._warm_buf = np.zeros(
                 (self.cfg.batch.max_batch + 1, words), np.uint32)
-        t0 = time.perf_counter()
+        stamps = _inline_stamps(time.perf_counter())
         if rung > 1 and self._arena is not None:
-            self._dispatch_mega([(self._warm_buf, t0)] * rung)
+            self._dispatch_mega([(self._warm_buf, stamps)] * rung)
         else:
-            self._dispatch(self._warm_buf, t0)
+            self._dispatch(self._warm_buf, stamps)
         self._reap(0)
 
     # -- latency-budget (SLO) policy ----------------------------------------
@@ -1263,7 +1315,8 @@ class Engine:
                 self._reap(self.readback_depth)
             if not short and not (
                     slo and self._pending
-                    and self._slo_pressed(self._pending[0][1])):
+                    and self._slo_pressed(
+                        self._pending[0][1].t_enqueue)):
                 # a full poll means the backlog is still building
                 # toward the next round — hold the remainder (unless
                 # the budget says holding is no longer free)
@@ -1274,18 +1327,19 @@ class Engine:
             del self._pending[:top]
             self._reap(self.readback_depth)
         if not self._pending or not (short or (
-                slo and self._slo_pressed(self._pending[0][1]))):
+                slo and self._slo_pressed(
+                        self._pending[0][1].t_enqueue))):
             return
         while self._pending:
             g = self._rung_for(len(self._pending))
             if slo:
-                g = min(g, self._slo_cap(self._pending[0][1]))
+                g = min(g, self._slo_cap(
+                    self._pending[0][1].t_enqueue))
             if g > 1:
                 self._dispatch_mega(self._pending[:g])
                 del self._pending[:g]
             else:
-                raw, t_seal = self._pending.pop(0)
-                self._dispatch(raw, t_seal)
+                self._dispatch(*self._pending.pop(0))
             self._reap(self.readback_depth)
 
     @staticmethod
@@ -1387,9 +1441,12 @@ class Engine:
             # quantum: a wedged-but-alive worker (no WorkerCrash to
             # break the wait) must dump stacks and fail loudly instead
             # of parking this wait forever (engine/watchdog.py)
-            self._chan.wait_below(
-                down_to,
-                on_wait=lambda: self._watchdog.check(self._chan.pending))
+            if self._chan.pending > down_to:
+                with self.metrics.backpressure:
+                    self._chan.wait_below(
+                        down_to,
+                        on_wait=lambda: self._watchdog.check(
+                            self._chan.pending))
             self._check_sink()
             return
         total = sum(g.n_chunks for g in self._inflight)
@@ -1399,7 +1456,11 @@ class Engine:
             total -= g.n_chunks
             group.append(g)
         if group:
-            self._sink_group(group)
+            # single-thread mode: the blocking sink IS the wait for the
+            # pipe to drain (here alone a span holds the sink
+            # section's fetch/decode/apply inside it)
+            with self.metrics.backpressure:
+                self._sink_group(group)
 
     def _reap_ready(self) -> None:
         """Sink every batch the device has ALREADY finished, oldest
@@ -1434,7 +1495,7 @@ class Engine:
             self._gov.update(now)
             age = self.batcher.pending_age_s()
             if self._pending:
-                age = max(age, now - self._pending[0][1])
+                age = max(age, now - self._pending[0][1].t_enqueue)
             pressure = self._gov.pressure(age, self._slo_budget_s)
         if self.gossip is not None:
             # merge peers' gossiped verdicts between dispatches (also
@@ -1515,8 +1576,9 @@ class Engine:
         by a single worker preserves record order for ``on_reap``."""
         try:
             while True:
-                group = self._chan.pop(
-                    coalesce=lambda e: self._out_ready(e.out))
+                with self.metrics.sink_wait:
+                    group = self._chan.pop(
+                        coalesce=lambda e: self._out_ready(e.out))
                 if group is None:
                     return  # stop requested and queue drained
                 t0 = time.perf_counter()
@@ -1537,7 +1599,7 @@ class Engine:
         except BaseException as e:  # noqa: BLE001 — surfaced by _check_sink
             self._chan.record_exc(e)
 
-    def _submit(self, kind: str, payload: Any, t_enqueue: float,
+    def _submit(self, kind: str, payload: Any, stamps: _Stamps,
                 n_records: int, n_chunks: int,
                 put_s: float = 0.0) -> None:
         """Hand one pre-launch work item to the device-pipeline worker
@@ -1545,7 +1607,7 @@ class Engine:
         SUBMIT time, so the ``readback_depth`` backpressure bound
         covers queued-but-unlaunched work too — the wire/arena
         reuse-safety arguments both lean on that."""
-        self._chan.submit((kind, payload, t_enqueue, n_records, n_chunks,
+        self._chan.submit((kind, payload, stamps, n_records, n_chunks,
                            put_s),
                           n_chunks)
 
@@ -1562,24 +1624,25 @@ class Engine:
         double-buffered H2D overlap the report measures."""
         try:
             while True:
-                got = self._chan.pop()
+                with self.metrics.sink_wait:
+                    got = self._chan.pop()
                 if got is None:
                     return  # stop requested and queue drained
-                kind, payload, t_e, n_rec, n_chunks, put_s = got[0]
+                kind, payload, stamps, n_rec, n_chunks, put_s = got[0]
                 t0 = time.perf_counter()
                 exc: BaseException | None = None
                 try:
                     if kind == "ring":
-                        entry = self._launch_ring(payload, t_e, n_rec,
+                        entry = self._launch_ring(payload, stamps, n_rec,
                                                   put_s)
                     elif kind == "group_dev":
-                        entry = self._launch_group(payload, t_e, n_rec,
+                        entry = self._launch_group(payload, stamps, n_rec,
                                                    on_device=True,
                                                    put_s=put_s)
                     elif kind == "group":
-                        entry = self._launch_group(payload, t_e, n_rec)
+                        entry = self._launch_group(payload, stamps, n_rec)
                     else:
-                        entry = self._launch_single(payload, t_e, n_rec)
+                        entry = self._launch_single(payload, stamps, n_rec)
                     self._sink_group([entry])
                 except BaseException as e:  # noqa: BLE001
                     exc = e
@@ -1614,10 +1677,10 @@ class Engine:
         if group[0].out.wire is not None:
             self._sink_group_wire(group)
             return
-        t_fetch = time.perf_counter()
+        seq = group[0].seq
         # .reshape(-1) everywhere: a mega-dispatch entry carries stacked
         # [N, B] fields (now/route_drop [N]); single entries are [B]/[].
-        with self.metrics.readback.time():
+        with self.metrics.fetch(seq) as fetch:
             # jax.device_get, not np.asarray: the D2H boundary stays
             # EXPLICIT (class docstring / transfer_guard contract)
             if len(group) <= 2:
@@ -1657,8 +1720,9 @@ class Engine:
                 self._route_drop += int(jax.device_get(jnp.sum(
                     jnp.concatenate([jnp.ravel(jnp.asarray(rd))
                                      for rd in rds]))))
-        self._apply_updates(extract_updates(keys, untils), now, group,
-                            t_fetch)
+        with self.metrics.decode(seq):
+            upd = extract_updates(keys, untils)
+        self._apply_updates(upd, now, group, fetch.t0, fetch.t1)
 
     def _sink_group_wire(self, group: list[_InFlight]) -> None:
         """The compact-wire sink (see :meth:`_sink_group`).
@@ -1670,8 +1734,8 @@ class Engine:
         slot wire falls back to the full block-array fetch for the
         whole entry — the arrays cover every slot in chunk order, so
         last-wins decode stays exact and no block is lost."""
-        t_fetch = time.perf_counter()
-        with self.metrics.readback.time():
+        seq = group[0].seq
+        with self.metrics.fetch(seq) as fetch:
             if len(group) <= 2 or any(g.out.wire.ndim == 2
                                       for g in group):
                 # per-entry fetch: ring wires are already deep-
@@ -1681,90 +1745,105 @@ class Engine:
             else:
                 wires = jax.device_get(
                     jnp.stack([g.out.wire for g in group]))
-            parts_k: list[np.ndarray] = []
-            parts_u: list[np.ndarray] = []
-            now = 0.0
-            for g, w in zip(group, wires):
-                rows = w.reshape(-1, w.shape[-1])
+            # K_MAX-overflow fallback: a batch (or a ring slot's
+            # merged window) condemned more flows than its wire holds
+            # — pay the full fetch once rather than lose a single
+            # block.  Fetched here, with the wires: the fetch span is
+            # all of the sink's waiting on the device.
+            full = {}
+            for i, (g, w) in enumerate(zip(group, wires)):
                 self._d2h_bytes += w.nbytes
-                overflow = False
-                entry_k: list[np.ndarray] = []
-                entry_u: list[np.ndarray] = []
-                for row in rows:
-                    vw = decode_verdict_wire(row)
-                    overflow |= vw.overflow
-                    entry_k.append(vw.key)
-                    entry_u.append(vw.until_s)
-                    self._route_drop += vw.route_drop
-                    now = max(now, vw.now)
-                if overflow:
-                    # K_MAX-overflow fallback: a batch (or a ring
-                    # slot's merged window) condemned more flows than
-                    # its wire holds — pay the full fetch once rather
-                    # than lose a single block.  The wire slots of the
-                    # WHOLE entry are discarded: the full arrays carry
-                    # every block in the same chunk order.
+                if wire_overflowed(w):
                     fk = jax.device_get(g.out.block_key).reshape(-1)
                     fu = jax.device_get(g.out.block_until).reshape(-1)
                     self._d2h_bytes += fk.nbytes + fu.nbytes
+                    full[i] = (fk, fu)
+        with self.metrics.decode(seq):
+            parts_k: list[np.ndarray] = []
+            parts_u: list[np.ndarray] = []
+            now = 0.0
+            for i, w in enumerate(wires):
+                rows = [decode_verdict_wire(row)
+                        for row in w.reshape(-1, w.shape[-1])]
+                for vw in rows:
+                    self._route_drop += vw.route_drop
+                    now = max(now, vw.now)
+                if i in full:
+                    # the wire slots of the WHOLE entry are discarded:
+                    # the full arrays carry every block in the same
+                    # chunk order
                     self._sink_fallback += 1
-                    parts_k.append(fk)
-                    parts_u.append(fu)
+                    parts_k.append(full[i][0])
+                    parts_u.append(full[i][1])
                 else:
                     self._sink_compact += len(rows)
-                    parts_k.extend(entry_k)
-                    parts_u.extend(entry_u)
+                    parts_k.extend(vw.key for vw in rows)
+                    parts_u.extend(vw.until_s for vw in rows)
             keys = (np.concatenate(parts_k) if len(parts_k) > 1
                     else parts_k[0])
             untils = (np.concatenate(parts_u) if len(parts_u) > 1
                       else parts_u[0])
-        self._apply_updates(extract_updates(keys, untils), now, group,
-                            t_fetch)
+            upd = extract_updates(keys, untils)
+        self._apply_updates(upd, now, group, fetch.t0, fetch.t1)
 
     def _apply_updates(self, upd, now: float, group: list[_InFlight],
-                       t_fetch: float) -> None:
+                       t_fetch: float, t_wire: float) -> None:
         """Shared sink tail: writeback, clock/metric bookkeeping, the
         per-record latency plane, and the per-batch reap hook
         (record-FIFO order — both sink modes process groups
         oldest-first on a single thread).  ``t_fetch`` is when the
-        group's wire fetch began — the sink-stage anchor of the
-        latency decomposition."""
-        self.sink.apply(upd)
-        if self.gossip is not None:
-            # republish to every peer engine RIGHT where the local
-            # sink applied — the gossip TX mailboxes' single producer
-            # is this sink section, whichever thread owns it
-            self.gossip.publish(upd, now)
-        self._blocked.update(upd.key.tolist())
-        self._device_now = max(self._device_now, now)
-        self._sunk_batches += sum(g.n_chunks for g in group)
-        t_done = time.perf_counter()
-        if (self._first_verdict_s is None
-                and any(g.n_records for g in group)):
-            # time-to-first-verdict (EngineReport.boot): anchored at
-            # construction; masked warm batches carry zero records and
-            # never trip it, so this is the first REAL verdict served
-            self._first_verdict_s = t_done - self._boot_t0
-        self._last_sink_t = t_done
-        sink_s = t_done - t_fetch
-        for g in group:
-            self.metrics.e2e.add(t_done - g.t_enqueue)
-            # per-record accounting: every record of the entry is
-            # charged the entry's OLDEST-record path (a conservative
-            # upper bound — earlier members waited for the group, the
-            # same anchoring e2e has always used)
-            self._lat.record(
-                total_s=t_done - g.t_enqueue,
-                staged_s=(g.t_launch - g.t_enqueue
-                          if g.t_launch else 0.0),
-                upload_s=g.put_s,
-                compute_s=g.launch_s,
-                sink_s=sink_s,
-                n=g.n_records,
-                budget_s=self._slo_budget_s,
-            )
-            if self.on_reap is not None:
-                self.on_reap(g.n_records, t_done)
+        group's wire fetch began — the ``sink`` stage's anchor —
+        and ``t_wire`` when the wire was on the host, where the
+        chain's ``device`` ends and ``sink_host`` begins."""
+        with self.metrics.apply(group[0].seq):
+            self.sink.apply(upd)
+            if self.gossip is not None:
+                # republish to every peer engine RIGHT where the local
+                # sink applied — the gossip TX mailboxes' single
+                # producer is this sink section, whichever thread owns
+                # it
+                self.gossip.publish(upd, now)
+            self._blocked.update(upd.key.tolist())
+            self._device_now = max(self._device_now, now)
+            self._sunk_batches += sum(g.n_chunks for g in group)
+            t_done = time.perf_counter()
+            if (self._first_verdict_s is None
+                    and any(g.n_records for g in group)):
+                # time-to-first-verdict (EngineReport.boot): anchored
+                # at construction; masked warm batches carry zero
+                # records and never trip it, so this is the first REAL
+                # verdict served
+                self._first_verdict_s = t_done - self._boot_t0
+            self._last_sink_t = t_done
+            for g in group:
+                st = g.stamps
+                self.metrics.e2e.add(t_done - st.t_enqueue)
+                # per-record accounting: every record of the entry is
+                # charged the entry's OLDEST-record path (a
+                # conservative upper bound — earlier members waited
+                # for the group, the same anchoring e2e has always
+                # used).  hold ends where the step call began, less an
+                # upload made ahead of it, so the chain closes exactly
+                # whether the put ran in the launch section or, for a
+                # ring slot, before it.
+                self._lat.record(
+                    total_s=t_done - st.t_enqueue,
+                    staged_s=(g.t_launch - st.t_enqueue
+                              if g.t_launch else 0.0),
+                    upload_s=g.put_s,
+                    compute_s=g.launch_s,
+                    sink_s=t_done - t_fetch,
+                    n=g.n_records,
+                    budget_s=self._slo_budget_s,
+                    fill_s=st.t_seal - st.t_enqueue,
+                    queue_s=st.t_dequeue - st.t_seal,
+                    hold_s=(g.t_launched - g.launch_s - g.put_s
+                            - st.t_dequeue),
+                    device_s=t_wire - g.t_launched,
+                    sink_host_s=t_done - t_wire,
+                )
+                if self.on_reap is not None:
+                    self.on_reap(g.n_records, t_done)
         # a completed sink group is the watchdog's progress signal —
         # one float store, whichever thread owns the sink section
         self._watchdog.note_progress()
@@ -1865,13 +1944,13 @@ class Engine:
             if timed:
                 self._rung_ewma_s.clear()
             t0 = time.perf_counter()
-            self._dispatch(warm, t0)
+            self._dispatch(warm, _inline_stamps(t0))
             self._reap(0)
             if timed:
                 self._rung_ewma_s[1] = time.perf_counter() - t0
             for g in serving_sizes:
                 t0 = time.perf_counter()
-                self._dispatch_mega([(warm, t0)] * g)
+                self._dispatch_mega([(warm, _inline_stamps(t0))] * g)
                 self._reap(0)
                 if timed:
                     self._rung_ewma_s[g] = time.perf_counter() - t0
@@ -1880,7 +1959,7 @@ class Engine:
                     (self._ring_chunks,) + warm.shape, np.uint32)
                 t0 = time.perf_counter()
                 self._dispatch_ring([
-                    self._upload_slot(zero_slot, t0, 0)
+                    self._upload_slot(zero_slot, _inline_stamps(t0), 0)
                     for _ in range(self.ring)])
                 self._reap(0)
                 if timed:
@@ -2485,7 +2564,7 @@ class Engine:
             return False
 
         while not bounded():
-            with self.metrics.fill.time():
+            with self.metrics.poll:
                 # Mega mode polls up to the remaining GROUP capacity
                 # (one whole ring round in device-loop mode) so a deep
                 # source backlog can seal several batches in one
@@ -2548,11 +2627,11 @@ class Engine:
                 # the kernel tier mostly drops still means a deep
                 # source backlog, exactly when coalescing pays most.
                 for raw in sealed:
-                    self._pending.append((raw, self.batcher.pop_seal_time()))
+                    self._pending.append((raw, self._pop_stamps()))
                 self._drain_pending(short=n_polled < requested)
             else:
                 for raw in sealed:
-                    self._dispatch(raw, self.batcher.pop_seal_time())
+                    self._dispatch(raw, self._pop_stamps())
                     self._reap(self.readback_depth)
             # Latency path: sink whatever the device has finished, every
             # iteration — including iterations that sealed nothing (the
@@ -2561,7 +2640,7 @@ class Engine:
             self._reap_ready()
             if not sealed and self.source.exhausted():
                 if self.batcher.fill:
-                    self._dispatch(self.batcher.take(), self.batcher.pop_seal_time())
+                    self._dispatch(self.batcher.take(), self._pop_stamps())
                 break
             if not sealed and not n_polled:
                 if self._busy_depth() == 0:
@@ -2596,13 +2675,15 @@ class Engine:
                     # daemon-matched cadence).  A fraction of the batch
                     # deadline keeps added latency under the flush
                     # budget.
-                    time.sleep(tuning.idle_sleep_s(cfg_b.deadline_us))
+                    with self.metrics.idle:
+                        time.sleep(tuning.idle_sleep_s(cfg_b.deadline_us))
                 elif self._sink_active:
                     # Pipe busy, nothing new to dispatch: YIELD the GIL
                     # (sync/tuning.py GIL_YIELD_S — a spinning dispatch
                     # loop starved the sink thread's pure-Python
                     # decode/writeback, measured 10-25 ms sinks).
-                    time.sleep(tuning.GIL_YIELD_S)
+                    with self.metrics.idle:
+                        time.sleep(tuning.GIL_YIELD_S)
 
         # A bounded exit (max_batches/max_seconds) can in principle trip
         # with sealed group candidates still pending (span-boundary
@@ -2610,11 +2691,12 @@ class Engine:
         # dispatch them singly — their records are already counted in
         # records_emitted, and leaving them would also wedge a later
         # reset_stream on a genuinely idle engine.
-        for raw, t_seal in self._pending:
-            self._dispatch(raw, t_seal)
+        for raw, stamps in self._pending:
+            self._dispatch(raw, stamps)
         self._pending.clear()
         self._reap(0)
-        return self._build_report(time.perf_counter() - t_start)
+        with self.metrics.report:
+            return self._build_report(time.perf_counter() - t_start)
 
     def _run_sealed(
         self,
@@ -2664,11 +2746,12 @@ class Engine:
                 self._sealed_loop_arena(src, bounded)
         else:
             self._sealed_loop_copy(src, bounded)
-        for raw, t_seal in self._pending:
-            self._dispatch(raw, t_seal)
+        for raw, stamps in self._pending:
+            self._dispatch(raw, stamps)
         self._pending.clear()
         self._reap(0)
-        return self._build_report(time.perf_counter() - t_start)
+        with self.metrics.report:
+            return self._build_report(time.perf_counter() - t_start)
 
     def _adopt_fleet_t0(self, src) -> None:
         """The fleet's epoch handshake picked t0; adopt it for the
@@ -2684,10 +2767,12 @@ class Engine:
         if src.exhausted():
             return True
         if self._busy_depth() == 0:
-            time.sleep(tuning.idle_sleep_s(self.cfg.batch.deadline_us))
+            with self.metrics.idle:
+                time.sleep(tuning.idle_sleep_s(self.cfg.batch.deadline_us))
         elif self._sink_active:
             # yield the GIL to the sink thread (sync/tuning.py)
-            time.sleep(tuning.GIL_YIELD_S)
+            with self.metrics.idle:
+                time.sleep(tuning.GIL_YIELD_S)
         return False
 
     def _sealed_loop_arena(self, src, bounded) -> None:
@@ -2704,7 +2789,7 @@ class Engine:
         slo = self._slo_budget_s
         rows: np.ndarray | None = None
         fill = done = 0
-        metas: list[tuple[float, int]] = []  # (t_enqueue, n_records)/row
+        metas: list[tuple[_Stamps, int]] = []  # (stamps, n_records)/row
         while not bounded():
             if rows is None or (fill and fill == done):
                 rows = self._arena.rows(self._arena.claim())
@@ -2713,10 +2798,13 @@ class Engine:
             want = len(rows) - fill
             if top:
                 want = min(want, max(top - (fill - done), 0))
-            batches = (src.poll_batches_into(
-                rows[fill:], want,
-                pop_timer=self.metrics.pop,
-                stage_timer=self.metrics.stage) if want > 0 else [])
+            batches = []
+            if want > 0:
+                with self.metrics.poll:
+                    batches = src.poll_batches_into(
+                        rows[fill:], want,
+                        pop_timer=self.metrics.pop,
+                        stage_timer=self.metrics.stage)
             if self._t0_auto and batches and src.t0_ns:
                 self._adopt_fleet_t0(src)
             for sb in batches:
@@ -2726,7 +2814,7 @@ class Engine:
                 self.batcher.records_emitted += sb.n_records
                 self._staged_batches += 1
                 self._staged_bytes += int(sb.raw.nbytes)
-                metas.append((sb.t_enqueue, sb.n_records))
+                metas.append((_sealed_stamps(sb), sb.n_records))
                 fill += 1
             if self._gov is not None and batches:
                 self._gov.note_arrivals(
@@ -2739,9 +2827,9 @@ class Engine:
             def flush(g: int) -> None:
                 nonlocal done
                 if g > 1:
-                    t_e = min(m[0] for m in metas[done:done + g])
+                    oldest = min(m[0] for m in metas[done:done + g])
                     n = sum(m[1] for m in metas[done:done + g])
-                    self._dispatch_group(rows[done:done + g], t_e, n)
+                    self._dispatch_group(rows[done:done + g], oldest, n)
                 else:
                     self._dispatch(rows[done], metas[done][0])
                 done += g
@@ -2758,11 +2846,12 @@ class Engine:
             # oldest staged record its budget
             if short or not top or (
                     slo and fill - done
-                    and self._slo_pressed(metas[done][0])):
+                    and self._slo_pressed(metas[done][0].t_enqueue)):
                 while fill - done:
                     g = self._rung_for(fill - done)
                     if slo:
-                        g = min(g, self._slo_cap(metas[done][0]))
+                        g = min(g, self._slo_cap(
+                                metas[done][0].t_enqueue))
                     flush(g)
             self._reap_ready()
             if not batches and self._sealed_idle(src):
@@ -2798,17 +2887,20 @@ class Engine:
         uploaded: list[_Uploaded] = []
         rows: np.ndarray | None = None
         fill = 0
-        metas: list[tuple[float, int]] = []  # (t_enqueue, n_records)/row
+        metas: list[tuple[_Stamps, int]] = []  # (stamps, n_records)/row
         while not bounded():
             if rows is None:
                 rows = self._arena.rows(self._arena.claim())
                 fill = 0
                 metas = []
             want = c - fill
-            batches = src.poll_batches_into(
-                rows[fill:c], want,
-                pop_timer=self.metrics.pop,
-                stage_timer=self.metrics.stage) if want > 0 else []
+            batches = []
+            if want > 0:
+                with self.metrics.poll:
+                    batches = src.poll_batches_into(
+                        rows[fill:c], want,
+                        pop_timer=self.metrics.pop,
+                        stage_timer=self.metrics.stage)
             if self._t0_auto and batches and src.t0_ns:
                 self._adopt_fleet_t0(src)
             for sb in batches:
@@ -2816,7 +2908,7 @@ class Engine:
                 self.batcher.records_emitted += sb.n_records
                 self._staged_batches += 1
                 self._staged_bytes += int(sb.raw.nbytes)
-                metas.append((sb.t_enqueue, sb.n_records))
+                metas.append((_sealed_stamps(sb), sb.n_records))
                 fill += 1
             if self._gov is not None and batches:
                 self._gov.note_arrivals(
@@ -2840,7 +2932,7 @@ class Engine:
                         # the partial-round path below
                         for u in uploaded:
                             self._dispatch_group_dev(
-                                u.dev, u.t_enqueue, u.n_records,
+                                u.dev, u.stamps, u.n_records,
                                 u.put_s)
                     uploaded = []
                     self._reap(self.readback_depth)
@@ -2848,7 +2940,7 @@ class Engine:
                 # partial round: flush uploaded slots as megasteps
                 # (arrival order before the younger partial slot)...
                 for u in uploaded:
-                    self._dispatch_group_dev(u.dev, u.t_enqueue,
+                    self._dispatch_group_dev(u.dev, u.stamps,
                                              u.n_records, u.put_s)
                     self._reap(self.readback_depth)
                 uploaded = []
@@ -2859,7 +2951,8 @@ class Engine:
                     while fill - done:
                         g = self._rung_for(fill - done)
                         if slo:
-                            g = min(g, self._slo_cap(metas[done][0]))
+                            g = min(g, self._slo_cap(
+                                metas[done][0].t_enqueue))
                         if g > 1:
                             self._dispatch_group(
                                 rows[done:done + g],
@@ -2871,7 +2964,8 @@ class Engine:
                         self._reap(self.readback_depth)
                     rows = None
             if (slo and uploaded
-                    and not self._slo_round_fits(uploaded[0].t_enqueue)):
+                    and not self._slo_round_fits(
+                        uploaded[0].stamps.t_enqueue)):
                 # the device-loop round sizer: waiting to fill the
                 # whole ring would cost the oldest uploaded slot its
                 # budget — flush the uploaded slots through the
@@ -2879,7 +2973,7 @@ class Engine:
                 # ring's slot body IS that megastep) and let the next
                 # round start fresh.  Degrade-to-smaller, not queue.
                 for u in uploaded:
-                    self._dispatch_group_dev(u.dev, u.t_enqueue,
+                    self._dispatch_group_dev(u.dev, u.stamps,
                                              u.n_records, u.put_s)
                     self._reap(self.readback_depth)
                 uploaded = []
@@ -2888,7 +2982,7 @@ class Engine:
                 break
         # bounded exit: drain uploaded slots, then any staged rows
         for u in uploaded:
-            self._dispatch_group_dev(u.dev, u.t_enqueue, u.n_records,
+            self._dispatch_group_dev(u.dev, u.stamps, u.n_records,
                                      u.put_s)
         if rows is not None and fill:
             for i in range(fill):
@@ -2900,7 +2994,7 @@ class Engine:
         the inline pending ladder (arena staging happens at dispatch
         time in :meth:`_dispatch_mega`)."""
         while not bounded():
-            with self.metrics.fill.time():
+            with self.metrics.poll:
                 want = (max(self._pending_cap - len(self._pending), 1)
                         if self.mega_n > 0 else 4)
                 batches = src.poll_batches(want)
@@ -2915,11 +3009,11 @@ class Engine:
                         sum(sb.n_records for sb in batches))
             if self.mega_n > 0:
                 for sb in batches:
-                    self._pending.append((sb.raw, sb.t_enqueue))
+                    self._pending.append((sb.raw, _sealed_stamps(sb)))
                 self._drain_pending(short=len(batches) < want)
             else:
                 for sb in batches:
-                    self._dispatch(sb.raw, sb.t_enqueue)
+                    self._dispatch(sb.raw, _sealed_stamps(sb))
                     self._reap(self.readback_depth)
             self._reap_ready()
             if not batches and self._sealed_idle(src):
@@ -2946,6 +3040,11 @@ class Engine:
             "sink_occupancy": (round(
                 self._chan.busy_s / max(wall, 1e-9), 4)
                 if self.sink_thread else None),
+            # blocks decided that did not fit the verdict ring
+            # (ShmVerdictSink.dropped): each leaves its source
+            # unsuppressed in the kernel until it offends again.  None
+            # for a sink that cannot drop.
+            "verdict_ring_dropped": getattr(self.sink, "dropped", None),
         }
 
         # Dispatch-pipeline accounting.  host_copies_per_batch counts
@@ -3049,6 +3148,11 @@ class Engine:
                 # read AFTER table_summary above: its compile is part
                 # of what a boot of this process costs
                 boot_rep["jax_cache"] = self.boot_jax_compiles.report()
+        hists = {sp.name: sp.hist for sp in self.metrics.spans()}
+        hists.update(self._lat.hists())
+        if self.sealed and hasattr(self.source, "ingest_spans"):
+            hists.update((sp.name, sp.hist)
+                         for sp in self.source.ingest_spans())
         predict_rep = None
         if self._gov is not None:
             predict_rep = self._gov.report()
@@ -3090,11 +3194,13 @@ class Engine:
                 gossip=cluster_rep,
                 watchdog=self._watchdog.to_dict(),
                 restore_fallbacks=self._restore_fallbacks,
-                rebalance=self._rebalance or None),
+                rebalance=self._rebalance or None,
+                readback=readback),
             rebalance=dict(self._rebalance) or None,
             predict=predict_rep,
             boot=boot_rep,
             device=dict(self._device),
+            spans=span_store(hists),
         )
 
 

@@ -16,7 +16,8 @@ from the counters themselves):
   batches, corrupt-slot skips, gossip TX drops / RX seq gaps, the
   multi-host transport's drop/gap/dup/reorder/skew accounting
   (``net_*``, cluster/transport.py), a watchdog soft trip, a restore
-  that fell back to the ``.prev`` generation.  Each reason is a
+  that fell back to the ``.prev`` generation, blocks that did not fit
+  the verdict ring (``verdict_ring_dropped``).  Each reason is a
   ``name:count`` string an alert can key on.
 * **FAILED** — the engine cannot serve its span: every ingest shard is
   dead, or the watchdog hard-tripped (the process is already dying
@@ -48,6 +49,7 @@ def engine_health(
     restore_fallbacks: int = 0,
     rebalance: dict | None = None,
     elastic: dict | None = None,
+    readback: dict | None = None,
 ) -> dict:
     """Derive one engine's health from its report blocks (module
     docstring).  Every argument is the corresponding
@@ -122,6 +124,14 @@ def engine_health(
             failed = True
     if restore_fallbacks:
         reasons.append(f"restore_fallbacks:{restore_fallbacks}")
+    if readback:
+        # blocks the engine decided that the verdict ring had no room
+        # for (ShmVerdictSink.dropped): serving continues, but those
+        # sources stay unsuppressed in the kernel — the guarantee
+        # "every block decided is written back" is broken
+        v = int(readback.get("verdict_ring_dropped") or 0)
+        if v:
+            reasons.append(f"verdict_ring_dropped:{v}")
     if rebalance:
         # live-handoff loss accounting (cluster/rebalance.py): each
         # of these means rows or a stream went somewhere other than

@@ -1,23 +1,29 @@
-"""Per-stage latency/throughput accounting for the serving pipeline.
+"""Time-keeping for the serving pipeline: one span type, one store.
 
 The reference's only observability is printk in the packet path
 (SURVEY.md §5.1, which it even identifies as a perf bug).  Here every
-pipeline stage records its wall time per batch; percentiles come out in
-the engine report and feed the bench harness.
+stage boundary of the serving path is a :class:`Span`:
 
-Two accounting families live here:
+* entered (``with span:`` / ``with span(seq):``) it is a
+  ``jax.profiler.TraceAnnotation`` — a host span on the profiler's own
+  clock, in the same trace as the device planes, present exactly when
+  a ``jax.profiler`` session is on (``fsx serve --profile``, the
+  benchmark's ``--trace 1``) and about a microsecond when none is;
+* always, its duration goes into a :class:`LatencyHist` — count, exact
+  ``sum_us``, max, 1/16-octave log buckets: FIXED memory, O(buckets)
+  percentiles, no per-sample storage.  ``Span.add(seconds, n)`` feeds
+  the histogram alone, for durations measured elsewhere (the ingest
+  workers' seal stamps, the staging memcpy inside a poll).
 
-* :class:`StageTimer` — a rolling sample ring per pipeline stage
-  (host-cost attribution; full-precision recent window, per-report
-  ``np.percentile`` sort over ≤ ``keep`` samples).
-* :class:`LatencyHist` / :class:`LatencyRecorder` — the per-RECORD
-  seal→verdict latency plane (ISSUE 11): an HDR-style log-bucketed
-  histogram with FIXED memory and O(buckets) percentile extraction —
-  no per-report full sort, no per-record storage — that merges across
-  sink/pipeline-worker contexts, across streams, and across cluster
-  ranks (``supervisor.aggregate``).  Everything is numpy-only so the
-  jax-free consumers (cluster supervisor, ``fsx status``) can import
-  it on their sub-second path.
+:class:`LatencyRecorder` is the per-RECORD seal→verdict plane (ISSUE
+11) on the same histograms, with the closed stage chain of ISSUE 29.
+Everything is cumulative since boot (or ``reset_stream``): the report's
+``spans`` block carries every histogram's mergeable counts, so a
+reader gets any window by subtracting two reports, and the per-rank
+merge (``supervisor.aggregate``) by adding them.  Numpy-only at import:
+``jax.profiler`` is imported where the first span is entered, so the
+jax-free consumers (cluster supervisor, ``fsx status``) can import the
+histogram half on their sub-second path.
 """
 
 from __future__ import annotations
@@ -25,63 +31,6 @@ from __future__ import annotations
 import time
 
 import numpy as np
-
-
-class StageTimer:
-    """Rolling record of one stage's per-batch durations (seconds).
-
-    A RING of the most recent ``keep`` samples: once full, new samples
-    overwrite the oldest, so a week-long serve reports percentiles of
-    its recent window — not of its first 100k batches (the old
-    stop-at-keep behavior silently froze the distribution early in long
-    runs).  ``percentiles_ms()["n"]`` stays the TOTAL sample count ever
-    recorded; ``max`` likewise tracks the all-time maximum (a one-off
-    stall must not age out of the report)."""
-
-    def __init__(self, name: str, keep: int = 100_000):
-        self.name = name
-        self.keep = keep
-        self._samples: list[float] = []  # grows to keep, then ring-writes
-        self._n = 0                       # total ever recorded
-        self._max = 0.0
-
-    def add(self, seconds: float) -> None:
-        if len(self._samples) < self.keep:
-            self._samples.append(seconds)
-        else:
-            self._samples[self._n % self.keep] = seconds
-        self._n += 1
-        if seconds > self._max:
-            self._max = seconds
-
-    def time(self):
-        """Context manager: ``with timer.time(): ...``"""
-        return _Timing(self)
-
-    def percentiles_ms(self) -> dict[str, float]:
-        if not self._n:
-            return {}
-        a = np.asarray(self._samples) * 1e3
-        return {
-            "p50": round(float(np.percentile(a, 50)), 4),
-            "p99": round(float(np.percentile(a, 99)), 4),
-            "max": round(self._max * 1e3, 4),
-            "mean": round(float(a.mean()), 4),
-            "n": self._n,
-        }
-
-
-class _Timing:
-    def __init__(self, timer: StageTimer):
-        self.timer = timer
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.timer.add(time.perf_counter() - self.t0)
-        return False
 
 
 #: LatencyHist geometry: 16 linear sub-buckets per power-of-two octave
@@ -218,23 +167,135 @@ class LatencyHist:
         return h
 
 
+class Span:
+    """One named stage of the serving path (module docstring): a host
+    span in the profiler's trace while a session is on, and always a
+    :class:`LatencyHist` of its durations.
+
+    ``with span:`` times the block; ``with span(seq) as s:`` also tags
+    the trace event with the dispatch ordinal of the group it works on
+    (one group's spans share ``seq`` across threads) and leaves
+    ``s.t0`` / ``s.t1`` / ``s.seconds`` for the caller's own stamps;
+    ``span.add(seconds, n)`` counts a duration measured elsewhere.
+    Every span has one writing thread at a time (docs/CONCURRENCY.md
+    names the owners), so the histogram needs no lock."""
+
+    __slots__ = ("name", "hist", "_open")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.hist = LatencyHist()
+        self._open: _OpenSpan | None = None
+
+    def add(self, seconds: float, n: int = 1) -> None:
+        self.hist.add(max(seconds, 0.0), n)
+
+    def __call__(self, seq: int | None = None) -> "_OpenSpan":
+        return _OpenSpan(self, seq)
+
+    def __enter__(self) -> "_OpenSpan":
+        self._open = _OpenSpan(self, None)
+        return self._open.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        return self._open.__exit__(*exc)
+
+    def percentiles_ms(self) -> dict[str, float]:
+        """The ``stages_ms`` / ``fill_ms`` / ``queue_ms`` face: all-time
+        bucket upper edges (at most 1/16 over), exact max and mean."""
+        h = self.hist
+        if not h.n:
+            return {}
+        return {
+            "p50": round(h.percentile_us(50) / 1e3, 4),
+            "p99": round(h.percentile_us(99) / 1e3, 4),
+            "max": round(h.max_us / 1e3, 4),
+            "mean": round(h.sum_us / h.n / 1e3, 4),
+            "n": int(h.n),
+        }
+
+
+_TraceAnnotation = None
+
+
+class _OpenSpan:
+    """One entry of a :class:`Span`."""
+
+    __slots__ = ("span", "ann", "t0", "t1")
+
+    def __init__(self, span: Span, seq: int | None):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            # deferred: the histogram half of this module stays
+            # importable without jax (module docstring)
+            from jax.profiler import TraceAnnotation
+
+            _TraceAnnotation = TraceAnnotation
+        self.span = span
+        self.ann = (_TraceAnnotation(span.name) if seq is None
+                    else _TraceAnnotation(span.name, seq=seq))
+
+    def __enter__(self) -> "_OpenSpan":
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        self.span.hist.add(self.t1 - self.t0)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+def span_store(hists: dict[str, LatencyHist]) -> dict:
+    """The report's ``spans`` block: every histogram's cumulative
+    count, exact sum, max and mergeable bucket counts by name.  A
+    window is two of these subtracted (``n``, ``sum_us`` and each
+    bucket; ``max_us`` is all-time and does not subtract)."""
+    return {name: {"n": int(h.n), "sum_us": h.sum_us,
+                   "max_us": h.max_us, "hist": h.to_counts()}
+            for name, h in hists.items()}
+
+
 class LatencyRecorder:
     """The engine's per-record latency plane: one total (seal→verdict)
-    histogram plus the stage decomposition the SLO mode is tuned by —
-    ``staged_wait`` (seal → launch: batcher/pending/arena/sink-queue
-    residency), ``upload`` (the explicit H2D put), ``compute`` (the
-    step call's wall — on synchronously-dispatching backends like
-    XLA:CPU this IS the compute; on async backends it is the enqueue
-    cost and the compute lands in staged totals instead — disclosed in
-    the report's ``compute_is_wall`` flag), and ``sink`` (wire fetch →
-    writeback applied).  All histograms weight by the batch's record
-    count; a batch with zero valid records (warm) records nothing.
+    histogram, the stage decomposition the SLO mode is tuned by, and
+    the closed stage chain.
+
+    SLO stages — ``staged_wait`` (seal → launch: batcher/pending/
+    arena/sink-queue residency), ``upload`` (the explicit H2D put),
+    ``compute`` (the step call's wall — on synchronously-dispatching
+    backends like XLA:CPU this IS the compute; on async backends it is
+    the enqueue cost — disclosed in the report's ``compute_is_wall``
+    flag), and ``sink`` (wire fetch begins → writeback applied: on an
+    async backend it holds the sink's wait on the device).
+
+    The chain — ``fill`` (first record → seal), ``queue`` (seal →
+    engine dequeue), ``hold`` (dequeue → the launch section picks the
+    entry up, less an upload made ahead of it: arena residency and the
+    ladder's wait for a rung), then ``upload`` and ``compute`` as
+    above, ``device`` (the step call returned → the group's wire is on
+    the host: device queue + step + D2H) and ``sink_host`` (wire on
+    the host → verdict sunk).  CHAIN sums to the total for every entry
+    (test-pinned), so the window sums of the seven say where a
+    record's time went with nothing left over.
+
+    An entry is charged from its OLDEST member batch, and all
+    histograms weight by the entry's record count; an entry with zero
+    valid records (warm) records nothing.
 
     ``negatives`` counts stage deltas that arrived negative (clock
     inversion between the seal and sink stamps) — the smoke gate pins
     it at 0 every run."""
 
-    STAGES = ("staged_wait", "upload", "compute", "sink")
+    CHAIN = ("fill", "queue", "hold", "upload", "compute", "device",
+             "sink_host")
+    STAGES = ("staged_wait", "upload", "compute", "sink",
+              "fill", "queue", "hold", "device", "sink_host")
 
     def __init__(self) -> None:
         self.total = LatencyHist()
@@ -244,18 +305,24 @@ class LatencyRecorder:
 
     def record(self, total_s: float, staged_s: float, upload_s: float,
                compute_s: float, sink_s: float, n: int,
-               budget_s: float = 0.0) -> None:
+               budget_s: float = 0.0, *, fill_s: float = 0.0,
+               queue_s: float = 0.0, hold_s: float = 0.0,
+               device_s: float = 0.0, sink_host_s: float = 0.0) -> None:
         if n <= 0:
             return
-        for v in (total_s, staged_s, upload_s, compute_s, sink_s):
-            if v < 0.0:
-                self.negatives += 1
+        stages = (staged_s, upload_s, compute_s, sink_s,
+                  fill_s, queue_s, hold_s, device_s, sink_host_s)
+        self.negatives += sum(v < 0.0 for v in (total_s, *stages))
         self.total.add(max(total_s, 0.0), n)
-        for name, v in zip(self.STAGES,
-                           (staged_s, upload_s, compute_s, sink_s)):
+        for name, v in zip(self.STAGES, stages):
             self.stages[name].add(max(v, 0.0), n)
         if budget_s and total_s > budget_s:
             self.slo_miss_records += n
+
+    def hists(self) -> dict[str, LatencyHist]:
+        """The plane's histograms under their ``spans`` names."""
+        return {"latency.seal_to_verdict": self.total,
+                **{f"latency.{s}": h for s, h in self.stages.items()}}
 
     def merge(self, other: "LatencyRecorder") -> "LatencyRecorder":
         self.total.merge(other.total)
@@ -287,17 +354,19 @@ class LatencyRecorder:
 
 
 class WorkerIngestMetrics:
-    """Per-drain-worker stage timers of the sharded ingest subsystem
+    """Per-drain-worker spans of the sharded ingest subsystem
     (flowsentryx_tpu/ingest/): ``fill`` is first-record-arrival → seal
     inside the worker (the parallelized decode/assembly stage), ``queue``
     is seal → engine dequeue (sealed-batch queue residency — the
-    pipelining debt the engine's dispatch loop imposes).  Surfaced per
-    worker in the engine report's ``ingest`` block."""
+    pipelining debt the engine's dispatch loop imposes).  One sample a
+    sealed batch, unweighted (the latency plane's ``fill``/``queue``
+    weigh by records and charge an entry's oldest batch).  Surfaced per
+    worker in the engine report's ``ingest`` block, and in ``spans``."""
 
     def __init__(self, worker: int):
         self.worker = worker
-        self.fill = StageTimer(f"w{worker}.fill")
-        self.queue = StageTimer(f"w{worker}.queue")
+        self.fill = Span(f"fsx.ingest.w{worker}.fill")
+        self.queue = Span(f"fsx.ingest.w{worker}.queue")
 
     def to_dict(self) -> dict:
         return {
@@ -307,29 +376,60 @@ class WorkerIngestMetrics:
 
 
 class PipelineMetrics:
-    """The engine's stage set.
+    """The engine's spans (docs/ENGINE.md §Observability has the
+    table).  Each thread's spans are disjoint — ``poll`` alone holds
+    parts, ``pop`` and ``stage`` — so a thread's time is the sum of its
+    spans, and the rest of its wall is what it did unnamed.
 
-    ``fill`` covers the inline loop's source poll + batcher pack; the
-    sealed-batch loop splits its half of that work into ``pop`` (queue
-    peek + header decode + seq/metrics bookkeeping) and ``stage`` (the
-    ONE shm-slot-view → dispatch-arena memcpy of the zero-copy
-    pipeline) so the dispatch-thread budget is attributable per
-    sub-stage — a regression that re-grows a second copy shows up as a
-    ``stage`` p50 jump, not as undifferentiated ``fill`` noise.  The
-    inline loop also records ``stage`` when it packs a mega group into
-    the arena."""
+    Dispatch thread: ``poll`` (the sealed loops' ``poll_batches_into``;
+    the inline loop's source poll + batcher pack), of which the sealed
+    path counts ``pop`` (queue peek + header decode + seq bookkeeping)
+    and ``stage`` (the ONE shm-slot-view → dispatch-arena memcpy of
+    the zero-copy pipeline; the inline loop's arena pack too) — a
+    regression that re-grows a second copy shows up as a ``stage``
+    jump, not as undifferentiated ``poll`` noise; ``upload`` (the
+    explicit H2D put); ``launch`` (the step call: the enqueue on an
+    async backend); ``backpressure`` (the blocking wait for the pipe
+    to drain to ``readback_depth``); ``idle`` (the sleeps on an empty
+    poll); ``report``.  In device-loop mode the pipeline worker owns
+    ``launch``, and ``upload`` has two writers (slot uploads on the
+    dispatch thread, a partial flush's put on the worker): a lost
+    count there is tolerated, nothing reads it in that mode.
+
+    Sink section (the sink thread; the dispatch thread in
+    single-thread mode): ``wait`` (nothing queued), ``fetch`` (the
+    D2H: on an async backend the wait on the device), ``decode``,
+    ``apply`` (writeback, gossip publish, book-keeping, ``on_reap``);
+    ``e2e`` is first record in → sunk, one sample an in-flight entry.
+
+    ``stages_ms`` is the older face of the same histograms."""
 
     def __init__(self) -> None:
-        self.fill = StageTimer("fill")          # source poll + batcher copy
-        self.pop = StageTimer("pop")            # sealed-queue peek/bookkeeping
-        self.stage = StageTimer("stage")        # slot view -> arena memcpy
-        self.dispatch = StageTimer("dispatch")  # step call (async enqueue)
-        self.readback = StageTimer("readback")  # D2H verdict fetch
-        self.e2e = StageTimer("e2e")            # first record in -> sink
+        self.poll = Span("fsx.dispatch.poll")
+        self.pop = Span("fsx.dispatch.pop")
+        self.stage = Span("fsx.dispatch.stage")
+        self.upload = Span("fsx.dispatch.upload")
+        self.launch = Span("fsx.dispatch.launch")
+        self.backpressure = Span("fsx.dispatch.backpressure")
+        self.idle = Span("fsx.dispatch.idle")
+        self.report = Span("fsx.report")
+        self.sink_wait = Span("fsx.sink.wait")
+        self.fetch = Span("fsx.sink.fetch")
+        self.decode = Span("fsx.sink.decode")
+        self.apply = Span("fsx.sink.apply")
+        self.e2e = Span("fsx.e2e")
+
+    def spans(self) -> tuple[Span, ...]:
+        """Every span: the attributes are the spans and nothing else."""
+        return tuple(vars(self).values())
 
     def to_dict(self) -> dict:
+        """``EngineReport.stages_ms``, under the names it always had."""
         return {
-            t.name: t.percentiles_ms()
-            for t in (self.fill, self.pop, self.stage, self.dispatch,
-                      self.readback, self.e2e)
+            name: span.percentiles_ms()
+            for name, span in (("fill", self.poll), ("pop", self.pop),
+                               ("stage", self.stage),
+                               ("dispatch", self.launch),
+                               ("readback", self.fetch),
+                               ("e2e", self.e2e))
         }

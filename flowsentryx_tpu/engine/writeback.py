@@ -72,6 +72,14 @@ def decode_verdict_wire(wire: np.ndarray) -> VerdictWire:
     )
 
 
+def wire_overflowed(wire: np.ndarray) -> bool:
+    """Whether a fetched wire — one ``[2K+4]`` buffer or a ``[R, 2K+4]``
+    stack of per-slot wires — has any overflow flag set, i.e. the entry
+    needs the full block-array fetch (:func:`decode_verdict_wire`)."""
+    wire = np.asarray(wire)
+    return bool(wire.reshape(-1, wire.shape[-1])[:, -3].any())
+
+
 class VerdictSink(Protocol):
     def apply(self, update: BlacklistUpdate) -> None: ...
 

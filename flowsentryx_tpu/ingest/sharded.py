@@ -67,6 +67,10 @@ class SealedBatch(NamedTuple):
     t_seal: float         # worker seal time, perf_counter domain
     worker: int
     seq: int
+    #: engine-side dequeue time (perf_counter domain): where the
+    #: latency plane's ``queue`` stage ends and ``hold`` begins.  0.0 =
+    #: not stamped (a stand-in source); the engine then stamps its own.
+    t_dequeue: float = 0.0
 
 
 class SeqTracker:
@@ -454,7 +458,7 @@ class ShardedIngest:
 
     def _note_batch(self, wid: int, hdr: np.ndarray) -> tuple:
         """Header decode + per-worker bookkeeping shared by both
-        dequeue paths: ``(seq, n_records, t_seal, fill_s)``."""
+        dequeue paths: ``(seq, n_records, t_seal, fill_s, t_dequeue)``."""
         seq = (int(hdr[schema.BATCHQ_SEQ_LO_WORD])
                | (int(hdr[schema.BATCHQ_SEQ_HI_WORD]) << 32))
         n = int(hdr[schema.BATCHQ_N_RECORDS_WORD])
@@ -468,10 +472,11 @@ class ShardedIngest:
         self._seqs.note(wid, seq)
         self._batches[wid] += 1
         self._records[wid] += n
+        t_dequeue = time.perf_counter()
         m = self._metrics[wid]
         m.fill.add(fill_s)
-        m.queue.add(max(0.0, time.perf_counter() - t_seal))
-        return seq, n, t_seal, fill_s
+        m.queue.add(t_dequeue - t_seal)
+        return seq, n, t_seal, fill_s, t_dequeue
 
     def _slot_problem(self, hdr: np.ndarray,
                       meta: np.ndarray) -> tuple[str, str] | None:
@@ -578,7 +583,7 @@ class ShardedIngest:
                     self._discard_slot(wid, hdr, payload, *prob)
                     wid = (wid + 1) % n_q
                     continue
-                seq, n, t_seal, fill_s = self._note_batch(wid, hdr)
+                seq, n, t_seal, fill_s, t_deq = self._note_batch(wid, hdr)
                 out.append(SealedBatch(
                     raw=rows,
                     n_records=n,
@@ -586,6 +591,7 @@ class ShardedIngest:
                     t_seal=t_seal,
                     worker=wid,
                     seq=seq,
+                    t_dequeue=t_deq,
                 ))
             wid = (wid + 1) % n_q
         self._rr = wid
@@ -611,7 +617,7 @@ class ShardedIngest:
         slot can never reach it (test-pinned).
 
         ``pop_timer``/``stage_timer`` are optional
-        :class:`~flowsentryx_tpu.engine.metrics.StageTimer` hooks:
+        :class:`~flowsentryx_tpu.engine.metrics.Span` hooks (``add``):
         per-batch staging memcpy time goes to ``stage``, everything
         else in a non-empty call (peek, header decode, seq/metric
         bookkeeping) to ``pop``.
@@ -653,7 +659,7 @@ class ShardedIngest:
                     self._discard_slot(wid, hdr, row, *prob)
                     wid = (wid + 1) % n_q
                     continue
-                seq, n, t_seal, fill_s = self._note_batch(wid, hdr)
+                seq, n, t_seal, fill_s, t_deq = self._note_batch(wid, hdr)
                 out.append(SealedBatch(
                     raw=row,
                     n_records=n,
@@ -661,6 +667,7 @@ class ShardedIngest:
                     t_seal=t_seal,
                     worker=wid,
                     seq=seq,
+                    t_dequeue=t_deq,
                 ))
             wid = (wid + 1) % n_q
         self._rr = wid
@@ -686,6 +693,11 @@ class ShardedIngest:
         return True
 
     # -- reporting ----------------------------------------------------------
+
+    def ingest_spans(self) -> list:
+        """Every worker's ``fill`` and ``queue`` span, for the engine
+        report's ``spans`` block."""
+        return [sp for m in self._metrics for sp in (m.fill, m.queue)]
 
     def ingest_stats(self) -> dict:
         assert self._seqs is not None

@@ -144,7 +144,8 @@ def _flow_core(
     # from zeroed state — a reclaimed slot must not leak the previous
     # flow's counters.
     C = TableCol
-    rows = jnp.where(asg.inserted[:, None], 0.0, table.state[slot])
+    with jax.named_scope("gather"):
+        rows = jnp.where(asg.inserted[:, None], 0.0, table.state[slot])
 
     win = limiters.WindowState(
         win_start=rows[:, C.WIN_START],
@@ -171,10 +172,11 @@ def _flow_core(
 
     # 2. limiter transition on aggregated deltas (needs a slot: only
     #    tracked flows carry limiter state)
-    dec = limiters.apply_limiter(
-        lim, win, bucket, fa.rep_pkts, fa.rep_bytes, fa.rep_ts,
-        is_new=asg.inserted,
-    )
+    with jax.named_scope("limiter"):
+        dec = limiters.apply_limiter(
+            lim, win, bucket, fa.rep_pkts, fa.rep_bytes, fa.rep_ts,
+            is_new=asg.inserted,
+        )
     over_rate = asg.tracked & dec.over_limit & ~already_blocked
 
     # 3. ML verdict with the young-flow vote (first records
@@ -266,10 +268,11 @@ def _flow_core(
         ],
         axis=1,
     )
-    new_table = IpTableState(
-        key=table.key.at[safe_slot].set(fa.rep_key, mode="drop"),
-        state=table.state.at[safe_slot].set(new_rows, mode="drop"),
-    )
+    with jax.named_scope("scatter"):
+        new_table = IpTableState(
+            key=table.key.at[safe_slot].set(fa.rep_key, mode="drop"),
+            state=table.state.at[safe_slot].set(new_rows, mode="drop"),
+        )
 
     return new_table, FlowDecision(
         flow_verdict=flow_verdict,
@@ -540,6 +543,15 @@ def merge_verdict_wires(wires: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([ck, bits(cu, jnp.uint32), scalars])
 
 
+#: The stages of the fused step, in order: each is a
+#: ``jax.named_scope("fsx.<stage>")`` in :func:`make_step` (``decode``
+#: also wraps the wire decode of the raw/compact steps, ``emit`` the
+#: megastep's wire merge; ``fsx.evict`` exists only where the aging
+#: sweep is compiled in).  The names reach the device trace as each
+#: operation's scope; the benchmark's ``step.stage_*`` metrics read them.
+STEP_SCOPES = ("decode", "classify", "probe", "aggregate", "update", "emit")
+
+
 def make_step(
     cfg: FsxConfig,
     classify_batch: Callable[[Any, jnp.ndarray], jnp.ndarray],
@@ -577,109 +589,123 @@ def make_step(
         # first run wins).  The sharded path keeps the two-stage
         # composition (it aggregates before any table exists on the
         # owner side); parity is pinned by tests/test_fused.py.
+        # The ``fsx.<stage>`` scopes (STEP_SCOPES) are metadata only:
+        # the compiled program is the same with or without them.
         b = batch.key.shape[0]
-        now = jnp.max(jnp.where(batch.valid, batch.ts, 0.0))
+        with jax.named_scope("fsx.decode"):
+            now = jnp.max(jnp.where(batch.valid, batch.ts, 0.0))
         # In-step aging epoch (evict_idle_epoch): sweep BEFORE probing
         # so freed slots are claimable by this very batch's inserts.
         # Statically absent when disabled — the pre-eviction graph.
         n_evicted = None
         if cfg.table.evict_ttl_s > 0:
-            table, n_evicted = evict_idle_epoch(cfg.table, table, stats,
-                                                now)
-        score = classify_batch(params, batch.feat)  # [B] f32, MXU path
-        mal = (score > cfg.model.threshold) & batch.valid
-
-        # key sanitization (agg.aggregate's contract): 0 must not
-        # masquerade as the empty-slot sentinel; invalid rows park at
-        # INVALID_KEY, which sorts past every real key
-        key = jnp.where(batch.key == 0, jnp.uint32(0xFFFFFFFE), batch.key)
-        key = jnp.where(batch.valid, key, agg.INVALID_KEY)
+            with jax.named_scope("fsx.evict"):
+                table, n_evicted = evict_idle_epoch(cfg.table, table,
+                                                    stats, now)
+        with jax.named_scope("fsx.classify"):
+            score = classify_batch(params, batch.feat)  # [B] f32, MXU path
+            mal = (score > cfg.model.threshold) & batch.valid
 
         # --- per-packet probe + slot selection (the ONE probe-math
         # copy, shared with assign_slots — cross-path slot decisions
         # must stay bit-identical) ---
         n = table.key.shape[0]
-        pr = hashtable.probe_slots(table.key, table.last_seen, key,
-                                   batch.valid, now, cfg.table)
-        slot, found, usable = pr.slot, pr.found, pr.usable
+        with jax.named_scope("fsx.probe"):
+            # key sanitization (agg.aggregate's contract): 0 must not
+            # masquerade as the empty-slot sentinel; invalid rows park
+            # at INVALID_KEY, which sorts past every real key
+            key = jnp.where(batch.key == 0, jnp.uint32(0xFFFFFFFE),
+                            batch.key)
+            key = jnp.where(batch.valid, key, agg.INVALID_KEY)
+            pr = hashtable.probe_slots(table.key, table.last_seen, key,
+                                       batch.valid, now, cfg.table)
+            slot, found, usable = pr.slot, pr.found, pr.usable
 
-        # --- the one sort: (slot-priority, key), carrying iota --------
-        slot_pri = jnp.where(
-            usable, slot * 2 + (~found).astype(jnp.int32), jnp.int32(2 * n))
-        iota = jnp.arange(b, dtype=jnp.int32)
-        sp_s, key_s, order = jax.lax.sort(
-            (slot_pri, key, iota), num_keys=2)
+        with jax.named_scope("fsx.aggregate"):
+            # --- the one sort: (slot-priority, key), carrying iota ----
+            slot_pri = jnp.where(
+                usable, slot * 2 + (~found).astype(jnp.int32),
+                jnp.int32(2 * n))
+            iota = jnp.arange(b, dtype=jnp.int32)
+            sp_s, key_s, order = jax.lax.sort(
+                (slot_pri, key, iota), num_keys=2)
 
-        key_head = jnp.concatenate(
-            [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
-        seg = (jnp.cumsum(key_head) - 1).astype(jnp.int32)
-        inv = jnp.zeros((b,), jnp.int32).at[order].set(seg)
-        sv = batch.valid[order]
+            key_head = jnp.concatenate(
+                [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
+            seg = (jnp.cumsum(key_head) - 1).astype(jnp.int32)
+            inv = jnp.zeros((b,), jnp.int32).at[order].set(seg)
+            sv = batch.valid[order]
 
-        def seg_sum(v):
-            return jax.ops.segment_sum(v, seg, num_segments=b)
+            def seg_sum(v):
+                return jax.ops.segment_sum(v, seg, num_segments=b)
 
-        pkts = seg_sum(sv.astype(jnp.float32))
-        bytes_ = seg_sum(jnp.where(sv, batch.pkt_len[order], 0.0))
-        ts_max = jax.ops.segment_max(
-            jnp.where(sv, batch.ts[order], -jnp.inf), seg, num_segments=b)
-        ml_count = seg_sum(mal[order].astype(jnp.float32))
-        rep_key = jax.ops.segment_max(key_s, seg, num_segments=b)
-        rep_valid = pkts > 0
-        rep_key = jnp.where(rep_valid, rep_key, agg.INVALID_KEY)
-        ts_max = jnp.where(rep_valid, ts_max, 0.0)
-        rep_slot = jax.ops.segment_max(slot[order], seg, num_segments=b)
-        rep_found = jax.ops.segment_max(
-            found[order].astype(jnp.int32), seg, num_segments=b) > 0
-        rep_usable = jax.ops.segment_max(
-            usable[order].astype(jnp.int32), seg, num_segments=b) > 0
+            def seg_max(v):
+                return jax.ops.segment_max(v, seg, num_segments=b)
 
-        # arbitration: a flow wins iff its first packet opens its slot
-        # group (the found-first bit in slot_pri already ordered the
-        # groups; parked rows share slot_pri 2n but usable=False)
-        slot_head = jnp.concatenate(
-            [jnp.ones((1,), bool), (sp_s[1:] >> 1) != (sp_s[:-1] >> 1)])
-        rep_winner = jax.ops.segment_max(
-            (key_head & slot_head).astype(jnp.int32), seg,
-            num_segments=b) > 0
+            pkts = seg_sum(sv.astype(jnp.float32))
+            bytes_ = seg_sum(jnp.where(sv, batch.pkt_len[order], 0.0))
+            ts_max = seg_max(jnp.where(sv, batch.ts[order], -jnp.inf))
+            ml_count = seg_sum(mal[order].astype(jnp.float32))
+            rep_key = seg_max(key_s)
+            rep_valid = pkts > 0
+            rep_key = jnp.where(rep_valid, rep_key, agg.INVALID_KEY)
+            ts_max = jnp.where(rep_valid, ts_max, 0.0)
+            rep_slot = seg_max(slot[order])
+            rep_found = seg_max(found[order].astype(jnp.int32)) > 0
+            rep_usable = seg_max(usable[order].astype(jnp.int32)) > 0
 
-        fa = agg.FlowAgg(rep_key=rep_key, rep_pkts=pkts, rep_bytes=bytes_,
-                         rep_ts=ts_max, rep_valid=rep_valid, inv=inv)
-        asg = hashtable.SlotAssignment(
-            slot=rep_slot,
-            found=rep_found & rep_winner,
-            inserted=rep_usable & ~rep_found & rep_winner,
-            tracked=rep_usable & rep_winner,
-        )
-        all_flows = jnp.ones_like(rep_valid)
-        new_table, dec = _flow_core(cfg, table, fa, asg, all_flows,
-                                    ml_count, now)
+            # arbitration: a flow wins iff its first packet opens its
+            # slot group (the found-first bit in slot_pri already
+            # ordered the groups; parked rows share slot_pri 2n but
+            # usable=False)
+            slot_head = jnp.concatenate(
+                [jnp.ones((1,), bool),
+                 (sp_s[1:] >> 1) != (sp_s[:-1] >> 1)])
+            rep_winner = seg_max(
+                (key_head & slot_head).astype(jnp.int32)) > 0
 
-        verdict = resolve_record_verdicts(dec.flow_verdict, fa.inv, mal,
-                                          batch.valid)
-        new_stats = update_stats(stats, verdict, batch.valid)
-        if n_evicted is not None:
-            from flowsentryx_tpu.core.schema import u64_add
+            fa = agg.FlowAgg(rep_key=rep_key, rep_pkts=pkts,
+                             rep_bytes=bytes_, rep_ts=ts_max,
+                             rep_valid=rep_valid, inv=inv)
+            asg = hashtable.SlotAssignment(
+                slot=rep_slot,
+                found=rep_found & rep_winner,
+                inserted=rep_usable & ~rep_found & rep_winner,
+                tracked=rep_usable & rep_winner,
+            )
+        with jax.named_scope("fsx.update"):
+            all_flows = jnp.ones_like(rep_valid)
+            new_table, dec = _flow_core(cfg, table, fa, asg, all_flows,
+                                        ml_count, now)
 
-            new_stats = new_stats._replace(
-                evicted=u64_add(new_stats.evicted, n_evicted))
+        with jax.named_scope("fsx.emit"):
+            verdict = resolve_record_verdicts(dec.flow_verdict, fa.inv,
+                                              mal, batch.valid)
+            new_stats = update_stats(stats, verdict, batch.valid)
+            if n_evicted is not None:
+                from flowsentryx_tpu.core.schema import u64_add
 
-        block_key = jnp.where(dec.newly_blocked, fa.rep_key, agg.INVALID_KEY)
-        block_until = jnp.where(dec.newly_blocked, dec.new_blocked_until, 0.0)
-        k_max = cfg.batch.verdict_k
-        out = StepOutput(
-            # uint8 pack: 4 verdict classes; the [B] int32 was 4x the
-            # bytes for readers (parity tests, offline analysis) that
-            # only ever compare against small codes
-            verdict=verdict.astype(jnp.uint8),
-            score=score if emit_score else None,
-            block_key=block_key,
-            block_until=block_until,
-            now=now,
-            wire=(pack_verdict_wire(block_key, block_until, now,
-                                    np.uint32(0), k_max)
-                  if k_max else None),
-        )
+                new_stats = new_stats._replace(
+                    evicted=u64_add(new_stats.evicted, n_evicted))
+
+            block_key = jnp.where(dec.newly_blocked, fa.rep_key,
+                                  agg.INVALID_KEY)
+            block_until = jnp.where(dec.newly_blocked,
+                                    dec.new_blocked_until, 0.0)
+            k_max = cfg.batch.verdict_k
+            out = StepOutput(
+                # uint8 pack: 4 verdict classes; the [B] int32 was 4x
+                # the bytes for readers (parity tests, offline
+                # analysis) that only ever compare against small codes
+                verdict=verdict.astype(jnp.uint8),
+                score=score if emit_score else None,
+                block_key=block_key,
+                block_until=block_until,
+                now=now,
+                wire=(pack_verdict_wire(block_key, block_until, now,
+                                        np.uint32(0), k_max)
+                      if k_max else None),
+            )
         return new_table, new_stats, out
 
     return step
@@ -704,7 +730,9 @@ def make_raw_step(
     base = make_step(cfg, classify_batch, emit_score=emit_score)
 
     def step(table, stats, params, raw):
-        return base(table, stats, params, schema.decode_raw(raw))
+        with jax.named_scope("fsx.decode"):
+            batch = schema.decode_raw(raw)
+        return base(table, stats, params, batch)
 
     return step
 
@@ -739,7 +767,8 @@ def make_compact_step(
     base = make_step(cfg, classify_batch, emit_score=emit_score)
 
     def step(table, stats, params, raw):
-        batch = schema.decode_compact(raw, **quant)
+        with jax.named_scope("fsx.decode"):
+            batch = schema.decode_compact(raw, **quant)
         return base(table, stats, params, batch)
 
     return step
@@ -862,7 +891,8 @@ def wrap_megastep(base, n_chunks: int, donate_argnums: tuple):
 
         (table, stats), outs = jax.lax.scan(body, (table, stats), raws)
         if outs.wire is not None:
-            outs = outs._replace(wire=merge_verdict_wires(outs.wire))
+            with jax.named_scope("fsx.emit"):
+                outs = outs._replace(wire=merge_verdict_wires(outs.wire))
         return table, stats, outs
 
     return jax.jit(mega, donate_argnums=donate_argnums)

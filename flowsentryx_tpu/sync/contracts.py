@@ -367,9 +367,13 @@ ENGINE_PLAN = ClassPlan(
             "sync/channel.py's plan; engine-side use is deep calls"),
         "metrics": FieldContract(
             "documented",
-            "per-stage timers with per-stage owners: fill/pop/stage "
-            "on the dispatch thread, dispatch in the launch section, "
-            "readback/e2e in the sink section — one writer per timer"),
+            "the spans (metrics.PipelineMetrics), one writing thread "
+            "each: poll/pop/stage/backpressure/idle/report on the "
+            "dispatch thread, upload/launch in the launch section, "
+            "sink_wait/fetch/decode/apply/e2e in the sink section; "
+            "ring mode alone gives upload a second writer (slot "
+            "uploads on the dispatch thread beside a partial flush's "
+            "put on the pipeline worker) and tolerates a lost count"),
         "sink": FieldContract(
             "documented",
             "t0_ns written on the dispatch thread only before the "

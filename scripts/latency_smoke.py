@@ -116,10 +116,11 @@ def main() -> int:
 
     def seed_pending(eng, n):
         from flowsentryx_tpu.core import schema as _schema
+        from flowsentryx_tpu.engine.engine import _Stamps
 
         warm = np.zeros((eng.cfg.batch.max_batch + 1,
                          _schema.COMPACT_RECORD_WORDS), np.uint32)
-        now = _t.perf_counter()
+        now = _Stamps(*[_t.perf_counter()] * 3)
         eng._pending = [(warm.copy(), now) for _ in range(n)]
 
     ctl = Engine(_cfg(), ArraySource(recs[:0].copy()), NullSink(),

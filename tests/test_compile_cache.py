@@ -473,12 +473,16 @@ class TestRuntime:
         monkeypatch.setattr(jax.config, "update",
                             lambda k, v: set_in_code.append((k, v)))
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        # either way the key covers each program's metadata (a cached
+        # executable must not carry another build's scope names)
+        meta = ("jax_compilation_cache_include_metadata_in_key", True)
         assert runtime.place_compile_cache() == str(tmp_path)
-        assert set_in_code == []  # JAX reads the variable itself
+        assert set_in_code == [meta]  # JAX reads the variable itself
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         want = str(runtime.CHECKOUT / ".jax_cache")
         assert runtime.place_compile_cache() == want
-        assert set_in_code == [("jax_compilation_cache_dir", want)]
+        assert set_in_code == [meta, meta,
+                               ("jax_compilation_cache_dir", want)]
         assert (runtime.CHECKOUT / "flowsentryx_tpu").is_dir()
 
     def test_compile_counters_count_from_their_own_start(self):

@@ -1197,30 +1197,6 @@ class TestSinkThread:
         assert rb["compact_sinks"] > 0
 
 
-class TestStageTimer:
-    def test_ring_late_samples_influence_percentiles(self):
-        """The old StageTimer stopped recording at ``keep`` samples —
-        long runs reported percentiles of only their first window.  The
-        ring must let late samples move the percentiles."""
-        from flowsentryx_tpu.engine.metrics import StageTimer
-
-        t = StageTimer("x", keep=8)
-        for _ in range(8):
-            t.add(0.001)
-        assert t.percentiles_ms()["p50"] == pytest.approx(1.0)
-        for _ in range(8):
-            t.add(0.1)  # overwrites the ring — must dominate now
-        p = t.percentiles_ms()
-        assert p["p50"] == pytest.approx(100.0)
-        assert p["n"] == 16  # total ever, not ring length
-        # the all-time max survives aging out of the ring
-        t2 = StageTimer("y", keep=4)
-        t2.add(0.5)
-        for _ in range(8):
-            t2.add(0.001)
-        assert t2.percentiles_ms()["max"] == pytest.approx(500.0)
-
-
 class TestServeCheckpointEvery:
     def test_periodic_checkpoint_and_restore(self, tmp_path, capsys):
         """fsx serve --checkpoint-every snapshots mid-serve (crash loses
@@ -1911,11 +1887,13 @@ class TestSloServing:
         spiral)."""
         import time as _t
 
+        from flowsentryx_tpu.engine.engine import _Stamps
+
         def seed_pending(eng, n):
             warm = np.zeros(
                 (eng.cfg.batch.max_batch + 1,
                  schema.COMPACT_RECORD_WORDS), np.uint32)
-            now = _t.perf_counter()
+            now = _Stamps(*[_t.perf_counter()] * 3)
             eng._pending = [(warm.copy(), now) for _ in range(n)]
 
         def mk(**kw):
@@ -1937,7 +1915,8 @@ class TestSloServing:
         late = mk(mega_n="auto", slo_us=1)
         late._rung_ewma_s.update({2: 9e9, 4: 9e9, 8: 9e9})
         seed_pending(late, 5)
-        late._pending = [(r, t - 1.0) for r, t in late._pending]
+        late._pending = [(r, _Stamps(*[t.t_enqueue - 1.0] * 3))
+                         for r, t in late._pending]
         late._drain_pending(short=True)
         assert {int(g): n for g, n in late._group_hist.items()} \
             == {4: 1, 1: 1}
@@ -1956,7 +1935,9 @@ class TestSloServing:
                      slo_us=1000)
         eng._rung_ewma_s.update({2: 9e9, 4: 9e9, 8: 9e9})
         warm = np.zeros((257, schema.COMPACT_RECORD_WORDS), np.uint32)
-        now = _t.perf_counter()
+        from flowsentryx_tpu.engine.engine import _Stamps
+
+        now = _Stamps(*[_t.perf_counter()] * 3)
         eng._pending = [(warm.copy(), now) for _ in range(8)]
         eng._drain_pending(short=True)
         assert {int(g): n for g, n in eng._group_hist.items()} == {8: 1}
@@ -2020,9 +2001,10 @@ class TestSloServing:
         assert eng.batcher.flush_due()
         assert eng._deadline_flush_due()  # idle pipe: fires
         # in-flight work (dispatch-staged entry) blocks the flush
-        from flowsentryx_tpu.engine.engine import _InFlight
+        from flowsentryx_tpu.engine.engine import _InFlight, _Stamps
 
-        eng._inflight.append(_InFlight(out=None, t_enqueue=0.0,
+        eng._inflight.append(_InFlight(out=None,
+                                       stamps=_Stamps(0.0, 0.0, 0.0),
                                        n_records=1))
         assert eng._busy_depth() == 1
         assert not eng._deadline_flush_due()  # never mid-flight
@@ -2065,9 +2047,10 @@ class TestSloServing:
         assert not eng2._deadline_flush_due()  # fresh: floored
         _t.sleep(0.003)
         assert eng2._deadline_flush_due()      # past budget/2 = 2.5ms
-        from flowsentryx_tpu.engine.engine import _InFlight
+        from flowsentryx_tpu.engine.engine import _InFlight, _Stamps
 
-        eng._inflight.append(_InFlight(out=None, t_enqueue=0.0,
+        eng._inflight.append(_InFlight(out=None,
+                                       stamps=_Stamps(0.0, 0.0, 0.0),
                                        n_records=1))
         assert not eng._deadline_flush_due()  # idle-pipe rule dominates
 
