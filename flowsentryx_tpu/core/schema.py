@@ -677,7 +677,13 @@ class IpTableState(NamedTuple):
     transaction each way instead of twelve scattered ones, which is the
     difference between latency-bound and bandwidth-shaped table access
     on TPU.  Named column views are exposed as read-only properties so
-    reporting/tests keep field-style access.
+    reporting/tests keep field-style access.  On the device a view is a
+    table-wide COPY (``state[:, c]`` is a strided slice into a fresh
+    ``[capacity]`` array: 256 MB and 3.35 ms at 2^26 rows on a v5e,
+    whatever the batch holds), so a step never takes one: it reads
+    ``state[slot]`` or ``state[slot, c]`` by gather and writes by
+    scatter (``tests/test_fused.py::TestStepNeverTakesATableColumn``,
+    ``tests/test_chip_compile.py``).
 
     All times are float32 seconds on a process-relative clock; counters
     are float32 (exactly representable well past any 1-second window's
@@ -691,8 +697,9 @@ class IpTableState(NamedTuple):
     def capacity(self) -> int:
         return self.key.shape[-1]
 
-    # -- read-only column views (reporting/tests; the hot path slices
-    #    the matrix directly) ------------------------------------------
+    # -- read-only column views: reporting and tests ONLY.  Each is a
+    #    table-wide copy on the device; the step gathers from the
+    #    matrix instead (class docstring) --------------------------------
     def _col(self, c: "TableCol") -> jnp.ndarray:
         return self.state[..., int(c)]
 

@@ -115,9 +115,7 @@ def flow_step(
     the young-flow vote (``ModelConfig.vote_k``/``vote_m``) decides
     whether that evidence blocks."""
     asg = hashtable.assign_slots(
-        table.key, table.last_seen, fa.rep_key, fa.rep_valid & flow_mask,
-        now, cfg.table,
-    )
+        table, fa.rep_key, fa.rep_valid & flow_mask, now, cfg.table)
     return _flow_core(cfg, table, fa, asg, flow_mask, ml_count, now)
 
 
@@ -617,8 +615,8 @@ def make_step(
             key = jnp.where(batch.key == 0, jnp.uint32(0xFFFFFFFE),
                             batch.key)
             key = jnp.where(batch.valid, key, agg.INVALID_KEY)
-            pr = hashtable.probe_slots(table.key, table.last_seen, key,
-                                       batch.valid, now, cfg.table)
+            pr = hashtable.probe_slots(table, key, batch.valid, now,
+                                       cfg.table)
             slot, found, usable = pr.slot, pr.found, pr.usable
 
         with jax.named_scope("fsx.aggregate"):

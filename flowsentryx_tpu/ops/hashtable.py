@@ -7,8 +7,10 @@ in HBM and is updated in place via donated buffers.  Design constraints
 that shaped it (SURVEY.md §7.4.2):
 
 * **Static shapes, bounded probes.**  Open addressing with a
-  compile-time probe count ``P``: lookup is one ``[R, P]`` gather + a
-  reduction — no data-dependent loops, so XLA vectorizes it flat.
+  compile-time probe count ``P``: lookup is ``[R, P]`` gathers (keys,
+  and ``last_seen`` out of the state matrix) + a reduction — no
+  data-dependent loops and nothing table-wide, so XLA vectorizes it
+  flat and its cost follows the batch, not the capacity.
 * **Batch-internal collision resolution.**  Two distinct keys in one
   micro-batch can select the same slot (hash collision on insert); a
   sort-based arbitration picks exactly one winner per slot
@@ -33,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from flowsentryx_tpu.core.config import TableConfig
+from flowsentryx_tpu.core.schema import IpTableState, TableCol
 
 # numpy scalar, not jnp (see agg.INVALID_KEY note).
 EMPTY_KEY = np.uint32(0)
@@ -73,8 +76,7 @@ class ProbeResult(NamedTuple):
 
 
 def probe_slots(
-    table_key: jnp.ndarray,
-    table_last_seen: jnp.ndarray,
+    table: IpTableState,
     key: jnp.ndarray,
     valid: jnp.ndarray,
     now: jnp.ndarray,
@@ -91,9 +93,14 @@ def probe_slots(
     second salted hash — odd steps generate the full ring for
     power-of-two ``N``, so probes don't clump under adversarial floods.
     Claim priority per key: exact match > first empty > earliest stale
-    reclaimable.  All candidates are examined in one ``[R, P]`` gather;
-    selection is ``argmin`` over a priority score — branch-free."""
-    n = table_key.shape[0]
+    reclaimable.  All candidates are examined in two ``[R, P]`` gathers
+    — the keys from ``table.key``, their ``last_seen`` from the state
+    matrix at ``(slot, LAST_SEEN)`` — and selection is ``argmin`` over a
+    priority score, branch-free.  The function takes the TABLE, never a
+    column of it: ``table.last_seen`` is a table-wide copy on the
+    device (:class:`IpTableState`), paid every step whatever the batch
+    holds."""
+    n = table.key.shape[0]
     mask = jnp.uint32(n - 1)
     p = cfg.probes
 
@@ -104,8 +111,8 @@ def probe_slots(
     slots = (h1[:, None] + offs[None, :] * step[:, None]) & mask  # [R, P]
     slots = slots.astype(jnp.int32)
 
-    cand_key = table_key[slots]            # [R, P] gather
-    cand_seen = table_last_seen[slots]     # [R, P]
+    cand_key = table.key[slots]                             # [R, P] gather
+    cand_seen = table.state[slots, int(TableCol.LAST_SEEN)]  # [R, P] gather
 
     match = cand_key == key[:, None]
     empty = cand_key == EMPTY_KEY
@@ -133,20 +140,19 @@ def probe_slots(
 
 
 def assign_slots(
-    table_key: jnp.ndarray,
-    table_last_seen: jnp.ndarray,
+    table: IpTableState,
     rep_key: jnp.ndarray,
     rep_valid: jnp.ndarray,
     now: jnp.ndarray,
     cfg: TableConfig,
 ) -> SlotAssignment:
     """Find-or-claim a table slot for each representative key (probe
-    math shared with the fused step via :func:`probe_slots`)."""
-    n = table_key.shape[0]
+    math shared with the fused step via :func:`probe_slots`, which
+    reads ``table`` by gather only)."""
+    n = table.key.shape[0]
     r = rep_key.shape[0]
 
-    pr = probe_slots(table_key, table_last_seen, rep_key, rep_valid,
-                     now, cfg)
+    pr = probe_slots(table, rep_key, rep_valid, now, cfg)
     slot, found, usable = pr.slot, pr.found, pr.usable
     inserted = usable & ~found
 

@@ -7,7 +7,11 @@ Nothing runs, so these say nothing about results or times — they say
 the chip's compiler accepts each program at BASELINE config 5 shapes
 (table 2^20 rows, batch 16,384, `artifacts/logreg_int8.npz`), which
 interpret-mode tests and XLA:CPU cannot: Mosaic refuses misaligned
-slices, oversized VMEM use and kernels it cannot partition.
+slices, oversized VMEM use and kernels it cannot partition.  The two
+single-device step programs are also compiled at the benchmark's
+`c4-syn-mix` shapes (2^26 rows, batch 2,048), and every step program is
+searched for a temporary as long as the table: what the chip's compiler
+makes of a column view that XLA:CPU would fuse away.
 
 The topology is described only inside the module-scoped fixture below
 (one process may hold the TPU library: see the guide for why that rules
@@ -16,6 +20,7 @@ everything built from it is built in a fixture or a test.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +42,14 @@ from flowsentryx_tpu.parallel import layout
 CAPACITY = 1 << 20
 BATCH = 16384
 WORDS = schema.COMPACT_RECORD_WORDS
-CFG = FsxConfig(table=TableConfig(capacity=CAPACITY, salt=0x5EED5EED),
-                batch=BatchConfig(max_batch=BATCH))
+
+
+def _cfg(capacity, batch):
+    return FsxConfig(table=TableConfig(capacity=capacity, salt=0x5EED5EED),
+                     batch=BatchConfig(max_batch=batch))
+
+
+CFG = _cfg(CAPACITY, BATCH)
 #: [capacity, 12] f32 rows + the u32 key column, as the host counts them
 #: (the chip pads the 12-wide minor dimension, so it aliases more).
 TABLE_BYTES = CAPACITY * (schema.NUM_TABLE_COLS + 1) * 4
@@ -94,10 +105,10 @@ def _abstract(tree, sharding):
                                        sharding=sharding), tree)
 
 
-def _state(params, key_sh, state_sh, rest_sh):
+def _state(params, key_sh, state_sh, rest_sh, capacity=CAPACITY):
     table = schema.IpTableState(
-        key=jax.ShapeDtypeStruct((CAPACITY,), jnp.uint32, sharding=key_sh),
-        state=jax.ShapeDtypeStruct((CAPACITY, schema.NUM_TABLE_COLS),
+        key=jax.ShapeDtypeStruct((capacity,), jnp.uint32, sharding=key_sh),
+        state=jax.ShapeDtypeStruct((capacity, schema.NUM_TABLE_COLS),
                                    jnp.float32, sharding=state_sh))
     stats = _abstract(jax.eval_shape(schema.make_stats), rest_sh)
     return table, stats, _abstract(params, rest_sh)
@@ -128,30 +139,73 @@ def test_score_int8_kernel_compiles_with_mosaic(one_chip, mosaic, served,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_single_compact_step_compiles_and_aliases_the_table(one_chip,
-                                                            served):
-    classify, quant, params = served
-    step = fused.make_jitted_compact_step(CFG, classify, **quant)
-    compiled = step.lower(
-        *_state(params, one_chip, one_chip, one_chip),
-        _wire((BATCH + 1, WORDS), one_chip)).compile()
+#: (table rows, batch, limit of the temporaries): BASELINE config 5 as
+#: `chip_smoke.py` serves it, where the temporaries are the batch's own
+#: (20 MB at 16,384 records), and the benchmark's `c4-syn-mix`
+#: (`benchmark/configs/`), where the table is 4.56 GB and the limit is
+#: one f32 column of it: the temporaries were 272 MB while the probe
+#: took `table.last_seen`, and are 4 MB since it gathers (ISSUE 30).
+STEP_SHAPES = [pytest.param(CAPACITY, BATCH, 32 << 20, id="c5-smoke"),
+               pytest.param(1 << 26, 2048, (1 << 26) * 4, id="c4-benchmark")]
+
+
+def _foreign_table_sized_results(text, capacity):
+    """(opcode, dtype, dims) of every instruction of the compiled
+    program whose result has `capacity` among its dimensions and is
+    neither the key column nor the state matrix: a column, a copy, a
+    transpose or a fusion output as long as the table."""
+    own = {("u32", (capacity,)),
+           ("f32", (capacity, schema.NUM_TABLE_COLS))}
+    found = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                      m.group(1)):
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            if capacity in dims and (dtype, dims) not in own:
+                found.add((m.group(2), dtype, dims))
+    return found
+
+
+def _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
+                                                  temp_limit):
     mem = compiled.memory_analysis()
     # donation really aliases: the whole table updates in place
-    assert mem.alias_size_in_bytes >= TABLE_BYTES
-    assert mem.temp_size_in_bytes < TABLE_BYTES  # no second table
+    assert (mem.alias_size_in_bytes
+            >= capacity * (schema.NUM_TABLE_COLS + 1) * 4)
+    # the step reads the table by gather and writes it by scatter: a
+    # column view stages a `capacity`-long temporary every step
+    assert _foreign_table_sized_results(compiled.as_text(), capacity) == set()
+    assert mem.temp_size_in_bytes < temp_limit
 
 
-def test_top_mega_rung_compiles_and_aliases_the_table(one_chip, served):
+@pytest.mark.parametrize("capacity,batch,temp_limit", STEP_SHAPES)
+def test_single_compact_step_compiles_and_aliases_the_table(
+        one_chip, served, capacity, batch, temp_limit):
+    classify, quant, params = served
+    step = fused.make_jitted_compact_step(_cfg(capacity, batch), classify,
+                                          **quant)
+    compiled = step.lower(
+        *_state(params, one_chip, one_chip, one_chip, capacity),
+        _wire((batch + 1, WORDS), one_chip)).compile()
+    _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
+                                                  temp_limit)
+
+
+@pytest.mark.parametrize("capacity,batch,temp_limit", STEP_SHAPES)
+def test_top_mega_rung_compiles_and_aliases_the_table(
+        one_chip, served, capacity, batch, temp_limit):
     classify, quant, params = served
     top = max(fused.pow2_group_sizes(8))
     mega = fused.make_compact_megastep_family(
-        CFG, classify, (top,), **quant)[top]
+        _cfg(capacity, batch), classify, (top,), **quant)[top]
     compiled = mega.lower(
-        *_state(params, one_chip, one_chip, one_chip),
-        _wire((top, BATCH + 1, WORDS), one_chip)).compile()
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= TABLE_BYTES
-    assert mem.temp_size_in_bytes < TABLE_BYTES
+        *_state(params, one_chip, one_chip, one_chip, capacity),
+        _wire((top, batch + 1, WORDS), one_chip)).compile()
+    _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
+                                                  temp_limit)
 
 
 def test_sharded_step_compiles_for_four_chips(mesh4, served):
@@ -167,6 +221,8 @@ def test_sharded_step_compiles_for_four_chips(mesh4, served):
         _wire((BATCH + 1, WORDS), rep)).compile()
     text = compiled.as_text()
     assert text.count("all-to-all(") == 2  # flows out, verdicts back
+    # `flow_step` probes its shard by gather too: no shard-long column
+    assert _foreign_table_sized_results(text, CAPACITY // 4) == set()
     mem = compiled.memory_analysis()  # bytes on EACH device
     assert TABLE_BYTES // 4 <= mem.alias_size_in_bytes < TABLE_BYTES // 2
 

@@ -11,6 +11,7 @@ from flowsentryx_tpu.core.config import (
 from flowsentryx_tpu.core.schema import (
     FeatureBatch, Verdict, make_stats, make_table, stat_value,
 )
+from flowsentryx_tpu.audit.graph import iter_eqns
 from flowsentryx_tpu.models import get_model
 from flowsentryx_tpu.ops import fused
 
@@ -637,3 +638,70 @@ class TestBatchesWrapEviction:
             self._tcfg(), table, stats, jnp.float32(100.0))
         assert int(np.asarray(new_table.key)[off]) == off + 1  # kept
         assert int(n) == fused.evict_window(self.CAP, self.EVERY) - 1
+
+
+class TestStepNeverTakesATableColumn:
+    """The step may touch the table only by gather and scatter (ISSUE
+    30).  `table.last_seen` is `state[:, LAST_SEEN]`: handed to the
+    probe it made every step copy a whole column out of the state
+    matrix, 256 MB at 2^26 rows, 73 % of the step on the chip.  Read
+    from the jaxpr, so it holds whatever the backend would fuse away."""
+
+    #: unlike every other dimension of the traced step (batch 256,
+    #: 8 probes, 12 columns, a 256-row eviction window)
+    CAP = 1 << 12
+
+    @classmethod
+    def _table_sized_primitives(cls, fn, *args):
+        """Names of the primitives with a table-sized operand or result
+        anywhere in the traced graph (sub-jaxprs walked)."""
+        return {
+            eqn.primitive.name
+            for _, eqn in iter_eqns(jax.make_jaxpr(fn)(*args))
+            if any(cls.CAP in getattr(v.aval, "shape", ())
+                   for v in (*eqn.invars, *eqn.outvars))}
+
+    def _cfg(self, kind, evict_ttl_s=0.0):
+        return FsxConfig(
+            limiter=LimiterConfig(kind=kind),
+            table=TableConfig(capacity=self.CAP, probes=8,
+                              evict_ttl_s=evict_ttl_s, evict_every=16),
+            batch=BatchConfig(max_batch=256))
+
+    @pytest.mark.parametrize("evict_ttl_s", [0.0, 5.0],
+                             ids=["no-evict", "evict"])
+    @pytest.mark.parametrize("kind", list(LimiterKind),
+                             ids=lambda k: k.value)
+    def test_make_step(self, kind, evict_ttl_s):
+        cfg = self._cfg(kind, evict_ttl_s)
+        spec = get_model(cfg.model.name)
+        used = self._table_sized_primitives(
+            fused.make_step(cfg, spec.classify_batch),
+            make_table(self.CAP), make_stats(), spec.init(),
+            build_batch([(1001, 5, 100, 0.1, ML_COLD)]))
+        assert used == {"gather", "scatter"}
+
+    @pytest.mark.parametrize("kind", list(LimiterKind),
+                             ids=lambda k: k.value)
+    def test_flow_step_of_the_sharded_path(self, kind):
+        from flowsentryx_tpu.ops import agg
+
+        cfg = self._cfg(kind)
+        b = build_batch([(1001, 5, 100, 0.1, ML_COLD)])
+
+        def two_stage(table):
+            fa = agg.aggregate(b.key, b.pkt_len, b.ts, b.valid)
+            ones = jnp.ones_like(fa.rep_valid)
+            return fused.flow_step(cfg, table, fa, ones,
+                                   jnp.zeros_like(fa.rep_pkts),
+                                   jnp.float32(0.1))
+
+        assert (self._table_sized_primitives(two_stage, make_table(self.CAP))
+                == {"gather", "scatter"})
+
+    def test_the_guard_sees_a_column_view(self):
+        """What the guard is for: the probe's old read."""
+        used = self._table_sized_primitives(
+            lambda table, slots: table.last_seen[slots],
+            make_table(self.CAP), jnp.zeros((256, 8), jnp.int32))
+        assert used - {"gather"}  # slice/squeeze of the whole column

@@ -5,6 +5,8 @@ import jax.numpy as jnp
 import pytest
 
 from flowsentryx_tpu.core.config import LimiterConfig, LimiterKind, TableConfig
+from flowsentryx_tpu.core.schema import NUM_TABLE_COLS, IpTableState, TableCol
+from flowsentryx_tpu.engine import table as table_plan
 from flowsentryx_tpu.ops import agg, hashtable, limiters
 
 
@@ -219,17 +221,28 @@ class TestHashTable:
     def _fresh(self, cap):
         return (jnp.zeros((cap,), jnp.uint32), jnp.zeros((cap,), jnp.float32))
 
+    @staticmethod
+    def _table(tk, seen):
+        """The table the probe takes, built from a key and a `last_seen`
+        vector.  Every other column holds NaN: a probe that read the
+        wrong column would find nothing stale and nothing live."""
+        state = jnp.full((tk.shape[0], NUM_TABLE_COLS), jnp.nan, jnp.float32)
+        return IpTableState(
+            key=tk, state=state.at[:, int(TableCol.LAST_SEEN)].set(seen))
+
     def test_insert_then_find(self):
         tk, seen = self._fresh(1 << 10)
         keys = jnp.array([111, 222, 333, agg.INVALID_KEY], jnp.uint32)
         valid = jnp.array([True, True, True, False])
-        a1 = hashtable.assign_slots(tk, seen, keys, valid, jnp.float32(1.0), self.CFG4)
+        a1 = hashtable.assign_slots(self._table(tk, seen), keys, valid,
+                                    jnp.float32(1.0), self.CFG4)
         assert list(np.asarray(a1.inserted)) == [True, True, True, False]
         assert not bool(a1.found.any())
         # caller scatters keys (as the fused step does)
         tk = tk.at[a1.slot].set(jnp.where(a1.tracked, keys, tk[a1.slot]))
         seen = seen.at[a1.slot].set(jnp.where(a1.tracked, 1.0, seen[a1.slot]))
-        a2 = hashtable.assign_slots(tk, seen, keys, valid, jnp.float32(2.0), self.CFG4)
+        a2 = hashtable.assign_slots(self._table(tk, seen), keys, valid,
+                                    jnp.float32(2.0), self.CFG4)
         assert list(np.asarray(a2.found)) == [True, True, True, False]
         np.testing.assert_array_equal(np.asarray(a2.slot[:3]), np.asarray(a1.slot[:3]))
 
@@ -239,7 +252,8 @@ class TestHashTable:
         tk, seen = self._fresh(16)
         keys = jnp.asarray(rng.integers(1, 2**31, 64).astype(np.uint32))
         valid = jnp.ones((64,), bool)
-        a = hashtable.assign_slots(tk, seen, keys, valid, jnp.float32(1.0), cfg)
+        a = hashtable.assign_slots(self._table(tk, seen), keys, valid,
+                                   jnp.float32(1.0), cfg)
         slots = np.asarray(a.slot)[np.asarray(a.tracked)]
         assert len(slots) == len(set(slots.tolist()))
         assert len(slots) <= 16
@@ -250,10 +264,12 @@ class TestHashTable:
         seen = jnp.array([0.0, 1.0], jnp.float32)
         key = jnp.array([12345], jnp.uint32)
         # at t=3 (999 fresh): key lands in the empty slot 0 or loses
-        a_fresh = hashtable.assign_slots(tk, seen, key, jnp.array([True]),
+        a_fresh = hashtable.assign_slots(self._table(tk, seen), key,
+                                         jnp.array([True]),
                                          jnp.float32(3.0), cfg)
         # at t=20 (999 stale): key must be tracked somewhere
-        a_stale = hashtable.assign_slots(tk, seen, key, jnp.array([True]),
+        a_stale = hashtable.assign_slots(self._table(tk, seen), key,
+                                         jnp.array([True]),
                                          jnp.float32(20.0), cfg)
         assert bool(a_stale.tracked[0])
         assert bool(a_fresh.tracked[0])  # capacity-2, probes=2 covers both slots
@@ -266,7 +282,8 @@ class TestHashTable:
         tk = jnp.array([777, 888], jnp.uint32)
         seen = jnp.zeros((2,), jnp.float32)
         keys = jnp.array([888, 555, 666], jnp.uint32)
-        a = hashtable.assign_slots(tk, seen, keys, jnp.ones((3,), bool),
+        a = hashtable.assign_slots(self._table(tk, seen), keys,
+                                   jnp.ones((3,), bool),
                                    jnp.float32(100.0), cfg)
         assert bool(a.found[0]) and bool(a.tracked[0])
         b_slot = int(a.slot[0])
@@ -279,9 +296,75 @@ class TestHashTable:
         tk = jnp.array([777, 888], jnp.uint32)  # full, never stale
         seen = jnp.full((2,), 1e9, jnp.float32)
         keys = jnp.array([111, 222, 333], jnp.uint32)
-        a = hashtable.assign_slots(tk, seen, keys, jnp.ones((3,), bool),
+        a = hashtable.assign_slots(self._table(tk, seen), keys,
+                                   jnp.ones((3,), bool),
                                    jnp.float32(2e9), cfg)
         assert not bool(a.tracked.any())  # untracked, not mis-tracked
+
+    @staticmethod
+    def _ring(key, cfg):
+        """[R, P] probe ring of each key: the host twin of the device's
+        probe sequence (`engine/table.py`, pinned by test_table.py)."""
+        return table_plan._global_candidates(key, table_plan.TablePlan.of(cfg))
+
+    @classmethod
+    def _probe_by_column(cls, tk, seen, key, valid, now, cfg):
+        """`probe_slots` as it read `last_seen` before it took the
+        table: from a `[capacity]` column vector, written out in numpy."""
+        p = cfg.probes
+        slots = cls._ring(key, cfg)
+        cand_key, cand_seen = tk[slots], seen[slots]
+        match = cand_key == key[:, None]
+        empty = cand_key == 0
+        stale = ~match & ~empty & (np.float32(now) - cand_seen
+                                   > np.float32(cfg.stale_s))
+        idx = np.arange(p, dtype=np.int32)[None, :]
+        score = np.where(match, idx, np.where(
+            empty, p + idx, np.where(stale, 2 * p + idx, 4 * p)))
+        best = score.argmin(axis=1)
+        rows = np.arange(key.shape[0])
+        return hashtable.ProbeResult(
+            slot=slots[rows, best],
+            found=valid & (score[rows, best] < p),
+            usable=valid & (score[rows, best] < 4 * p))
+
+    def test_probe_from_the_matrix_matches_the_column_form(self):
+        """One seeded batch whose 4 probes a key meet matches, empties,
+        stale rows and live foreign rows: the gather at
+        `(slot, LAST_SEEN)` of the state matrix selects bit for bit
+        what the `[capacity]` column form selects."""
+        rng = np.random.default_rng(30)
+        cap = 64
+        cfg = TableConfig(capacity=cap, probes=4, stale_s=30.0, salt=0xFEED)
+        # residents sit on a probe of their own ring, any of the four
+        resident = rng.choice(1 << 20, 56, replace=False).astype(np.uint32) + 1
+        tk = np.zeros(cap, np.uint32)
+        for k, ring in zip(resident, self._ring(resident, cfg)):
+            free = ring[tk[ring] == 0]
+            if free.size:
+                tk[rng.choice(free)] = k
+        # at now = 100 an occupied row is stale below 70 s, live above
+        seen = np.where(tk != 0, rng.choice([10.0, 69.5, 70.5, 99.0], cap),
+                        0.0).astype(np.float32)
+        key = np.concatenate([tk[tk != 0][:24],
+                              rng.integers(1 << 20, 1 << 30, 40)]
+                             ).astype(np.uint32)
+        valid = rng.random(key.shape[0]) < 0.9
+        now = jnp.float32(100.0)
+        want = self._probe_by_column(tk, seen, key, valid, now, cfg)
+        got = hashtable.probe_slots(
+            self._table(jnp.asarray(tk), jnp.asarray(seen)),
+            jnp.asarray(key), jnp.asarray(valid), now, cfg)
+        for name in hashtable.ProbeResult._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          getattr(want, name), err_msg=name)
+        # the batch really holds every kind of outcome
+        found, usable = np.asarray(got.found), np.asarray(got.usable)
+        picked = tk[np.asarray(got.slot)]
+        assert found.sum() >= 16                            # matches
+        assert (usable & ~found & (picked == 0)).any()      # empties
+        assert (usable & ~found & (picked != 0)).any()      # stale reclaim
+        assert (valid & ~usable).any()                      # all live foreign
 
     def test_hash_avalanche(self):
         # sequential keys must not map to sequential slots
@@ -301,9 +384,9 @@ class TestHashTable:
         keys = jnp.asarray(rng.integers(1, 2**31, 64).astype(np.uint32))
         valid = jnp.ones((64,), bool)
         tk, seen = self._fresh(1 << 10)
-        a0 = hashtable.assign_slots(tk, seen, keys, valid,
+        a0 = hashtable.assign_slots(self._table(tk, seen), keys, valid,
                                     jnp.float32(1.0), cfg0)
-        a_s = hashtable.assign_slots(tk, seen, keys, valid,
+        a_s = hashtable.assign_slots(self._table(tk, seen), keys, valid,
                                      jnp.float32(1.0), cfg_s)
         # (a) layouts differ almost everywhere
         same = np.asarray(a0.slot) == np.asarray(a_s.slot)
@@ -313,7 +396,7 @@ class TestHashTable:
         slot_w = jnp.where(a_s.tracked, a_s.slot, 1 << 10)
         tk2 = tk.at[slot_w].set(keys, mode="drop")
         seen2 = seen.at[slot_w].set(1.0, mode="drop")
-        a2 = hashtable.assign_slots(tk2, seen2, keys, valid,
+        a2 = hashtable.assign_slots(self._table(tk2, seen2), keys, valid,
                                     jnp.float32(2.0), cfg_s)
         tr = np.asarray(a_s.tracked)
         assert np.asarray(a2.found)[tr].all()
