@@ -278,6 +278,9 @@ def phase_serve(kids: Children, work: Path, fsxd: Path, cfg: Path,
         "boot": rep["boot"], "dispatch_groups": rep["dispatch"]["group_hist"],
         "stats": rep["stats"], "blocked_sources": rep["blocked_sources"],
         "verdict_ring_written": status["verdict_ring"]["produced"],
+        "verdict_ring": {k: rep["readback"].get(k) for k in (
+            "verdict_ring_dropped", "verdict_ring_waits",
+            "verdict_ring_fill_peak")},
         "feature_backlog": [r["feature_ring"]["backlog"] for r in rings],
         "ingest_drops": {k: ingest[k] for k in (
             "dropped_tail_batches", "dropped_emit_batches",
@@ -305,6 +308,9 @@ def phase_serve(kids: Children, work: Path, fsxd: Path, cfg: Path,
           f"serve: ingest fail-opens counted: {line['ingest_drops']}")
     check(line["verdict_ring_written"] > 0 and rep["blocked_sources"] > 0,
           "serve: no block reached the verdict ring")
+    check(rep["readback"].get("verdict_ring_dropped") == 0,
+          f"serve: blocks decided and not written to the verdict ring: "
+          f"{line['verdict_ring']}")
     check(rep["health"]["state"] == "healthy",
           f"serve: health {rep['health']}")
     check(rep["table"]["tracked"] > 0,

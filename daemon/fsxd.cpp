@@ -54,6 +54,23 @@ uint64_t now_ns() {
         .count();
 }
 
+// Verdict-ring slots (16 B each), the one size both modes create the
+// ring with.  The engine's sink (engine/shm.py ShmVerdictSink) writes
+// an update in order and WAITS for room while this daemon's cursor
+// moves, so the size decides how often the sink waits, not whether a
+// block arrives; what it has to hold is what the sink writes while
+// this loop is elsewhere:
+//   * one sunk group: the engine admits batches of up to 16,384 records
+//     and sinks up to 8 of them as one update (MEGA_AUTO_MAX), and every
+//     record can carry a new block: 131,072 blocks at once;
+//   * one wave while the reader is away (a restart of this daemon, the
+//     drain after it was told to stop): a source holds at most one live
+//     block, and BASELINE config 5 states 1M concurrent sources: 2^20.
+// 2^20 slots x 16 B = 16 MB of shm covers both (the second holds the
+// first 8 times over).  The loops below take 4,096 verdicts an
+// iteration, so a live ring stays a few per cent full.
+constexpr uint64_t kVerdictRingSlots = 1ull << 20;
+
 struct Options {
     std::string mode = "sim";
     std::string feature_ring = "/tmp/fsx_feature_ring";
@@ -68,6 +85,9 @@ struct Options {
     uint32_t shards = 1;
     std::string replay_file;
     uint64_t ring_capacity = 1 << 16;  // feature-ring record slots
+    // test hook (--verdict-ring-capacity, not in the usage text): the
+    // tests work the sink's wait against a ring smaller than one update
+    uint64_t verdict_ring_capacity = kVerdictRingSlots;
     double rate_pps = 1e6;             // sim packet rate
     uint64_t total_packets = 0;        // 0 = unbounded
     double duration_s = 0;             // 0 = unbounded
@@ -109,7 +129,12 @@ struct Options {
     std::fprintf(stderr,
                  "usage: %s [--sim|--replay FILE|--bpf IFACE] [options]\n"
                  "  --feature-ring PATH   shm feature ring (default /tmp/fsx_feature_ring)\n"
-                 "  --verdict-ring PATH   shm verdict ring (default /tmp/fsx_verdict_ring)\n"
+                 "  --verdict-ring PATH   shm verdict ring (default /tmp/fsx_verdict_ring),\n"
+                 "                        1048576 slots (16 MB: a block for each of 1M live\n"
+                 "                        sources).  The engine's sink writes a full ring in\n"
+                 "                        pieces and waits for this daemon to make room; it\n"
+                 "                        discards (and counts verdict_ring_dropped) only\n"
+                 "                        once the daemon's cursor has stood still for 2 s\n"
                  "  --ring-capacity N     feature ring slots, power of 2 (default 65536)\n"
                  "  --shards N            fan features out over N rings by source-IP\n"
                  "                        hash (<feature-ring>.<k>, one per ingest\n"
@@ -334,7 +359,7 @@ int run_bpf(const Options &o) {
                                       : sizeof(fsx_flow_record);
     ShardedRings frings(o.feature_ring, o.shards, o.ring_capacity, rec_size,
                         o.compact ? 0 : offsetof(fsx_flow_record, saddr));
-    auto vring = fsx::ShmRing::create(o.verdict_ring, 1 << 14,
+    auto vring = fsx::ShmRing::create(o.verdict_ring, o.verdict_ring_capacity,
                                       sizeof(fsx_verdict_record));
 
     const fsxbpf::ImageMapSpec *rspec = lp.spec("feature_ring");
@@ -418,12 +443,14 @@ int run_bpf(const Options &o) {
             std::this_thread::sleep_for(std::chrono::microseconds(200));
     }
 
-    // final verdict drain (mirrors the sim path's exit contract)
-    uint64_t extra = vring.consume(vbatch.data(), vbatch.size());
-    for (uint64_t i = 0; i < extra; i++)
-        fsxbpf::map_update(blacklist_fd, &vbatch[i].saddr,
-                           &vbatch[i].until_ns);
-    verdicts += extra;
+    // final verdict drain, to the last verdict the ring holds (mirrors
+    // the sim path's exit contract)
+    while (uint64_t extra = vring.consume(vbatch.data(), vbatch.size())) {
+        for (uint64_t i = 0; i < extra; i++)
+            fsxbpf::map_update(blacklist_fd, &vbatch[i].saddr,
+                               &vbatch[i].until_ns);
+        verdicts += extra;
+    }
 
     fsx_stats s = read_stats(stats_fd);
     std::printf("{\"produced\": %" PRIu64 ", \"verdicts\": %" PRIu64
@@ -523,6 +550,8 @@ Options parse(int argc, char **argv) {
             o.verdict_ring = next();
         else if (a == "--ring-capacity")
             o.ring_capacity = std::stoull(next());
+        else if (a == "--verdict-ring-capacity")
+            o.verdict_ring_capacity = std::stoull(next());
         else if (a == "--shards")
             o.shards = (uint32_t)std::stoul(next());
         else if (a == "--rate")
@@ -660,7 +689,7 @@ int main(int argc, char **argv) {
     ShardedRings frings(o.feature_ring, o.shards, o.ring_capacity,
                         sizeof(fsx_flow_record),
                         offsetof(fsx_flow_record, saddr));
-    auto vring = fsx::ShmRing::create(o.verdict_ring, 1 << 14,
+    auto vring = fsx::ShmRing::create(o.verdict_ring, o.verdict_ring_capacity,
                                       sizeof(fsx_verdict_record));
 
     std::fprintf(stderr,
@@ -786,9 +815,9 @@ int main(int argc, char **argv) {
 
     // Final verdict drain on every exit path: verdicts racing the
     // shutdown still get counted (and, in --bpf mode, applied), so an
-    // engine that was mid-flush when the duration expired is not lost.
-    {
-        uint64_t extra = vring.consume(vbatch.data(), vbatch.size());
+    // engine that was mid-flush when the duration expired is not lost:
+    // to the last verdict the ring holds, not one 4,096-record take.
+    while (uint64_t extra = vring.consume(vbatch.data(), vbatch.size())) {
         for (uint64_t i = 0; i < extra; i++)
             blacklist[vbatch[i].saddr] = vbatch[i].until_ns;
         verdicts += extra;

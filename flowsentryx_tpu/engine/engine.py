@@ -1701,7 +1701,7 @@ class Engine:
                     [g.out.block_until.reshape(-1) for g in group]))
             now = float(np.max(jax.device_get(group[-1].out.now)))
             self._d2h_bytes += keys.nbytes + untils.nbytes
-            self._sink_fallback += len(group)
+            self._sink_fallback += sum(g.n_chunks for g in group)
             # routing-overflow fail-opens (sharded step): single-device
             # steps carry a module-level numpy zero here — free, no
             # device fetch.  Sharded jax scalars: per-batch fetch on the
@@ -1768,15 +1768,17 @@ class Engine:
                 for vw in rows:
                     self._route_drop += vw.route_drop
                     now = max(now, vw.now)
+                # both counters in batches (an entry's n_chunks), so
+                # that they add up to the batches sunk
                 if i in full:
                     # the wire slots of the WHOLE entry are discarded:
                     # the full arrays carry every block in the same
                     # chunk order
-                    self._sink_fallback += 1
+                    self._sink_fallback += group[i].n_chunks
                     parts_k.append(full[i][0])
                     parts_u.append(full[i][1])
                 else:
-                    self._sink_compact += len(rows)
+                    self._sink_compact += group[i].n_chunks
                     parts_k.extend(vw.key for vw in rows)
                     parts_u.extend(vw.until_s for vw in rows)
             keys = (np.concatenate(parts_k) if len(parts_k) > 1
@@ -3040,12 +3042,16 @@ class Engine:
             "sink_occupancy": (round(
                 self._chan.busy_s / max(wall, 1e-9), 4)
                 if self.sink_thread else None),
-            # blocks decided that did not fit the verdict ring
-            # (ShmVerdictSink.dropped): each leaves its source
+            # blocks given up on a verdict ring whose reader stood
+            # still (ShmVerdictSink.dropped): each leaves its source
             # unsuppressed in the kernel until it offends again.  None
             # for a sink that cannot drop.
             "verdict_ring_dropped": getattr(self.sink, "dropped", None),
         }
+        if hasattr(self.sink, "ring_accounting"):
+            # a ring sink's own accounting: dropped, applies that waited
+            # for room (span fsx.sink.vring_wait), peak fill
+            readback.update(self.sink.ring_accounting())
 
         # Dispatch-pipeline accounting.  host_copies_per_batch counts
         # ENGINE-side host memcpys per dispatched batch: arena staging
@@ -3150,6 +3156,8 @@ class Engine:
                 boot_rep["jax_cache"] = self.boot_jax_compiles.report()
         hists = {sp.name: sp.hist for sp in self.metrics.spans()}
         hists.update(self._lat.hists())
+        if hasattr(self.sink, "spans"):
+            hists.update((sp.name, sp.hist) for sp in self.sink.spans())
         if self.sealed and hasattr(self.source, "ingest_spans"):
             hists.update((sp.name, sp.hist)
                          for sp in self.source.ingest_spans())

@@ -16,9 +16,9 @@ from the counters themselves):
   batches, corrupt-slot skips, gossip TX drops / RX seq gaps, the
   multi-host transport's drop/gap/dup/reorder/skew accounting
   (``net_*``, cluster/transport.py), a watchdog soft trip, a restore
-  that fell back to the ``.prev`` generation, blocks that did not fit
-  the verdict ring (``verdict_ring_dropped``).  Each reason is a
-  ``name:count`` string an alert can key on.
+  that fell back to the ``.prev`` generation, blocks given up on a
+  verdict ring whose reader stood still (``verdict_ring_dropped``).
+  Each reason is a ``name:count`` string an alert can key on.
 * **FAILED** — the engine cannot serve its span: every ingest shard is
   dead, or the watchdog hard-tripped (the process is already dying
   loudly; the state is its last words).
@@ -125,8 +125,9 @@ def engine_health(
     if restore_fallbacks:
         reasons.append(f"restore_fallbacks:{restore_fallbacks}")
     if readback:
-        # blocks the engine decided that the verdict ring had no room
-        # for (ShmVerdictSink.dropped): serving continues, but those
+        # blocks the engine decided and gave up on, because the verdict
+        # ring stayed full and its reader stood still for the whole
+        # bound (ShmVerdictSink.dropped): serving continues, but those
         # sources stay unsuppressed in the kernel — the guarantee
         # "every block decided is written back" is broken
         v = int(readback.get("verdict_ring_dropped") or 0)

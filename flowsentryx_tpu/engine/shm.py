@@ -20,6 +20,8 @@ from pathlib import Path
 import numpy as np
 
 from flowsentryx_tpu.core import schema
+from flowsentryx_tpu.engine.metrics import Span
+from flowsentryx_tpu.sync import tuning
 
 # The cursor protocol below publishes with plain u64 loads/stores and
 # relies on the total-store-order guarantee of x86 (a numpy scalar store
@@ -186,6 +188,10 @@ class ShmRing:
 
     def readable(self) -> int:
         return int(self._head[0]) - int(self._tail[0])
+
+    def tail(self) -> int:
+        """The reader's cursor (a producer watches it for progress)."""
+        return int(self._tail[0])
 
 
 class SealedBatchQueue:
@@ -421,12 +427,46 @@ class ShmVerdictSink:
     """VerdictSink into the daemon's verdict ring.
 
     Expiry translation: the engine works in f32 seconds relative to its
-    ``t0_ns``; the daemon/kernel want absolute kernel-clock ns."""
+    ``t0_ns``; the daemon/kernel want absolute kernel-clock ns.
+
+    Nothing is discarded while the ring's reader advances: an update
+    larger than the room is written in order, in as many pieces as the
+    ring admits, and :meth:`apply` waits between pieces (the span
+    ``fsx.sink.vring_wait``, entered only when a push came back short;
+    the daemon takes 4,096 verdicts a loop iteration, so a wait is
+    microseconds to a few milliseconds).  The wait is bounded: a reader
+    that has not moved for ``tuning.VRING_WAIT_TIMEOUT_S`` is given up,
+    the remainder is counted in ``dropped`` (the report's
+    ``verdict_ring_dropped``; ``health`` DEGRADED), and until the
+    reader moves again later updates push what fits and count the rest
+    at once, so an engine behind a dead daemon goes on serving
+    (fail-open)."""
 
     def __init__(self, path: str | Path, t0_ns: int = 0, timeout_s: float = 10.0):
         self.ring = ShmRing.wait_for(path, schema.VERDICT_RECORD_DTYPE, timeout_s)
         self.t0_ns = t0_ns
-        self.dropped = 0
+        self.dropped = 0           # blocks given up on a reader that stood still
+        self.waits = 0             # applies that had to wait for room
+        self.fill_peak = 0         # most slots unread after a push
+        self.vring_wait = Span("fsx.sink.vring_wait")
+        self._given_up_at: int | None = None  # reader's cursor at the give-up
+
+    def ring_accounting(self) -> dict:
+        """The ring's face in ``EngineReport.readback``."""
+        return {
+            "verdict_ring_dropped": self.dropped,
+            "verdict_ring_waits": self.waits,
+            "verdict_ring_fill_peak": round(
+                self.fill_peak / self.ring.capacity, 6),
+        }
+
+    def spans(self) -> tuple[Span, ...]:
+        return (self.vring_wait,)
+
+    def _push(self, rec: np.ndarray) -> int:
+        pushed = self.ring.produce(rec)
+        self.fill_peak = max(self.fill_peak, self.ring.readable())
+        return pushed
 
     def apply(self, update) -> None:
         n = len(update.key)
@@ -437,5 +477,29 @@ class ShmVerdictSink:
         rec["until_ns"] = (
             update.until_s.astype(np.float64) * 1e9
         ).astype(np.uint64) + np.uint64(self.t0_ns)
-        pushed = self.ring.produce(rec)
-        self.dropped += n - pushed
+        done = self._push(rec)
+        # a reader given up on is not waited for until its cursor moves
+        if done < n and self.ring.tail() != self._given_up_at:
+            self.waits += 1
+            with self.vring_wait:
+                done += self._push_waiting(rec[done:])
+        self.dropped += n - done
+
+    def _push_waiting(self, rec: np.ndarray) -> int:
+        """Write ``rec`` as room appears; returns how much went in
+        before the reader stood still for the whole bound."""
+        done = 0
+        self._given_up_at = None
+        tail = self.ring.tail()
+        deadline = time.monotonic() + tuning.VRING_WAIT_TIMEOUT_S
+        while done < len(rec):
+            time.sleep(tuning.IDLE_SLEEP_S)
+            done += self._push(rec[done:])
+            now_tail = self.ring.tail()
+            if now_tail != tail:
+                tail = now_tail
+                deadline = time.monotonic() + tuning.VRING_WAIT_TIMEOUT_S
+            elif time.monotonic() > deadline:
+                self._given_up_at = tail
+                break
+        return done

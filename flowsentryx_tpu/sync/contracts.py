@@ -379,7 +379,10 @@ ENGINE_PLAN = ClassPlan(
             "t0_ns written on the dispatch thread only before the "
             "first batch reaches the sink section (handoff through "
             "the channel's cv is the happens-before edge); apply() "
-            "runs in the sink section"),
+            "runs in the sink section, and with it a ring sink's own "
+            "accounting (dropped, waits, fill_peak, its vring_wait "
+            "span), which the report reads only once the pipe has "
+            "drained"),
         "on_reap": FieldContract(
             "documented",
             "bound by the caller before run() and cleared quiescent "

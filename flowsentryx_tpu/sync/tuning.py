@@ -95,6 +95,18 @@ SLO_EWMA_ALPHA = 0.2
 #: the queue's ``emit_drop`` counter (``ingest/worker.py::_Emitter``).
 EMIT_STOP_TIMEOUT_S = 2.0
 
+#: How long the verdict ring's writer (``engine/shm.py::ShmVerdictSink``)
+#: waits on a full ring whose reader's cursor does not move before it
+#: gives the reader up and counts what is left in ``dropped``
+#: (``verdict_ring_dropped``, ``health`` DEGRADED).  A live ``fsxd``
+#: takes 4,096 verdicts every loop iteration and sleeps at most 200 µs
+#: between two, so its cursor moves within a millisecond; 2 s is three
+#: orders above that and the bound EMIT_STOP_TIMEOUT_S already puts on
+#: the same question one stage upstream ("is the consumer gone?").
+#: Progress restarts the clock, and between looks the writer sleeps
+#: IDLE_SLEEP_S, the daemon's own idle cadence.
+VRING_WAIT_TIMEOUT_S = 2.0
+
 # -- robustness plane (PR 13: fsx chaos + the hardening it forced) ----------
 
 #: Dispatch-watchdog stall bound (``engine/watchdog.py``): batches in

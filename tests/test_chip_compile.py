@@ -144,9 +144,12 @@ def test_score_int8_kernel_compiles_with_mosaic(one_chip, mosaic, served,
 #: (20 MB at 16,384 records), and the benchmark's `c4-syn-mix`
 #: (`benchmark/configs/`), where the table is 4.56 GB and the limit is
 #: one f32 column of it: the temporaries were 272 MB while the probe
-#: took `table.last_seen`, and are 4 MB since it gathers (ISSUE 30).
+#: took `table.last_seen`, and are 4 MB since it gathers (ISSUE 30);
+#: and the benchmark's `c5-l34-1m` (ISSUE 32): that table under
+#: 16,384-record batches, where the temporaries are the batch's again.
 STEP_SHAPES = [pytest.param(CAPACITY, BATCH, 32 << 20, id="c5-smoke"),
-               pytest.param(1 << 26, 2048, (1 << 26) * 4, id="c4-benchmark")]
+               pytest.param(1 << 26, 2048, (1 << 26) * 4, id="c4-benchmark"),
+               pytest.param(1 << 26, BATCH, 32 << 20, id="c5-benchmark")]
 
 
 def _foreign_table_sized_results(text, capacity):

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
 from flowsentryx_tpu.core.config import BatchConfig, FsxConfig, TableConfig
 from flowsentryx_tpu.core.signature import (
@@ -51,6 +52,24 @@ def flood_records(cfg, n_batches=24, seed=3):
                     n_attack_ips=8, n_benign_ips=24,
                     attack_fraction=0.8, seed=seed)
     ).next_records(n_batches * cfg.batch.max_batch)
+
+
+@pytest.fixture
+def no_jax_persistent_cache():
+    """The AOT store serialises what ``compile()`` hands it, and on the
+    CPU backend an executable that JAX's own persistent cache handed
+    over does not load again from the store (counted ``corrupt``, so a
+    boot recompiles: fail-open, but not the hit these tests count).
+    That cache is on in a test process once any CLI verb has run in it
+    (``runtime.place_compile_cache``) or where the environment names a
+    directory, and holds whatever compiled slowly enough to be kept, so
+    the boots counted here compile without it."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 class TestSignature:
@@ -87,6 +106,7 @@ def _tiny_compiled():
     return fn.lower(jax.ShapeDtypeStruct((8,), jnp.int32)).compile()
 
 
+@pytest.mark.usefixtures("no_jax_persistent_cache")
 class TestCompileCacheUnit:
     """CompileCache against a tiny real executable: the refusal ladder
     (miss / corrupt / version drift / foreign digest), each counted
@@ -147,6 +167,7 @@ class TestCompileCacheUnit:
         assert "failed to store" in capsys.readouterr().err
 
 
+@pytest.mark.usefixtures("no_jax_persistent_cache")
 class TestEngineCacheBoots:
     def _boot(self, cfg, recs, cache_dir, **kw):
         sink = CollectSink()
@@ -252,6 +273,7 @@ class TestEngineCacheBoots:
         assert sink1.blocked == sink2.blocked
 
 
+@pytest.mark.usefixtures("no_jax_persistent_cache")
 class TestTieredWarm:
     def test_partial_ladder_is_byte_identical(self, tmp_path):
         """The tiered warm's core promise: serving with ONLY the top

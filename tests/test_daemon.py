@@ -70,20 +70,31 @@ class TestShmTransport:
         assert stats["produced"] == 5000
         assert stats["dropped_ring_full"] == 0
 
-    def test_verdict_ring_blacklists_in_daemon(self, fsxd_bin, tmp_path):
-        """Verdicts written by Python suppress future daemon records."""
+    @pytest.mark.parametrize("ring_args,slots,writer", [
+        ((), 1 << 20, "raw"),        # the default: kVerdictRingSlots
+        (("--verdict-ring-capacity", "2"), 2, "sink"),  # < the 4 verdicts
+    ], ids=["default_ring", "ring_smaller_than_the_update"])
+    def test_verdict_ring_blacklists_in_daemon(self, fsxd_bin, tmp_path,
+                                               ring_args, slots, writer):
+        """Verdicts written by Python suppress future daemon records:
+        pushed raw into the default ring, and through the engine's sink
+        (which waits for room) into a ring they do not fit at once."""
         fring, vring = _rings(tmp_path)
         proc = subprocess.Popen(
-            [str(fsxd_bin), "--sim", "--duration", "6", "--rate", "2e5",
-             "--attack-ips", "4", "--attack-fraction", "0.9",
+            [str(fsxd_bin), "--sim", "--duration", "30", "--rate", "2e5",
+             "--attack-ips", "4", "--attack-fraction", "0.9", *ring_args,
              "--feature-ring", fring, "--verdict-ring", vring, "--seed", "5"],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
         )
         try:
-            from flowsentryx_tpu.engine.shm import ShmRing, ShmRingSource
+            from flowsentryx_tpu.engine.shm import (
+                ShmRing, ShmRingSource, ShmVerdictSink,
+            )
+            from flowsentryx_tpu.engine.writeback import BlacklistUpdate
 
             src = ShmRingSource(fring)
             vsink_ring = ShmRing.wait_for(vring, schema.VERDICT_RECORD_DTYPE)
+            assert vsink_ring.capacity == slots
 
             # identify attack sources from the first records, then "block"
             # them far into the sim future
@@ -100,24 +111,76 @@ class TestShmTransport:
             attackers = np.unique(rec["saddr"][rec["saddr"] < (1 << 24)])
             assert len(attackers) == 4
 
-            v = np.zeros(len(attackers), schema.VERDICT_RECORD_DTYPE)
-            v["saddr"] = attackers
-            v["until_ns"] = np.uint64(1 << 62)  # far future
-            assert vsink_ring.produce(v) == len(v)
+            if writer == "raw":
+                v = np.zeros(len(attackers), schema.VERDICT_RECORD_DTYPE)
+                v["saddr"] = attackers
+                v["until_ns"] = np.uint64(1 << 62)  # far future
+                assert vsink_ring.produce(v) == len(v)
+            else:
+                sink = ShmVerdictSink(vring)
+                sink.apply(BlacklistUpdate(
+                    key=attackers.astype(np.uint32),
+                    until_s=np.full(len(attackers), 4e9, np.float32)))
+                assert sink.dropped == 0 and sink.waits == 1
 
             # after the daemon ingests the verdicts, attack records stop
-            time.sleep(1.0)
-            src.poll(1 << 16)  # discard transition window
-            time.sleep(1.0)
-            tail = src.poll(1 << 16)
-            assert len(tail) > 0, "benign traffic should keep flowing"
-            assert not np.isin(tail["saddr"], attackers).any()
+            # while benign traffic keeps flowing: three polls in a row
+            # that hold records and no attacker's (what was already in
+            # the ring comes first; no fixed sleep stands for "by now")
+            clean = 0
+            deadline = time.monotonic() + 15
+            while clean < 3:
+                assert time.monotonic() < deadline, \
+                    "attack records kept arriving after the verdicts"
+                assert proc.poll() is None, "daemon ended before the check"
+                time.sleep(0.05)
+                tail = src.poll(1 << 16)
+                if len(tail):
+                    hit = np.isin(tail["saddr"], attackers).any()
+                    clean = 0 if hit else clean + 1
+            proc.terminate()
         finally:
             out, _ = proc.communicate(timeout=15)
         stats = json.loads(out)
         assert stats["verdicts"] == 4
         assert stats["blacklisted"] == 4
         assert stats["suppressed"] > 0
+
+
+    def test_a_burst_larger_than_the_old_ring_arrives_whole(self, fsxd_bin,
+                                                            tmp_path):
+        """(c) of ISSUE 32: more verdicts in one update than the 16,384
+        slots the ring used to have, written by the engine's sink while
+        the daemon serves: every one is counted by the daemon, none is
+        dropped, and the daemon's exit takes the ring to its last
+        verdict."""
+        from flowsentryx_tpu.engine.shm import ShmVerdictSink
+        from flowsentryx_tpu.engine.writeback import BlacklistUpdate
+
+        fring, vring = _rings(tmp_path)
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--pace", "--rate", "1000",
+             "--duration", "60",
+             "--feature-ring", fring, "--verdict-ring", vring],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+        n = 40_000
+        try:
+            sink = ShmVerdictSink(vring)
+            assert sink.ring.capacity == 1 << 20
+            sink.apply(BlacklistUpdate(
+                key=np.arange(1, n + 1, dtype=np.uint32),
+                until_s=np.full(n, 4e9, np.float32)))
+            assert sink.dropped == 0
+            proc.terminate()
+            out, _ = proc.communicate(timeout=15)
+        finally:
+            if proc.poll() is None:
+                proc.kill()
+                proc.communicate()
+        stats = json.loads(out)
+        assert stats["verdicts"] == n
+        assert stats["blacklisted"] == n
+        assert sink.ring.readable() == 0
 
 
 class TestEndToEnd:

@@ -1130,6 +1130,24 @@ class TestCompactReadback:
         assert rep_c.stats == rep_full.stats
         assert rep_c.readback["fallback_sinks"] > 0
 
+    @pytest.mark.parametrize("eng_kw", [
+        {}, {"mega_n": 4}, {"mega_n": 4, "sink_thread": False}],
+        ids=["singles", "mega4", "mega4_single_loop"])
+    def test_fallback_and_compact_count_every_sunk_batch_once(self, eng_kw):
+        """``fallback_sinks`` and ``compact_sinks`` are both in batches:
+        an overflowed mega entry counts each batch it carries, so the
+        two add up to the batches sunk (ISSUE 32: the fallback share
+        of sunk batches is read from them)."""
+        recs = self._recs(512 * 16)
+        rep, _ = self._run(recs, verdict_k=2, **eng_kw)
+        rb = rep.readback
+        assert rb["fallback_sinks"] > 0 and rb["compact_sinks"] > 0
+        assert rb["fallback_sinks"] + rb["compact_sinks"] == rep.batches == 16
+        if "mega_n" in eng_kw:
+            # whole entries of 4 fall back, never a part of one
+            assert rep.dispatch["group_hist"] == {"4": 4}
+            assert rb["fallback_sinks"] % 4 == 0
+
 
 class TestSinkThread:
     """The two-thread engine's failure/shutdown contract."""
