@@ -1,8 +1,19 @@
-"""Traffic driver ``sim_paced``: ``fsxd --sim --pace`` at a fixed offered
-rate -> sharded shm feature rings -> the engine's sealed-ingest path ->
-the shm verdict ring -> ``fsxd`` (which then suppresses blocked sources,
-as the kernel would).  Open loop: the daemon keeps to its schedule
-whatever the engine does.
+"""Traffic driver ``sim_paced``: ``fsxd --sim`` -> sharded shm feature
+rings -> the engine's sealed-ingest path -> the shm verdict ring ->
+``fsxd`` (which then suppresses blocked sources, as the kernel would).
+Two modes, by the cell's ``traffic.pace`` (default true):
+
+* open loop (``pace`` true): ``fsxd --sim --pace`` at a fixed offered
+  ``rate``; the daemon keeps to its schedule whatever the engine does,
+  and what a full ring cannot take is shed and counted.
+* closed loop (``pace`` false): ``fsxd --sim`` free-running against ring
+  backpressure.  ``rate`` is then each source's density in record time
+  (the daemon stamps records from its own counter), and
+  ``benchmark/governor.py`` holds the daemon once a ring holds
+  ``high_water`` records and lets it go once all hold under
+  ``low_water``: the fuller ring stays that full, nothing is shed, and
+  the engine is served as fast as it asks.  ``ring_capacity`` stays well
+  above ``high_water``: the room between is what a late poll may cost.
 
 The launch is ``chip_smoke.py:239-255`` with the order turned round: the
 engine is built and warmed first and the daemon started last, so that no
@@ -16,14 +27,16 @@ from __future__ import annotations
 import json
 import signal
 import subprocess
+import sys
 import time
 
 import numpy as np
 
-from benchmark import harness, reference, trafficgen
+from benchmark import governor, harness, reference, trafficgen
+from benchmark.governor import HDR_SIZE, HEAD_OFFSET, TAIL_OFFSET
 
-#: shm ring header (daemon/shm_ring.hpp): producer and consumer cursors
-HEAD_OFFSET, TAIL_OFFSET, HDR_SIZE = 64, 128, 192
+#: closed loop: how often the governor looks at the rings' cursors
+GOVERNOR_POLL_US = 500
 #: the sim generator's clock starts here (daemon/fsxd.cpp SimSource), so
 #: this is the stream epoch the ingest workers agree on
 SIM_T0_NS = 1_000_000_000
@@ -65,7 +78,7 @@ class Driver:
     def __init__(self, ctx):
         self.ctx = ctx
         self.p = ctx.cell["traffic"]
-        self.proc = None
+        self.proc = self.gov = None
         self.cursors: list[RingCursor] = []
         self.final: dict = {}
         self.prefilled = 0
@@ -97,7 +110,8 @@ class Driver:
         self.err = open(self.ctx.workdir / "fsxd.err", "w")
         self.t_start = time.perf_counter()
         self.proc = subprocess.Popen(
-            [str(self.fsxd), "--sim", "--shards", str(self.shards), "--pace",
+            [str(self.fsxd), "--sim", "--shards", str(self.shards),
+             *(["--pace"] if p.get("pace", True) else []),
              "--rate", str(p["rate"]), "--packets", str(1 << 40),
              "--attack-fraction", str(t["attack_fraction"]),
              "--attack-ips", str(t["attack_ips"]),
@@ -112,7 +126,24 @@ class Driver:
         paths = [schema.shard_ring_path(str(self.fring), k, self.shards)
                  for k in range(self.shards)]
         self.cursors = [RingCursor(path) for path in paths]
+        if not p.get("pace", True):
+            self.start_governor(paths)
         self.tap.wait_ready(60.0)
+
+    def start_governor(self, ring_paths) -> None:
+        """Closed loop: hold the free-running daemon while a ring holds
+        ``high_water`` records (``benchmark/governor.py``)."""
+        p = self.p
+        self.proc.send_signal(signal.SIGSTOP)  # held until the governor is up
+        self.gov_status = self.ctx.workdir / "governor.status"
+        self.gov = subprocess.Popen(
+            [sys.executable, governor.__file__, "--pid", str(self.proc.pid),
+             "--high-water", str(int(p["high_water"])),
+             "--low-water", str(int(p["low_water"])),
+             "--poll-us", str(GOVERNOR_POLL_US),
+             "--status", str(self.gov_status),
+             "--verdict-ring", str(self.vring), *map(str, ring_paths)],
+            stdout=subprocess.PIPE, text=True, start_new_session=True)
 
     def backlog(self) -> int:
         return sum(c.head() - c.tail() for c in self.cursors)
@@ -149,25 +180,41 @@ class Driver:
         eng.run(max_seconds=float(self.p["warmup_s"]))
 
     def counters(self) -> dict:
-        return {"forwarded": self.prefilled
-                + sum(c.head() for c in self.cursors),
-                "backlog": self.backlog(),
-                "dropped_ring_full": self.final.get("dropped_ring_full", 0)}
+        out = {"forwarded": self.prefilled
+               + sum(c.head() for c in self.cursors),
+               "backlog": self.backlog(),
+               "ring_fill": [c.head() - c.tail() for c in self.cursors],
+               "dropped_ring_full": self.final.get("dropped_ring_full", 0)}
+        if getattr(self, "gov", None):  # closed loop: held and alive, ns
+            out.update(self.final.get("governor")
+                       or governor.read_status(self.gov_status) or {})
+        return out
+
+    @staticmethod
+    def last_line_of(proc, timeout_s: float) -> dict:
+        """Tell a child to end, wait for it, and read its last line."""
+        proc.send_signal(signal.SIGTERM)
+        try:
+            out, _ = proc.communicate(timeout=timeout_s)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            out, _ = proc.communicate()
+        return json.loads(out.strip().splitlines()[-1]) if out.strip() else {}
 
     def stop(self) -> dict:
-        """End the daemon and read its last line."""
+        """End the governor, where there is one (it lets the daemon go),
+        then the daemon, and read their last lines."""
+        gov = None
+        if self.gov is not None:
+            gov = self.last_line_of(self.gov, 10)
+            self.proc.send_signal(signal.SIGCONT)
         self.t_stop = time.perf_counter()
-        self.proc.send_signal(signal.SIGTERM)
-        try:
-            out, _ = self.proc.communicate(timeout=30)
-        except subprocess.TimeoutExpired:
-            self.proc.kill()
-            out, _ = self.proc.communicate()
+        self.final = self.last_line_of(self.proc, 30)
         self.err.close()
-        line = out.strip().splitlines()[-1] if out.strip() else "{}"
-        self.final = json.loads(line)
         self.final["elapsed_s"] = self.t_stop - self.t_start
         self.final["rate"] = self.p["rate"]
+        if gov is not None:
+            self.final["governor"] = gov
         return self.final
 
     def drain(self, eng) -> None:
@@ -250,9 +297,10 @@ class Driver:
         }
 
     def close(self) -> None:
-        if self.proc is not None and self.proc.poll() is None:
-            self.proc.kill()
-            self.proc.wait()
+        for proc in (self.gov, self.proc):
+            if proc is not None and proc.poll() is None:
+                proc.kill()
+                proc.wait()
         self.tap.close()
 
     def dispatched(self, config: dict):

@@ -168,7 +168,9 @@ def run(args, bench, config, cell, driver, compiles, import_s) -> int:
     # of all of it is too large to reduce and slows the host); the
     # profiler is started once in set-up so that its start costs little
     # here, and stopped after the window's end so that its stop costs
-    # nothing.  The report-derived metrics cover the whole window.
+    # nothing: after the generator too, or an open-loop generator fills
+    # the rings and sheds while the profiler stops.  The report-derived
+    # metrics cover the whole window.
     traced = None
     if args.trace:
         slice_s = min(TRACE_SLICE_S, args.seconds)
@@ -180,6 +182,7 @@ def run(args, bench, config, cell, driver, compiles, import_s) -> int:
         t_started = time.perf_counter()
         rep = eng.run(max_seconds=slice_s)
         snap1 = snapshot(rep, driver, reaps, compiles)
+        gen_final = driver.stop()
         jax.profiler.stop_trace()
         traced = {"dir": trace_dir, "snap0": t0, "snap1": snap1}
         note({"trace_start_s": round(t_started - t0["t"], 3),
@@ -187,12 +190,12 @@ def run(args, bench, config, cell, driver, compiles, import_s) -> int:
     else:
         rep = eng.run(max_seconds=args.seconds)
         snap1 = snapshot(rep, driver, reaps, compiles)
+        gen_final = driver.stop()
     window_s = snap1["t"] - snap0["t"]
     mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
               for d in jax.local_devices())
 
     # -- after the window --------------------------------------------------
-    gen_final = driver.stop()
     driver.drain(eng)
     rep_end = eng.run(max_seconds=0.0)._asdict()
     if hasattr(source, "ingest_stats") and hasattr(source, "close"):
@@ -233,6 +236,8 @@ def run(args, bench, config, cell, driver, compiles, import_s) -> int:
           - snap0["rep"]["batches"],
           "generator": gen_final,
           "backlog": [snap0["gen"]["backlog"], snap1["gen"]["backlog"]],
+          "ring_fill": [snap0["gen"].get("ring_fill"),
+                        snap1["gen"].get("ring_fill")],
           "sunk_in_window": snap1["sunk"] - snap0["sunk"],
           "ring_wait_ms": ring_wait_ms(snap0, snap1, window_s),
           "dispatch_groups": rep_end["dispatch"]["group_hist"],

@@ -22,6 +22,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 FILE_CELL = "c3-offline-ml.file"
 RING_CELL = "c4-syn-mix.steady"
+CLOSED_CELL = "c4-syn-mix.saturate"  # the ring cell fed closed-loop
 
 
 def result(cmd: list[str]) -> dict:
@@ -37,7 +38,7 @@ def cell_args(cell: str, seed: int) -> list[str]:
             "--trace", "0", "--rehearse"]
 
 
-@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL])
+@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL, CLOSED_CELL])
 @pytest.mark.parametrize("seed", [11, 2147483659, 4000000007])
 def test_control_int4_is_not_correct(cell, seed):
     r = result(["benchmark/run.py", *cell_args(cell, seed),
@@ -48,7 +49,7 @@ def test_control_int4_is_not_correct(cell, seed):
             or c["counters_gap"]["value"] > c["counters_gap"]["limit"])
 
 
-@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL])
+@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL, CLOSED_CELL])
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "answer_altered"])
 def test_fault_is_not_correct(cell, fault):
@@ -65,7 +66,7 @@ def test_block_lost_before_the_ring_is_not_correct():
     assert r["compared"]["blocks_gap"]["value"] == 0
 
 
-@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL])
+@pytest.mark.parametrize("cell", [FILE_CELL, RING_CELL, CLOSED_CELL])
 def test_no_fault_is_correct(cell):
     r = result(["benchmark/tests/faulty_run.py", "none",
                 *cell_args(cell, 23)])
