@@ -135,6 +135,27 @@ def iter_eqns(jaxpr: Any, path: str = "") -> Iterator[tuple[str, Any]]:
                 yield from iter_eqns(sub, f"{where}/{pname}/")
 
 
+def iter_staged_eqns(jaxpr: Any, stage: str | None = None
+                     ) -> Iterator[tuple[str | None, Any]]:
+    """Depth-first ``(stage, eqn)`` walk like :func:`iter_eqns`, where
+    ``stage`` is the first ``fsx.<stage>`` ``jax.named_scope`` an
+    equation lies under (``None``: under none).  An equation's own
+    name stack starts afresh inside a sub-jaxpr (a ``jnp.where`` is a
+    ``jit`` of its own), so the enclosing equation's stage is handed
+    down: what the lowering does with the stacks, and what a device
+    trace then shows."""
+    if hasattr(jaxpr, "jaxpr"):
+        jaxpr = jaxpr.jaxpr
+    for eqn in jaxpr.eqns:
+        own = next((c[4:] for c in str(eqn.source_info.name_stack).split("/")
+                    if c.startswith("fsx.")), None)
+        inner = stage or own
+        yield inner, eqn
+        for pval in eqn.params.values():
+            for sub in _sub_jaxprs(pval):
+                yield from iter_staged_eqns(sub, inner)
+
+
 def _eqn_txt(eqn: Any, limit: int = 160) -> str:
     txt = " ".join(str(eqn).split())
     return txt if len(txt) <= limit else txt[: limit - 3] + "..."

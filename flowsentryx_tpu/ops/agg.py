@@ -20,7 +20,7 @@ Everything is fixed-shape, fuses under ``jit``, and shards cleanly.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +39,16 @@ INVALID_KEY = np.uint32(0xFFFFFFFF)
 class FlowAgg(NamedTuple):
     """Micro-batch aggregated by flow key.
 
-    ``rep_*`` arrays are ``[B]``-shaped with only the first ``n_flows``
-    entries meaningful (masked by ``rep_valid``); ``inv`` is ``[B]``
-    mapping each input packet position to its flow's segment index, so
-    per-flow decisions broadcast back to packets as ``decision[inv]``.
+    ``rep_*`` arrays are ``[B]``-shaped and ``rep_valid`` says which
+    entries are flows: that mask is the contract, not a position.
+    :func:`aggregate` packs its flows to the front in key order and
+    gives ``inv``, ``[B]`` mapping each input packet position to its
+    flow's entry, so per-flow decisions broadcast back to packets as
+    ``decision[inv]``.  The fused step
+    (:func:`flowsentryx_tpu.ops.fused.make_step`) leaves each flow at
+    the last position of its run in ITS sort's order, padding between,
+    and hands verdicts back along the runs itself: its ``inv`` is
+    ``None``.  Padding entries read ``INVALID_KEY`` and zeros.
     """
 
     rep_key: jnp.ndarray    # [B] uint32, INVALID_KEY padded
@@ -50,7 +56,7 @@ class FlowAgg(NamedTuple):
     rep_bytes: jnp.ndarray  # [B] f32: bytes of this flow in the batch
     rep_ts: jnp.ndarray     # [B] f32: newest timestamp of this flow
     rep_valid: jnp.ndarray  # [B] bool
-    inv: jnp.ndarray        # [B] int32: packet -> segment index
+    inv: Any                # [B] int32: packet -> entry (None: fused step)
 
 
 class KeySegments(NamedTuple):

@@ -71,20 +71,30 @@ class StepOutput(NamedTuple):
 ML_RECORD_GATE = 100
 
 
+def gate_record_verdicts(
+    per_pkt: jnp.ndarray,        # [B] int32 flow verdict of each record
+    mal: jnp.ndarray,            # [B] bool: record scored malicious
+    valid: jnp.ndarray,          # [B] bool
+) -> jnp.ndarray:
+    """Record verdicts from each record's flow verdict: the
+    :data:`ML_RECORD_GATE` sentinel is translated record by record,
+    and an invalid row passes."""
+    gated = per_pkt == ML_RECORD_GATE
+    per_pkt = jnp.where(
+        gated, jnp.where(mal, int(Verdict.DROP_ML), int(Verdict.PASS)),
+        per_pkt)
+    return jnp.where(valid, per_pkt, int(Verdict.PASS))
+
+
 def resolve_record_verdicts(
     flow_verdict: jnp.ndarray,   # [R] int32 (may carry ML_RECORD_GATE)
     inv: jnp.ndarray,            # [B] packet -> flow segment
     mal: jnp.ndarray,            # [B] bool: record scored malicious
     valid: jnp.ndarray,          # [B] bool
 ) -> jnp.ndarray:
-    """Broadcast flow verdicts to packets, translating the
-    :data:`ML_RECORD_GATE` sentinel per record."""
-    per_pkt = flow_verdict[inv]
-    gated = per_pkt == ML_RECORD_GATE
-    per_pkt = jnp.where(
-        gated, jnp.where(mal, int(Verdict.DROP_ML), int(Verdict.PASS)),
-        per_pkt)
-    return jnp.where(valid, per_pkt, int(Verdict.PASS))
+    """Broadcast flow verdicts to packets by ``inv`` (the two-stage
+    composition's form), then :func:`gate_record_verdicts`."""
+    return gate_record_verdicts(flow_verdict[inv], mal, valid)
 
 
 class FlowDecision(NamedTuple):
@@ -545,9 +555,77 @@ def merge_verdict_wires(wires: jnp.ndarray) -> jnp.ndarray:
 #: ``jax.named_scope("fsx.<stage>")`` in :func:`make_step` (``decode``
 #: also wraps the wire decode of the raw/compact steps, ``emit`` the
 #: megastep's wire merge; ``fsx.evict`` exists only where the aging
-#: sweep is compiled in).  The names reach the device trace as each
-#: operation's scope; the benchmark's ``step.stage_*`` metrics read them.
+#: sweep is compiled in).  ``aggregate`` holds the step's one sort by
+#: (slot-priority, key) and the scan over its runs; ``emit`` the sort
+#: that undoes it for the verdicts.  The names reach the device trace
+#: as each operation's scope; the benchmark's ``step.stage_*`` metrics
+#: read them.
 STEP_SCOPES = ("decode", "classify", "probe", "aggregate", "update", "emit")
+
+
+def scan_runs(head: jnp.ndarray, cols: jnp.ndarray, maxed: tuple
+              ) -> jnp.ndarray:
+    """Segmented inclusive scan along the rows of ``cols`` (``[K, B]``:
+    ``K`` columns of a batch whose runs are contiguous).  ``head``
+    (``[B]``) marks each run's first position; row ``k`` of the result
+    holds, at position ``i``, the maximum (where ``maxed[k]``) or the
+    sum of its column from the run's head to ``i`` — so the value at a
+    run's LAST position is the run's total.
+
+    ``ceil(log2 B)`` doubling steps of one shifted read each: after the
+    step of distance ``d`` a position holds its run's values over the
+    last ``2d`` positions, and ``reached`` says that window already
+    holds the run's head.  Positions ``i < d`` have always reached
+    (position 0 is a head), so what the rotation wraps round to them
+    is never read.  A sum only ever adds values of its own run, so it
+    rounds where the run's own total does — not at the batch's, as the
+    difference of two whole-batch ``f32`` prefix sums would (16,384
+    records of 1,500 B pass 2^24).  One stacked array, not an array a
+    column: every boot traces these steps for every program, and each
+    ``jnp`` call in them is a ``jit`` of its own to trace."""
+    b = head.shape[0]
+    pick_max = np.asarray(maxed)[:, None]
+
+    def rotated(x, d):
+        return jax.lax.concatenate(
+            [jax.lax.slice_in_dim(x, b - d, b, axis=1),
+             jax.lax.slice_in_dim(x, 0, b - d, axis=1)], 1)
+
+    reached = head[None, :]
+    d = 1
+    while d < b:
+        back = rotated(cols, d)
+        cols = jnp.where(reached, cols,
+                         jnp.where(pick_max, jnp.maximum(back, cols),
+                                   back + cols))
+        reached = reached | rotated(reached, d)
+        d *= 2
+    return cols
+
+
+#: Bits of a flow verdict code in :func:`spread_from_tails`' packed
+#: word (the codes are Verdict's four and ML_RECORD_GATE).
+_CODE_BITS = 7
+
+
+def spread_from_tails(tail: jnp.ndarray, code: jnp.ndarray) -> jnp.ndarray:
+    """Each position's run-tail ``code`` (``[B]`` int32 in
+    ``[0, 2^7)``): the flow verdict, which the fused step holds at a
+    run's last position, handed to every record of the run.
+
+    One reverse running minimum: a tail holds ``position << 7 | code``
+    and every other position the largest int32, so the minimum over
+    ``[i, B)`` is the nearest tail at or after ``i`` — ``i``'s own
+    run's, runs being contiguous — and its low bits are that tail's
+    code.  Integer, so exact."""
+    b = tail.shape[0]
+    if b << _CODE_BITS > np.iinfo(np.int32).max:
+        raise ValueError(f"batch of {b} records: position and verdict "
+                         "code do not fit one int32")
+    packed = jnp.where(
+        tail, (jnp.arange(b, dtype=jnp.int32) << _CODE_BITS) | code,
+        np.iinfo(np.int32).max)
+    return jax.lax.cummin(packed, reverse=True) & ((1 << _CODE_BITS) - 1)
 
 
 def make_step(
@@ -584,9 +662,21 @@ def make_step(
         # groupings at once: equal keys form contiguous runs (the
         # aggregation), and runs sharing a slot are adjacent with
         # found-first priority (the arbitration — the slot group's
-        # first run wins).  The sharded path keeps the two-stage
-        # composition (it aggregates before any table exists on the
-        # owner side); parity is pinned by tests/test_fused.py.
+        # first run wins).  The sort carries every per-record column
+        # the flows need as a payload, and a run is reduced where it
+        # lies, by a segmented scan (:func:`scan_runs`): the stage
+        # holds no gather and no scatter (on the chip one of either
+        # costs ten to twenty such sorts).  THE FLOWS LIVE AT THEIR
+        # RUNS' LAST POSITIONS, in run order, under ``rep_valid``; every
+        # other position is padding.  Nothing downstream wants them at the
+        # front: ``_flow_core`` is elementwise under its masks and
+        # ``compact_blocklist`` keeps order.  A record's verdict comes
+        # back the same way: spread over the run in sorted order
+        # (:func:`spread_from_tails`), then one sort keyed on the
+        # first sort's permutation puts it in the batch's order.  The
+        # sharded path keeps the two-stage composition (it aggregates
+        # before any table exists on the owner side); parity is pinned
+        # by tests/test_fused.py.
         # The ``fsx.<stage>`` scopes (STEP_SCOPES) are metadata only:
         # the compiled program is the same with or without them.
         b = batch.key.shape[0]
@@ -620,56 +710,60 @@ def make_step(
             slot, found, usable = pr.slot, pr.found, pr.usable
 
         with jax.named_scope("fsx.aggregate"):
-            # --- the one sort: (slot-priority, key), carrying iota ----
+            # --- the one sort: (slot-priority, key), carrying the
+            # record's length, its time, and one word of its place in
+            # the batch and its two flags (an operand costs the chip's
+            # compiler 2.4 s at 16,384 records).  Two keys and stable:
+            # records of one key keep the batch's order
             slot_pri = jnp.where(
                 usable, slot * 2 + (~found).astype(jnp.int32),
                 jnp.int32(2 * n))
-            iota = jnp.arange(b, dtype=jnp.int32)
-            sp_s, key_s, order = jax.lax.sort(
-                (slot_pri, key, iota), num_keys=2)
+            tag = (jnp.arange(b, dtype=jnp.int32) * 4
+                   + 2 * mal.astype(jnp.int32)
+                   + batch.valid.astype(jnp.int32))
+            sp_s, key_s, tag_s, len_s, ts_s = jax.lax.sort(
+                (slot_pri, key, tag, batch.pkt_len, batch.ts), num_keys=2)
+            order = tag_s >> 2
+            valid_s = (tag_s & 1) > 0
+            mal_s = (tag_s & 2) > 0
+            # probe_slots' three answers, read back off the sorted
+            # key: equal keys probed equal slots
+            usable_s = sp_s != 2 * n
+            found_s = usable_s & ((sp_s & 1) == 0)
+            slot_s = jnp.minimum(sp_s >> 1, n - 1)
 
-            key_head = jnp.concatenate(
-                [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
-            seg = (jnp.cumsum(key_head) - 1).astype(jnp.int32)
-            inv = jnp.zeros((b,), jnp.int32).at[order].set(seg)
-            sv = batch.valid[order]
-
-            def seg_sum(v):
-                return jax.ops.segment_sum(v, seg, num_segments=b)
-
-            def seg_max(v):
-                return jax.ops.segment_max(v, seg, num_segments=b)
-
-            pkts = seg_sum(sv.astype(jnp.float32))
-            bytes_ = seg_sum(jnp.where(sv, batch.pkt_len[order], 0.0))
-            ts_max = seg_max(jnp.where(sv, batch.ts[order], -jnp.inf))
-            ml_count = seg_sum(mal[order].astype(jnp.float32))
-            rep_key = seg_max(key_s)
-            rep_valid = pkts > 0
-            rep_key = jnp.where(rep_valid, rep_key, agg.INVALID_KEY)
-            ts_max = jnp.where(rep_valid, ts_max, 0.0)
-            rep_slot = seg_max(slot[order])
-            rep_found = seg_max(found[order].astype(jnp.int32)) > 0
-            rep_usable = seg_max(usable[order].astype(jnp.int32)) > 0
-
+            one = jnp.ones((1,), bool)
+            key_head = jnp.concatenate([one, key_s[1:] != key_s[:-1]])
+            tail = jnp.concatenate([key_head[1:], one])
             # arbitration: a flow wins iff its first packet opens its
             # slot group (the found-first bit in slot_pri already
             # ordered the groups; parked rows share slot_pri 2n but
-            # usable=False)
+            # usable=False).  Read at the run's head, so it rides the
+            # scan to the tail
             slot_head = jnp.concatenate(
-                [jnp.ones((1,), bool),
-                 (sp_s[1:] >> 1) != (sp_s[:-1] >> 1)])
-            rep_winner = seg_max(
-                (key_head & slot_head).astype(jnp.int32)) > 0
+                [one, (sp_s[1:] >> 1) != (sp_s[:-1] >> 1)])
+            runs = scan_runs(
+                key_head,
+                jnp.stack([valid_s.astype(jnp.float32),
+                           jnp.where(valid_s, len_s, 0.0),
+                           mal_s.astype(jnp.float32),
+                           jnp.where(valid_s, ts_s, -jnp.inf),
+                           (key_head & slot_head).astype(jnp.float32)]),
+                maxed=(False, False, False, True, True))
+            rep_valid = tail & (runs[0] > 0)
+            pkts, bytes_, ml_count, ts_max, won = jnp.where(
+                rep_valid, runs, 0.0)
+            rep_winner = won > 0
 
-            fa = agg.FlowAgg(rep_key=rep_key, rep_pkts=pkts,
-                             rep_bytes=bytes_, rep_ts=ts_max,
-                             rep_valid=rep_valid, inv=inv)
+            fa = agg.FlowAgg(
+                rep_key=jnp.where(rep_valid, key_s, agg.INVALID_KEY),
+                rep_pkts=pkts, rep_bytes=bytes_, rep_ts=ts_max,
+                rep_valid=rep_valid, inv=None)
             asg = hashtable.SlotAssignment(
-                slot=rep_slot,
-                found=rep_found & rep_winner,
-                inserted=rep_usable & ~rep_found & rep_winner,
-                tracked=rep_usable & rep_winner,
+                slot=slot_s,
+                found=found_s & rep_winner,
+                inserted=usable_s & ~found_s & rep_winner,
+                tracked=usable_s & rep_winner,
             )
         with jax.named_scope("fsx.update"):
             all_flows = jnp.ones_like(rep_valid)
@@ -677,8 +771,14 @@ def make_step(
                                         ml_count, now)
 
         with jax.named_scope("fsx.emit"):
-            verdict = resolve_record_verdicts(dec.flow_verdict, fa.inv,
-                                              mal, batch.valid)
+            # flow verdict -> its run's records (sorted order) ->
+            # the batch's order: ``order`` is a permutation, so a sort
+            # by it is the inverse of the first, and a Verdict code
+            # rides in the two bits under it
+            verdict_s = gate_record_verdicts(
+                spread_from_tails(tail, dec.flow_verdict), mal_s, valid_s)
+            verdict = jax.lax.sort(order * 4 + verdict_s,
+                                   is_stable=False) & 3
             new_stats = update_stats(stats, verdict, batch.valid)
             if n_evicted is not None:
                 from flowsentryx_tpu.core.schema import u64_add

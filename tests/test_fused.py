@@ -55,6 +55,35 @@ def make_env(cfg=CFG):
     return step, make_table(cfg.table.capacity), make_stats(), spec.init()
 
 
+def two_stage_step(cfg, spec, params):
+    """The aggregate → assign_slots → core composition the sharded path
+    still uses, as ``step(table, stats, batch) -> (table, stats,
+    verdict, block_key, block_until)``: the reference twin of
+    ``make_step``.  Its flows lie at the front in KEY order, so its
+    block arrays agree with the fused step's as sets, not by place."""
+    from flowsentryx_tpu.ops import agg
+
+    def step(table, stats, batch):
+        fa = agg.aggregate(batch.key, batch.pkt_len, batch.ts,
+                           batch.valid)
+        now = jnp.max(jnp.where(batch.valid, batch.ts, 0.0))
+        score = spec.classify_batch(params, batch.feat)
+        mal = (score > cfg.model.threshold) & batch.valid
+        ml_count = fused.ml_flow_count(cfg, score, batch.valid,
+                                       fa.inv)
+        all_flows = jnp.ones_like(fa.rep_valid)
+        table, dec = fused.flow_step(cfg, table, fa, all_flows,
+                                     ml_count, now)
+        verdict = fused.resolve_record_verdicts(
+            dec.flow_verdict, fa.inv, mal, batch.valid)
+        return (table, fused.update_stats(stats, verdict, batch.valid),
+                verdict,
+                jnp.where(dec.newly_blocked, fa.rep_key, agg.INVALID_KEY),
+                jnp.where(dec.newly_blocked, dec.new_blocked_until, 0.0))
+
+    return step
+
+
 class TestFusedStep:
     def test_benign_passes(self):
         step, table, stats, params = make_env()
@@ -202,32 +231,15 @@ class TestFusedStep:
         counts, and repeat batches against evolving table state."""
         import dataclasses
 
-        from flowsentryx_tpu.ops import agg as agg_mod
-        from flowsentryx_tpu.ops import fused as fused_mod
-
         cfg = dataclasses.replace(
             CFG, table=TableConfig(capacity=cap, probes=probes,
                                    stale_s=1e6, salt=salt))
         spec = get_model(cfg.model.name)
         params = spec.init()
-        step = fused_mod.make_jitted_step(cfg, spec.classify_batch,
-                                          donate=False)
+        step = fused.make_jitted_step(cfg, spec.classify_batch,
+                                      donate=False)
 
-        def legacy_step(table, stats, batch):
-            fa = agg_mod.aggregate(batch.key, batch.pkt_len, batch.ts,
-                                   batch.valid)
-            now = jnp.max(jnp.where(batch.valid, batch.ts, 0.0))
-            score = spec.classify_batch(params, batch.feat)
-            mal = (score > cfg.model.threshold) & batch.valid
-            ml_count = fused_mod.ml_flow_count(cfg, score, batch.valid,
-                                               fa.inv)
-            all_flows = jnp.ones_like(fa.rep_valid)
-            table, dec = fused_mod.flow_step(cfg, table, fa, all_flows,
-                                             ml_count, now)
-            verdict = fused_mod.resolve_record_verdicts(
-                dec.flow_verdict, fa.inv, mal, batch.valid)
-            return table, fused_mod.update_stats(stats, verdict,
-                                                 batch.valid), verdict
+        legacy_step = two_stage_step(cfg, spec, params)
 
         rng = np.random.default_rng(3)
         t1, s1 = make_table(cap), make_stats()
@@ -251,7 +263,7 @@ class TestFusedStep:
                 valid=jnp.asarray(rng.random(b) < 0.95),
             )
             t1, s1, out = step(t1, s1, params, batch)
-            t2, s2, v2 = legacy_step(t2, s2, batch)
+            t2, s2, v2, *_ = legacy_step(t2, s2, batch)
             np.testing.assert_array_equal(np.asarray(out.verdict),
                                           np.asarray(v2), f"batch {i}")
             for a, c in zip(s1, s2):
@@ -705,3 +717,294 @@ class TestStepNeverTakesATableColumn:
             lambda table, slots: table.last_seen[slots],
             make_table(self.CAP), jnp.zeros((256, 8), jnp.int32))
         assert used - {"gather"}  # slice/squeeze of the whole column
+
+
+class TestFlowsAtRunTails:
+    """ISSUE 36: the fused step reduces each run where the sort left it
+    and keeps the flows at their runs' last positions.  Held against
+    the two-stage composition (:func:`two_stage_step`) on the batches
+    that lean on each property of the new form, and by the jaxpr."""
+
+    LIM = LimiterConfig(pps_threshold=8.0, bps_threshold=1e9, block_s=10.0)
+    ROOMY = TableConfig(capacity=1 << 12, probes=8, stale_s=1e6,
+                        salt=0xA5A5)
+    #: two probes a key in sixteen rows: every insert is contested
+    TINY = TableConfig(capacity=16, probes=2, stale_s=1e6, salt=0xBEEF)
+    #: the same, with rows that go stale between two batches
+    TINY_STALE = TableConfig(capacity=16, probes=2, stale_s=1.0,
+                             salt=0xBEEF)
+
+    SCENARIOS = ["one_key", "all_distinct", "mixed_runs",
+                 "invalid_interleaved", "key_zero", "new_keys_contest",
+                 "found_and_new_share", "full_table", "small_verdict_k"]
+
+    _steps: dict = {}
+
+    @classmethod
+    def _env(cls, tcfg, verdict_k, b):
+        """(cfg, jitted fused step, jitted two-stage step, params), one
+        compile a (table, verdict_k, batch) shape."""
+        key = (tcfg, verdict_k, b)
+        if key not in cls._steps:
+            cfg = FsxConfig(limiter=cls.LIM, table=tcfg, model=CFG.model,
+                            batch=BatchConfig(max_batch=b,
+                                              verdict_k=verdict_k))
+            spec = get_model(cfg.model.name)
+            params = spec.init()
+            cls._steps[key] = (
+                cfg,
+                fused.make_jitted_step(cfg, spec.classify_batch,
+                                       donate=False),
+                jax.jit(two_stage_step(cfg, spec, params)), params)
+        return cls._steps[key]
+
+    @staticmethod
+    def _batch(rng, keys, t0, valid=None):
+        keys = np.asarray(keys, np.uint32)
+        b = len(keys)
+        return FeatureBatch(
+            key=jnp.asarray(keys),
+            feat=jnp.asarray(rng.uniform(0, 3e6, (b, 8)).astype(np.float32)),
+            pkt_len=jnp.asarray(
+                rng.integers(64, 1500, b).astype(np.float32)),
+            # NOT sorted: a run's newest record may be any of its records
+            ts=jnp.asarray(rng.uniform(t0, t0 + 0.5, b).astype(np.float32)),
+            valid=jnp.asarray(np.ones(b, bool) if valid is None else valid))
+
+    @staticmethod
+    def _probe(tcfg, table, keys, now):
+        from flowsentryx_tpu.ops import hashtable
+
+        keys = jnp.asarray(np.asarray(keys, np.uint32))
+        pr = hashtable.probe_slots(table, keys, jnp.ones(keys.shape, bool),
+                                   jnp.float32(now), tcfg)
+        return (np.asarray(pr.slot), np.asarray(pr.found),
+                np.asarray(pr.usable))
+
+    def _scenario(self, name, b, rng):
+        """(table config, verdict_k, [(keys, valid, t0), ...])."""
+        pad = lambda k: np.resize(np.asarray(k, np.uint32), b)  # noqa: E731
+        if name == "one_key":
+            return self.ROOMY, 64, [(np.full(b, 77), None, 0.0),
+                                    (np.full(b, 77), None, 0.6)]
+        if name == "all_distinct":
+            k = rng.permutation(b) + 1000
+            return self.ROOMY, 64, [(k, None, 0.0), (k[::-1], None, 0.6)]
+        if name == "mixed_runs":
+            k = np.repeat(np.arange(5000, 5000 + b),
+                          rng.integers(1, 40, b))[:b]
+            return self.ROOMY, 64, [(rng.permutation(k), None, 0.0),
+                                    (rng.permutation(k), None, 0.6)]
+        if name == "invalid_interleaved":
+            k = rng.integers(1, b // 4, b)
+            return self.ROOMY, 64, [(k, rng.random(b) < 0.6, 0.0),
+                                    (k, rng.random(b) < 0.3, 0.6)]
+        if name == "key_zero":
+            # 0 is remapped to 0xFFFFFFFE and shares its flow
+            k = rng.choice([0, 0xFFFFFFFE, 5, 6, 7], b)
+            return self.ROOMY, 64, [(k, rng.random(b) < 0.9, 0.0),
+                                    (k, None, 0.6)]
+        if name == "new_keys_contest":
+            # keys whose first probe is the same row of an empty table
+            cand = np.arange(1, 400)
+            slot, _, _ = self._probe(self.TINY, make_table(16), cand, 0.0)
+            groups = [cand[slot == s] for s in range(16)]
+            assert sum(len(g) >= 2 for g in groups) >= 8
+            k = np.concatenate([g[:3] for g in groups])
+            return self.TINY, 64, [(rng.permutation(pad(k)), None, 0.0)]
+        if name == "found_and_new_share":
+            # batch 1 fills the sixteen rows; 100 s on every row is
+            # stale, so a row's owner (found) and a newcomer (its
+            # reclaimer) pick the same row, and the owner must win
+            return self.TINY_STALE, 64, [
+                (pad(np.arange(1, 121)), None, 0.0),
+                (rng.permutation(pad(np.arange(1, 241))), None, 100.0)]
+        if name == "full_table":
+            return self.TINY, 64, [
+                (pad(np.arange(1, 121)), None, 0.0),
+                (rng.permutation(pad(np.arange(200, 260))), None, 0.6)]
+        if name == "small_verdict_k":
+            # runs of 10 records pass 8 pps: about b // 10 blocks, k = 4
+            k = np.repeat(np.arange(9000, 9000 + b // 10), 10)
+            return self.ROOMY, 4, [(rng.permutation(pad(k)), None, 0.0)]
+        raise KeyError(name)
+
+    def _expected_order(self, cfg, table, block_key, now):
+        """The fused step's flows lie in the order of its one sort:
+        (slot-priority, key)."""
+        keys = block_key[block_key != 0xFFFFFFFF]
+        slot, found, usable = self._probe(cfg.table, table, keys, now)
+        pri = np.where(usable, slot.astype(np.int64) * 2 + ~found,
+                       2 * cfg.table.capacity)
+        return keys[np.lexsort((keys, pri))]
+
+    def _check_batch(self, cfg, table, out, ref, note):
+        """One batch's outputs of the fused step against the two-stage
+        reference's; returns the blocks in the fused step's order."""
+        t1, s1 = out[0], out[1]
+        t2, s2, v2, bk2, bu2 = ref
+        o = out[2]
+        np.testing.assert_array_equal(np.asarray(o.verdict),
+                                      np.asarray(v2), note)
+        for a, c in zip(s1, s2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(c), note)
+        np.testing.assert_array_equal(np.asarray(t1.key), np.asarray(t2.key),
+                                      note)
+        np.testing.assert_allclose(np.asarray(t1.state),
+                                   np.asarray(t2.state), rtol=1e-6,
+                                   err_msg=note)
+        bk1, bu1 = np.asarray(o.block_key), np.asarray(o.block_until)
+        bk2, bu2 = np.asarray(bk2), np.asarray(bu2)
+        hit1, hit2 = bk1 != 0xFFFFFFFF, bk2 != 0xFFFFFFFF
+        assert sorted(zip(bk1[hit1], bu1[hit1])) \
+            == sorted(zip(bk2[hit2], bu2[hit2])), note
+        assert (bu1[~hit1] == 0).all(), note
+        np.testing.assert_array_equal(
+            bk1[hit1], self._expected_order(cfg, table, bk2, float(o.now)),
+            note)
+        return bk1[hit1], bu1[hit1]
+
+    @staticmethod
+    def _check_wire(wire, k, keys, untils, now, note):
+        wire = np.asarray(wire)
+        want = np.full(k, 0xFFFFFFFF, np.uint32)
+        want[:min(k, len(keys))] = keys[:k]
+        np.testing.assert_array_equal(wire[:k], want, note)
+        np.testing.assert_array_equal(
+            wire[k:2 * k].view(np.float32)[:min(k, len(keys))],
+            untils[:k], note)
+        assert wire[2 * k] == len(keys), note
+        assert wire[2 * k + 1] == (len(keys) > k), note
+        assert wire[2 * k + 3:].view(np.float32)[0] == now, note
+
+    @pytest.mark.parametrize("b", [256, 2048])
+    @pytest.mark.parametrize("name", SCENARIOS)
+    def test_step_matches_two_stage_composition(self, name, b):
+        rng = np.random.default_rng([b, self.SCENARIOS.index(name)])
+        tcfg, k, batches = self._scenario(name, b, rng)
+        cfg, step, ref_step, params = self._env(tcfg, k, b)
+        t1, s1 = make_table(tcfg.capacity), make_stats()
+        t2, s2 = make_table(tcfg.capacity), make_stats()
+        blocks = 0
+        for i, (keys, valid, t0) in enumerate(batches):
+            batch = self._batch(rng, keys, t0, valid)
+            before = t1
+            t1, s1, out = step(t1, s1, params, batch)
+            ref = ref_step(t2, s2, batch)
+            t2, s2 = ref[0], ref[1]
+            bk, bu = self._check_batch(cfg, before, (t1, s1, out), ref,
+                                       f"{name} batch {i}")
+            self._check_wire(out.wire, k, bk, bu, float(out.now),
+                             f"{name} batch {i}")
+            blocks += len(bk)
+        if name == "small_verdict_k":
+            assert blocks > 4 * k
+            assert np.asarray(out.wire)[2 * k + 1] == 1
+        if name in ("one_key", "mixed_runs"):
+            assert blocks > 0
+        if name in ("found_and_new_share", "full_table"):
+            # the second batch met a table with no empty row
+            assert (np.asarray(before.key) != 0).all()
+
+    def test_the_shared_row_goes_to_its_owner(self):
+        """What ``found_and_new_share`` is there for, spelled out: of a
+        stale row's owner and a newcomer that would reclaim it, in one
+        batch, the owner keeps the row."""
+        b = 256
+        cfg, step, _, params = self._env(self.TINY_STALE, 64, b)
+        rng = np.random.default_rng(5)
+        table, stats, _ = step(make_table(16), make_stats(), params,
+                               self._batch(rng, np.resize(
+                                   np.arange(1, 121, dtype=np.uint32), b),
+                                   0.0))
+        owners = np.asarray(table.key)
+        assert (owners != 0).all()
+        cand = np.arange(121, 400)
+        slot, found, usable = self._probe(cfg.table, table, cand, 100.0)
+        assert usable.all() and not found.any()  # every row is stale
+        new = cand[:8]
+        keys = np.resize(np.concatenate([owners[slot[:8]], new]), b)
+        t2, _, _ = step(table, stats, params,
+                        self._batch(rng, keys.astype(np.uint32), 100.0))
+        np.testing.assert_array_equal(np.asarray(t2.key), owners)
+
+    @pytest.mark.parametrize("b", [256, 2048])
+    def test_megastep_over_eight_such_batches(self, b):
+        """The scan of the step over a stacked group, a scenario a
+        chunk: verdicts, table, stats, each chunk's fallback arrays and
+        the one merged wire against eight two-stage steps."""
+        from flowsentryx_tpu.core import schema
+
+        k = 4
+        cfg = FsxConfig(limiter=self.LIM, table=self.ROOMY, model=CFG.model,
+                        batch=BatchConfig(max_batch=b, verdict_k=k))
+        spec = get_model(cfg.model.name)
+        params = spec.init()
+        quant = schema.wire_quant_for(params)
+        mega = fused.make_jitted_compact_megastep(
+            cfg, spec.classify_batch, n_chunks=8, donate=False, **quant)
+        ref_step = jax.jit(two_stage_step(cfg, spec, params))
+        rng = np.random.default_rng(b)
+        raws = []
+        for i, name in enumerate(self.SCENARIOS[:5] + ["small_verdict_k",
+                                                      "mixed_runs",
+                                                      "one_key"]):
+            keys, valid, _ = self._scenario(name, b, rng)[2][0]
+            n = b if valid is None else int(b * 0.7)  # a part-filled chunk
+            buf = np.zeros(b, dtype=schema.FLOW_RECORD_DTYPE)
+            buf["saddr"] = np.asarray(keys, np.uint32)
+            buf["pkt_len"] = rng.integers(64, 1500, b)
+            buf["ts_ns"] = (i * b + rng.permutation(b)) * 20_000
+            buf["feat"] = rng.integers(0, 1 << 22, (b, 8))
+            raws.append(schema.encode_compact(buf[:n], b, t0_ns=0, **quant))
+        t1, s1, outs = mega(make_table(cfg.table.capacity), make_stats(),
+                            params, jnp.asarray(np.stack(raws)))
+        t2, s2 = make_table(cfg.table.capacity), make_stats()
+        keys, untils = [], []
+        for i, raw in enumerate(raws):
+            before = t2
+            ref = ref_step(t2, s2, schema.decode_compact(raw, **quant))
+            t2, s2 = ref[0], ref[1]
+            one = jax.tree.map(lambda a: a[i], outs._replace(wire=None))
+            bk, bu = self._check_batch(
+                cfg, before, (t2, s2, one), ref, f"chunk {i}")
+            keys.append(bk[:k])  # a chunk's own wire holds k
+            untils.append(bu[:k])
+        np.testing.assert_array_equal(np.asarray(t1.key), np.asarray(t2.key))
+        np.testing.assert_allclose(np.asarray(t1.state),
+                                   np.asarray(t2.state), rtol=1e-6)
+        for a, c in zip(s1, s2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        wire = np.asarray(outs.wire)
+        keys, untils = np.concatenate(keys), np.concatenate(untils)
+        total = int((np.asarray(outs.block_key) != 0xFFFFFFFF).sum())
+        np.testing.assert_array_equal(wire[:k], keys[:k])
+        np.testing.assert_array_equal(wire[k:2 * k].view(np.float32),
+                                      untils[:k])
+        assert wire[2 * k] == total > k and wire[2 * k + 1] == 1
+
+    @pytest.mark.parametrize("kind", list(LimiterKind),
+                             ids=lambda k: k.value)
+    def test_aggregate_holds_no_gather_and_no_scatter(self, kind):
+        """Beside PR 30's guard: what the stage was (six gathers, nine
+        segment scatters and one ``.at[order].set`` behind a sort that
+        had already made every run contiguous) cannot come back, and
+        the step pays for exactly one sort more than it did: the one
+        that puts the verdicts back in the batch's order."""
+        from flowsentryx_tpu.audit.graph import iter_staged_eqns
+
+        cfg = FsxConfig(limiter=LimiterConfig(kind=kind),
+                        table=TableConfig(capacity=1 << 12, probes=8),
+                        batch=BatchConfig(max_batch=256, verdict_k=64))
+        spec = get_model(cfg.model.name)
+        staged = [(stage, eqn.primitive.name) for stage, eqn in
+                  iter_staged_eqns(jax.make_jaxpr(
+                      fused.make_step(cfg, spec.classify_batch))(
+                          make_table(1 << 12), make_stats(), spec.init(),
+                          build_batch([(1001, 5, 100, 0.1, ML_COLD)])))]
+        in_aggregate = {p for stage, p in staged if stage == "aggregate"}
+        assert "sort" in in_aggregate
+        assert not {p for p in in_aggregate
+                    if p.startswith(("gather", "scatter", "dynamic"))}
+        assert [stage for stage, p in staged if p == "sort"] \
+            == ["aggregate", "emit"]

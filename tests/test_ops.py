@@ -215,6 +215,76 @@ class TestAggregate:
         assert float(fa.rep_pkts[i]) == b
 
 
+class TestRunScans:
+    """The two passes the fused step makes over its sorted runs
+    (``ops/fused.py``), against a loop."""
+
+    @pytest.mark.parametrize("b,head_share", [
+        (1, 1.0), (2, 0.5), (255, 0.3), (256, 0.0), (256, 1.0),
+        (1000, 0.05), (2048, 0.6)])
+    def test_scan_runs_against_a_loop(self, b, head_share):
+        from flowsentryx_tpu.ops import fused
+
+        rng = np.random.default_rng([b, int(head_share * 100)])
+        head = rng.random(b) < head_share
+        head[0] = True
+        add = rng.integers(0, 1500, b).astype(np.float32)
+        mx = rng.normal(size=b).astype(np.float32)
+        mx[rng.random(b) < 0.2] = -np.inf  # an invalid record's time
+        got_add, got_max = fused.scan_runs(
+            jnp.asarray(head), jnp.asarray(np.stack([add, mx])),
+            maxed=(False, True))
+        want_add, want_max = add.copy(), mx.copy()
+        for i in range(1, b):
+            if not head[i]:
+                want_add[i] += want_add[i - 1]
+                want_max[i] = max(want_max[i], want_max[i - 1])
+        # whole numbers under 2^24: exact in any order of addition
+        np.testing.assert_array_equal(np.asarray(got_add), want_add)
+        np.testing.assert_array_equal(np.asarray(got_max), want_max)
+
+    def test_a_sum_rounds_at_its_run_not_at_the_batch(self):
+        """Why the runs are not read off two whole-batch prefix sums:
+        behind 2^24 of other runs a run of three halves still sums to
+        1.5."""
+        from flowsentryx_tpu.ops import fused
+
+        col = np.array([2.0 ** 24, 2.0 ** 24, 0.5, 0.5, 0.5], np.float32)
+        head = np.array([True, False, True, False, False])
+        (got,) = fused.scan_runs(jnp.asarray(head), jnp.asarray(col)[None],
+                                 maxed=(False,))
+        assert float(got[-1]) == 1.5
+        prefix = np.cumsum(col, dtype=np.float32)
+        assert float(prefix[-1] - prefix[1]) != 1.5
+
+    @pytest.mark.parametrize("b,tail_share", [
+        (1, 1.0), (2, 0.5), (255, 0.3), (256, 0.0), (256, 1.0),
+        (2048, 0.6)])
+    def test_spread_from_tails_against_a_loop(self, b, tail_share):
+        from flowsentryx_tpu.ops import fused
+
+        rng = np.random.default_rng([b, int(tail_share * 100)])
+        tail = rng.random(b) < tail_share
+        tail[-1] = True
+        code = rng.choice([0, 1, 2, 3, fused.ML_RECORD_GATE], b).astype(
+            np.int32)
+        got = np.asarray(fused.spread_from_tails(jnp.asarray(tail),
+                                                 jnp.asarray(code)))
+        want = code.copy()
+        for i in range(b - 2, -1, -1):
+            if not tail[i]:
+                want[i] = want[i + 1]
+        np.testing.assert_array_equal(got, want)
+
+    def test_spread_from_tails_refuses_a_batch_its_word_cannot_hold(self):
+        from flowsentryx_tpu.ops import fused
+
+        with pytest.raises(ValueError, match="do not fit"):
+            fused.spread_from_tails(np.zeros((1 << 24) + 1, bool),
+                                    np.zeros(1, np.int32))
+        assert fused.ML_RECORD_GATE < 1 << fused._CODE_BITS
+
+
 class TestHashTable:
     CFG4 = TableConfig(capacity=1 << 10, probes=4, stale_s=30.0)
 

@@ -300,22 +300,43 @@ class TestHostSpansInTheTrace:
 
 
 class TestStepScopes:
-    @pytest.mark.parametrize("program", ["compact_step", "mega_rung"])
-    def test_every_stage_is_named_in_the_lowered_step(self, program):
+    @staticmethod
+    def _program(program):
+        """(engine, one of its step programs, an argument for it)."""
         eng = Engine(small_cfg(), ArraySource(flood(8)), NullSink(),
                      mega_n="auto", sink_thread=False)
-        words = schema.COMPACT_RECORD_WORDS
-        raw = np.zeros((257, words), np.uint32)
+        raw = np.zeros((257, schema.COMPACT_RECORD_WORDS), np.uint32)
         if program == "compact_step":
-            fn, arg = eng.step, raw
-        else:
-            g = max(eng.megasteps)
-            fn, arg = eng.megasteps[g], np.stack([raw] * g)
+            return eng, eng.step, raw
+        g = max(eng.megasteps)
+        return eng, eng.megasteps[g], np.stack([raw] * g)
+
+    @pytest.mark.parametrize("program", ["compact_step", "mega_rung"])
+    def test_every_stage_is_named_in_the_lowered_step(self, program):
+        eng, fn, arg = self._program(program)
         text = fn.lower(eng.table, eng.stats, eng.params, arg).as_text(
             debug_info=True)
         for stage in fused.STEP_SCOPES:
             assert f"fsx.{stage}" in text, stage
         assert "fsx.evict" not in text  # aging is off in this config
+
+    @pytest.mark.parametrize("program,outside", [
+        ("compact_step", ["jit"]), ("mega_rung", ["jit", "scan"])])
+    def test_every_operation_lies_under_a_stage(self, program, outside):
+        """``step.stage_unscoped_ms.tput`` is what the step spends under
+        no ``fsx.<stage>``: an operation a change adds outside the
+        scopes lands there and the stage table stops adding up to
+        anything one can name (ISSUE 36).  Outside them lie the
+        program's own ``jit`` and the megastep's ``scan``, as before."""
+        from flowsentryx_tpu.audit.graph import iter_staged_eqns
+
+        eng, fn, arg = self._program(program)
+        staged = list(iter_staged_eqns(jax.make_jaxpr(fn)(
+            eng.table, eng.stats, eng.params, arg)))
+        assert [eqn.primitive.name for stage, eqn in staged
+                if stage is None] == outside
+        assert {stage for stage, _ in staged} - {None} \
+            == set(fused.STEP_SCOPES)
 
 
 class TestVerdictRingDropped:
