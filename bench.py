@@ -308,70 +308,11 @@ def phase_throughput(side: Progress, deadline_rel: float) -> dict:
             log(f"mega chunk (N={n_mega}): {mpps:.2f} Mpps")
         return chunks
 
-    def run_devloop_tier(ring: int, n_mega: int, max_rounds: int) -> list:
-        """Drain-ring chunks: ``ring`` arena slots of ``n_mega``
-        batches per deep-scan dispatch (fused/device_loop.py) — the
-        device consumes a whole staging ring per host round-trip, so
-        the per-dispatch fixed cost is paid once per ``ring * n_mega``
-        batches and the next round's slots upload while the current
-        computes."""
-        from flowsentryx_tpu.engine.arena import DispatchArena
-        from flowsentryx_tpu.fused import device_loop as _dl
-        from flowsentryx_tpu.models import get_model
-
-        nonlocal table, stats
-        spec = get_model(cfg.model.name)
-        quant_m = schema.model_quant_args(params)
-        loop = _dl.make_compact_device_loop(
-            cfg, spec.classify_batch, ring, n_mega, donate=True,
-            **quant_m)
-        arena = DispatchArena(slots=2 * ring + 2, group_max=n_mega,
-                              max_batch=cfg.batch.max_batch,
-                              words=schema.COMPACT_RECORD_WORDS)
-
-        def stage_round(r0: int) -> list:
-            slots = []
-            for r in range(ring):
-                rows = arena.rows(arena.claim())
-                for i in range(n_mega):
-                    rows[i][...] = raws[(r0 + r * n_mega + i) % len(raws)]
-                slots.append(jax.device_put(rows[:n_mega]))
-            return slots
-
-        t0 = time.perf_counter()
-        table, stats, outs = loop(table, stats, params, *stage_round(0))
-        jax.block_until_ready(outs.wire)
-        side.emit("devloop_compile", ring=ring, n=n_mega,
-                  s=round(time.perf_counter() - t0, 1))
-        per_round = ring * n_mega
-        chunks: list = []
-        rk = 0
-        riters = max(2, min(12, int(5.0 / max(per_iter * per_round,
-                                              1e-6))))
-        while len(chunks) < max_rounds:
-            if time.perf_counter() + riters * per_iter * per_round * 2 \
-                    + reserve > deadline:
-                break
-            t0 = time.perf_counter()
-            for _ in range(riters):
-                table, stats, outs = loop(table, stats, params,
-                                          *stage_round(rk * per_round))
-                rk += 1
-            jax.block_until_ready(outs.wire)
-            dt = time.perf_counter() - t0
-            mpps = riters * per_round * B / dt / 1e6
-            chunks.append(round(mpps, 2))
-            side.emit("devloop_chunk", ring=ring, n=n_mega,
-                      mpps=round(mpps, 2), iters=riters)
-            log(f"devloop chunk ({ring}x{n_mega}): {mpps:.2f} Mpps")
-        return chunks
-
     def _finalize(res: dict) -> None:
         """Fold chunk series into the headline fields.  mega_chunk_mpps
-        is ALWAYS the N=8 series, mega32_chunk_mpps always N=32, and
-        devloop_chunk_mpps always the 2x8 drain ring — keys never
-        change meaning across rounds; dispatch_mode records which mode
-        won the headline."""
+        is ALWAYS the N=8 series and mega32_chunk_mpps always N=32 —
+        keys never change meaning across rounds; dispatch_mode records
+        which mode won the headline."""
         steady_ = res["chunk_mpps"][1:] or res["chunk_mpps"]
         res["single_mpps"] = float(np.median(steady_))
         res["mpps"] = res["single_mpps"]
@@ -379,8 +320,7 @@ def phase_throughput(side: Progress, deadline_rel: float) -> dict:
         res.pop("dispatch_mode", None)
         res.pop("mega_mpps", None)
         for key, label in (("mega_chunk_mpps", "mega8"),
-                           ("mega32_chunk_mpps", "mega32"),
-                           ("devloop_chunk_mpps", "devloop2x8")):
+                           ("mega32_chunk_mpps", "mega32")):
             chunks_ = res.get(key) or []
             if not chunks_:
                 continue
@@ -407,14 +347,6 @@ def phase_throughput(side: Progress, deadline_rel: float) -> dict:
             m32 = run_mega_tier(32, 4)
             if m32:
                 result["mega32_chunk_mpps"] = m32
-        if m8 and time.perf_counter() + 40 < deadline:
-            # the drain ring rides the same amortization curve one
-            # level up (unbounded compile: log a result first)
-            _finalize(result)
-            side.emit("result", **result)
-            dl8 = run_devloop_tier(2, MEGA_N, 4)
-            if dl8:
-                result["devloop_chunk_mpps"] = dl8
 
     # Median over steady-state chunks (exclude the probe when real
     # chunks exist: the probe is tiny and noisy).  The max chunk is
@@ -857,8 +789,7 @@ def main() -> int:
         )
         for k in ("h2d_mbps", "device_mpps", "burst_mpps",
                   "single_mpps", "mega_mpps", "mega_chunk_mpps",
-                  "mega32_chunk_mpps", "devloop_chunk_mpps",
-                  "dispatch_mode"):
+                  "mega32_chunk_mpps", "dispatch_mode"):
             if k in tput:
                 detail[k] = tput[k]
         log(f"throughput: {mpps:.2f} Mpps median over {tput.get('chunk_mpps')}")

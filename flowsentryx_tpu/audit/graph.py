@@ -255,12 +255,11 @@ def check_collectives(closed_jaxpr: Any, verdict_k: int,
       ``psum``/``pmax`` reductions — nothing may gather or reduce a
       ``[B]``-shaped per-record array across the mesh.
 
-    The budget holds for EVERY staged variant, the device-loop ring
-    included: megasteps and the ring are (nested) ``lax.scan``\\s, and
-    a scan stages its body jaxpr once — so the graph text carries the
-    designed per-step collective set exactly once regardless of group
-    size or ring depth (test-pinned by the sharded_device_loop audit
-    acceptance)."""
+    The budget holds for EVERY staged variant: a megastep is a
+    ``lax.scan``, and a scan stages its body jaxpr once — so the graph
+    text carries the designed per-step collective set exactly once
+    regardless of group size (test-pinned by the sharded_megastep
+    audit acceptance)."""
     findings: list[Finding] = []
     counts: dict[str, int] = {}
     for where, eqn in iter_eqns(closed_jaxpr):

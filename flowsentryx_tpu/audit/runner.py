@@ -42,15 +42,8 @@ CARRY_NAMES = ["table.key", "table.state"] + [
 #: scan-over-shard_map graph a mesh+mega engine actually serves — its
 #: contracts are NOT implied by sharded and megastep separately (the
 #: scan could drop the table donation or add a collective of its own).
-#: "device_loop"/"sharded_device_loop" are the drain-ring deep scans
-#: (fused/device_loop.py) a ``--device-loop N`` engine serves — again
-#: their own compiled artifacts: the nested scan carries table/stats
-#: across a whole ring round and its wire output is ``[R, 2K+4]``
-#: (one merged wire per slot), both of which must be proved on THAT
-#: graph, not inferred from the megastep's.
 ALL_VARIANTS = ("raw", "compact", "sharded", "megastep",
-                "sharded_megastep", "device_loop",
-                "sharded_device_loop")
+                "sharded_megastep")
 
 
 @dataclasses.dataclass
@@ -136,7 +129,6 @@ def _audit_one(
     donate_leaves: int,
     quantized: bool,
     n_param_leaves: int,
-    ring_depth: int = 0,
     n_shards: int = 1,
 ) -> VariantReport:
     """Stage one variant and run every contract on it."""
@@ -170,22 +162,8 @@ def _audit_one(
                         "dtype": str(np.dtype(leaf.dtype)),
                         "bytes": int(nbytes)})
         if n.endswith(".wire"):
-            shape = tuple(int(s) for s in leaf.shape)
-            if ring_depth:
-                # the ring's wire output is [R, 2K+4]: ONE merged wire
-                # PER SLOT — reported and pinned per slot (the round's
-                # total D2H is ring * that, fetched as one buffer)
-                if len(shape) != 2 or shape[0] != ring_depth:
-                    findings.append(Finding(
-                        contract="transfer", where=n,
-                        reason=(f"device-loop wire has shape {shape}, "
-                                f"expected [{ring_depth}, 2K+4] — one "
-                                "merged verdict wire per ring slot")))
-                wire_words = shape[-1]
-                wire_bytes = wire_words * 4
-            else:
-                wire_words = int(np.prod(leaf.shape, dtype=np.int64))
-                wire_bytes = int(nbytes)
+            wire_words = int(np.prod(leaf.shape, dtype=np.int64))
+            wire_bytes = int(nbytes)
             if np.dtype(leaf.dtype) != np.uint32:
                 findings.append(Finding(
                     contract="transfer", where=n,
@@ -294,7 +272,6 @@ class StagedVariant:
     donate_leaves: int
     quantized: bool
     n_param_leaves: int
-    ring_depth: int = 0
     n_shards: int = 1
     wire: str = schema.WIRE_COMPACT16  # which wire format `make_args`
     #                                    builds (the range seeder keys
@@ -309,15 +286,13 @@ def stage_variants(
     variants: tuple[str, ...] | None = None,
     donate: bool = True,
     mega_sizes: tuple[int, ...] | None = None,
-    device_loop: int = 0,
 ) -> tuple[list[StagedVariant], list[str], Any]:
     """Build (without tracing) every requested step variant under
     ``cfg``; returns ``(staged, notes, params)``.  Argument semantics
     are exactly :func:`run_audit`'s — this IS its staging loop,
     factored out so other static passes prove the same artifacts."""
     staged, notes, params, _donate, _sizes = _stage_variants(
-        cfg, params, mesh, mega_n, variants, donate, mega_sizes,
-        device_loop)
+        cfg, params, mesh, mega_n, variants, donate, mega_sizes)
     return staged, notes, params
 
 
@@ -329,7 +304,6 @@ def _stage_variants(
     variants: tuple[str, ...] | None,
     donate: bool,
     mega_sizes: tuple[int, ...] | None,
-    device_loop: int,
 ) -> tuple[list[StagedVariant], list[str], Any, bool, tuple[int, ...]]:
     notes: list[str] = []
     spec = get_model(cfg.model.name)
@@ -340,13 +314,11 @@ def _stage_variants(
     shardable = mesh is not None and int(mesh.devices.size) > 1
     sizes = _normalize_mega_sizes(mega_sizes, mega_n)
     mega_ok = bool(sizes)
-    ring_ok = device_loop >= 1 and mega_ok
     if variants is None:
         variants = tuple(
             v for v in ALL_VARIANTS
             if (shardable or not v.startswith("sharded"))
-            and (mega_ok or "megastep" not in v)
-            and (ring_ok or "device_loop" not in v))
+            and (mega_ok or "megastep" not in v))
         if not shardable:
             notes.append("sharded variants skipped: need a >1-device "
                          "mesh (run under "
@@ -354,20 +326,14 @@ def _stage_variants(
                          "count=N or on a real slice)")
         if not mega_ok:
             notes.append("megastep variants skipped: mega_n < 1")
-        if device_loop >= 1 and not mega_ok:
-            notes.append("device_loop variants skipped: the ring needs "
-                         "mega group sizes (mega_n >= 1)")
     else:
         bad = [v for v in variants
                if ("megastep" in v and not mega_ok)
-               or ("device_loop" in v and not ring_ok)
                or (v.startswith("sharded") and not shardable)]
         if bad:
             raise ValueError(
                 f"variant(s) {bad} need "
-                + ("device_loop >= 1 and mega_n >= 1"
-                   if "device_loop" in bad[0]
-                   else "mega_n >= 1" if "megastep" in bad[0]
+                + ("mega_n >= 1" if "megastep" in bad[0]
                    else "a >1-device mesh"))
 
     def table_args(sharded: bool):
@@ -455,38 +421,6 @@ def _stage_variants(
                     quantized=cfg.model.quantized,
                     n_param_leaves=n_param_leaves,
                     n_shards=(int(mesh.devices.size) if is_sh else 1)))
-        elif name in ("device_loop", "sharded_device_loop"):
-            # the drain-ring deep scan: ring slots of top-rung groups,
-            # staged with the exact shapes a --device-loop engine
-            # uploads (R separate [chunks, B+1, words] slot arguments)
-            from flowsentryx_tpu.fused import device_loop as dl
-
-            is_sh = name == "sharded_device_loop"
-            chunks = max(sizes)
-            if is_sh:
-                jitted = dl.make_sharded_compact_device_loop(
-                    cfg, spec.classify_batch, mesh, device_loop,
-                    chunks, donate=donate, **quant)
-            else:
-                jitted = dl.make_compact_device_loop(
-                    cfg, spec.classify_batch, device_loop, chunks,
-                    donate=donate, **quant)
-
-            def mk(is_sh=is_sh, chunks=chunks):
-                slots = tuple(
-                    np.zeros((chunks, cfg.batch.max_batch + 1,
-                              schema.COMPACT_RECORD_WORDS), np.uint32)
-                    for _ in range(device_loop))
-                return (*table_args(is_sh), params, *slots)
-            staged.append(StagedVariant(
-                f"{name}@{device_loop}x{chunks}", jitted, mk,
-                verdict_k=cfg.batch.verdict_k, expect_sharded=is_sh,
-                donate_leaves=((2 if is_sh else len(CARRY_NAMES))
-                               if donate else 0),
-                quantized=cfg.model.quantized,
-                n_param_leaves=n_param_leaves,
-                ring_depth=device_loop,
-                n_shards=(int(mesh.devices.size) if is_sh else 1)))
         else:
             raise ValueError(f"unknown audit variant {name!r}")
     return staged, notes, params, donate, sizes
@@ -500,7 +434,6 @@ def run_audit(
     variants: tuple[str, ...] | None = None,
     donate: bool = True,
     mega_sizes: tuple[int, ...] | None = None,
-    device_loop: int = 0,
 ) -> AuditReport:
     """Stage and audit the requested step variants under ``cfg``.
 
@@ -518,25 +451,15 @@ def run_audit(
     more than one size the per-size reports are named
     ``megastep@<n>``; ``None`` keeps the single-``mega_n`` staging and
     plain names.
-
-    ``device_loop >= 1`` additionally stages the drain-ring deep scan
-    (``device_loop@<ring>x<chunks>``, chunks = the ladder's top rung):
-    the 528 B-PER-SLOT wire pin on the ``[ring, 2K+4]`` output, the
-    donation aliasing proof for the carried ring state (table/stats
-    threading the nested scan), the no-hidden-callback sweep, and the
-    retrace sentinel, each on the graph a ``--device-loop`` engine
-    actually serves.
     """
     staged, notes, params, donate, sizes = _stage_variants(
-        cfg, params, mesh, mega_n, variants, donate, mega_sizes,
-        device_loop)
+        cfg, params, mesh, mega_n, variants, donate, mega_sizes)
     reports = [
         _audit_one(
             sv.name, sv.jitted, sv.make_args, verdict_k=sv.verdict_k,
             expect_sharded=sv.expect_sharded,
             donate_leaves=sv.donate_leaves, quantized=sv.quantized,
-            n_param_leaves=sv.n_param_leaves, ring_depth=sv.ring_depth,
-            n_shards=sv.n_shards)
+            n_param_leaves=sv.n_param_leaves, n_shards=sv.n_shards)
         for sv in staged
     ]
 
@@ -559,7 +482,6 @@ def run_audit(
             else 1,
             "mega_n": mega_n,
             "mega_sizes": list(sizes),
-            "device_loop": device_loop,
             "donate": bool(donate),
         },
         backend=jax.default_backend(),
@@ -584,7 +506,6 @@ def boot_audit(
     mega_n: int,
     params: Any | None = None,
     mega_sizes: tuple[int, ...] | None = None,
-    device_loop: int = 0,
 ) -> AuditReport | None:
     """Audit exactly the variants a booting engine is about to serve
     and refuse the boot (raise :class:`AuditError`) on any violated
@@ -593,10 +514,7 @@ def boot_audit(
     ``mega_sizes`` is the adaptive engine's group-size ladder: every
     size stages (and is cached) as its own variant, and the cache key
     includes the SET — an engine re-booting with a different ladder is
-    serving different compiled artifacts and must re-prove them.
-    ``device_loop`` is the drain-ring depth, in the cache key for the
-    same reason: a different ring depth is a different deep-scan
-    artifact."""
+    serving different compiled artifacts and must re-prove them."""
     shardable = mesh is not None and int(mesh.devices.size) > 1
     variants: list[str] = []
     if shardable:
@@ -610,15 +528,11 @@ def boot_audit(
         # auditing sharded + single-device megastep separately would
         # leave the variant that actually serves unproved
         variants.append("sharded_megastep" if shardable else "megastep")
-    device_loop = int(device_loop)
-    if device_loop >= 1 and sizes:
-        variants.append("sharded_device_loop" if shardable
-                        else "device_loop")
     # The cache key must cover everything that changes the STAGED
-    # graph: config, wire, mesh, the group-size set, the ring depth —
-    # and the params leaves' shapes/dtypes (a later engine serving a
-    # different artifact, e.g. an f64-poisoned .npz, is a different
-    # graph and must re-audit).  The ONE definition of that rule is
+    # graph: config, wire, mesh, the group-size set — and the params
+    # leaves' shapes/dtypes (a later engine serving a different
+    # artifact, e.g. an f64-poisoned .npz, is a different graph and
+    # must re-audit).  The ONE definition of that rule is
     # core/signature.staging_signature — shared with the range
     # certifier (same staging surface) and the persistent AOT compile
     # cache (engine/compile_cache.py), so the three can never drift on
@@ -630,13 +544,13 @@ def boot_audit(
     sig = staging_signature(
         cfg, wire=wire,
         mesh_devices=int(mesh.devices.size) if shardable else 1,
-        mega_sizes=sizes, device_loop=device_loop, params=params)
+        mega_sizes=sizes, params=params)
     key = (signature_digest(sig), tuple(variants))
     if _BOOT_CACHE.get(key):
         return None
     rep = run_audit(cfg, params=params, mesh=mesh,
                     mega_n=mega_n or 2, variants=tuple(variants),
-                    mega_sizes=sizes or None, device_loop=device_loop)
+                    mega_sizes=sizes or None)
     rep.raise_if_failed()
     _BOOT_CACHE[key] = True
     return rep

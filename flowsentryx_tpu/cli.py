@@ -19,25 +19,17 @@ import sys
 from pathlib import Path
 
 
-def _int_or_auto(flag: str):
-    """argparse type for flags taking an int or the string ``auto``."""
-    def parse(s: str):
-        if s == "auto":
-            return "auto"
-        try:
-            return int(s)
-        except ValueError:
-            raise argparse.ArgumentTypeError(
-                f"{flag} takes an integer or 'auto', got {s!r}")
-    return parse
-
-
-#: ``--mega``: a fixed group size or the adaptive power-of-two
-#: coalescing ladder (``Engine(mega_n="auto")``).
-_mega_arg = _int_or_auto("--mega")
-#: ``--device-loop``: an explicit ring depth or a depth picked from a
-#: short boot-time calibration drain (``engine.calibrate_ring_depth``).
-_device_loop_arg = _int_or_auto("--device-loop")
+def _mega_arg(s: str):
+    """argparse type of ``--mega``: a fixed group size or ``auto``, the
+    adaptive power-of-two coalescing ladder
+    (``Engine(mega_n="auto")``)."""
+    if s == "auto":
+        return "auto"
+    try:
+        return int(s)
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"--mega takes an integer or 'auto', got {s!r}")
 
 
 def _cmd_codegen(args: argparse.Namespace) -> int:
@@ -316,13 +308,6 @@ def _cmd_audit(args: argparse.Namespace) -> int:
     # Flag validation BEFORE any JAX/mesh boot (the fsx serve
     # fail-fast ordering): a usage error must not cost the user the
     # multi-second backend init.
-    if args.device_loop < 0:
-        print("fsx audit: --device-loop must be >= 0", file=sys.stderr)
-        return 1
-    if args.device_loop and not args.mega:
-        print("fsx audit: --device-loop needs --mega N|auto (the ring "
-              "scans top-rung mega groups)", file=sys.stderr)
-        return 1
     cfg = _load_cfg(args)
     if args.verdict_k is not None:
         if args.verdict_k < 1:
@@ -354,8 +339,7 @@ def _cmd_audit(args: argparse.Namespace) -> int:
         # quick config and are labeled as such in the report
         cfg = _quick_shapes(cfg)
     mesh, mega = _stage_mesh_and_mega(args)
-    rep = run_audit(cfg, mesh=mesh, device_loop=args.device_loop,
-                    **mega)
+    rep = run_audit(cfg, mesh=mesh, **mega)
     if args.out:
         runner.write_artifact(rep, args.out)
     if args.json:
@@ -391,7 +375,7 @@ def _cmd_sync(args: argparse.Namespace) -> int:
       exhaustive cooperative schedules over the REAL protocol objects
       (SinkChannel, SealedBatchQueue, DispatchArena), including the
       arena reuse-bound tightness proof — all interleavings pass at
-      ``ring_safe_slots`` and a concrete staged-copy-overwrite
+      ``safe_slots`` and a concrete staged-copy-overwrite
       schedule is printed one slot below it.
 
     Both are jax-free; ``--quick`` runs the contract lint only (the
@@ -436,7 +420,7 @@ def _cmd_sync(args: argparse.Namespace) -> int:
                 cx = next(c.counterexample for c in irep.checks
                           if c.expect_violation
                           and c.check.startswith("arena"))
-                print(f"fsx sync: arena bound TIGHT: depth+ring+1 = "
+                print(f"fsx sync: arena bound TIGHT: depth+2 = "
                       f"{b['safe_slots']} slots pass all "
                       f"{b['interleavings_at_safe']} interleavings; "
                       f"{b['counterexample_at']} slots fail:")
@@ -672,13 +656,6 @@ def _cmd_ranges(args: argparse.Namespace) -> int:
     _place_compile_cache()
     from flowsentryx_tpu.ranges import runner as ranges_runner
 
-    if args.device_loop < 0:
-        print("fsx ranges: --device-loop must be >= 0", file=sys.stderr)
-        return 1
-    if args.device_loop and not args.mega:
-        print("fsx ranges: --device-loop needs --mega N|auto (the ring "
-              "scans top-rung mega groups)", file=sys.stderr)
-        return 1
     cfg = _load_cfg(args)
     if args.evict_ttl < 0:
         print("fsx ranges: --evict-ttl must be >= 0", file=sys.stderr)
@@ -694,8 +671,7 @@ def _cmd_ranges(args: argparse.Namespace) -> int:
         cfg = _quick_shapes(cfg)
     mesh, mega = _stage_mesh_and_mega(args)
     rep = ranges_runner.run_ranges(
-        cfg, mesh=mesh, device_loop=args.device_loop,
-        artifact=args.artifact, **mega)
+        cfg, mesh=mesh, artifact=args.artifact, **mega)
     if args.out:
         ranges_runner.write_artifact(rep, args.out)
     if args.json:
@@ -1190,34 +1166,6 @@ def _cmd_serve(args: argparse.Namespace) -> int:
               "(deploy the real tier via fsx distill --pin instead)",
               file=sys.stderr)
         return 1
-    # Device-loop refusals BEFORE the multi-second JAX boot.  The ring
-    # rides the mega ladder (each slot carries one top-rung group) and
-    # reads verdicts back exclusively through the per-slot compact
-    # wires — both are structural, not preferences, so a combination
-    # that breaks them (or the arena slot-safety accounting built on
-    # them) is refused here with its actual problem named.  ``auto``
-    # (the boot-time ring-depth calibration) obeys the SAME rules as
-    # an explicit depth — a calibration that could only refuse after
-    # its multi-compile drain would be the exact hostility this block
-    # exists to prevent.
-    if args.device_loop != "auto" and args.device_loop < 0:
-        print("fsx serve: --device-loop must be >= 0 (0 = per-group "
-              "dispatch, the parity baseline) or 'auto'",
-              file=sys.stderr)
-        return 1
-    if args.device_loop and not args.mega:
-        print("fsx serve: --device-loop requires --mega N|auto: each "
-              "ring slot carries one top-rung coalescing group (the "
-              "deep scan is a ring of megasteps)", file=sys.stderr)
-        return 1
-    if args.device_loop and args.verdict_k == 0:
-        print("fsx serve: --device-loop is incompatible with "
-              "--verdict-k 0: the ring's only steady-state readback is "
-              "the per-slot compact verdict wire, and without it every "
-              "round would fetch full [ring*mega, B] block arrays — "
-              "the exact transfer the ring exists to amortize",
-              file=sys.stderr)
-        return 1
     if args.tiered_warm and not args.mega:
         print("fsx serve: --tiered-warm requires --mega N|auto: the "
               "serving tier IS the top coalescing rung — with no "
@@ -1537,30 +1485,8 @@ def _cmd_serve(args: argparse.Namespace) -> int:
                   f"{args.sim_kernel_tier!r}: {e} (generate one with "
                   "fsx distill ARTIFACT --out PLAN.npz)", file=sys.stderr)
             return 1
-    device_loop = args.device_loop
-    if device_loop == "auto":
-        # ring-depth autotuning: a short synthetic calibration drain
-        # per candidate depth, judged on the measured H2D overlap
-        # (engine.calibrate_ring_depth / fused.choose_ring_depth).
-        # One XLA compile per candidate — a boot cost, announced, paid
-        # once for a long-lived server exactly like warm().
-        from flowsentryx_tpu.engine.engine import calibrate_ring_depth
-
-        print("fsx serve: --device-loop auto: calibrating ring depth "
-              "(one short drain + XLA compile per candidate)...",
-              file=sys.stderr)
-        device_loop, detail = calibrate_ring_depth(
-            cfg, params=params, mesh=mesh, mega_n=args.mega)
-        print(f"fsx serve: --device-loop auto -> ring depth "
-              f"{device_loop} ({detail['reason']}; measured: "
-              + ", ".join(
-                  f"ring {m['ring']}: overlap "
-                  f"{m['overlap_fraction']}" for m in
-                  detail["candidates"]) + ")",
-              file=sys.stderr)
     eng = Engine(cfg, source, sink, params=params, mesh=mesh,
                  mega_n=args.mega or 0,
-                 device_loop=device_loop,
                  t0_ns=t0_ns,
                  sink_thread=False if args.no_sink_thread else None,
                  audit=True if args.audit else None,
@@ -1591,8 +1517,8 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         # distill --pin push, brought to the TPU tier)
         eng.watch_artifact(args.artifact)
     if args.mega or args.slo_us:
-        # pay every staged compile (each ladder rung, and the deep-scan
-        # ring graph) at boot, not on the first traffic backlog; SLO
+        # pay every staged compile (each ladder rung) at boot, not on
+        # the first traffic backlog; SLO
         # mode additionally needs warm()'s timed pass to seed the
         # per-rung step-time EWMA the budget policy reads.  Tiered:
         # only the serving tier (singles + top rung) blocks boot, a
@@ -1801,22 +1727,8 @@ def _cmd_cluster(args: argparse.Namespace) -> int:
         print("fsx cluster: --checkpoint-every requires --checkpoint "
               "TEMPLATE (with a {rank} placeholder)", file=sys.stderr)
         return 1
-    if args.device_loop < 0:
-        print("fsx cluster: --device-loop must be >= 0",
-              file=sys.stderr)
-        return 1
-    if args.device_loop and not args.mega:
-        print("fsx cluster: --device-loop requires --mega N|auto "
-              "(each ring slot carries one top-rung coalescing "
-              "group)", file=sys.stderr)
-        return 1
     if args.verdict_k is not None and args.verdict_k < 0:
         print("fsx cluster: --verdict-k must be >= 0", file=sys.stderr)
-        return 1
-    if args.device_loop and args.verdict_k == 0:
-        print("fsx cluster: --device-loop is incompatible with "
-              "--verdict-k 0 (the ring's steady-state readback is the "
-              "per-slot compact wire)", file=sys.stderr)
         return 1
     if args.tiered_warm and not args.mega:
         print("fsx cluster: --tiered-warm requires --mega N|auto "
@@ -1948,7 +1860,6 @@ def _cmd_cluster(args: argparse.Namespace) -> int:
             "verdict_ring": (f"{args.verdict_ring}.r{r}"
                              if args.verdict_ring else None),
             "mega": args.mega or 0,
-            "device_loop": args.device_loop,
             "slo_us": args.slo_us,
             "predict": bool(args.predict),
             "artifact": args.artifact,
@@ -2888,12 +2799,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "or 'auto' to audit every rung of the "
                          "adaptive power-of-two ladder (one staged "
                          "artifact per group size)")
-    au.add_argument("--device-loop", type=int, default=0, metavar="N",
-                    help="also stage + audit the drain-ring deep scan "
-                         "at ring depth N (the graph fsx serve "
-                         "--device-loop N serves: [N, 2K+4] per-slot "
-                         "wire pin, ring-carry donation proof, no "
-                         "hidden callbacks); needs --mega")
     au.add_argument("--evict-ttl", type=float, default=0.0,
                     metavar="S",
                     help="also prove the eviction-epoch step variants: "
@@ -2987,9 +2892,6 @@ def build_parser() -> argparse.ArgumentParser:
     rg.add_argument("--mega", type=_mega_arg, default=2,
                     help="megastep chunk count, or 'auto' for every "
                          "rung of the adaptive ladder")
-    rg.add_argument("--device-loop", type=int, default=0, metavar="N",
-                    help="also prove the drain-ring deep scan at ring "
-                         "depth N (needs --mega)")
     rg.add_argument("--evict-ttl", type=float, default=0.0,
                     metavar="S",
                     help="prove the eviction-epoch variants (the "
@@ -3162,24 +3064,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "power-of-two group size up to 8 and dispatch "
                         "the largest the instantaneous backlog fills, "
                         "so partial backlogs amortize too")
-    s.add_argument("--device-loop", type=_device_loop_arg, default=0,
-                   metavar="N",
-                   help="device-resident drain ring of depth N: a deep-"
-                        "scan dispatch consumes N staged ring slots "
-                        "(one top-rung --mega group each) per host "
-                        "round-trip, carrying table/stats on-device "
-                        "across the whole round while the NEXT round's "
-                        "slots upload (double-buffered H2D) and the "
-                        "pipeline worker harvests per-slot verdict "
-                        "wires; requires --mega; 0 = per-group "
-                        "dispatch, the parity baseline. 'auto' picks "
-                        "the depth from a short boot-time calibration "
-                        "drain's measured H2D overlap (one XLA compile "
-                        "per candidate, announced)")
     s.add_argument("--compile-cache", metavar="DIR",
                    help="persistent AOT executable store: staged "
-                        "variants (each --mega rung, the --device-loop "
-                        "ring) serialize here on first boot and later "
+                        "variants (singles and each --mega rung) "
+                        "serialize here on first boot and later "
                         "boots of the same staged shape + toolchain "
                         "load them in milliseconds instead of "
                         "recompiling — sub-second boot-to-serving. "
@@ -3189,7 +3077,7 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--tiered-warm", action="store_true",
                    help="open serving on the top-rung tier (singles + "
                         "largest --mega rung) and fill the remaining "
-                        "rungs/ring from a background thread — "
+                        "rungs from a background thread — "
                         "byte-identical verdicts throughout (unready "
                         "rungs degrade to top-rung flushes); pair "
                         "with --compile-cache for the sub-second "
@@ -3331,10 +3219,6 @@ def build_parser() -> argparse.ArgumentParser:
     cl.add_argument("--mega", type=_mega_arg, default=0,
                     help="per-engine coalescing ladder (fsx serve "
                          "--mega)")
-    cl.add_argument("--device-loop", type=int, default=0, metavar="N",
-                    help="per-engine drain-ring depth (explicit only: "
-                         "the auto calibration is a serve-boot "
-                         "feature; requires --mega)")
     cl.add_argument("--compile-cache", metavar="DIR",
                     help="per-fleet persistent AOT executable store "
                          "(fsx serve --compile-cache; every rank "

@@ -182,7 +182,6 @@ def _serve(spec: dict, plane: GossipPlane) -> None:
         params=params,
         t0_ns=spec["t0_ns"],
         mega_n=spec.get("mega") or 0,
-        device_loop=spec.get("device_loop", 0),
         slo_us=spec.get("slo_us") or 0,
         predict=bool(spec.get("predict")),
         watchdog_s=spec.get("watchdog_s"),
@@ -321,10 +320,9 @@ def prewarm_main(spec: dict) -> int:
 
     Spawned by the supervisor at elastic-fleet boot when the engine
     specs carry ``compile_cache``.  Spare ranks are provisioned at max
-    with the SAME spec (same cfg/mega/device-loop/params geometry), so
-    one child with a null source covers every rank: ``warm()`` the
-    FULL ladder — every rung plus the deep-scan ring — storing each
-    executable, then exit.  Best-effort and non-blocking: the fleet
+    with the SAME spec (same cfg/mega/params geometry), so one child
+    with a null source covers every rank: ``warm()`` the FULL ladder,
+    storing each executable, then exit.  Best-effort and non-blocking: the fleet
     never waits on it, and any failure just means the spare compiles
     (fail-open, like every cache path)."""
     _own_process_group()
@@ -352,7 +350,6 @@ def prewarm_main(spec: dict) -> int:
             NullSink(),
             params=params,
             mega_n=spec.get("mega") or 0,
-            device_loop=spec.get("device_loop", 0),
             slo_us=spec.get("slo_us") or 0,
             sink_thread=False,
             compile_cache=spec["compile_cache"],

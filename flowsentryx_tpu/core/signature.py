@@ -12,8 +12,8 @@ f64-poisoned artifact).  This module is the single copy of the rule:
 
     a staged shape is keyed by everything that changes the compiled
     graph — the full config JSON (eviction knobs included), the wire
-    format, the mesh device count, the coalescing-ladder size set, the
-    drain-ring depth, donation, and the params leaves' dtypes/shapes.
+    format, the mesh device count, the coalescing-ladder size set,
+    donation, and the params leaves' dtypes/shapes.
 
 What it deliberately does NOT include: toolchain versions (jax /
 jaxlib / XLA backend).  Version drift invalidates *serialized
@@ -58,7 +58,6 @@ def staging_signature(
     wire: str,
     mesh_devices: int = 1,
     mega_sizes: tuple[int, ...] | list[int] | None = None,
-    device_loop: int = 0,
     params: Any | None = None,
     donate: bool | None = None,
 ) -> dict:
@@ -74,7 +73,6 @@ def staging_signature(
         "wire": wire,
         "mesh_devices": int(mesh_devices or 1),
         "mega_sizes": [int(s) for s in (mega_sizes or ())],
-        "device_loop": int(device_loop),
         "donate": None if donate is None else bool(donate),
         "params": params_signature(params, cfg.model.name),
     }

@@ -24,10 +24,10 @@ round-robin; the safety rule mirrors ``MicroBatcher.n_buffers``:
     readback_depth + 2``, because the engine claims a fresh slot only
     after dispatching everything staged in the current one, and
     ``_reap`` keeps at most ``readback_depth`` dispatched-but-unsunk
-    batches (each occupying >= 1 slot) at any time.  The ring-aware
-    generalization is :meth:`DispatchArena.ring_safe_slots`; the full
-    derivation is docs/CONCURRENCY.md §arena, and ``fsx sync`` proves
-    the bound TIGHT by exhaustive interleaving of this class.
+    batches (each occupying >= 1 slot) at any time.  The rule is
+    :meth:`DispatchArena.safe_slots`; the full derivation is
+    docs/CONCURRENCY.md §arena, and ``fsx sync`` proves the bound
+    TIGHT by exhaustive interleaving of this class.
 
 This also covers the CPU backend, where ``device_put`` of an aligned
 buffer may alias rather than copy: rows stay immutable for the whole
@@ -69,10 +69,10 @@ class DispatchArena:
         self.buf = np.frombuffer(self._mm, np.uint32).reshape(
             slots, group_max, max_batch + 1, words)
         # Pre-fault every page NOW: anonymous mmap pages materialize on
-        # first write, and a ring-sized arena left lazy pays its page
-        # faults inside the first serving rounds' staging memcpys — a
-        # boot cost billed to the hot path (measured as a consistently
-        # slow first drain window on the ring arena).
+        # first write, and an arena left lazy pays its page faults
+        # inside the first serving groups' staging memcpys — a boot
+        # cost billed to the hot path (measured as a consistently
+        # slow first drain window).
         self.buf[...] = 0
         self._cur = -1
 
@@ -81,27 +81,22 @@ class DispatchArena:
         return self.buf.nbytes
 
     @staticmethod
-    def ring_safe_slots(readback_depth: int, ring: int) -> int:
-        """Slot count that keeps the reuse-safety rule when a
-        device-loop ring holds up to ``ring`` uploaded slices in
-        flight — the generalization of the single-buffer
-        ``readback_depth + 2`` rule (which is the ``ring = 1`` case).
+    def safe_slots(readback_depth: int) -> int:
+        """Slot count that keeps the reuse-safety rule:
+        ``readback_depth + 2``.
 
         In one line: at any claim, at most ``readback_depth``
         sunk-pending slots (trickle singles, one slot each, worst
-        case) plus up to ``ring`` slots of the just-submitted round
-        whose uploaded ALIASES the worker has not consumed, plus the
-        overlapped claim itself must coexist — hence
-        ``readback_depth + ring + 1``.  The full derivation lives in
+        case) plus the slot of the just-submitted work whose staged
+        rows the device has not consumed, plus the overlapped claim
+        itself must coexist.  The full derivation lives in
         docs/CONCURRENCY.md §arena, and the bound is not argued but
         MACHINE-CHECKED: ``fsx sync`` (sync/interleave.py) drives this
         class over exhaustive thread interleavings, passing every
         schedule at this bound and printing a staged-copy-overwrite
         counterexample one slot below it.
         """
-        if ring < 1:
-            raise ValueError(f"ring must be >= 1, got {ring}")
-        return max(readback_depth, 1) + ring + 1
+        return max(readback_depth, 1) + 2
 
     def claim(self) -> int:
         """Next slot index, recycling the oldest.  Callers claim only

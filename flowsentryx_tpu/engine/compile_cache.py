@@ -2,8 +2,8 @@
 recompile.
 
 Every ``Engine.warm()`` used to pay the full XLA compile for every
-staged variant — each coalescing-ladder rung, the deep-scan ring, the
-eviction epochs folded into each — seconds of wall per boot, paid
+staged variant — singles, each coalescing-ladder rung, the eviction
+epochs folded into each — seconds of wall per boot, paid
 again by every crash-respawn and every elastic GROW spare while the
 burst it was spawned for is already landing.  The compile is a pure
 function of the staged shape and the toolchain, so it is paid ONCE:

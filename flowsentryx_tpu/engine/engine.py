@@ -29,15 +29,13 @@ The blacklist tolerates the remaining small writeback delay by design —
 the kernel limiter stands alone during the gap (fail-open, SURVEY.md
 §5.3).
 
-**Device-loop mode** (``device_loop=N`` / ``fsx serve --device-loop``)
-replaces the sink thread with the device-PIPELINE worker: the second
-thread both LAUNCHES the deep-scan rounds (fused/device_loop.py — on
-XLA:CPU the step's scatter custom-calls execute synchronously, so the
-launch call blocks for the whole round's compute; putting it on the
-worker is what lets staging overlap compute at all) and harvests their
-per-slot verdict wires.  The dispatch thread's steady state becomes
-poll → stage → upload → submit, with the upload↔compute overlap
-measured in ``EngineReport.dispatch["device_loop"]["h2d"]``.
+How batches reach the device is decided in TWO serving loops —
+:meth:`Engine._run_inline` (a record source; the batcher lives in the
+engine) and :meth:`Engine._sealed_loop_arena` (a sealed-batch source;
+the ingest workers own the batchers) — and both launch one of TWO
+kinds of dispatch: ``("single",)`` through :meth:`Engine._dispatch`,
+or ``("mega", g)``, one ``lax.scan`` over a staged group of ``g``
+batches, through :meth:`Engine._dispatch_group`.
 """
 
 from __future__ import annotations
@@ -230,15 +228,6 @@ class _InFlight(NamedTuple):
     seq: int = 0
 
 
-class _Uploaded(NamedTuple):
-    """One staged-and-uploaded ring slot awaiting its round."""
-
-    dev: Any            # device buffer ([chunks, B+1, words])
-    stamps: _Stamps     # of the slot's oldest member batch
-    n_records: int
-    put_s: float        # the slot's explicit H2D wall
-
-
 class Engine:
     """Owns the device state (table/stats/params) and runs the loop.
 
@@ -247,14 +236,6 @@ class Engine:
     ``readback_depth`` is how many batches may be in flight before the
     oldest verdicts are fetched and sunk (``None`` = the config's
     ``BatchConfig.readback_depth``).
-
-    ``device_loop`` (0 = off) is the drain-ring depth: N staged ring
-    slots — one top-rung ``mega_n`` group each — consumed by ONE
-    deep-scan dispatch per host round-trip, with the next round's
-    slots uploading while the current one computes (module docstring;
-    requires mega grouping and ``verdict_k >= 1``; ``readback_depth``
-    must cover one round — the config default is auto-raised, an
-    explicit smaller value refused).
 
     ``audit`` (``None`` = on when ``FSX_AUDIT=1``) statically audits
     the serving step's graph contracts at boot — dtypes, donation
@@ -283,7 +264,6 @@ class Engine:
         wire: str | None = None,
         mega_n: int | str = 0,
         mega_auto: bool = False,
-        device_loop: int = 0,
         sink_thread: bool | None = None,
         audit: bool | None = None,
         kernel_tier: Any | None = None,
@@ -327,34 +307,24 @@ class Engine:
         #: legacy full [B] fetch per batch).
         self.verdict_k = cfg.batch.verdict_k
         #: Latency-budget serving mode (``fsx serve --slo-us N``): the
-        #: feature→verdict budget, µs, that the coalescing ladder, the
-        #: device-loop round sizer and the batcher deadline flush are
-        #: bounded by (docs/ENGINE.md §latency).  0 — the default — is
-        #: the throughput-tuned engine, BIT-IDENTICAL to every prior
-        #: PR (test-pinned like every other mode flag): no EWMA
-        #: bookkeeping, no policy checks on the hot path.
+        #: feature→verdict budget, µs, that the coalescing ladder and
+        #: the batcher deadline flush are bounded by (docs/ENGINE.md
+        #: §latency).  0 — the default — is the throughput-tuned
+        #: engine, BIT-IDENTICAL to every prior PR (test-pinned like
+        #: every other mode flag): no EWMA bookkeeping, no policy
+        #: checks on the hot path.
         self.slo_us = int(slo_us)
         if self.slo_us < 0:
             raise ValueError(f"slo_us must be >= 0, got {slo_us}")
         self._slo_budget_s = self.slo_us * 1e-6
         #: Warm-measured per-group-size step-time EWMA (seconds), keyed
-        #: by dispatched chunk count (1 and each ladder rung); a ring
-        #: ROUND keys as the NEGATED round size (a ``device_loop=1``
-        #: round spans exactly the top rung's chunk count, and the
-        #: round wall includes uploads+reap — sharing the key would
-        #: cross-contaminate the two estimates).  Seeded by
+        #: by dispatched chunk count (1 and each ladder rung).  Seeded by
         #: :meth:`warm`'s timed second pass when SLO mode is on;
         #: refined online by the launch section whenever a launch call
         #: absorbed its compute (synchronous backends).  The
         #: deadline-aware policy reads it advisorily — a stale
         #: estimate can only mis-size a group, never corrupt state.
         self._rung_ewma_s: dict[int, float] = {}
-        #: Warm-seed floors for the NEGATED ring-round keys: the seed
-        #: is the only measurement whose wall covers uploads AND reap,
-        #: so :meth:`_note_round_s` may refine the round EWMA upward
-        #: but never below it (the decaying-optimistic-estimate
-        #: hazard PR 11 documented).  Written by :meth:`warm` only.
-        self._round_floor_s: dict[int, float] = {}
         #: Per-record seal→verdict latency plane (always on; the sink
         #: section is its single writer — sync/contracts.py).
         self._lat = LatencyRecorder()
@@ -489,10 +459,6 @@ class Engine:
         self.stats = self._put(schema.make_stats())
         # None = the config's pipe depth (BatchConfig.readback_depth,
         # validated >= 1 at construction); an explicit int overrides.
-        # The explicitness is remembered: a device-loop engine may
-        # auto-raise a config-default depth to cover one ring round but
-        # must REFUSE an explicit depth that can't.
-        self._depth_explicit = readback_depth is not None
         if readback_depth is None:
             readback_depth = cfg.batch.readback_depth
         self.readback_depth = readback_depth
@@ -553,58 +519,6 @@ class Engine:
                     **quant,
                 )
             self.megastep = self.megasteps[max(self.megasteps)]
-        # -- device-resident drain ring (fused/device_loop.py) ----------
-        # ``device_loop=R`` makes the steady-state loop pull-based from
-        # the device: R staged ring slots (one top-rung group each) go
-        # to the device as ONE deep-scan dispatch carrying table/stats
-        # across all R*C batches, while the NEXT round's slots upload
-        # during the current round's compute (double-buffered H2D).
-        # 0 = today's per-group dispatch (the fallback and the parity
-        # baseline — short backlogs always drain through it).
-        self.ring = int(device_loop)
-        if self.ring < 0:
-            raise ValueError(
-                f"device_loop must be >= 0, got {device_loop}")
-        self._ring_chunks = 0
-        self.ring_step = None
-        if self.ring:
-            if not self.megasteps:
-                raise ValueError(
-                    "device_loop requires mega grouping (mega_n >= 2 or "
-                    "'auto'): each ring slot carries one top-rung group")
-            if self.verdict_k < 1:
-                raise ValueError(
-                    "device_loop requires the compact verdict wire "
-                    "(verdict_k >= 1): the ring's steady-state readback "
-                    "is one [ring, 2K+4] buffer per round")
-            self._ring_chunks = max(self.megasteps)
-            round_b = self.ring * self._ring_chunks
-            if self._depth_explicit and readback_depth < round_b:
-                raise ValueError(
-                    f"device_loop={self.ring} with readback_depth="
-                    f"{readback_depth} < {round_b} (one ring round of "
-                    f"{self.ring}x{self._ring_chunks} batches): the pipe "
-                    "could never keep a round in flight while the next "
-                    "stages, so every H2D upload would serialize behind "
-                    "the drain — raise readback_depth to >= "
-                    f"{round_b} or shrink the ring")
-            if not self._depth_explicit:
-                # a config-default depth grows to cover one full round,
-                # or the ring would be refused for every default
-                # config; an EXPLICIT depth below the round is refused
-                # above instead of silently inflated.
-                readback_depth = max(readback_depth, round_b)
-                self.readback_depth = readback_depth
-            from flowsentryx_tpu.fused import device_loop as dl
-
-            if self.mesh is not None:
-                self.ring_step = dl.make_sharded_compact_device_loop(
-                    cfg, spec.classify_batch, self.mesh, self.ring,
-                    self._ring_chunks, donate=donate, **quant)
-            else:
-                self.ring_step = dl.make_compact_device_loop(
-                    cfg, spec.classify_batch, self.ring,
-                    self._ring_chunks, donate=donate, **quant)
         # Static graph audit at boot (class docstring): prove the
         # serving variant's dtype/donation/transfer/retrace/collective
         # contracts on the staged jaxpr + executable BEFORE the first
@@ -624,7 +538,6 @@ class Engine:
             boot_audit(cfg, wire=self.wire, mesh=self.mesh,
                        mega_n=self.mega_n if self._mega_sizes else 0,
                        mega_sizes=self._mega_sizes or None,
-                       device_loop=self.ring,
                        params=self.params)
         #: Sealed-but-undispatched (raw, stamps) group candidates.
         self._pending: list[tuple[np.ndarray, _Stamps]] = []
@@ -638,62 +551,26 @@ class Engine:
         self.sealed = bool(getattr(source, "provides_sealed", False))
         if self.sealed:
             source.start(cfg.batch, self.wire, quant)
-        # -- dispatch arena (engine/arena.py) ---------------------------
-        # Page-aligned staging rows for the zero-copy pipeline: sealed
-        # sources memcpy shm-slot VIEWS straight into arena rows (the
-        # ONE host copy) and mega groups assemble contiguously in one
-        # slot, so the device_put slice needs no np.stack.  Slot count
-        # follows the reuse safety rule (arena module docstring):
-        # readback_depth + 2 guarantees every batch staged in a slot is
-        # SUNK before the slot recycles.  Inline engines without
-        # grouping never stage, so they skip the allocation.
-        words = (schema.COMPACT_RECORD_WORDS
-                 if self.wire == schema.WIRE_COMPACT16
-                 else schema.RECORD_WORDS)
-        if self.sealed or self.megasteps:
-            group_max = max(self.megasteps) if self.megasteps else 1
-            # Slot count: the plain readback_depth+2 rule assumes ONE
-            # in-flight device buffer; a device-loop ring holds up to
-            # ``ring`` uploaded slices in flight per unsunk round, so
-            # the bound is recomputed (ring_safe_slots docstring has
-            # the proof — the non-ring rule is its ring=chunks=1 case).
-            slots = DispatchArena.ring_safe_slots(
-                readback_depth, self.ring or 1)
-            self._arena = DispatchArena(
-                slots=slots,
-                # sealed singles still batch their queue drains: give
-                # the slot a few rows even when no megastep is staged
-                group_max=max(group_max, 4) if self.sealed else group_max,
-                max_batch=cfg.batch.max_batch,
-                words=words,
-            )
-        else:
-            self._arena = None
+        #: u32 words in one wire row (the last axis of every staged
+        #: buffer).
+        self._words = (schema.COMPACT_RECORD_WORDS
+                       if self.wire == schema.WIRE_COMPACT16
+                       else schema.RECORD_WORDS)
+        self._arena = self._make_arena()
         # dispatch-block accounting (EngineReport.dispatch)
         self._group_hist: dict[int, int] = {}
         self._dispatch_calls = 0
         self._dispatched_chunks = 0
         self._staged_batches = 0
         self._staged_bytes = 0
-        # device-loop accounting (EngineReport.dispatch["device_loop"])
-        self._ring_rounds = 0
-        self._ring_partial_slots = 0
-        self._h2d_put_s = 0.0
-        self._h2d_overlap_s = 0.0
-        self._h2d_puts = 0
-        self._h2d_puts_overlapped = 0
-        #: How many sealed-but-undispatched batches the loops
-        #: accumulate before the coalescing policy must fire: one ring
-        #: round in device-loop mode, one top-rung group otherwise.
-        self._pending_cap = ((self.ring * self._ring_chunks)
-                             if self.ring else self.mega_n)
         # A wire buffer may be reused only after its batch is off the
         # in-flight queue (or, for a pending group member, dispatched):
         # keep more buffers than in-flight batches + the pending group
-        # (a whole ring round in device-loop mode).
+        # (at most one top-rung group of sealed-but-undispatched
+        # batches accumulates before the coalescing policy fires).
         self.batcher = MicroBatcher(
             cfg.batch, t0_ns=t0_ns or 0,
-            n_buffers=readback_depth + 2 + self._pending_cap,
+            n_buffers=readback_depth + 2 + self.mega_n,
             wire=wire, quant=quant,
         )
         # t0 anchors the device clock (f32 seconds).  None = auto: take
@@ -735,7 +612,7 @@ class Engine:
                                    cfg.batch.deadline_us * 1e-6 / 2)
         # -- sink-thread machinery (module docstring) -------------------
         # The SinkChannel (sync/channel.py) is the ONLY shared state
-        # between the dispatch and sink/pipeline threads: the handoff
+        # between the dispatch and sink threads: the handoff
         # queue, the dispatched-but-unsunk BATCH count backpressure
         # waits on (chunks, not entries — a mega entry is mega_n
         # batches), the stop flag, and the crash slot a worker death
@@ -744,16 +621,6 @@ class Engine:
         self._chan = SinkChannel("sink thread")
         self._sink_active = False
         self._sink_thread_obj: threading.Thread | None = None
-        # Device-loop mode replaces the post-launch sink thread with
-        # the device-PIPELINE worker: the queue carries pre-launch
-        # submissions (the jit call itself runs on the worker), so the
-        # dispatch thread's steady state is pure stage→upload→submit.
-        # On backends whose step graphs execute synchronously at
-        # dispatch (XLA:CPU runs the step's scatter custom-calls
-        # inline), this is what makes "upload slot i+1 while round i
-        # computes" REAL rather than aspirational — the launch blocks
-        # the worker, not the stager.
-        self._pipe_active = False
         # readback accounting (EngineReport.readback)
         self._d2h_bytes = 0
         self._sink_compact = 0
@@ -834,7 +701,7 @@ class Engine:
                     cfg, wire=self.wire,
                     mesh_devices=(int(self.mesh.devices.size)
                                   if self.mesh is not None else 1),
-                    mega_sizes=self._mega_sizes, device_loop=self.ring,
+                    mega_sizes=self._mega_sizes,
                     params=self.params,
                     donate=bool(donate))
                 self._cache = CompileCache(compile_cache, sig)
@@ -844,8 +711,8 @@ class Engine:
         #: variant, captured HERE (quiescent, the live device state in
         #: scope) so AOT lowering — including on the background warm
         #: fill thread — never touches launch-section fields.  Keys:
-        #: ("single",), ("mega", g), ("ring",).
-        self._aot_specs = self._capture_aot_specs(words)
+        #: ("single",), ("mega", g).
+        self._aot_specs = self._capture_aot_specs()
         #: The READY rung set: the rungs of the coalescing ladder whose
         #: executables are installed and safe to dispatch without an
         #: inline compile.  Defaults to the whole ladder (legacy warm
@@ -855,10 +722,6 @@ class Engine:
         #: only, so the SHAPES dispatched change but the results never
         #: do (the PR 5 invariant the partial-ladder parity test pins).
         self._ready_sizes: tuple[int, ...] = self._mega_sizes
-        #: Whether the deep-scan ring may engage (same tiered-warm
-        #: story: rings not yet filled degrade to top-rung megastep
-        #: slot flushes, byte-identical by construction).
-        self._ring_ready: bool = bool(self.ring)
         #: Background warm-fill plan + thread (warm(tiered=True)).
         self._warm_plan: tuple = ()
         self._warm_thread_obj: threading.Thread | None = None
@@ -877,7 +740,29 @@ class Engine:
         #: point that placed the persistent cache; None = not counted.
         self.boot_jax_compiles = None
 
-    def _capture_aot_specs(self, words: int) -> dict:
+    def _make_arena(self) -> DispatchArena | None:
+        """The dispatch arena (engine/arena.py): page-aligned staging
+        rows for the zero-copy pipeline.  Sealed sources memcpy
+        shm-slot VIEWS straight into arena rows (the ONE host copy)
+        and mega groups assemble contiguously in one slot, so the
+        device_put slice needs no np.stack.  Slot count follows the
+        reuse safety rule (arena module docstring): readback_depth + 2
+        guarantees every batch staged in a slot is SUNK before the
+        slot recycles.  Inline engines without grouping never stage,
+        so they skip the allocation (None)."""
+        if not (self.sealed or self.megasteps):
+            return None
+        group_max = max(self.megasteps) if self.megasteps else 1
+        return DispatchArena(
+            slots=DispatchArena.safe_slots(self.readback_depth),
+            # sealed singles still batch their queue drains: give
+            # the slot a few rows even when no megastep is staged
+            group_max=max(group_max, 4) if self.sealed else group_max,
+            max_batch=self.cfg.batch.max_batch,
+            words=self._words,
+        )
+
+    def _capture_aot_specs(self) -> dict:
         """Abstract (ShapeDtypeStruct) argument specs and the pristine
         jit wrapper for every staged variant — the inputs to
         ``wrapper.lower(*specs).compile()``.  Shardings are taken from
@@ -899,15 +784,11 @@ class Engine:
                                         sharding=self._in_sharding)
 
         specs: dict[tuple, tuple] = {
-            ("single",): (self.step, (*state, _wire((b + 1, words)))),
+            ("single",): (self.step, (*state, _wire((b + 1, self._words)))),
         }
         for g, fn in self.megasteps.items():
             specs[("mega", g)] = (fn, (*state,
-                                       _wire((g, b + 1, words))))
-        if self.ring:
-            slot = _wire((self._ring_chunks, b + 1, words))
-            specs[("ring",)] = (self.ring_step,
-                                (*state, *([slot] * self.ring)))
+                                       _wire((g, b + 1, self._words))))
         return specs
 
     # -- pipeline stages ----------------------------------------------------
@@ -935,37 +816,9 @@ class Engine:
             dt if prev is None
             else prev + tuning.SLO_EWMA_ALPHA * (dt - prev))
 
-    def _note_round_s(self, key: int, dt: float, out: Any) -> None:
-        """Guarded online refinement of the ring-ROUND EWMA key (the
-        PR 11 follow-up: rounds previously had NO refinement at all).
-
-        Three guards keep the hazard documented in PR 11 closed:
-        launch-absorbed rounds only (the readiness proof of
-        :meth:`_note_step_s`); ``dt`` must already carry the round's
-        upload wall on top of the launch wall (the caller sums them —
-        the reap is still invisible to a launch-side observation); and
-        the refined value is FLOORED at the warm seed, which is the
-        only measurement that saw uploads AND reap.  Net effect: a
-        round that measures slower than the seed raises the estimate
-        (a throttled host degrades to smaller rungs sooner), while a
-        round that measures faster — necessarily missing cost the
-        seed saw — leaves the conservative seed standing.  The key is
-        never CREATED here: warm() owns the seed, and an unseeded
-        engine self-warms at run() start."""
-        if not self._slo_budget_s or not self._out_ready(out):
-            return
-        prev = self._rung_ewma_s.get(key)
-        if prev is None:
-            return
-        floor = self._round_floor_s.get(key, prev)
-        self._rung_ewma_s[key] = max(
-            prev + tuning.SLO_EWMA_ALPHA * (dt - prev), floor)
-
     def _launch_single(self, raw: Any, stamps: _Stamps,
                        n_records: int) -> _InFlight:
-        """The step call + accounting of a single-batch dispatch (runs
-        on the dispatch thread directly, or on the device-pipeline
-        worker in device-loop mode)."""
+        """The step call + accounting of a single-batch dispatch."""
         self._dispatch_calls += 1
         seq = self._dispatch_calls
         with self.metrics.upload(seq) as up:
@@ -987,40 +840,26 @@ class Engine:
 
     def _dispatch(self, raw: np.ndarray, stamps: _Stamps) -> None:
         n_records = int(raw[self.cfg.batch.max_batch, 0])
-        if self._pipe_active:
-            self._submit("single", raw, stamps, n_records, 1)
-            return
         self._inflight.append(self._launch_single(raw, stamps,
                                                   n_records))
 
-    def _launch_group(self, raws: Any, stamps: _Stamps, n_records: int,
-                      on_device: bool = False,
-                      put_s: float = 0.0) -> _InFlight:
-        """The megastep call + accounting of a group dispatch.
-        ``on_device=True`` skips the H2D put — the buffer is an
-        already-uploaded ring slot (``put_s`` then carries the upload
-        wall :meth:`_upload_slot` already paid for it)."""
+    def _launch_group(self, raws: np.ndarray, stamps: _Stamps,
+                      n_records: int) -> _InFlight:
+        """The megastep call + accounting of a group dispatch."""
         g = int(raws.shape[0])
         self._dispatch_calls += 1
         seq = self._dispatch_calls
-        if on_device:
-            dev, t_l = raws, time.perf_counter()
-        else:
-            with self.metrics.upload(seq) as up:
-                dev = self._put(raws)
-            t_l = up.t0
+        with self.metrics.upload(seq) as up:
+            dev = self._put(raws)
         with self.metrics.launch(seq) as la:
             self.table, self.stats, out = self.megasteps[g](
                 self.table, self.stats, self.params, dev
             )
         self._dispatched_chunks += g
         self._group_hist[g] = self._group_hist.get(g, 0) + 1
-        if on_device:
-            self._ring_partial_slots += 1
         self._note_step_s(g, la.seconds, out)
         return _InFlight(out, stamps, n_records, n_chunks=g,
-                         t_launch=t_l,
-                         put_s=put_s if on_device else la.t0 - t_l,
+                         t_launch=up.t0, put_s=la.t0 - up.t0,
                          launch_s=la.seconds, t_launched=la.t1, seq=seq)
 
     def _dispatch_group(self, raws: np.ndarray, stamps: _Stamps,
@@ -1033,10 +872,6 @@ class Engine:
         :meth:`_sink_group` ravels, so verdict extraction is unchanged.
         Latency is anchored at the OLDEST member's stamps (the honest
         group latency: earlier members waited for the group)."""
-        if self._pipe_active:
-            self._submit("group", raws, stamps, n_records,
-                         int(raws.shape[0]))
-            return
         self._inflight.append(self._launch_group(raws, stamps,
                                                  n_records))
 
@@ -1056,112 +891,6 @@ class Engine:
         self._staged_bytes += int(rows[0].nbytes) * g
         n_records = int(sum(int(raw[b, 0]) for raw, _ in group))
         self._dispatch_group(rows[:g], min(t for _, t in group), n_records)
-
-    # -- device-loop (drain ring) dispatch ----------------------------------
-
-    def _upload_slot(self, rows: np.ndarray, stamps: _Stamps,
-                     n_records: int) -> _Uploaded:
-        """EXPLICIT H2D of one staged ring slice — issued the moment
-        the slot fills, so the transfer overlaps whatever round is
-        still computing (the double-buffered half of the ring).  The
-        overlap accounting feeds
-        ``EngineReport.dispatch["device_loop"]["h2d"]``: an upload
-        issued while dispatched-but-unsunk work exists counts as
-        overlapped — that is the "device never waits on the host"
-        claim, measured rather than asserted."""
-        busy = self._busy_depth() > 0
-        # a slot uploads before its round has a dispatch ordinal: the
-        # span carries the slot's own upload ordinal
-        with self.metrics.upload(self._h2d_puts + 1) as up:
-            buf = self._put(rows)
-        dt = up.seconds
-        self._h2d_put_s += dt
-        self._h2d_puts += 1
-        if busy:
-            self._h2d_overlap_s += dt
-            self._h2d_puts_overlapped += 1
-        return _Uploaded(buf, stamps, n_records, dt)
-
-    def _launch_ring(self, devs: list, stamps: _Stamps,
-                     n_records: int, put_s: float = 0.0) -> _InFlight:
-        """The deep-scan call + accounting of a full ring round."""
-        g = self.ring * self._ring_chunks
-        self._dispatch_calls += 1
-        seq = self._dispatch_calls
-        with self.metrics.launch(seq) as la:
-            self.table, self.stats, out = self.ring_step(
-                self.table, self.stats, self.params, *devs
-            )
-        self._dispatched_chunks += g
-        self._group_hist[g] = self._group_hist.get(g, 0) + 1
-        self._ring_rounds += 1
-        # Ring-round refinement is GUARDED (PR 11 follow-up closed;
-        # :meth:`_note_round_s`): the launch wall alone omits the
-        # uploads+reap the warm seed deliberately includes, so the
-        # observation fed in is launch + the round's own upload wall,
-        # launch-absorbed rounds only, and the EWMA is floored at the
-        # warm seed — the estimate may sharpen UP toward the true
-        # round cost but can never decay below the seed and let
-        # _slo_round_fits keep waiting for rounds that land past the
-        # budget (the decaying-optimistic-estimate hazard).
-        self._note_round_s(-g, la.seconds + put_s, out)
-        return _InFlight(out, stamps, n_records, n_chunks=g,
-                         t_launch=la.t0, put_s=put_s,
-                         launch_s=la.seconds, t_launched=la.t1, seq=seq)
-
-    def _dispatch_ring(self, uploaded: list[_Uploaded]) -> None:
-        """ONE deep-scan dispatch over a full ring round (R uploaded
-        slot buffers; fused/device_loop.py): one in-flight entry of
-        ``ring * chunks`` batches whose RingOutput carries one merged
-        verdict wire PER SLOT — the sink harvests the round as a
-        single ``[R, 2K+4]`` fetch."""
-        devs = [u.dev for u in uploaded]
-        stamps = min(u.stamps for u in uploaded)
-        n_records = sum(u.n_records for u in uploaded)
-        put_s = sum(u.put_s for u in uploaded)
-        if self._pipe_active:
-            self._submit("ring", devs, stamps, n_records,
-                         self.ring * self._ring_chunks, put_s)
-            return
-        self._inflight.append(self._launch_ring(devs, stamps,
-                                                n_records, put_s))
-
-    def _dispatch_group_dev(self, dev: Any, stamps: _Stamps,
-                            n_records: int, put_s: float = 0.0) -> None:
-        """Megastep dispatch of an ALREADY-UPLOADED ring slot (a short
-        backlog left the round partial: the uploaded slices flush
-        through the ordinary top-rung megastep, byte-identical by
-        construction — the ring's slot body IS that megastep)."""
-        if self._pipe_active:
-            self._submit("group_dev", dev, stamps, n_records,
-                         self._ring_chunks, put_s)
-            return
-        self._inflight.append(self._launch_group(dev, stamps,
-                                                 n_records,
-                                                 on_device=True,
-                                                 put_s=put_s))
-
-    def _ring_from_pending(self) -> None:
-        """Stage one full ring round out of the inline pending list:
-        R arena slots of C wire buffers each, uploaded slot-by-slot
-        (each ``device_put`` overlapping in-flight compute), then one
-        deep-scan dispatch."""
-        b = self.cfg.batch.max_batch
-        c = self._ring_chunks
-        uploaded: list[tuple] = []
-        for _ in range(self.ring):
-            rows = self._arena.rows(self._arena.claim())
-            group = self._pending[:c]
-            del self._pending[:c]
-            with self.metrics.stage:
-                for i, (raw, _) in enumerate(group):
-                    rows[i][...] = raw
-            self._staged_batches += c
-            self._staged_bytes += int(rows[0].nbytes) * c
-            uploaded.append(self._upload_slot(
-                rows[:c], min(t for _, t in group),
-                int(sum(int(raw[b, 0]) for raw, _ in group))))
-        self._dispatch_ring(uploaded)
 
     def _rung_for(self, backlog: int) -> int:
         """THE coalescing policy, shared by the inline and sealed
@@ -1194,11 +923,8 @@ class Engine:
         to empty before returning — the pipe must read idle again
         before real traffic arrives."""
         if self._warm_buf is None:
-            words = (schema.COMPACT_RECORD_WORDS
-                     if self.wire == schema.WIRE_COMPACT16
-                     else schema.RECORD_WORDS)
             self._warm_buf = np.zeros(
-                (self.cfg.batch.max_batch + 1, words), np.uint32)
+                (self.cfg.batch.max_batch + 1, self._words), np.uint32)
         stamps = _inline_stamps(time.perf_counter())
         if rung > 1 and self._arena is not None:
             self._dispatch_mega([(self._warm_buf, stamps)] * rung)
@@ -1207,7 +933,7 @@ class Engine:
         self._reap(0)
 
     # -- latency-budget (SLO) policy ----------------------------------------
-    # Three advisory predicates over the warm-measured per-rung step-
+    # Two advisory predicates over the warm-measured per-rung step-
     # time EWMA, all no-ops at --slo-us 0.  They bound COALESCING, not
     # results: whatever group shapes they pick, the verdict state is
     # byte-identical (grouping is dispatch-granularity only, the PR 5
@@ -1253,21 +979,6 @@ class Engine:
         headroom = self._slo_budget_s - (time.perf_counter() - t_oldest)
         return self._rung_ewma_s.get(top, 0.0) >= headroom
 
-    def _slo_round_fits(self, t_oldest: float) -> bool:
-        """Device-loop round sizer: whether waiting to launch a FULL
-        deep-scan round can still land the oldest staged record
-        inside the budget.  With positive headroom smaller than a
-        round, the loops degrade to megastep slot flushes / the
-        ladder — smaller rungs instead of queueing; once the record
-        is already late the ring is BACK on (it is the
-        highest-throughput recovery path, the same reasoning as
-        :meth:`_slo_cap`'s no-cap rule)."""
-        headroom = self._slo_budget_s - (time.perf_counter() - t_oldest)
-        if headroom <= 0.0:
-            return True
-        key = -(self.ring * self._ring_chunks)  # ring-round EWMA key
-        return self._rung_ewma_s.get(key, 0.0) < headroom
-
     def _drain_pending(self, short: bool) -> None:
         """Apply the coalescing ladder to the inline pending list.
 
@@ -1280,47 +991,24 @@ class Engine:
         (``mega_n="auto"``) is where partial backlogs stop paying the
         full per-dispatch tax batch by batch.
 
-        Device-loop mode adds one rung ABOVE the ladder: a backlog
-        holding a whole ring round (``ring * top_rung``) goes as one
-        deep-scan dispatch; anything less falls through to the ladder
-        exactly as before — the ring only ever engages on backlogs
-        that were queueing anyway, so light-load latency is untouched
-        and ``device_loop=0`` remains the byte-identical baseline.
-
         Under ``--slo-us`` the WAITING is budget-bounded (policy block
         above): budget pressure turns the hold-for-backlog into the
         greedy flush — the existing flush IS the budget-exceeded
         path, just entered earlier — and the greedy flush skips
         CLIMBING to a rung whose expected step time the oldest
         record's remaining headroom no longer covers.  Existing
-        full-rung/round backlogs dispatch at full amortization either
+        full-rung backlogs dispatch at full amortization either
         way (the sub-linear-step argument in the SLO note below)."""
-        # SLO note: the full-amortization paths below (an EXISTING
-        # round/top-rung backlog) deliberately stay un-capped even in
+        # SLO note: the full-amortization path below (an EXISTING
+        # top-rung backlog) deliberately stays un-capped even in
         # budget mode — step time is sub-linear in group size, so for
         # a backlog that already exists the largest rung finishes
         # EVERY record soonest (splitting it only delays the tail and
         # collapses capacity; measured: capping a saturated drain's
         # rungs cost ~35 % throughput and spiralled pulse p99 ~50x).
-        # The budget bounds what the engine WAITS for — holds, round
-        # fills, batcher residency — and the greedy flush's climb.
+        # The budget bounds what the engine WAITS for — holds,
+        # batcher residency — and the greedy flush's climb.
         slo = self._slo_budget_s
-        # ring gating also covers the tiered-warm fill window: until
-        # the background thread installs the deep-scan executable
-        # (_ring_ready), backlogs drain through the ladder below —
-        # byte-identical, the ring's slot body IS the top megastep.
-        if self.ring and self._ring_ready:
-            while len(self._pending) >= self._pending_cap:
-                self._ring_from_pending()
-                self._reap(self.readback_depth)
-            if not short and not (
-                    slo and self._pending
-                    and self._slo_pressed(
-                        self._pending[0][1].t_enqueue)):
-                # a full poll means the backlog is still building
-                # toward the next round — hold the remainder (unless
-                # the budget says holding is no longer free)
-                return
         top = self._mega_sizes[0]
         while len(self._pending) >= top:
             self._dispatch_mega(self._pending[:top])
@@ -1480,7 +1168,7 @@ class Engine:
         stacking up, and consecutive ready batches go as one group."""
         # every serving loop passes through here each iteration — the
         # one place the artifact watcher's throttled mtime check covers
-        # inline, sealed, and ring loops alike (and the dispatch
+        # the inline and sealed loops alike (and the dispatch
         # watchdog's no-progress poll, same coverage argument)
         self._maybe_reload_artifact()
         self._watchdog.check(self._busy_depth())
@@ -1528,24 +1216,12 @@ class Engine:
     # -- the sink thread ----------------------------------------------------
 
     def _start_sink_thread(self) -> None:
-        if self._sink_active:
+        if self._sink_active or not self.sink_thread:
             return
-        if self.ring:
-            # device-loop mode: the pipeline worker (launch + sink)
-            # runs regardless of the sink_thread flag — it IS the
-            # mechanism that overlaps host staging with device compute
-            target, name = self._ring_worker, "fsx-devpipe"
-        elif self.sink_thread:
-            target, name = self._sink_worker, "fsx-sink"
-        else:
-            return
-        self._chan.name = ("device-pipeline worker" if self.ring
-                           else "sink thread")
         self._chan.reset()
         self._sink_thread_obj = threading.Thread(
-            target=target, name=name, daemon=True)
+            target=self._sink_worker, name="fsx-sink", daemon=True)
         self._sink_active = True
-        self._pipe_active = bool(self.ring)
         self._sink_thread_obj.start()
 
     def _stop_sink_thread(self) -> None:
@@ -1567,7 +1243,6 @@ class Engine:
             self._sink_thread_obj.join()
         self._sink_thread_obj = None
         self._sink_active = False
-        self._pipe_active = False
 
     def _sink_worker(self) -> None:
         """Sink-thread main: pop the oldest entry (blocking on its
@@ -1597,62 +1272,6 @@ class Engine:
                 if exc is not None:
                     return
         except BaseException as e:  # noqa: BLE001 — surfaced by _check_sink
-            self._chan.record_exc(e)
-
-    def _submit(self, kind: str, payload: Any, stamps: _Stamps,
-                n_records: int, n_chunks: int,
-                put_s: float = 0.0) -> None:
-        """Hand one pre-launch work item to the device-pipeline worker
-        (device-loop mode).  The channel's pending count rises at
-        SUBMIT time, so the ``readback_depth`` backpressure bound
-        covers queued-but-unlaunched work too — the wire/arena
-        reuse-safety arguments both lean on that."""
-        self._chan.submit((kind, payload, stamps, n_records, n_chunks,
-                           put_s),
-                          n_chunks)
-
-    def _ring_worker(self) -> None:
-        """Device-pipeline worker main (device-loop mode): pop the
-        oldest submission, LAUNCH it (the jit call — which on backends
-        whose step graphs execute synchronously, like XLA:CPU with its
-        inline scatter custom-calls, blocks for the whole round's
-        compute), then sink its output immediately.  FIFO by a single
-        worker: the carry chain (table/stats donation) stays
-        sequential, and ``on_reap`` still sees records in exact
-        arrival order.  Meanwhile the dispatch thread keeps polling,
-        staging and ``device_put``-ing the NEXT round's slots — the
-        double-buffered H2D overlap the report measures."""
-        try:
-            while True:
-                with self.metrics.sink_wait:
-                    got = self._chan.pop()
-                if got is None:
-                    return  # stop requested and queue drained
-                kind, payload, stamps, n_rec, n_chunks, put_s = got[0]
-                t0 = time.perf_counter()
-                exc: BaseException | None = None
-                try:
-                    if kind == "ring":
-                        entry = self._launch_ring(payload, stamps, n_rec,
-                                                  put_s)
-                    elif kind == "group_dev":
-                        entry = self._launch_group(payload, stamps, n_rec,
-                                                   on_device=True,
-                                                   put_s=put_s)
-                    elif kind == "group":
-                        entry = self._launch_group(payload, stamps, n_rec)
-                    else:
-                        entry = self._launch_single(payload, stamps, n_rec)
-                    self._sink_group([entry])
-                except BaseException as e:  # noqa: BLE001
-                    exc = e
-                # exception recorded ATOMICALLY with the pending
-                # decrement (the SinkChannel.complete discipline)
-                self._chan.complete(n_chunks,
-                                    time.perf_counter() - t0, exc)
-                if exc is not None:
-                    return
-        except BaseException as e:  # noqa: BLE001 — _check_sink surfaces
             self._chan.record_exc(e)
 
     def _sink_group(self, group: list[_InFlight]) -> None:
@@ -1727,29 +1346,23 @@ class Engine:
     def _sink_group_wire(self, group: list[_InFlight]) -> None:
         """The compact-wire sink (see :meth:`_sink_group`).
 
-        An entry's wire is either one ``[2K+4]`` buffer (single / mega
-        dispatch) or a ``[R, 2K+4]`` stack of per-slot wires (a
-        device-loop round, harvested at ring granularity: still ONE
-        D2H fetch for the whole round).  A round with ANY overflowed
-        slot wire falls back to the full block-array fetch for the
-        whole entry — the arrays cover every slot in chunk order, so
-        last-wins decode stays exact and no block is lost."""
+        An entry's wire is one ``[2K+4]`` buffer, a single's or a mega
+        dispatch's merged window.  An overflowed wire falls back to
+        the full block-array fetch for the whole entry — the arrays
+        cover every chunk in order, so last-wins decode stays exact
+        and no block is lost."""
         seq = group[0].seq
         with self.metrics.fetch(seq) as fetch:
-            if len(group) <= 2 or any(g.out.wire.ndim == 2
-                                      for g in group):
-                # per-entry fetch: ring wires are already deep-
-                # amortized, and mixed [2K+4]/[R, 2K+4] shapes cannot
-                # stack anyway
+            if len(group) <= 2:
                 wires = [jax.device_get(g.out.wire) for g in group]
             else:
                 wires = jax.device_get(
                     jnp.stack([g.out.wire for g in group]))
-            # K_MAX-overflow fallback: a batch (or a ring slot's
-            # merged window) condemned more flows than its wire holds
-            # — pay the full fetch once rather than lose a single
-            # block.  Fetched here, with the wires: the fetch span is
-            # all of the sink's waiting on the device.
+            # K_MAX-overflow fallback: a batch (or a group's merged
+            # window) condemned more flows than its wire holds — pay
+            # the full fetch once rather than lose a single block.
+            # Fetched here, with the wires: the fetch span is all of
+            # the sink's waiting on the device.
             full = {}
             for i, (g, w) in enumerate(zip(group, wires)):
                 self._d2h_bytes += w.nbytes
@@ -1763,11 +1376,9 @@ class Engine:
             parts_u: list[np.ndarray] = []
             now = 0.0
             for i, w in enumerate(wires):
-                rows = [decode_verdict_wire(row)
-                        for row in w.reshape(-1, w.shape[-1])]
-                for vw in rows:
-                    self._route_drop += vw.route_drop
-                    now = max(now, vw.now)
+                vw = decode_verdict_wire(w)
+                self._route_drop += vw.route_drop
+                now = max(now, vw.now)
                 # both counters in batches (an entry's n_chunks), so
                 # that they add up to the batches sunk
                 if i in full:
@@ -1779,8 +1390,8 @@ class Engine:
                     parts_u.append(full[i][1])
                 else:
                     self._sink_compact += group[i].n_chunks
-                    parts_k.extend(vw.key for vw in rows)
-                    parts_u.extend(vw.until_s for vw in rows)
+                    parts_k.append(vw.key)
+                    parts_u.append(vw.until_s)
             keys = (np.concatenate(parts_k) if len(parts_k) > 1
                     else parts_k[0])
             untils = (np.concatenate(parts_u) if len(parts_u) > 1
@@ -1824,10 +1435,8 @@ class Engine:
                 # charged the entry's OLDEST-record path (a
                 # conservative upper bound — earlier members waited
                 # for the group, the same anchoring e2e has always
-                # used).  hold ends where the step call began, less an
-                # upload made ahead of it, so the chain closes exactly
-                # whether the put ran in the launch section or, for a
-                # ring slot, before it.
+                # used).  hold ends where the step call began, less the
+                # upload made ahead of it, so the chain closes exactly.
                 self._lat.record(
                     total_s=t_done - st.t_enqueue,
                     staged_s=(g.t_launch - st.t_enqueue
@@ -1871,14 +1480,12 @@ class Engine:
 
         ``tiered=True`` is the boot-latency mode: only the SERVING
         TIER — singles plus the top rung, the shapes every drain
-        starts from — warms in the foreground (plus, under ``--slo-us``
-        with a drain ring, the ring itself: the round sizer's EWMA
-        seed must cover uploads AND reap, which only this quiescent
-        pass can measure).  The engine is serving the moment this
-        returns; a background thread (:meth:`_warm_worker`) fills the
-        remaining rungs/ring AOT-only — it never dispatches — and
-        publishes each executable with one reference rebind, growing
-        the ready set until the full ladder is live.  Byte-identity to
+        starts from — warms in the foreground.  The engine is serving
+        the moment this returns; a background thread
+        (:meth:`_warm_worker`) fills the remaining rungs AOT-only —
+        it never dispatches — and publishes each executable with one
+        reference rebind, growing the ready set until the full ladder
+        is live.  Byte-identity to
         a full-ladder warm is pinned by test: grouping is
         dispatch-granularity only."""
         if (self._warm_thread_obj is not None
@@ -1889,18 +1496,10 @@ class Engine:
                 "staged executables while the fill thread installs)")
         self._warm_thread_obj = None
         serving_sizes = self._mega_sizes
-        ring_now = bool(self.ring)
         fill_plan: list[tuple] = []
         if tiered and self._mega_sizes:
             serving_sizes = self._mega_sizes[:1]
-            # SLO + ring keeps the ring in the serving tier: run()'s
-            # auto-warm gate needs the negated round key seeded by a
-            # quiescent pass (the only measurement covering uploads
-            # AND reap), and the fill thread may never dispatch.
-            ring_now = bool(self.ring) and bool(self._slo_budget_s)
             fill_plan = [("mega", g) for g in self._mega_sizes[1:]]
-            if self.ring and not ring_now:
-                fill_plan.append(("ring",))
         boot: dict[str, Any] = {
             "tiered": bool(fill_plan),
             "variants": {},
@@ -1908,29 +1507,24 @@ class Engine:
         }
         # AOT install (cache load or lower().compile()) BEFORE the
         # dispatch ladder: installed executables replace the jit
-        # wrappers on self.step/self.megasteps/self.ring_step, so the
-        # ladder below triggers no compile on a warm cache.  Without a
+        # wrappers on self.step/self.megasteps, so the ladder below
+        # triggers no compile on a warm cache.  Without a
         # cache the ladder itself is the compile trigger, exactly the
         # historical path (tiered mode still AOT-compiles so the
         # background fill has executables to install).
         if self._cache is not None or fill_plan:
             names: list[tuple] = [("single",)]
             names += [("mega", g) for g in serving_sizes]
-            if ring_now:
-                names.append(("ring",))
             for name in names:
                 exe, entry = self._aot_build(name)
                 if exe is not None:
                     self._aot_install(name, exe)
                 boot["variants"][self._variant_label(name)] = entry
-        words = (schema.COMPACT_RECORD_WORDS
-                 if self.wire == schema.WIRE_COMPACT16
-                 else schema.RECORD_WORDS)
-        warm = np.zeros((self.cfg.batch.max_batch + 1, words), np.uint32)
+        warm = np.zeros((self.cfg.batch.max_batch + 1, self._words),
+                        np.uint32)
         # ONE dispatch ladder, run once to compile every staged
-        # variant (each ladder rung and the deep-scan ring graph are
-        # their own XLA artifacts) and — in SLO mode — a second,
-        # TIMED time to seed the per-rung step-time EWMA with
+        # variant (each ladder rung is its own XLA artifact) and — in
+        # SLO mode — a second, TIMED time to seed the per-rung step-time EWMA with
         # compile-free launch→sunk walls (backend-agnostic: the reap
         # blocks on the fetch, so the measure covers the compute the
         # launch call alone would hide on async backends).  Masked
@@ -1956,28 +1550,9 @@ class Engine:
                 self._reap(0)
                 if timed:
                     self._rung_ewma_s[g] = time.perf_counter() - t0
-            if ring_now:
-                zero_slot = np.zeros(
-                    (self._ring_chunks,) + warm.shape, np.uint32)
-                t0 = time.perf_counter()
-                self._dispatch_ring([
-                    self._upload_slot(zero_slot, _inline_stamps(t0), 0)
-                    for _ in range(self.ring)])
-                self._reap(0)
-                if timed:
-                    # ring ROUNDS key negated (attribute docstring):
-                    # a depth-1 round spans the top rung's chunk
-                    # count but its wall includes uploads+reap —
-                    # never share slots.  The seed is also the FLOOR
-                    # the online refinement may never dip below
-                    # (_note_round_s).
-                    key = -(self.ring * self._ring_chunks)
-                    self._rung_ewma_s[key] = time.perf_counter() - t0
-                    self._round_floor_s[key] = self._rung_ewma_s[key]
         # publish the ready set LAST: every executable above is
         # installed and compile-free before a drain may pick its rung
         self._ready_sizes = serving_sizes
-        self._ring_ready = ring_now
         # warm dispatches are compile triggers, not traffic — keep them
         # out of the dispatch-block accounting
         self._reset_dispatch_counters()
@@ -2041,15 +1616,13 @@ class Engine:
         wrapper or the executable runs, byte-identical results)."""
         if name[0] == "single":
             self.step = exe
-        elif name[0] == "mega":
-            self.megasteps = {**self.megasteps, name[1]: exe}
         else:
-            self.ring_step = exe
+            self.megasteps = {**self.megasteps, name[1]: exe}
 
     def _warm_worker(self) -> None:
         """Background warm fill (warm(tiered=True)): AOT-stage the
-        remaining ladder rungs / ring, largest value first, and grow
-        the ready set as each lands.  NEVER dispatches — the launch
+        remaining ladder rungs, largest first, and grow the ready
+        set as each lands.  NEVER dispatches — the launch
         and sink sections keep their single owners; everything this
         thread publishes (executables, ready set, boot block) is one
         reference rebind.  Fail-open: an error leaves the jit
@@ -2061,12 +1634,9 @@ class Engine:
                 label = self._variant_label(name)
                 if exe is not None:
                     self._aot_install(name, exe)
-                    if name[0] == "mega":
-                        self._ready_sizes = tuple(sorted(
-                            set(self._ready_sizes) | {name[1]},
-                            reverse=True))
-                    elif name[0] == "ring":
-                        self._ring_ready = True
+                    self._ready_sizes = tuple(sorted(
+                        set(self._ready_sizes) | {name[1]},
+                        reverse=True))
                 boot = dict(self._boot or {})
                 boot["variants"] = {**boot.get("variants", {}),
                                     label: entry}
@@ -2103,12 +1673,6 @@ class Engine:
         self._dispatched_chunks = 0
         self._staged_batches = 0
         self._staged_bytes = 0
-        self._ring_rounds = 0
-        self._ring_partial_slots = 0
-        self._h2d_put_s = 0.0
-        self._h2d_overlap_s = 0.0
-        self._h2d_puts = 0
-        self._h2d_puts_overlapped = 0
 
     # -- stream rebinding ---------------------------------------------------
 
@@ -2148,12 +1712,16 @@ class Engine:
             self.sink = sink
         if readback_depth is not None:
             self.readback_depth = readback_depth
+        if self.sealed and self._arena is None:
+            # an engine built on a record source without grouping
+            # never staged; the sealed loop stages every batch
+            self._arena = self._make_arena()
         quant = self.batcher.quant or None
         keep_t0 = self.batcher.t0_ns if t0_ns is None else t0_ns
         self.batcher = MicroBatcher(
             self.cfg.batch,
             t0_ns=keep_t0,
-            n_buffers=self.readback_depth + 2 + self._pending_cap,
+            n_buffers=self.readback_depth + 2 + self.mega_n,
             wire=self.wire,
             quant=quant,
         )
@@ -2514,17 +2082,6 @@ class Engine:
         this call: started here, drained and joined before the report
         is built, crash surfaced as a RuntimeError (module
         docstring)."""
-        if (self._slo_budget_s and self.ring
-                and -(self.ring * self._ring_chunks)
-                not in self._rung_ewma_s):
-            # the device-loop round sizer has NO online refinement
-            # (its estimate must include uploads+reap, which only the
-            # warm pass measures) — an unseeded key would silently
-            # disable the degrade-to-smaller-rungs behavior the SLO
-            # flag advertises on the ring path.  Nothing is in flight
-            # at run() start, so self-warming here is safe; callers
-            # that already warmed skip it (the key persists).
-            self.warm()
         self._start_sink_thread()
         try:
             rep = (self._run_sealed(max_batches, max_seconds)
@@ -2567,11 +2124,10 @@ class Engine:
 
         while not bounded():
             with self.metrics.poll:
-                # Mega mode polls up to the remaining GROUP capacity
-                # (one whole ring round in device-loop mode) so a deep
-                # source backlog can seal several batches in one
-                # drain; otherwise exactly one batch's worth.
-                group_room = max(self._pending_cap - len(self._pending), 1)
+                # Mega mode polls up to the remaining GROUP capacity so
+                # a deep source backlog can seal several batches in
+                # one drain; otherwise exactly one batch's worth.
+                group_room = max(self.mega_n - len(self._pending), 1)
                 requested = group_room * cfg_b.max_batch - self.batcher.fill
                 records = self.source.poll(requested)
                 if self._t0_auto and len(records):
@@ -2718,10 +2274,7 @@ class Engine:
         Semantics otherwise mirror :meth:`run`: depth-capped pipe,
         readiness reaping, ladder grouping on backlog
         (:meth:`_drain_pending`'s policy), deadline behavior delegated
-        to the workers (they own the micro-batchers now).  A source
-        without the staging API (a stub fleet) falls back to the
-        copying ``poll_batches`` protocol with arena staging at
-        dispatch time."""
+        to the workers (they own the micro-batchers now)."""
         t_start = time.perf_counter()
         src = self.source
         if not self._t0_auto and hasattr(src, "set_t0"):
@@ -2741,16 +2294,7 @@ class Engine:
                 return True
             return False
 
-        if self._arena is not None and hasattr(src, "poll_batches_into"):
-            if self.ring:
-                self._sealed_loop_ring(src, bounded)
-            else:
-                self._sealed_loop_arena(src, bounded)
-        else:
-            self._sealed_loop_copy(src, bounded)
-        for raw, stamps in self._pending:
-            self._dispatch(raw, stamps)
-        self._pending.clear()
+        self._sealed_loop_arena(src, bounded)
         self._reap(0)
         with self.metrics.report:
             return self._build_report(time.perf_counter() - t_start)
@@ -2764,7 +2308,7 @@ class Engine:
         self._t0_auto = False
 
     def _sealed_idle(self, src) -> bool:
-        """Shared empty-poll tail of the sealed loops: True = source
+        """Empty-poll tail of the sealed loop: True = source
         exhausted, stop serving."""
         if src.exhausted():
             return True
@@ -2865,162 +2409,6 @@ class Engine:
             self._dispatch(rows[done], metas[done][0])
             done += 1
 
-    def _sealed_loop_ring(self, src, bounded) -> None:
-        """The device-loop sealed loop: the zero-copy staging protocol
-        of :meth:`_sealed_loop_arena` feeding the drain ring.
-
-        One arena slot at a time fills to exactly ``chunks`` batches
-        (the staging memcpy is still the pipeline's ONE host copy);
-        the moment a slot fills it is ``device_put`` — while the
-        previous round still computes, which is the double-buffered
-        H2D — and when ``ring`` slots are uploaded they launch as ONE
-        deep-scan dispatch carrying table/stats across the whole round.
-        A short poll degrades gracefully: uploaded slots flush through
-        the ordinary top-rung megastep (byte-identical — the ring's
-        slot body IS that megastep) and the partial slot drains through
-        the coalescing ladder, so the ring only ever engages on
-        backlogs that were queueing anyway.  The claim discipline is
-        unchanged (a fresh slot only after the current one is staged
-        away, never on an empty poll); the ring-aware slot bound
-        (``DispatchArena.ring_safe_slots``) covers the up-to-``ring``
-        in-flight uploads this loop adds."""
-        c = self._ring_chunks
-        slo = self._slo_budget_s
-        uploaded: list[_Uploaded] = []
-        rows: np.ndarray | None = None
-        fill = 0
-        metas: list[tuple[_Stamps, int]] = []  # (stamps, n_records)/row
-        while not bounded():
-            if rows is None:
-                rows = self._arena.rows(self._arena.claim())
-                fill = 0
-                metas = []
-            want = c - fill
-            batches = []
-            if want > 0:
-                with self.metrics.poll:
-                    batches = src.poll_batches_into(
-                        rows[fill:c], want,
-                        pop_timer=self.metrics.pop,
-                        stage_timer=self.metrics.stage)
-            if self._t0_auto and batches and src.t0_ns:
-                self._adopt_fleet_t0(src)
-            for sb in batches:
-                self.batcher.batches_emitted += 1
-                self.batcher.records_emitted += sb.n_records
-                self._staged_batches += 1
-                self._staged_bytes += int(sb.raw.nbytes)
-                metas.append((_sealed_stamps(sb), sb.n_records))
-                fill += 1
-            if self._gov is not None and batches:
-                self._gov.note_arrivals(
-                    time.perf_counter(),
-                    sum(sb.n_records for sb in batches))
-            short = len(batches) < want
-            if fill == c:
-                # slot full: upload NOW (overlapping in-flight compute)
-                uploaded.append(self._upload_slot(
-                    rows[:c], min(m[0] for m in metas),
-                    sum(m[1] for m in metas)))
-                rows = None
-                if len(uploaded) == self.ring:
-                    if self._ring_ready:
-                        self._dispatch_ring(uploaded)
-                    else:
-                        # tiered warm still filling the deep-scan
-                        # executable: flush the round's slots through
-                        # the top-rung megastep (byte-identical — the
-                        # ring's slot body IS that megastep), exactly
-                        # the partial-round path below
-                        for u in uploaded:
-                            self._dispatch_group_dev(
-                                u.dev, u.stamps, u.n_records,
-                                u.put_s)
-                    uploaded = []
-                    self._reap(self.readback_depth)
-            elif short:
-                # partial round: flush uploaded slots as megasteps
-                # (arrival order before the younger partial slot)...
-                for u in uploaded:
-                    self._dispatch_group_dev(u.dev, u.stamps,
-                                             u.n_records, u.put_s)
-                    self._reap(self.readback_depth)
-                uploaded = []
-                # ...then the partial slot through the ladder (rungs
-                # budget-capped under --slo-us, like every ladder)
-                if fill:
-                    done = 0
-                    while fill - done:
-                        g = self._rung_for(fill - done)
-                        if slo:
-                            g = min(g, self._slo_cap(
-                                metas[done][0].t_enqueue))
-                        if g > 1:
-                            self._dispatch_group(
-                                rows[done:done + g],
-                                min(m[0] for m in metas[done:done + g]),
-                                sum(m[1] for m in metas[done:done + g]))
-                        else:
-                            self._dispatch(rows[done], metas[done][0])
-                        done += g
-                        self._reap(self.readback_depth)
-                    rows = None
-            if (slo and uploaded
-                    and not self._slo_round_fits(
-                        uploaded[0].stamps.t_enqueue)):
-                # the device-loop round sizer: waiting to fill the
-                # whole ring would cost the oldest uploaded slot its
-                # budget — flush the uploaded slots through the
-                # ordinary top-rung megastep NOW (byte-identical; the
-                # ring's slot body IS that megastep) and let the next
-                # round start fresh.  Degrade-to-smaller, not queue.
-                for u in uploaded:
-                    self._dispatch_group_dev(u.dev, u.stamps,
-                                             u.n_records, u.put_s)
-                    self._reap(self.readback_depth)
-                uploaded = []
-            self._reap_ready()
-            if not batches and self._sealed_idle(src):
-                break
-        # bounded exit: drain uploaded slots, then any staged rows
-        for u in uploaded:
-            self._dispatch_group_dev(u.dev, u.stamps, u.n_records,
-                                     u.put_s)
-        if rows is not None and fill:
-            for i in range(fill):
-                self._dispatch(rows[i], metas[i][0])
-
-    def _sealed_loop_copy(self, src, bounded) -> None:
-        """Legacy copying protocol (sources without
-        ``poll_batches_into``): dequeue private copies, group through
-        the inline pending ladder (arena staging happens at dispatch
-        time in :meth:`_dispatch_mega`)."""
-        while not bounded():
-            with self.metrics.poll:
-                want = (max(self._pending_cap - len(self._pending), 1)
-                        if self.mega_n > 0 else 4)
-                batches = src.poll_batches(want)
-                if self._t0_auto and batches and src.t0_ns:
-                    self._adopt_fleet_t0(src)
-                for sb in batches:
-                    self.batcher.batches_emitted += 1
-                    self.batcher.records_emitted += sb.n_records
-                if self._gov is not None and batches:
-                    self._gov.note_arrivals(
-                        time.perf_counter(),
-                        sum(sb.n_records for sb in batches))
-            if self.mega_n > 0:
-                for sb in batches:
-                    self._pending.append((sb.raw, _sealed_stamps(sb)))
-                self._drain_pending(short=len(batches) < want)
-            else:
-                for sb in batches:
-                    self._dispatch(sb.raw, _sealed_stamps(sb))
-                    self._reap(self.readback_depth)
-            self._reap_ready()
-            if not batches and self._sealed_idle(src):
-                break
-
     def _build_report(self, wall: float) -> EngineReport:
         # "now" on the device clock (t0-anchored stream seconds, not wall
         # time) comes from the reaped step outputs — no extra reduction.
@@ -3060,49 +2448,17 @@ class Engine:
         # host↔device boundary itself, not a host copy.  Inline singles
         # dispatch the batcher's own buffer (no staging), so a pure
         # inline single-dispatch run reads 0.0.
-        # Device-loop accounting: rounds, the per-round shape, ring
-        # occupancy (how much of the staged flow went through full
-        # rounds vs partial-backlog slot flushes) and the measured H2D
-        # overlap — the "device never waits on the host" claim as a
-        # number, re-proved per run by scripts/device_loop_smoke.py.
-        device_loop = None
-        if self.ring:
-            full = self._ring_rounds * self.ring
-            staged_slots = full + self._ring_partial_slots
-            device_loop = {
-                "ring": self.ring,
-                "chunks_per_slot": self._ring_chunks,
-                "batches_per_round": self.ring * self._ring_chunks,
-                "rounds": self._ring_rounds,
-                "steps_per_round": self.ring,   # megasteps / round trip
-                "partial_slot_flushes": self._ring_partial_slots,
-                "ring_occupancy": round(full / staged_slots, 4)
-                if staged_slots else 0.0,
-                "h2d": {
-                    "puts": self._h2d_puts,
-                    "puts_overlapped": self._h2d_puts_overlapped,
-                    "put_s": round(self._h2d_put_s, 6),
-                    "overlap_s": round(self._h2d_overlap_s, 6),
-                    "overlap_fraction": round(
-                        self._h2d_overlap_s / self._h2d_put_s, 4)
-                    if self._h2d_put_s else 0.0,
-                },
-            }
         dispatch = {
-            "mode": ("device_loop" if self.ring
-                     else "adaptive" if self.mega_auto
+            "mode": ("adaptive" if self.mega_auto
                      else "fixed" if self.mega_n else "single"),
             "mega_n": self.mega_n,
-            "device_loop": device_loop,
             # latency-budget serving (--slo-us): the budget and the
             # warm-measured per-rung step-time EWMA the deadline-aware
             # policy bounded coalescing with.  None = throughput mode.
             "slo": ({
                 "slo_us": self.slo_us,
-                # negated keys are ring ROUNDS (attribute docstring)
                 "rung_ewma_ms": {
-                    (str(k) if k > 0 else f"round{-k}"):
-                        round(v * 1e3, 4)
+                    str(k): round(v * 1e3, 4)
                     for k, v in sorted(self._rung_ewma_s.items())},
             } if self.slo_us else None),
             "group_sizes": list(self._mega_sizes),
@@ -3210,69 +2566,3 @@ class Engine:
             device=dict(self._device),
             spans=span_store(hists),
         )
-
-
-# ---------------------------------------------------------------------------
-# ring-depth autotuning (fsx serve --device-loop auto)
-# ---------------------------------------------------------------------------
-
-def calibrate_ring_depth(
-    cfg: FsxConfig,
-    params: Any | None = None,
-    mesh: Any | None = None,
-    mega_n: int | str = "auto",
-    candidates: tuple[int, ...] = (2, 4, 8),
-    batches: int = 48,
-    seed: int = 17,
-) -> tuple[int, dict]:
-    """Measure a short synthetic calibration drain at each candidate
-    ring depth and pick one (``fsx serve --device-loop auto``).
-
-    The drive half of the autotuner: for every candidate depth a
-    throwaway engine serves a deep prefilled synthetic backlog through
-    the inline ring path, and the measured
-    ``dispatch["device_loop"]`` block — H2D ``overlap_fraction`` above
-    all, the number the ring exists to maximize — feeds the pure
-    policy in :func:`flowsentryx_tpu.fused.device_loop
-    .choose_ring_depth`.  Each candidate stages its own deep-scan
-    graph, so calibration costs one XLA compile per depth — seconds,
-    paid once at the boot of a long-lived server (announced by the
-    CLI), exactly like ``warm()``.
-
-    Table/stats state never leaks into serving: every candidate runs
-    its own engine and the caller boots a FRESH engine at the chosen
-    depth.
-    """
-    from flowsentryx_tpu.engine.sources import ArraySource
-    from flowsentryx_tpu.engine.traffic import (
-        Scenario, TrafficGen, TrafficSpec,
-    )
-    from flowsentryx_tpu.engine.writeback import NullSink
-
-    recs = TrafficGen(TrafficSpec(
-        scenario=Scenario.UDP_FLOOD_MULTI, rate_pps=1e7,
-        n_attack_ips=8, n_benign_ips=24, attack_fraction=0.8,
-        seed=seed,
-    )).next_records(batches * cfg.batch.max_batch)
-    measurements: list[dict] = []
-    for d in sorted(set(int(c) for c in candidates)):
-        eng = Engine(cfg, ArraySource(np.copy(recs)), NullSink(),
-                     params=params, mesh=mesh, mega_n=mega_n,
-                     device_loop=d, sink_thread=False)
-        eng.warm()
-        t0 = time.perf_counter()
-        rep = eng.run()
-        wall = time.perf_counter() - t0
-        dl = rep.dispatch["device_loop"]
-        measurements.append({
-            "ring": d,
-            "rounds": dl["rounds"],
-            "ring_occupancy": dl["ring_occupancy"],
-            "overlap_fraction": dl["h2d"]["overlap_fraction"],
-            "records_per_s": round(rep.records / max(wall, 1e-9), 1),
-        })
-    from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-    depth, detail = choose_ring_depth(measurements)
-    detail["calibration_batches"] = batches
-    return depth, detail

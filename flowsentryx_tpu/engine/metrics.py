@@ -391,10 +391,7 @@ class PipelineMetrics:
     explicit H2D put); ``launch`` (the step call: the enqueue on an
     async backend); ``backpressure`` (the blocking wait for the pipe
     to drain to ``readback_depth``); ``idle`` (the sleeps on an empty
-    poll); ``report``.  In device-loop mode the pipeline worker owns
-    ``launch``, and ``upload`` has two writers (slot uploads on the
-    dispatch thread, a partial flush's put on the worker): a lost
-    count there is tolerated, nothing reads it in that mode.
+    poll); ``report``.
 
     Sink section (the sink thread; the dispatch thread in
     single-thread mode): ``wait`` (nothing queued), ``fetch`` (the

@@ -185,7 +185,7 @@ class ShardedIngest:
         #: queue drains to empty, the remaining shards keep serving,
         #: the death is surfaced in ``ingest_stats()``.  True surfaces
         #: the crash as the same loud dispatch-side RuntimeError the
-        #: engine's sink/pipeline workers raise (the unified
+        #: engine's sink thread raises (the unified
         #: :class:`~flowsentryx_tpu.sync.channel.WorkerCrash` path) —
         #: once the corpse's queue is drained, so no sealed batch is
         #: lost.  ``fsx serve --strict-ingest`` wires it.
@@ -421,8 +421,7 @@ class ShardedIngest:
     def _surface_crash(self) -> None:
         """Strict-mode crash propagation: raise the recorded
         :class:`WorkerCrash` on the DISPATCH side — the same loud
-        RuntimeError shape the engine's sink thread and device-pipeline
-        worker die with — but only once every dead worker's queue is
+        RuntimeError shape the engine's sink thread dies with — but only once every dead worker's queue is
         drained, so sealed batches that escaped the corpse still
         serve (the drain guarantee strict mode keeps)."""
         if not self.strict or self._crash is None:

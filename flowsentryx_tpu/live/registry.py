@@ -89,16 +89,6 @@ PROGRESS: tuple[ProgressEntry, ...] = (
         bound="POP_WAIT_S",
         proof="channel_stop_drain_live"),
     ProgressEntry(
-        name="engine_ring_worker",
-        path="flowsentryx_tpu/engine/engine.py",
-        qualname="Engine._ring_worker",
-        kind="poll",
-        wake="SinkChannel.pop (submit/stop notify_all)",
-        fairness="weak (dispatch thread lives while work is queued)",
-        obligation="every staged launch retires or the exc recorded",
-        bound="POP_WAIT_S",
-        proof="channel_stop_drain_live"),
-    ProgressEntry(
         name="engine_run_inline",
         path="flowsentryx_tpu/engine/engine.py",
         qualname="Engine._run_inline",

@@ -258,10 +258,10 @@ def table_summary(
         use_pallas=(not table.capacity % _CHUNK and not sharded
                     and not _interpret()),
     )
-    counts = jax.device_get(counts)
+    counts = jax.device_get(counts)  # noqa: host half, outside any jit
     return {
         "tracked": int(counts[0]),
         "blocked": int(counts[1]),
         "stale": int(counts[2]),
-        "newest_seen_s": float(jax.device_get(newest)),
+        "newest_seen_s": float(jax.device_get(newest)),  # noqa: same
     }

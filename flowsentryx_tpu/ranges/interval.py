@@ -28,8 +28,8 @@ import numpy as np
 
 #: Elements above which a per-element interval array collapses to its
 #: scalar join.  The wire buffers ([B+1, 12] at the default batch) and
-#: the device-loop's on-device ``[R, C, B+1, 4]`` slot stack are far
-#: inside it; a 1M-row table column is outside (and needs no per-row
+#: a mega group's ``[N, B+1, 4]`` stack are far inside it; a 1M-row
+#: table column is outside (and needs no per-row
 #: precision: its seed is one contract for every row).  Object arrays
 #: store pointers, so even the cap costs ~16 MB transiently.
 FULL_CAP = 1 << 21

@@ -2,8 +2,8 @@
 
 Re-stages every serving-step variant through the audit runner's shared
 staging surface (:func:`flowsentryx_tpu.audit.runner.stage_variants` —
-singles, sharded, every mega rung, device-loop rings, eviction epochs
-via the caller's config), seeds each staged ``ClosedJaxpr``'s inputs
+singles, sharded, every mega rung, eviction epochs via the caller's
+config), seeds each staged ``ClosedJaxpr``'s inputs
 from the declared range registry, runs the interval prover, audits the
 ``WRAP_OK`` registry for staleness, proves the three planted negative
 controls still fire, and (when a distill artifact is available) runs
@@ -147,7 +147,6 @@ def run_ranges(
     mega_n: int = 2,
     variants: tuple[str, ...] | None = None,
     mega_sizes: tuple[int, ...] | None = None,
-    device_loop: int = 0,
     artifact: str | None = DEFAULT_ARTIFACT,
     with_negatives: bool = True,
 ) -> RangesReport:
@@ -159,8 +158,7 @@ def run_ranges(
     containment bridge."""
     staged, notes, params = stage_variants(
         cfg, params=params, mesh=mesh, mega_n=mega_n,
-        variants=variants, donate=False, mega_sizes=mega_sizes,
-        device_loop=device_loop)
+        variants=variants, donate=False, mega_sizes=mega_sizes)
 
     reports: list[VariantRanges] = []
     match_totals: dict[str, int] = {}
@@ -219,7 +217,6 @@ def run_ranges(
             "mesh_devices": int(mesh.devices.size)
             if mesh is not None else 1,
             "mega_n": mega_n,
-            "device_loop": device_loop,
             "deploy_horizon_s": schema.RANGE_DEPLOY_HORIZON_S,
         },
         backend=jax.default_backend(),

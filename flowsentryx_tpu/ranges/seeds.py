@@ -55,9 +55,8 @@ def _obj_full(shape, lo, hi) -> IVal:
 def wire_seed(shape: tuple, wire: str, max_batch: int) -> IVal:
     """Per-element seed of one wire buffer argument.
 
-    ``shape`` may carry leading group axes (``[N, B+1, w]`` mega
-    groups, ``[C, B+1, w]`` device-loop slots); the per-row contract is
-    tiled across them.  Record rows: full u32.  Metadata row (row B):
+    ``shape`` may carry a leading group axis (``[N, B+1, w]`` mega
+    groups); the per-row contract is tiled across it.  Record rows: full u32.  Metadata row (row B):
     the encoder contracts above."""
     words = shape[-1]
     rows = shape[-2]

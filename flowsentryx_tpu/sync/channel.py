@@ -1,7 +1,7 @@
 """The dispatch↔worker handoff protocol, as one small real class.
 
 :class:`SinkChannel` is the cv-guarded bounded pipe between the
-engine's dispatch thread and its sink/device-pipeline worker — the
+engine's dispatch thread and its sink worker — the
 queue, the dispatched-but-unsunk batch count the ``readback_depth``
 backpressure waits on, the stop flag, and the crash slot.  It used to
 live as five loose ``Engine`` attributes (``_sinkq``/``_sink_pending``/
@@ -24,8 +24,8 @@ woken by the completing notify can never observe (pending drained,
 crash unset) for work that actually crashed.  The dispatch side
 surfaces it loudly through :meth:`check` (a RuntimeError naming the
 worker), which every engine poll/reap passes through.  The sink
-thread, the device-pipeline worker and strict-mode ingest death all
-funnel through this same shape, so a dead worker of ANY type reads the
+thread and strict-mode ingest death both funnel through this same
+shape, so a dead worker of ANY type reads the
 same at the dispatch loop.
 
 Timing constants come from :mod:`flowsentryx_tpu.sync.tuning`.
@@ -42,7 +42,7 @@ from flowsentryx_tpu.sync import tuning
 
 
 class WorkerCrash(RuntimeError):
-    """A pipeline worker died; raised on the DISPATCH thread by
+    """A worker of the host pipeline died; raised on the DISPATCH thread by
     :meth:`SinkChannel.check` so the engine fails loudly instead of
     serving on with verdicts silently discarded."""
 
@@ -65,7 +65,7 @@ class SinkChannel:
 
     def __init__(self, name: str = "worker"):
         #: Worker name for crash diagnostics ("sink thread",
-        #: "device-pipeline worker", "ingest worker 3").
+        #: "ingest worker 3").
         self.name = name
         self.cv = threading.Condition()
         self._q: deque = deque()

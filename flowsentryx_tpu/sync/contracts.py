@@ -1,8 +1,8 @@
 """Thread-contract lint: the declarative registry of shared mutable
 state in the host pipeline plus the AST pass that enforces it.
 
-The host plane (PRs 1/3/5/7) is genuinely concurrent — dispatch
-thread, sink thread, device-pipeline worker, N drain-worker processes,
+The host plane (PRs 1/3/5) is genuinely concurrent — dispatch
+thread, sink thread, warm-fill thread, N drain-worker processes,
 SPSC queues with a TSO cursor protocol — and until now its disciplines
 lived only in docstrings.  This module makes them *checkable*:
 
@@ -78,9 +78,8 @@ class FieldContract:
     * ``"section:<name>"`` — accessed only inside the named exclusive
       code section (``ClassPlan.sections``): a set of methods that,
       by the runtime mode protocol, never run concurrently with each
-      other or with any other accessor (e.g. the launch section runs
-      on the dispatch thread OR the pipeline worker, never both —
-      the interleave checker exercises that exclusivity).
+      other or with any other accessor (e.g. the sink section runs
+      on the dispatch thread OR the sink thread, never both).
     * ``"cv"`` — every access lexically under ``with self.<lock>:``.
     * ``"cv-write"`` — writes under the lock; unlocked reads are
       declared benign (single CPython reference/int loads).
@@ -156,34 +155,28 @@ _ENGINE_QUIESCENT = (
 
 _ENGINE_LAUNCH = (
     # The launch section: mutates the device carry (table/stats) and
-    # the dispatch accounting.  Runs on the dispatch thread in
-    # sink-thread mode, on the device-pipeline worker in ring mode —
-    # never both: _pipe_active routes every _dispatch* through _submit
-    # while the worker owns launches (interleave.py exercises this).
-    # _note_step_s is the launch tail that folds the measured step
-    # wall into the SLO EWMA table — same single-launcher exclusivity;
-    # _note_round_s is its ring-round twin (guarded refinement of the
-    # negated round keys, floored at the warm seed).
-    "_launch_single", "_launch_group", "_launch_ring", "_note_step_s",
-    "_note_round_s",
+    # the dispatch accounting.  Runs on the dispatch thread, the one
+    # launcher.  _note_step_s is the launch tail that folds the
+    # measured step wall into the SLO EWMA table.
+    "_launch_single", "_launch_group", "_note_step_s",
 )
 
 _ENGINE_SINK = (
     # The sink section: fetch + decode + writeback accounting.  Runs
     # on the dispatch thread in single-thread mode, on the sink thread
-    # or pipeline worker otherwise — FIFO by a single owner either
-    # way, so each field has one writer at a time.
+    # otherwise — FIFO by a single owner either way, so each field has
+    # one writer at a time.
     "_sink_group", "_sink_group_wire", "_apply_updates",
 )
 
 _LAUNCH = FieldContract(
     "section:launch",
-    "device carry + dispatch accounting: single launcher at a time "
-    "(dispatch thread XOR pipeline worker, routed by _pipe_active)")
+    "device carry + dispatch accounting: the dispatch thread is the "
+    "one launcher")
 _SINK = FieldContract(
     "section:sink",
     "sink accounting: single sinker at a time (dispatch thread in "
-    "single-thread mode, else the sink/pipeline worker, FIFO)")
+    "single-thread mode, else the sink thread, FIFO)")
 _DISP = FieldContract(
     "dispatch",
     "dispatch-thread-owned staging/polling state; no worker touches it")
@@ -191,15 +184,14 @@ _DISP = FieldContract(
 ENGINE_PLAN = ClassPlan(
     module="flowsentryx_tpu/engine/engine.py",
     cls="Engine",
-    worker_targets=("_sink_worker", "_ring_worker", "_warm_worker"),
+    worker_targets=("_sink_worker", "_warm_worker"),
     sections={"launch": _ENGINE_LAUNCH, "sink": _ENGINE_SINK},
     quiescent=_ENGINE_QUIESCENT,
     fields={
         # -- launch section -------------------------------------------
         "table": _LAUNCH, "stats": _LAUNCH,
         "_dispatch_calls": _LAUNCH, "_dispatched_chunks": _LAUNCH,
-        "_group_hist": _LAUNCH, "_ring_rounds": _LAUNCH,
-        "_ring_partial_slots": _LAUNCH,
+        "_group_hist": _LAUNCH,
         # -- sink section ---------------------------------------------
         "_d2h_bytes": _SINK, "_sink_compact": _SINK,
         "_sink_fallback": _SINK, "_route_drop": _SINK,
@@ -225,19 +217,10 @@ ENGINE_PLAN = ClassPlan(
             "helpers read it ADVISORILY — a stale float read can only "
             "mis-size a coalescing group, never corrupt state (each "
             "value is a whole-object float store, atomic in CPython); "
-            "run()'s ring-seed probe reads it BEFORE any worker "
-            "thread is started (the auto-warm gate); _run_inline's "
-            "read feeds the governor's pre-warm lead window — the "
-            "same advisory-float argument",
-            extra=("_slo_cap", "_slo_pressed", "_slo_round_fits",
-                   "_deadline_flush_due", "run", "_run_inline")),
-        "_round_floor_s": FieldContract(
-            "section:launch",
-            "warm-seed floors for the negated ring-round EWMA keys: "
-            "written only by the quiescent warm pass, read by the "
-            "launch tail (_note_round_s) to keep the guarded online "
-            "refinement from decaying the round estimate below the "
-            "only measurement that saw uploads AND reap"),
+            "_run_inline's read feeds the governor's pre-warm lead "
+            "window — the same advisory-float argument",
+            extra=("_slo_cap", "_slo_pressed",
+                   "_deadline_flush_due", "_run_inline")),
         "slo_us": FieldContract(
             "quiescent-write",
             "latency-budget mode flag (--slo-us): written only at "
@@ -248,9 +231,7 @@ ENGINE_PLAN = ClassPlan(
         # -- dispatch-thread-owned ------------------------------------
         "_inflight": _DISP, "_pending": _DISP, "_arena": _DISP,
         "batcher": _DISP, "_staged_batches": _DISP,
-        "_staged_bytes": _DISP, "_h2d_put_s": _DISP,
-        "_h2d_overlap_s": _DISP, "_h2d_puts": _DISP,
-        "_h2d_puts_overlapped": _DISP, "_t0_auto": _DISP,
+        "_staged_bytes": _DISP, "_t0_auto": _DISP,
         "_watch_path": _DISP, "_watch_mtime": _DISP,
         "_watch_next": _DISP, "_hot_swaps": _DISP,
         "_gov": FieldContract(
@@ -291,11 +272,6 @@ ENGINE_PLAN = ClassPlan(
             "dict per AOT install ({**old, g: exe}) — never an item "
             "store — so a launch mid-install sees the old or the new "
             "dict, both serving byte-identical rungs"),
-        "ring_step": FieldContract(
-            "atomic-ref",
-            "the deep-scan executable, same rebind-only install story "
-            "as megasteps; the ring only engages after _ring_ready "
-            "flips, but the rebind alone is already safe"),
         "_ready_sizes": FieldContract(
             "atomic-ref",
             "the READY rung set (tiered warm): grown by the fill "
@@ -305,11 +281,6 @@ ENGINE_PLAN = ClassPlan(
             "uninstalled one (the install rebind happens-before the "
             "ready-set rebind on the fill thread, and CPython "
             "publishes stores in order under the GIL)"),
-        "_ring_ready": FieldContract(
-            "atomic-ref",
-            "ring-engagement flag, flipped once by the fill thread "
-            "after ring_step installs; a stale False only routes one "
-            "more round through the byte-identical megastep flush"),
         "_boot": FieldContract(
             "atomic-ref",
             "the EngineReport.boot block: warm() seeds it quiescent, "
@@ -358,9 +329,6 @@ ENGINE_PLAN = ClassPlan(
             "quiescent-write",
             "mode flag: written only while no worker exists "
             "(_start/_stop_sink_thread); racy reads are stable"),
-        "_pipe_active": FieldContract(
-            "quiescent-write",
-            "ring-mode routing flag, same lifecycle as _sink_active"),
         "_chan": FieldContract(
             "documented",
             "the SinkChannel: its own cv discipline is enforced in "
@@ -370,10 +338,7 @@ ENGINE_PLAN = ClassPlan(
             "the spans (metrics.PipelineMetrics), one writing thread "
             "each: poll/pop/stage/backpressure/idle/report on the "
             "dispatch thread, upload/launch in the launch section, "
-            "sink_wait/fetch/decode/apply/e2e in the sink section; "
-            "ring mode alone gives upload a second writer (slot "
-            "uploads on the dispatch thread beside a partial flush's "
-            "put on the pipeline worker) and tolerates a lost count"),
+            "sink_wait/fetch/decode/apply/e2e in the sink section"),
         "sink": FieldContract(
             "documented",
             "t0_ns written on the dispatch thread only before the "
@@ -413,7 +378,7 @@ GOSSIP_PLAN = ClassPlan(
     sections={
         # publish: called from Engine._apply_updates — the engine's
         # SINK section, single owner at a time (dispatch thread in
-        # single-thread mode, else the sink/pipeline worker).
+        # single-thread mode, else the sink thread).
         "publish": ("publish",),
         # merge: called from Engine._reap_ready — always the dispatch
         # thread.  The two sections therefore CAN run concurrently,

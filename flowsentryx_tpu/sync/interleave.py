@@ -47,22 +47,22 @@ What is checked (and why these workloads)
   order must hold.  The ``queue_premature_release`` negative releases
   before reading — the cursor misuse the SPSC contract forbids — and
   must produce an overwritten-view counterexample.
-* **DispatchArena reuse bound, proved TIGHT** — the ring bound
-  ``ring_safe_slots(depth, ring) = depth + ring + 1``
+* **DispatchArena reuse bound, proved TIGHT** — the bound
+  ``safe_slots(depth) = depth + 2``
   (engine/arena.py, derivation in docs/CONCURRENCY.md).  The model
   drives the real arena under the CONTRACT discipline — a claim needs
   only "previous slot fully dispatched", so staging the next slot may
   overlap the just-submitted work's backpressure wait (ONE slot of
   lookahead: the double-buffered order, and the point of having more
   than one slot), the ``readback_depth`` reap catching up before any
-  second claim, uploads aliasing arena rows until the round's launch
-  (the CPU ``device_put`` alias the arena docstring pins) — over a
-  worst-case workload of trickle singles followed by full ring
-  rounds.  At ``depth + ring + 1`` slots every interleaving passes;
-  at ``depth + ring`` the checker emits a concrete schedule in which
-  a claim recycles the slot of a still-unlaunched single and the
-  later launch reads the overwriting round's bytes — the staged-copy
-  overwrite the +1 exists to prevent.  The discipline checked is the
+  second claim, uploads aliasing arena rows until the device consumes
+  them (the CPU ``device_put`` alias the arena docstring pins) — over
+  the worst-case workload of trickle singles, one slot each.  At
+  ``depth + 2`` slots every interleaving passes; at ``depth + 1`` the
+  checker emits a concrete schedule in which a claim recycles the
+  slot of a still-unlaunched single and the later launch reads the
+  overwriting batch's bytes — the staged-copy overwrite the +1
+  exists to prevent.  The discipline checked is the
   *documented contract*, deliberately weaker than today's loop
   ordering (the loop reaps before claiming; the contract also permits
   the overlapped order) — the bound must hold for every
@@ -926,13 +926,14 @@ def _mk_queue(path: Path, premature_release: bool) -> Callable[[], tuple]:
 # check 6/7: the arena reuse bound, proved tight
 # ---------------------------------------------------------------------------
 
-def _mk_arena(slots: int, depth: int, ring: int,
-              n_singles: int, n_rounds: int) -> Callable[[], tuple]:
+def _mk_arena(slots: int, depth: int,
+              n_singles: int) -> Callable[[], tuple]:
     """Drive the REAL :class:`DispatchArena` under the documented
     claim/submit/reap contract with the worst-case workload the
-    ring_safe_slots derivation names: ``n_singles`` trickle singles
-    (one claim each — the copy-path ``_dispatch_mega`` shape) followed
-    by ``n_rounds`` full ring rounds of 1-chunk slots.
+    safe_slots derivation names: ``n_singles`` trickle singles, one
+    claim each (a group stages several batches in ONE slot and raises
+    the pending count by as many, so it only ever holds fewer slots
+    in flight).
 
     The modeled discipline is the CONTRACT's weakest ordering, not
     today's loop ordering (docs/CONCURRENCY.md has the derivation):
@@ -944,12 +945,12 @@ def _mk_arena(slots: int, depth: int, ring: int,
       point of having more than one slot);
     * before going a SECOND slot past a submit, the reap must catch
       up: ``wait_below(readback_depth)`` — pending ≤ depth;
-    * an upload ALIASES its arena rows until the round's launch
-      consumes them (the CPU ``device_put`` alias the arena docstring
-      pins; the view stands in for the device buffer).
+    * an upload ALIASES its arena rows until the device consumes them
+      (the CPU ``device_put`` alias the arena docstring pins; the view
+      stands in for the device buffer).
 
     The integrity invariant is checked where the real computation
-    reads: at LAUNCH, every aliased slot view must still carry the
+    reads: at LAUNCH, the aliased slot view must still carry the
     bytes staged at upload time.  A violation is the staged-copy
     overwrite — dispatch recycled a slot the device side had not
     consumed."""
@@ -962,61 +963,42 @@ def _mk_arena(slots: int, depth: int, ring: int,
         arena = DispatchArena(slots, group_max=1, max_batch=1,
                               words=_Q_WORDS)
         pending = [0]          # submitted-but-unsunk batches
-        subq: list = []        # submitted work: (kind, [(slot, b, view)])
+        subq: list = []        # submitted work: (slot, b, view)
 
         def dispatch():
-            b = 0
             armed = False   # a submit is in flight: reap before the
             #                 second claim beyond it
-
-            def unit(kind: str, n_slots: int, r: int):
-                nonlocal b, armed
-                ups = []
-                for j in range(n_slots):
-                    yield (f"claim+stage{'+upload' if kind == 'ring' else ''}"
-                           f"#{b}" + (f" (round {r})" if r >= 0 else ""))
-                    s = arena.claim()
-                    arena.rows(s)[...] = pat(b)
-                    ups.append((s, b, arena.rows(s)[0]))
-                    b += 1
-                    if j == 0 and armed:
-                        # one slot of staging lookahead is spent:
-                        # the reap catches up before any further claim
-                        yield (lambda: pending[0] <= depth,
-                               f"reap(depth={depth})")
-                yield f"submit {kind}#{ups[0][1]}"
-                subq.append((kind, ups))
-                pending[0] += n_slots
+            for b in range(n_singles):
+                yield f"claim+stage#{b}"
+                s = arena.claim()
+                arena.rows(s)[...] = pat(b)
+                view = arena.rows(s)[0]
+                if armed:
+                    # one slot of staging lookahead is spent: the
+                    # reap catches up before any further claim
+                    yield (lambda: pending[0] <= depth,
+                           f"reap(depth={depth})")
+                yield f"submit single#{b}"
+                subq.append((s, b, view))
+                pending[0] += 1
                 armed = True
 
-            # phase 1: trickle singles, one slot each
-            for _ in range(n_singles):
-                yield from unit("single", 1, -1)
-            # phase 2: full ring rounds (1 chunk per slot)
-            for r in range(n_rounds):
-                yield from unit("ring", ring, r)
-
         def worker():
-            done = 0
-            total = n_singles + n_rounds
-            while done < total:
+            for done in range(n_singles):
                 yield (lambda: len(subq) > 0, f"launch#{done}")
-                kind, ups = subq.pop(0)
-                for s, b, view in ups:
-                    got = int(view[0, 0])
-                    if not np.array_equal(view, np.full_like(
-                            view, pat(b))):
-                        raise ModelViolation(
-                            f"staged-copy overwrite: launch of {kind} "
-                            f"batch#{b} read arena slot {s} and found "
-                            f"the stamp of batch#{got - 1} — dispatch "
-                            f"recycled the slot before the device "
-                            f"consumed it ({slots} slots is below the "
-                            f"safe bound for readback_depth={depth}, "
-                            f"ring={ring})")
+                s, b, view = subq.pop(0)
+                got = int(view[0, 0])
+                if not np.array_equal(view, np.full_like(
+                        view, pat(b))):
+                    raise ModelViolation(
+                        f"staged-copy overwrite: launch of single "
+                        f"batch#{b} read arena slot {s} and found "
+                        f"the stamp of batch#{got - 1} — dispatch "
+                        f"recycled the slot before the device "
+                        f"consumed it ({slots} slots is below the "
+                        f"safe bound for readback_depth={depth})")
                 yield f"sink#{done}"
-                pending[0] -= len(ups)
-                done += 1
+                pending[0] -= 1
 
         return [("dispatch", dispatch()), ("worker", worker())], None
 
@@ -1027,11 +1009,11 @@ def _mk_arena(slots: int, depth: int, ring: int,
 # the suite
 # ---------------------------------------------------------------------------
 
-#: Tightness-proof geometry: small enough to exhaust, big enough that
-#: both phases of the worst case (trickle singles + ring rounds) are
-#: present.  ring_safe_slots(1, 2) == 4.
-_ARENA_DEPTH, _ARENA_RING = 1, 2
-_ARENA_SINGLES, _ARENA_ROUNDS = 1, 2
+#: Tightness-proof geometry: small enough to exhaust (1,720
+#: interleavings at the bound), big enough that the claims wrap the
+#: arena at the safe bound.  safe_slots(1) == 3.
+_ARENA_DEPTH = 1
+_ARENA_SINGLES = 4
 
 
 @dataclasses.dataclass
@@ -1077,15 +1059,13 @@ def run_interleave(tmp_dir: str | Path | None = None) -> InterleaveReport:
             expect_violation=True,
             expect_marker="overwritten before release"))
 
-    safe = _ARENA_DEPTH + _ARENA_RING + 1  # == ring_safe_slots
+    safe = _ARENA_DEPTH + 2  # == DispatchArena.safe_slots
     checks.append(explore(
         f"arena_bound@{safe}_slots",
-        _mk_arena(safe, _ARENA_DEPTH, _ARENA_RING,
-                  _ARENA_SINGLES, _ARENA_ROUNDS)))
+        _mk_arena(safe, _ARENA_DEPTH, _ARENA_SINGLES)))
     checks.append(explore(
         f"arena_bound@{safe - 1}_slots",
-        _mk_arena(safe - 1, _ARENA_DEPTH, _ARENA_RING,
-                  _ARENA_SINGLES, _ARENA_ROUNDS),
+        _mk_arena(safe - 1, _ARENA_DEPTH, _ARENA_SINGLES),
         expect_violation=True,
         expect_marker="staged-copy overwrite"))
 
@@ -1100,7 +1080,6 @@ def run_interleave(tmp_dir: str | Path | None = None) -> InterleaveReport:
         steps=sum(c.steps for c in checks),
         bound={
             "readback_depth": _ARENA_DEPTH,
-            "ring": _ARENA_RING,
             "safe_slots": safe,
             "interleavings_at_safe": proof.interleavings,
             "safe_ok": proof.ok,

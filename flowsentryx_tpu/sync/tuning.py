@@ -17,7 +17,7 @@ from __future__ import annotations
 
 #: Dispatch-thread GIL yield while the pipe is busy but nothing new is
 #: sealable.  A spinning dispatch loop holds the interpreter for the
-#: full 5 ms switch interval per slice, starving the sink/pipeline
+#: full 5 ms switch interval per slice, starving the sink
 #: thread's pure-Python decode/writeback — measured (PR 3) stretching
 #: sub-millisecond sinks to 10-25 ms.  20 µs is long enough to force a
 #: drop of the GIL and short enough to be invisible against the
@@ -113,7 +113,7 @@ VRING_WAIT_TIMEOUT_S = 2.0
 #: flight with zero completions for this long soft-trips (per-thread
 #: stack dump, DEGRADED reason), for 2x this long hard-trips (the
 #: drain fails loudly instead of hanging forever).  10 s is ~3 orders
-#: of magnitude above the worst healthy gap (a cold ring-round launch
+#: of magnitude above the worst healthy gap (a cold top-rung launch
 #: on a throttled host measures tens of ms; this container's cgroup
 #: throttle windows stretch seconds — PR 3/PR 11 measurements), so a
 #: trip means wedged, not slow.  The two-stage form exists precisely

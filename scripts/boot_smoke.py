@@ -6,7 +6,7 @@ every leg below is a subprocess of this script, each reporting its
 import wall, its engine boot block, and a digest of what it served:
 
 * **cold** — empty cache dir, full ``warm()``: every staged variant
-  (each ladder rung, the deep-scan ring) compiles and is stored.
+  (singles and each ladder rung) compiles and is stored.
 * **cached** — same staged shape, ``warm(tiered=True)``: every variant
   must load from the cache (zero misses/compiles), serving must open
   >= MIN_SPEEDUP x faster than the cold leg (engine boot-to-serving,
@@ -81,8 +81,7 @@ def _child(mode: str, cache_dir: str, out_path: str) -> int:
     )).next_records(N_BATCHES * BATCH)
     sink = CollectSink()
     eng = Engine(cfg, ArraySource(recs), sink, mega_n="auto",
-                 device_loop=2, readback_depth=16, sink_thread=False,
-                 compile_cache=cache_dir)
+                 sink_thread=False, compile_cache=cache_dir)
     eng.boot_import_s = round(import_s, 4)
     eng.warm(tiered=(mode != "cold"))
     fill_ok = eng.warm_fill_join(CHILD_TIMEOUT_S / 2)
@@ -110,7 +109,6 @@ def _prewarm(cache_dir: str) -> int:
     return prewarm_main({
         "cfg_json": _cfg_json(),
         "mega": "auto",
-        "device_loop": 2,
         "compile_cache": cache_dir,
     })
 
@@ -154,7 +152,7 @@ def main() -> int:
     if not (n_variants >= 4 and c["stores"] == n_variants):
         failures.append(
             f"cold leg stored {c['stores']} of {n_variants} variants "
-            f"(expected the full ladder + ring): {c}")
+            f"(expected singles + the full ladder): {c}")
 
     # -- gates: the cached leg is all hits, >= MIN_SPEEDUP x faster --------
     c = cached["boot"]["cache"]
@@ -203,7 +201,7 @@ def main() -> int:
         "ts": time.time(),
         "wall_s": round(time.perf_counter() - t_start, 2),
         "config": {"batch": BATCH, "n_batches": N_BATCHES,
-                   "mega": "auto", "device_loop": 2,
+                   "mega": "auto",
                    "min_speedup": MIN_SPEEDUP},
         "cold": {"import_s": cold["import_s"],
                  "boot": cold["boot"]},

@@ -8,7 +8,7 @@ same host — because the single engine funnels both shards through one
 dispatch thread (the measured bottleneck in every paced artifact since
 DISPATCH_r09), while the cluster gives each shard its own.
 
-Like DEVLOOP_r11, the claim is measured PER REGIME, because the two
+Like the paced comparison of PR 7, the claim is measured PER REGIME, because the two
 serving shapes bottleneck differently on a 2-vCPU host:
 
 * ``latency`` tier (batch 128, no mega coalescing — the PR 7 ring's
@@ -28,7 +28,7 @@ serving shapes bottleneck differently on a 2-vCPU host:
   count over pool efficiency (~2/1.4 plus the ~10-20% pinned-rank
   margin).  Reported alongside, not headlined.
 
-Methodology (the DEVLOOP_r11 discipline, adapted to processes):
+Methodology (the PR 7 discipline, adapted to processes):
 
 * the baseline runs from a PR 9 **worktree** (``git worktree add``,
   the commit before the cluster plane existed), so the comparison is
@@ -479,7 +479,7 @@ def orchestrate(args) -> int:
         "method": (
             "Interleaved ABAB sealed-drain trials vs the single-engine "
             "PR 9 worktree, measured PER SERVING REGIME (the "
-            "DEVLOOP_r11 discipline): three persistent engine "
+            "PR 7 discipline): three persistent engine "
             "processes (one baseline with 2 drain workers from the "
             "pre-cluster commit, two cluster ranks with 1 worker each "
             "from this tree), each holding one warmed engine per "
@@ -511,7 +511,7 @@ def orchestrate(args) -> int:
             "zero seq gaps asserted every step."),
         "host_noise": (
             "2-vCPU throttled container, noise swings 2-3x within "
-            "minutes (DEVLOOP_r11 finding); ABAB order alternates "
+            "minutes (PR 7 finding); ABAB order alternates "
             "per shape per trial, raw per-trial data below is the "
             f"evidence — loadavg {load0} -> {load1}."),
         "baseline_repo": args.baseline_repo,

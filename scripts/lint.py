@@ -19,15 +19,16 @@ Stages, in order; the gate fails if any stage fails:
    clean out of the engine's sink paths by hand).  ``# noqa`` exempts
    a line.
 4. **np default int** — an AST pass over the hot-path packages
-   (core/ops/fused/engine/ingest/cluster) that bans dtype-less
+   (core/ops/engine/ingest/cluster) that bans dtype-less
    ``np.array``/``np.zeros``/``np.ones``/``np.empty``/``np.arange``/
    ``np.full``: the default integer dtype is the platform C long,
    whose width varies by platform/ABI — an overflow hazard the
    ``fsx ranges`` prover cannot see from the staged graph.  ``# noqa``
    exempts a line.
-5. **device-loop purity** — an AST pass over
-   ``flowsentryx_tpu/fused/`` (the traced-region package: everything
-   in it runs inside ``jit``) that bans host round-trips —
+5. **traced-region purity** — an AST pass over
+   ``flowsentryx_tpu/ops/`` (the traced-region package: the step that
+   every engine serves is written there and runs inside ``jit``) that
+   bans host round-trips —
    ``device_get`` and the callback primitives (``pure_callback``,
    ``io_callback``, ``debug_callback``, ``jax.debug.print``) — at
    review speed.  ``fsx audit`` proves the same property statically on
@@ -51,7 +52,7 @@ Stages, in order; the gate fails if any stage fails:
    blocking loop escapes the registry.  ``# noqa`` exempts a line.
 8. **cluster jax-free** — an AST pass over
    ``flowsentryx_tpu/cluster/`` that bans MODULE-LEVEL imports of jax
-   or the known jax-importing modules (``fused``/``ops``/
+   or the known jax-importing modules (``ops``/
    ``engine.writeback``/``engine.checkpoint``/``engine.engine``): the
    cluster plane is the supervisor's and every rank's process-spawn
    import path, and one module-level jax import there turns every
@@ -248,9 +249,10 @@ TRACED_REGION_BANNED = frozenset({
     "host_callback", "block_until_ready",
 })
 
-#: The traced-region package: every module here builds code that runs
-#: INSIDE jit (fused/device_loop.py's deep scan above all).
-TRACED_REGION_TREE = "flowsentryx_tpu/fused"
+#: The traced-region package: the modules here build the code that
+#: runs INSIDE jit (ops/fused.py's step and megastep, and the table,
+#: aggregation and limiter stages they call).
+TRACED_REGION_TREE = "flowsentryx_tpu/ops"
 
 
 def _traced_purity_findings(path: Path) -> list[str]:
@@ -291,13 +293,13 @@ def _traced_purity_findings(path: Path) -> list[str]:
             rel = path
         out.append(
             f"{rel}:{node.lineno}: host round-trip {name!r} in "
-            "traced-region code — the device loop's graph must stay "
+            "traced-region code — the step's graph must stay "
             "free of device_get/callbacks (fsx audit proves it on the "
             "staged jaxpr; fix it here first)")
     return out
 
 
-def stage_device_loop_purity() -> list[str]:
+def stage_traced_region_purity() -> list[str]:
     fails = []
     for path in sorted((REPO / TRACED_REGION_TREE).rglob("*.py")):
         fails.extend(_traced_purity_findings(path))
@@ -312,8 +314,8 @@ def stage_device_loop_purity() -> list[str]:
 #: staged graph, where the dtype is already whatever numpy picked).
 NP_DEFAULT_INT_TREES = (
     "flowsentryx_tpu/core", "flowsentryx_tpu/ops",
-    "flowsentryx_tpu/fused", "flowsentryx_tpu/engine",
-    "flowsentryx_tpu/ingest", "flowsentryx_tpu/cluster",
+    "flowsentryx_tpu/engine", "flowsentryx_tpu/ingest",
+    "flowsentryx_tpu/cluster",
 )
 
 #: Banned-without-dtype numpy constructors -> positional index at
@@ -382,7 +384,6 @@ CLUSTER_JAX_FREE_TREE = "flowsentryx_tpu/cluster"
 #: level.  A prefix bans the module and everything under it.
 CLUSTER_JAX_IMPORTERS = (
     "jax",
-    "flowsentryx_tpu.fused",
     "flowsentryx_tpu.ops",
     "flowsentryx_tpu.engine.writeback",
     "flowsentryx_tpu.engine.checkpoint",
@@ -663,7 +664,7 @@ def main(argv: list[str] | None = None) -> int:
         "unused_imports": stage_unused_imports(),
         "local_imports": stage_local_imports(),
         "np_default_int": stage_np_default_int(),
-        "device_loop_purity": stage_device_loop_purity(),
+        "traced_region_purity": stage_traced_region_purity(),
         "sync_contracts": stage_sync_contracts(),
         "liveness_waits": stage_liveness_waits(),
         "cluster_jax_free": stage_cluster_jax_free(),

@@ -6,7 +6,7 @@ IS the reactive SLO engine, test-pinned byte-identical): two
 persistent warmed mega-auto engines at the SAME ``--slo-us`` budget —
 reactive (PR 11 deadline-flush point) vs governed (``--predict``
 forecast-end flush + rung pre-warm) — serve the SAME pulse-wave
-offered process in INTERLEAVED trials (DEVLOOP_r11 discipline:
+offered process in INTERLEAVED trials (PR 7 discipline:
 alternate arms within one process, trials >= 2.5 s so cgroup throttle
 bursts don't dominate, order swapped every pair, raw trials + loadavg
 disclosed; on this 2-3x-swinging host the per-trial ratios are the
@@ -58,7 +58,7 @@ RATE_PPS = 0.0128e6        # mean offered: ~3x headroom inside this
 #                            host's worst measured throttle window
 BURST_PERIOD_S = 0.0075    # 96 records/burst — SMALLER than one
 DUTY = 0.20                # batch, so every burst rides the flush
-PULSE_SECONDS = 3.0        # >= 2.5 s trial floor (DEVLOOP discipline)
+PULSE_SECONDS = 3.0        # >= 2.5 s trial floor (PR 7 discipline)
 STEADY_BATCHES = 192       # saturating drain trial size
 
 
@@ -192,7 +192,7 @@ def main() -> int:
     p99_r = med(pulse_rows, "slo", "p99_ms")
     p99_g = med(pulse_rows, "gov", "p99_ms")
     # per-trial pairwise ratios: the robust statistic on a host whose
-    # capacity swings 2-3x between windows (DEVLOOP_r11 discipline)
+    # capacity swings 2-3x between windows (PR 7 discipline)
     ratios = []
     for t in range(trials):
         a = [r for r in pulse_rows
@@ -233,7 +233,7 @@ def main() -> int:
         "ts": time.time(),
         "wall_s": round(time.perf_counter() - t_start, 1),
         "discipline": (
-            "DEVLOOP_r11: same-build A/B in one process, persistent "
+            "PR 7: same-build A/B in one process, persistent "
             "warmed engines, SAME slo budget both arms, interleaved "
             "trials with order swapped every pair, >= 2.5 s per "
             "trial, raw trials + loadavg + per-trial governor "

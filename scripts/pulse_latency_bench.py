@@ -4,7 +4,7 @@
 Same-build A/B (the ``--slo-us 0`` engine IS the PR 10 engine,
 test-pinned byte-identical): two persistent warmed mega-auto engines —
 throughput-tuned (slo 0) vs budget-bounded (``SLO_US``) — serve the
-SAME pulse-wave offered process in INTERLEAVED trials (DEVLOOP_r11
+SAME pulse-wave offered process in INTERLEAVED trials (PR 7
 discipline: alternate arms within one process, trials ≥ 2.5 s so
 cgroup throttle bursts don't dominate, order swapped every pair, raw
 trials + loadavg disclosed; on this 2-3x-swinging host the per-trial
@@ -54,7 +54,7 @@ DUTY = 0.20                # batch, so every burst rides the deadline
 #                            deadline (5 ms) taxes every record and
 #                            the budget-bounded flush (~2.5-4 ms
 #                            point) wins
-PULSE_SECONDS = 3.0        # >= 2.5 s trial floor (DEVLOOP discipline)
+PULSE_SECONDS = 3.0        # >= 2.5 s trial floor (PR 7 discipline)
 STEADY_BATCHES = 192       # saturating drain trial size
 
 
@@ -177,7 +177,7 @@ def main() -> int:
     p99_0 = med(pulse_rows, "slo0", "p99_ms")
     p99_s = med(pulse_rows, "slo", "p99_ms")
     # per-trial pairwise ratios: the robust statistic on a host whose
-    # capacity swings 2-3x between windows (DEVLOOP_r11 discipline)
+    # capacity swings 2-3x between windows (PR 7 discipline)
     ratios = []
     for t in range(trials):
         a = [r for r in pulse_rows
@@ -210,7 +210,7 @@ def main() -> int:
         "ts": time.time(),
         "wall_s": round(time.perf_counter() - t_start, 1),
         "discipline": (
-            "DEVLOOP_r11: same-build A/B in one process, persistent "
+            "PR 7: same-build A/B in one process, persistent "
             "warmed engines, interleaved trials with order swapped "
             "every pair, >= 2.5 s per trial, raw trials + loadavg "
             "disclosed; medians + per-trial ratios are the statistic "

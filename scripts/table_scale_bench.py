@@ -3,7 +3,7 @@
 
 Two claims, measured per the repo's established drain methodology
 (interleaved trials on persistent warmed engines, raw data + host-noise
-disclosure; see DEVLOOP_r11/DISPATCH_r09):
+disclosure; see DISPATCH_r09):
 
 1. **Drain stays flat at production scale** — sealed-drain Mpps of a
    4M-row (2^22) table with the in-step eviction sweep ACTIVE, versus
@@ -252,8 +252,8 @@ def main() -> int:
         "method": (
             "Interleaved inline-sealed drain trials (ArraySource -> "
             "MicroBatcher compact16 seal -> mega-auto dispatch; the "
-            "worker-fleet seal path is benched by DISPATCH_r09/"
-            "DEVLOOP_r11 and orthogonal to table scale) on two "
+            "worker-fleet seal path is benched by DISPATCH_r09 "
+            "and orthogonal to table scale) on two "
             "persistent warmed engines per pair (ABAB order per "
             "round): A = the "
             "PR 7 bench-shape table (2^20 rows = bench.py TABLE_CAP, "

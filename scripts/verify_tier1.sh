@@ -11,7 +11,7 @@
 #            kern/prop_driver and an fsxd --sim smoke)
 # Always-on pre-stages (each failure exits early, before pytest):
 #   * scripts/lint.py — syntax, unused-import, local-import,
-#     device-loop-purity and sync_contracts gates
+#     traced-region-purity and sync_contracts gates
 #   * fsx sync        — host thread contracts + bounded-interleaving
 #     model checks (arena bound tightness re-proved per run); writes
 #     artifacts/SYNC_r13.json
@@ -125,12 +125,9 @@ print(f"live+cluster import: {dt*1000:.0f} ms, jax-free")
 PY
 
 echo "== fsx audit: static step-graph contracts (docs/AUDIT.md) =="
-# --device-loop 2 also stages the drain-ring deep scans (single-device
-# and sharded) so the 528 B-per-slot wire pin and the ring-carry
-# donation proof are re-proved on every run.
 env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m flowsentryx_tpu.cli audit --mesh 8 --mega 2 \
-    --device-loop 2 --out artifacts/AUDIT_r08.json || exit 1
+    --out artifacts/AUDIT_r08.json || exit 1
 
 echo "== fsx audit: eviction-epoch step variants (quick shapes) =="
 # The in-step aging sweep changes every staged graph (a rolling
@@ -141,21 +138,20 @@ echo "== fsx audit: eviction-epoch step variants (quick shapes) =="
 # re-proved each run.
 env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m flowsentryx_tpu.cli audit --mesh 8 --mega 2 \
-    --device-loop 2 --evict-ttl 30 --quick \
+    --evict-ttl 30 --quick \
     --out artifacts/AUDIT_evict_r12.json || exit 1
 
 echo "== fsx ranges: whole-pipeline integer value-range proof =="
 # The fourth static leg (docs/RANGES.md): interval abstract
 # interpretation over every staged variant — singles, sharded, every
-# rung of the adaptive mega ladder, the drain-ring deep scan, the
-# eviction-epoch family (--evict-ttl stages the rolling-window
+# rung of the adaptive mega ladder, the eviction-epoch family (--evict-ttl stages the rolling-window
 # batches-counter arithmetic) — proving no equation can silently wrap
 # modulo the audited WRAP_OK registry (staleness-checked per run).
 # Also re-proves the planted negative controls fire and the BPF<->jaxpr
 # interval-containment bridge on the shipped distill artifact.
 env JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m flowsentryx_tpu.cli ranges --mesh 8 --mega auto \
-    --device-loop 2 --evict-ttl 30 --quick \
+    --evict-ttl 30 --quick \
     --out artifacts/RANGES_r16.json || exit 1
 
 echo "== table-scale smoke: eviction + occupancy bound + shard-local rows =="
@@ -260,14 +256,6 @@ echo "== predict smoke: burst forecast + pre-warm + pressure shedding =="
 # the "smoke" section of artifacts/PREDICT_r22.json (the paced A/B
 # evidence in the same file is preserved).
 env JAX_PLATFORMS=cpu python scripts/predict_smoke.py || exit 1
-
-echo "== device-loop smoke: drain ring + double-buffered H2D =="
-# Bounded CPU smoke of the device-resident drain ring: re-proves that
-# full deep-scan rounds fire, copies/batch stays 1.0, and H2D overlap
-# (uploads issued while a round is in flight) is > 0, re-writing the
-# "smoke" section of artifacts/DEVLOOP_r11.json (the paced PR-6
-# comparison evidence in the same file is preserved).
-env JAX_PLATFORMS=cpu python scripts/device_loop_smoke.py || exit 1
 
 echo "== boot smoke: persistent compile cache + tiered warm + GROW spare =="
 # Bounded CPU smoke of boot-to-serving (docs/ENGINE.md §boot), each leg

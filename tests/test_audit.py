@@ -138,64 +138,49 @@ class TestAcceptance:
                 "steady_state_d2h_bytes", "donation",
                 "collectives"} <= set(v0)
 
-    def test_device_loop_variant_pins_per_slot_wire(self):
-        """The drain-ring deep scan stages as its own variant: wire
-        output ``[ring, 2K+4]`` pinned PER SLOT (528 B at K=64-equiv:
-        here (2*16+4)*4), donation proved through the nested-scan ring
-        carry, no callbacks, retrace-stable."""
-        rep = runner.run_audit(CFG, mega_n=4, mega_sizes=(2, 4),
-                               variants=("device_loop",),
-                               device_loop=2)
+
+def bench_cell_cfg(name: str):
+    """``benchmark/configs/<name>.json`` at its ``rehearse`` sizes, as
+    the benchmark's own harness turns it into the program's config and
+    artifact: ``(FsxConfig, params, mega)``."""
+    import importlib.util
+    from pathlib import Path
+
+    from flowsentryx_tpu.models.registry import load_artifact
+
+    root = Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location(
+        "bench_harness", root / "benchmark" / "harness.py")
+    harness = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(harness)
+    config = harness.load_json(
+        root / "benchmark" / "configs" / f"{name}.json")
+    config = harness.merged(config, config.get("rehearse", {}))
+    cfg = harness.engine_config(config)
+    params = load_artifact(cfg.model.name,
+                           str(root / config["model"]["artifact"]))
+    return cfg, params, config["mega"]
+
+
+BENCH_CONFIGS = ("c3-offline-ml", "c4-syn-mix", "c5-l34-1m")
+
+
+class TestBenchmarkConfigs:
+    @pytest.mark.parametrize("name", BENCH_CONFIGS)
+    def test_the_programs_a_cell_stages_prove_clean(self, name):
+        """The graphs the benchmark's cells run — ``jit_step`` and every
+        rung of the ``--mega auto`` ladder, under the cell's own
+        limiter, widths and artifact at its rehearsal sizes — hold every
+        contract: what ``warm()`` stages there is what is audited
+        here."""
+        cfg, params, mega = bench_cell_cfg(name)
+        assert mega == "auto"
+        rep = runner.run_audit(
+            cfg, params=params, variants=("compact", "megastep"),
+            mega_n=8, mega_sizes=fused.pow2_group_sizes(8))
         assert rep.ok, [str(f) for v in rep.variants for f in v.findings]
-        [v] = rep.variants
-        assert v.name == "device_loop@2x4"
-        # per-SLOT pin: the round's one fetch is ring * this
-        assert v.wire_words == 2 * CFG.batch.verdict_k + 4
-        assert v.steady_state_d2h_bytes == (2 * CFG.batch.verdict_k
-                                            + 4) * 4
-        wire = [o for o in v.outputs if o["name"] == "out.wire"]
-        assert wire[0]["shape"] == [2, 2 * CFG.batch.verdict_k + 4]
-        assert v.donation["checked"]
-        assert set(range(len(runner.CARRY_NAMES))) <= set(
-            v.donation["aliased_params"])
-        assert v.collectives == {}
-        assert rep.config["device_loop"] == 2
-
-    def test_sharded_device_loop_variant(self):
-        rep = runner.run_audit(CFG, mesh=make_mesh(8), mega_n=2,
-                               variants=("sharded_device_loop",),
-                               device_loop=2)
-        assert rep.ok, [str(f) for v in rep.variants for f in v.findings]
-        [v] = rep.variants
-        assert v.name == "sharded_device_loop@2x2"
-        # the nested scan stages the shard-mapped body ONCE: the
-        # collective census stays the designed per-step set
-        assert v.collectives["all_to_all"] == 2
-        assert v.collectives["all_gather"] == 2
-
-    def test_device_loop_needs_mega_sizes(self):
-        with pytest.raises(ValueError, match="device_loop"):
-            runner.run_audit(CFG, mega_n=0, variants=("device_loop",),
-                             device_loop=2)
-
-    def test_boot_cache_keys_on_ring_depth(self):
-        """A re-boot with a different ring depth serves a different
-        deep-scan artifact: the boot cache must miss and re-prove."""
-        runner._BOOT_CACHE.clear()
-        rep = runner.boot_audit(CFG, wire=schema.WIRE_COMPACT16,
-                                mesh=None, mega_n=2, mega_sizes=(2,),
-                                device_loop=2)
-        assert rep is not None and rep.ok
         assert [v.name for v in rep.variants] == [
-            "compact", "megastep", "device_loop@2x2"]
-        assert runner.boot_audit(CFG, wire=schema.WIRE_COMPACT16,
-                                 mesh=None, mega_n=2, mega_sizes=(2,),
-                                 device_loop=2) is None  # cache hit
-        rep2 = runner.boot_audit(CFG, wire=schema.WIRE_COMPACT16,
-                                 mesh=None, mega_n=2, mega_sizes=(2,),
-                                 device_loop=3)
-        assert rep2 is not None and rep2.ok  # new depth: re-proved
-        assert "device_loop@3x2" in [v.name for v in rep2.variants]
+            "compact", "megastep@8", "megastep@4", "megastep@2"]
 
 
 def _staged(fn, *example_args):

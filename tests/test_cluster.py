@@ -645,12 +645,6 @@ class TestClusterCLI:
         rc, cap = self._run(
             ["cluster", "--checkpoint-every", "5"], capsys)
         assert rc == 1 and "--checkpoint" in cap.err
-        rc, cap = self._run(["cluster", "--device-loop", "2"], capsys)
-        assert rc == 1 and "--mega" in cap.err
-        rc, cap = self._run(
-            ["cluster", "--mega", "2", "--device-loop", "2",
-             "--verdict-k", "0"], capsys)
-        assert rc == 1 and "--verdict-k 0" in cap.err
 
     def test_cluster_multi_host_flag_refusals(self, capsys):
         # the --hosts trio (ISSUE 15), each refusal naming its problem
@@ -723,90 +717,6 @@ class TestClusterCLI:
                            "--cluster-dir", str(tmp_path / "plane")],
             capsys)
         assert rc == 1 and "epoch" in cap.err and "c_t0" in cap.err
-
-    def test_device_loop_auto_requires_mega_pre_boot(self, capsys):
-        # the autotuner obeys the SAME structural rule as an explicit
-        # depth, refused before any calibration drain compiles
-        rc, cap = self._run(
-            ["serve", "--scenario", "benign", "--packets", "64",
-             "--device-loop", "auto"], capsys)
-        assert rc == 1 and "--mega" in cap.err
-        with pytest.raises(SystemExit) as ex:
-            self._run(
-                ["serve", "--scenario", "benign", "--packets", "64",
-                 "--device-loop", "nope"], capsys)
-        assert ex.value.code == 2  # argparse: not an int, not 'auto'
-
-
-# ---------------------------------------------------------------------------
-# ring-depth autotuning policy (the pure half of --device-loop auto)
-# ---------------------------------------------------------------------------
-
-
-class TestChooseRingDepth:
-    def _m(self, ring, overlap, rounds=4):
-        return {"ring": ring, "overlap_fraction": overlap,
-                "rounds": rounds, "ring_occupancy": 1.0}
-
-    def test_shallowest_within_knee_wins(self):
-        from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-        depth, detail = choose_ring_depth(
-            [self._m(2, 0.85), self._m(4, 0.9), self._m(8, 0.91)])
-        assert depth == 2  # 0.85 >= 0.9 * 0.91: deeper buys nothing
-        assert "shallowest" in detail["reason"]
-
-    def test_knee_requires_real_gain(self):
-        from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-        depth, _ = choose_ring_depth(
-            [self._m(2, 0.3), self._m(4, 0.88), self._m(8, 0.9)])
-        assert depth == 4  # 2 is far off the knee, 4 is within it
-
-    def test_no_completed_round_defaults_shallow(self):
-        from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-        depth, detail = choose_ring_depth(
-            [self._m(2, 0.0, rounds=0), self._m(4, 0.0, rounds=0)])
-        assert depth == 2
-        assert "no candidate completed" in detail["reason"]
-
-    def test_zero_overlap_keeps_ring_shallow(self):
-        from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-        depth, detail = choose_ring_depth(
-            [self._m(2, 0.0), self._m(4, 0.0), self._m(8, 0.0)])
-        assert depth == 2
-        assert "no H2D overlap" in detail["reason"]
-
-    def test_unfired_candidates_are_skipped(self):
-        from flowsentryx_tpu.fused.device_loop import choose_ring_depth
-
-        depth, _ = choose_ring_depth(
-            [self._m(2, 0.9, rounds=0), self._m(4, 0.7)])
-        assert depth == 4  # ring 2 measured nothing, it can't win
-
-    def test_calibration_drive_measures_real_ring(self):
-        """The drive half (``engine.calibrate_ring_depth``): one
-        candidate, bounded small — the measurement must come from a
-        real completed ring drain (rounds fired, overlap measured),
-        and the verdict must carry the full evidence trail the CLI
-        prints.  One XLA ring compile, ~10 s."""
-        from test_engine import small_cfg
-
-        from flowsentryx_tpu.engine.engine import calibrate_ring_depth
-
-        cfg = small_cfg(batch=128, cap=1 << 12, pps_threshold=200.0,
-                        bps_threshold=1e9)
-        depth, detail = calibrate_ring_depth(
-            cfg, mega_n=2, candidates=(2,), batches=16)
-        assert depth == 2
-        [m] = detail["candidates"]
-        assert m["rounds"] >= 1
-        assert 0.0 <= m["overlap_fraction"] <= 1.0
-        assert m["records_per_s"] > 0
-        assert detail["calibration_batches"] == 16
-        assert detail["reason"]
 
 
 # ---------------------------------------------------------------------------

@@ -83,13 +83,13 @@ class TestSignature:
     def test_digest_is_deterministic_and_shape_sensitive(self):
         cfg = small_cfg()
         kw = dict(wire="compact16", mesh_devices=1, mega_sizes=(8, 4, 2),
-                  device_loop=0, params=None, donate=True)
+                  params=None, donate=True)
         a = staging_signature(cfg, **kw)
         b = staging_signature(cfg, **kw)
         assert signature_digest(a) == signature_digest(b)
         # every keyed axis moves the digest
         for change in (dict(wire="records"), dict(mesh_devices=8),
-                       dict(mega_sizes=(8, 4)), dict(device_loop=2),
+                       dict(mega_sizes=(8, 4)),
                        dict(donate=False), dict(donate=None)):
             c = staging_signature(cfg, **{**kw, **change})
             assert signature_digest(c) != signature_digest(a), change
@@ -321,29 +321,26 @@ class TestTieredWarm:
 
     def test_background_fill_completes_the_ladder(self, tmp_path):
         """Unheld tiered warm: serving opens on the top rung, the
-        fsx-warm thread installs every remaining rung + the ring, the
+        fsx-warm thread installs every remaining rung, the
         ready set converges to the full ladder, and the boot block
         records the whole story (every variant sourced, fill_done_s
         stamped, nothing left pending)."""
         cfg = small_cfg(batch=128)
         sink = CollectSink()
         eng = Engine(cfg, ArraySource(flood_records(cfg, 4).copy()),
-                     sink, mega_n="auto", device_loop=2,
-                     readback_depth=16, sink_thread=False,
+                     sink, mega_n="auto", sink_thread=False,
                      compile_cache=tmp_path / "cache")
         eng.warm(tiered=True)
         assert eng._ready_sizes == eng._mega_sizes[:1]
-        assert eng._ring_ready is False  # no SLO: ring fills behind
         assert eng.warm_fill_join(120.0)
         assert eng._ready_sizes == eng._mega_sizes
-        assert eng._ring_ready is True
         with jax.transfer_guard("disallow"):
             rep = eng.run()
         boot = rep.boot
         assert boot["fill_pending"] == [] and "fill_error" not in boot
         assert boot["fill_done_s"] >= boot["serving_ready_s"]
         assert boot["fill_active"] is False
-        labels = {"single", "ring"} | {
+        labels = {"single"} | {
             f"mega{g}" for g in eng._mega_sizes}
         assert set(boot["variants"]) == labels
         assert boot["cache"]["stores"] == len(labels)

@@ -276,7 +276,8 @@ class TestEndToEnd:
         been driven at rate.  The daemon's --pace mode offers benign
         records at a real-time rate; the engine must consume ≈ all of
         them (no ring loss) without blocking any benign source.  The
-        full-rate sweep is scripts/shm_stress.py → SHMSTRESS_r05.json;
+        full-rate sweep is scripts/shm_stress.py (it writes
+        artifacts/SHMSTRESS_inline.json, which is not kept in the repo);
         this pins the machinery at a CI-friendly load."""
         from flowsentryx_tpu.core.config import (
             BatchConfig, FsxConfig, ModelConfig, TableConfig,

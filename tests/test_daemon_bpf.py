@@ -8,8 +8,8 @@ This test plays the other two roles: the NIC (BPF_PROG_TEST_RUN with
 crafted packets against the pinned program) and the TPU engine (shm
 consumer + verdict producer).
 
-Covers VERDICT.md round-1 items 2 (the daemon's kernel-facing half) and
-3 (a verifier-accepted program) with live evidence rather than
+Covers the round-1 review's items 2 (the daemon's kernel-facing half)
+and 3 (a verifier-accepted program; docs/VERIFIER.md) with live evidence rather than
 compile-gated stubs.  The reference's corresponding path was
 `bpftool prog load` typed by hand (/root/reference/TODO.md:282-289).
 """

@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from flowsentryx_tpu.core import schema
-from flowsentryx_tpu.core.config import BatchConfig, FsxConfig, TableConfig
+from flowsentryx_tpu.core.config import (
+    BatchConfig, FsxConfig, LimiterKind, TableConfig,
+)
 from flowsentryx_tpu.engine import (
     ArraySource,
     CollectSink,
@@ -669,13 +671,16 @@ class TestEngineLoop:
 
     @staticmethod
     def _run_sharded(recs, n_workers, base, queue_slots=16, warm=False,
-                     readback_depth=4, **eng_kw):
+                     readback_depth=4, cfg=None, rebind=False, **eng_kw):
         """Serve ``recs`` through a real ShardedIngest fleet over
         Python-created ring shards; returns (report, sink).  ``warm``
         pays the XLA compiles BEFORE the workers start filling their
         bounded queues — multi-second cold compiles otherwise stall the
         drain long enough for emit timeouts to drop batches (the fsx
-        serve --mega boot order)."""
+        serve --mega boot order).  ``rebind`` builds the engine on an
+        empty record source and hands it the fleet through
+        ``reset_stream``, as a benchmark that keeps one warmed engine
+        across streams does."""
         import time as _time
 
         from flowsentryx_tpu.engine.shm import ShmRing
@@ -691,11 +696,17 @@ class TestEngineLoop:
         src = ShardedIngest(base, n_workers, queue_slots=queue_slots,
                             precompact=False, t0_grace_s=0.2)
         sink = CollectSink()
-        eng = Engine(small_cfg(batch=256, cap=1 << 14,
-                               pps_threshold=200.0, bps_threshold=1e9),
-                     src, sink, readback_depth=readback_depth, **eng_kw)
+        if cfg is None:
+            cfg = small_cfg(batch=256, cap=1 << 14,
+                            pps_threshold=200.0, bps_threshold=1e9)
+        first = (ArraySource(np.zeros(0, schema.FLOW_RECORD_DTYPE))
+                 if rebind else src)
+        eng = Engine(cfg, first, sink, readback_depth=readback_depth,
+                     **eng_kw)
         if warm:
             eng.warm()
+        if rebind:
+            eng.reset_stream(src, sink)
         try:
             deadline = _time.monotonic() + 30
             while src.t0_ns is None:  # epoch handshake, then drain-stop
@@ -716,13 +727,58 @@ class TestEngineLoop:
                         attack_fraction=0.8, seed=13)
         ).next_records(n)
 
-    def test_sharded_ingest_one_worker_bit_identical(self, tmp_path):
+    #: The two points of the dispatch lattice a sealed source is served
+    #: at: singles on the lossless raw48 wire, and the adaptive mega
+    #: ladder on compact16 (what the benchmark's ring cells run).
+    SEALED_POINTS = pytest.mark.parametrize(
+        "wire,mega_n",
+        [(schema.WIRE_RAW48, 0), (schema.WIRE_COMPACT16, "auto")],
+        ids=["raw48-singles", "compact16-auto"])
+
+    @SEALED_POINTS
+    @pytest.mark.parametrize("kind", list(LimiterKind),
+                             ids=lambda k: k.value)
+    def test_sharded_ingest_one_worker_bit_identical(
+            self, tmp_path, wire, mega_n, kind):
         """N=1 sharded vs the inline N=0 path on the SAME stream: one
         worker preserves the exact record order AND batch composition,
         so everything — verdict counts, blocked set, until-times, batch
         count — must be bit-identical through the queue transport (the
-        N=0-equivalence acceptance gate of the ingest subsystem, on the
-        lossless raw48 wire)."""
+        N=0-equivalence acceptance gate of the ingest subsystem), at
+        both points of the lattice and under every limiter: grouping
+        is dispatch granularity only."""
+        import platform
+
+        if platform.system() != "Linux":
+            pytest.skip("shm ingest requires Linux")
+        recs = self._flood_records(256 * 12)
+        cfg = small_cfg(batch=256, cap=1 << 14, kind=kind,
+                        pps_threshold=200.0, bps_threshold=1e9,
+                        bucket_rate_pps=200.0, bucket_burst=64.0)
+        sink0 = CollectSink()
+        rep0 = Engine(cfg, ArraySource(recs.copy()), sink0,
+                      readback_depth=4, wire=wire).run()
+        rep1, sink1 = self._run_sharded(
+            recs, 1, str(tmp_path / "fring"), cfg=cfg, wire=wire,
+            mega_n=mega_n, warm=bool(mega_n))
+        assert rep1.records == rep0.records == len(recs)
+        assert rep1.batches == rep0.batches
+        assert sink1.blocked == sink0.blocked  # keys AND until, exact
+        assert len(sink0.blocked) >= 8         # the flood was condemned
+        assert rep1.stats == rep0.stats
+        assert rep1.ingest["n_workers"] == 1
+        assert rep1.ingest["workers"]["0"]["seq_gaps"] == 0
+        d = rep1.dispatch
+        assert d["mode"] == ("adaptive" if mega_n else "single")
+        assert d["host_copies_per_batch"] == 1.0
+
+    def test_rebind_to_sealed_source_stages_through_the_arena(
+            self, tmp_path):
+        """An engine built on a record source without grouping never
+        allocated an arena; rebound to a sealed fleet
+        (``reset_stream``) it serves through the one sealed loop —
+        every batch staged once — and stays bit-identical to the
+        inline path."""
         import platform
 
         if platform.system() != "Linux":
@@ -734,16 +790,17 @@ class TestEngineLoop:
                       ArraySource(recs.copy()), sink0,
                       readback_depth=4, wire=schema.WIRE_RAW48).run()
         rep1, sink1 = self._run_sharded(
-            recs, 1, str(tmp_path / "fring"), wire=schema.WIRE_RAW48)
+            recs, 1, str(tmp_path / "fring"), wire=schema.WIRE_RAW48,
+            rebind=True)
         assert rep1.records == rep0.records == len(recs)
-        assert rep1.batches == rep0.batches
-        assert sink1.blocked == sink0.blocked  # keys AND until, exact
+        assert sink1.blocked == sink0.blocked
         assert rep1.stats == rep0.stats
-        assert rep1.ingest["n_workers"] == 1
-        assert rep1.ingest["workers"]["0"]["seq_gaps"] == 0
+        assert rep1.dispatch["host_copies_per_batch"] == 1.0
+        assert rep1.dispatch["arena"]["slots"] == 4 + 2
 
+    @SEALED_POINTS
     def test_sealed_slot_reuse_under_live_overwrite_bit_identical(
-            self, tmp_path):
+            self, tmp_path, wire, mega_n):
         """Mutate-after-release at serving scale: a 2-slot queue with
         16 batches forces every shm slot to be RE-USED by the live
         worker many times while earlier batches are still dispatched-
@@ -762,11 +819,12 @@ class TestEngineLoop:
         rep0 = Engine(small_cfg(batch=256, cap=1 << 14,
                                 pps_threshold=200.0, bps_threshold=1e9),
                       ArraySource(recs.copy()), sink0,
-                      readback_depth=4, wire=schema.WIRE_RAW48,
+                      readback_depth=4, wire=wire,
                       sink_thread=False).run()
         rep1, sink1 = self._run_sharded(
             recs, 1, str(tmp_path / "fring"), queue_slots=2,
-            wire=schema.WIRE_RAW48, sink_thread=False)
+            wire=wire, mega_n=mega_n, warm=bool(mega_n),
+            sink_thread=False)
         assert rep1.records == rep0.records == len(recs)
         assert rep1.batches == rep0.batches
         assert sink1.blocked == sink0.blocked
@@ -777,7 +835,9 @@ class TestEngineLoop:
         assert rep1.stages_ms["pop"].get("n", 0) > 0
         assert rep1.stages_ms["stage"].get("n", 0) > 0
 
-    def test_sharded_ingest_two_workers_equivalent(self, tmp_path):
+    @SEALED_POINTS
+    def test_sharded_ingest_two_workers_equivalent(self, tmp_path, wire,
+                                                   mega_n):
         """N=2 regroups records into per-shard batches, and the table
         updates are batch-granular — so records at a flow's decision
         boundary may legally move between verdict classes, and
@@ -795,9 +855,10 @@ class TestEngineLoop:
         rep0 = Engine(small_cfg(batch=256, cap=1 << 14,
                                 pps_threshold=200.0, bps_threshold=1e9),
                       ArraySource(recs.copy()), sink0,
-                      readback_depth=4, wire=schema.WIRE_RAW48).run()
+                      readback_depth=4, wire=wire).run()
         rep2, sink2 = self._run_sharded(
-            recs, 2, str(tmp_path / "fring"), wire=schema.WIRE_RAW48)
+            recs, 2, str(tmp_path / "fring"), wire=wire, mega_n=mega_n,
+            warm=bool(mega_n))
         assert rep2.records == rep0.records == len(recs)
         assert sink2.blocked.keys() == sink0.blocked.keys()
         for ip, until in sink0.blocked.items():
@@ -814,156 +875,18 @@ class TestEngineLoop:
         assert all(w["seq_gaps"] == 0 for w in ing["workers"].values())
 
 
-class TestDeviceLoop:
-    """The device-resident drain ring (``Engine(device_loop=N)``,
-    fused/device_loop.py): parity gates, the recomputed arena slot
-    bound, the per-slot wire overflow fallback, and the pre-boot
-    refusals.  Grouping — including the ring — is dispatch-granularity
-    only, so every run here must be BYTE-identical to the singles
-    baseline."""
+class TestGroupGranularity:
+    """What holds at GROUP granularity besides byte-identity (which
+    ``test_adaptive_mega_matches_single_and_fixed`` pins): the
+    simulated kernel tier's accounting and the arena's slot rule."""
 
-    @staticmethod
-    def _recs(n_batches, batch=256, seed=11, n_attack=32):
-        return TrafficGen(
-            TrafficSpec(scenario=Scenario.UDP_FLOOD_MULTI, rate_pps=1e7,
-                        n_attack_ips=n_attack, attack_fraction=0.8,
-                        seed=seed)
-        ).next_records(batch * n_batches)
-
-    @staticmethod
-    def _run(recs, verdict_k=64, **kw):
-        import jax
-
-        cfg = small_cfg(batch=256, verdict_k=verdict_k,
-                        pps_threshold=200.0, bps_threshold=1e9)
-        sink = CollectSink()
-        eng = Engine(cfg, ArraySource(recs.copy()), sink,
-                     sink_thread=False, **kw)
-        with jax.transfer_guard("disallow"):
-            rep = eng.run()
-        return rep, sink, eng
-
-    def test_device_loop_matches_single_and_mega_auto(self):
-        """device_loop=2 over the mega-auto ladder vs plain mega-auto
-        vs singles on one stream: byte-identical stats, blacklist
-        (keys AND untils) and final table, under the transfer guard —
-        while the ring actually fired (full 16-batch rounds in the
-        histogram) and the report carries the ring block."""
-        import jax
-
-        recs = self._recs(38)  # 2 rounds of 16 + 4 + 2: ring AND ladder
-        rep1, sink1, eng1 = self._run(recs, readback_depth=4)
-        repa, sinka, _ = self._run(recs, readback_depth=4, mega_n="auto")
-        repr_, sinkr, engr = self._run(recs, mega_n="auto", device_loop=2)
-        assert repr_.records == repa.records == rep1.records
-        assert repr_.stats == repa.stats == rep1.stats
-        assert sinkr.blocked == sinka.blocked == sink1.blocked
-        for a, b in zip(jax.tree_util.tree_leaves(eng1.table),
-                        jax.tree_util.tree_leaves(engr.table)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        d = repr_.dispatch
-        assert d["mode"] == "device_loop"
-        hist = {int(g): n for g, n in d["group_hist"].items()}
-        assert sum(g * n for g, n in hist.items()) == repr_.batches == 38
-        assert hist.get(16, 0) >= 2  # full deep-scan rounds fired
-        dl = d["device_loop"]
-        assert dl["ring"] == 2 and dl["chunks_per_slot"] == 8
-        assert dl["rounds"] >= 2 and dl["steps_per_round"] == 2
-        assert dl["h2d"]["puts"] >= 2 * dl["rounds"]
-        assert 0.0 <= dl["h2d"]["overlap_fraction"] <= 1.0
-
-    def test_device_loop_zero_is_todays_path(self):
-        """``device_loop=0`` must be EXACTLY today's engine: no ring
-        step staged, no pipeline worker, dispatch mode unchanged."""
-        recs = self._recs(6)
-        rep, _, eng = self._run(recs, readback_depth=4, mega_n="auto",
-                                device_loop=0)
-        assert eng.ring == 0 and eng.ring_step is None
-        assert rep.dispatch["mode"] == "adaptive"
-        assert rep.dispatch["device_loop"] is None
-
-    def test_device_loop_overflow_inside_ring_round(self):
-        """Forced verdict-wire overflow INSIDE a ring round: with
-        verdict_k=2 a flood blocks far more than 2 flows per merged
-        slot window, so the round's per-slot wires overflow and the
-        sink must fall back to the full block-array fetch — losing no
-        block and staying byte-identical to the singles run at the
-        same K."""
-        recs = self._recs(20, seed=7, n_attack=8)
-        rep1, sink1, _ = self._run(recs, verdict_k=2, readback_depth=4)
-        repr_, sinkr, _ = self._run(recs, verdict_k=2, mega_n=4,
-                                    device_loop=2)
-        assert sinkr.blocked == sink1.blocked  # keys AND untils
-        assert repr_.stats == rep1.stats
-        assert len(sinkr.blocked) > 2  # overflow genuinely forced
-        assert repr_.readback["fallback_sinks"] >= 1
-        assert repr_.dispatch["device_loop"]["rounds"] >= 1
-
-    def test_meshed_device_loop_matches_meshed_single(self):
-        """The sharded drain ring (deep scan over the shard-mapped
-        step): byte-identical to the per-batch meshed engine under the
-        transfer guard — the multi-device half of the ring parity
-        gate."""
-        import jax
-
-        from flowsentryx_tpu.parallel import make_mesh
-
-        recs = TrafficGen(
-            TrafficSpec(scenario=Scenario.UDP_FLOOD_MULTI, rate_pps=1e7,
-                        n_attack_ips=32, attack_fraction=0.8, seed=13)
-        ).next_records(512 * 10)  # 1 full 2x4 round + ladder tail
-
-        def run(**kw):
-            cfg = small_cfg(batch=512, cap=1 << 12, pps_threshold=200.0,
-                            bps_threshold=1e9)
-            sink = CollectSink()
-            eng = Engine(cfg, ArraySource(recs.copy()), sink,
-                         mesh=make_mesh(8), sink_thread=False, **kw)
-            with jax.transfer_guard("disallow"):
-                rep = eng.run()
-            return rep, sink
-
-        rep1, sink1 = run(readback_depth=8)
-        repr_, sinkr = run(mega_n=4, device_loop=2)
-        assert repr_.stats == rep1.stats
-        assert sinkr.blocked == sink1.blocked
-        assert repr_.batches == rep1.batches == 10
-        assert repr_.dispatch["device_loop"]["rounds"] >= 1
-
-    def test_sharded_ingest_device_loop_bit_identical(self, tmp_path):
-        """The full production shape: sealed worker fleet → zero-copy
-        arena staging → drain ring.  Must stay bit-identical to the
-        inline singles engine on the same records, with the single-copy
-        invariant intact and full rounds fired."""
-        import platform
-
-        if platform.system() != "Linux":
-            pytest.skip("shm ingest requires Linux")
-        recs = TestEngineLoop._flood_records(256 * 16)
-        sink0 = CollectSink()
-        rep0 = Engine(small_cfg(batch=256, cap=1 << 14,
-                                pps_threshold=200.0, bps_threshold=1e9),
-                      ArraySource(recs.copy()), sink0,
-                      readback_depth=4, sink_thread=False).run()
-        rep1, sink1 = TestEngineLoop._run_sharded(
-            recs, 1, str(tmp_path / "fring"), warm=True,
-            readback_depth=None, sink_thread=False,
-            mega_n=4, device_loop=2)
-        assert rep1.records == rep0.records == len(recs)
-        assert sink1.blocked == sink0.blocked
-        assert rep1.stats == rep0.stats
-        d = rep1.dispatch
-        assert d["host_copies_per_batch"] == 1.0
-        assert d["staged_batches"] == rep1.batches
-        assert d["device_loop"]["rounds"] >= 1
-
-    def test_sim_kernel_tier_accounting_at_ring_granularity(self):
-        """Escalated records arriving in ring-sized bursts: the tier's
+    def test_sim_kernel_tier_accounting_at_group_granularity(self):
+        """Escalated records arriving in group-sized bursts: the tier's
         per-band accounting and the engine's verdicts must match the
-        ringless run exactly, and the PR 6 rule — coalescing shortness
-        judged on the PRE-filter poll count — must hold at ring
-        granularity (a flood the tier mostly drops still fills rings,
-        it does not flush batch-by-batch)."""
+        ungrouped run exactly, and the PR 6 rule — coalescing shortness
+        judged on the PRE-filter poll count — must hold at group
+        granularity (a flood the tier mostly drops still fills the top
+        rung, it does not flush batch-by-batch)."""
 
         class DropMostTier:
             """Deterministic stand-in for distill.SimKernelTier: drops
@@ -983,7 +906,10 @@ class TestDeviceLoop:
                 return {"kernel_drops": self.seen - self.kept,
                         "escalated": self.kept}
 
-        recs = self._recs(40, seed=23)
+        recs = TrafficGen(
+            TrafficSpec(scenario=Scenario.UDP_FLOOD_MULTI, rate_pps=1e7,
+                        n_attack_ips=32, attack_fraction=0.8, seed=23)
+        ).next_records(256 * 40)
 
         def run(**kw):
             cfg = small_cfg(batch=256, pps_threshold=200.0,
@@ -995,9 +921,9 @@ class TestDeviceLoop:
             return eng.run(), sink, tier
 
         rep0, sink0, tier0 = run(readback_depth=4)
-        rep1, sink1, tier1 = run(mega_n=4, device_loop=2)
+        rep1, sink1, tier1 = run(mega_n="auto")
         # the tier saw every record, in both modes, exactly once —
-        # ring-sized polls must not double-filter or skip records
+        # group-sized polls must not double-filter or skip records
         assert tier1.seen == tier0.seen == len(recs)
         assert tier1.kept == tier0.kept
         assert rep1.escalation["kernel_drops"] == \
@@ -1016,44 +942,20 @@ class TestDeviceLoop:
                 == sum(rep0.stats[k] for k in classes) == tier1.kept)
         assert sink1.blocked.keys() == sink0.blocked.keys()
         # the dropped-in-kernel flood still counted as deep backlog:
-        # rings fired instead of short-poll flushing every batch
-        assert rep1.dispatch["device_loop"]["rounds"] >= 1
+        # the top rung fired instead of short-poll flushing every batch
+        assert rep1.dispatch["group_hist"].get("8", 0) >= 1
 
-    def test_ring_safe_slots_bound(self):
-        """The recomputed arena reuse-safety bound: depth + ring + 1,
-        reducing to the original depth + 2 at ring=1; engines allocate
+    def test_safe_slots_bound(self):
+        """The arena reuse-safety bound, depth + 2; engines allocate
         it."""
         from flowsentryx_tpu.engine.arena import DispatchArena
 
-        assert DispatchArena.ring_safe_slots(8, 1) == 10  # == depth + 2
-        assert DispatchArena.ring_safe_slots(8, 2) == 11
-        assert DispatchArena.ring_safe_slots(16, 4) == 21
-        with pytest.raises(ValueError, match="ring"):
-            DispatchArena.ring_safe_slots(8, 0)
-        recs = self._recs(2)
-        _, _, eng = self._run(recs, mega_n=4, device_loop=3)
-        # auto depth rose to one round (3*4), slots = 12 + 3 + 1
-        assert eng.readback_depth == 12
-        assert eng._arena.slots == 16
-
-    def test_device_loop_refusals(self):
-        """Structurally unsafe combinations are refused at
-        construction with their actual problem named."""
-        cfg = small_cfg(batch=256)
-        src = TrafficSource(TrafficSpec(), total=256)
-        with pytest.raises(ValueError, match="mega"):
-            Engine(cfg, src, NullSink(), device_loop=2)
-        with pytest.raises(ValueError, match=">= 0"):
-            Engine(cfg, src, NullSink(), mega_n=4, device_loop=-1)
-        with pytest.raises(ValueError, match="verdict"):
-            Engine(small_cfg(batch=256, verdict_k=0), src, NullSink(),
-                   mega_n=4, device_loop=2)
-        # an EXPLICIT readback_depth below one ring round is refused
-        # (the auto default is raised instead) — the slot-safety bound
-        # and the H2D overlap both assume the pipe holds a round
-        with pytest.raises(ValueError, match="readback_depth"):
-            Engine(cfg, src, NullSink(), mega_n=4, device_loop=2,
-                   readback_depth=4)
+        assert DispatchArena.safe_slots(8) == 10
+        assert DispatchArena.safe_slots(0) == 3  # depth floors at 1
+        eng = Engine(small_cfg(batch=256),
+                     TrafficSource(TrafficSpec(), total=256), NullSink(),
+                     mega_n=4, readback_depth=12)
+        assert eng._arena.slots == 14
 
 
 class TestCompactReadback:
@@ -1304,45 +1206,6 @@ class TestServeMegaAuto:
             cli.main(["serve", "--scenario", "syn_benign_mix",
                       "--packets", "256", "--mega", "four"])
         assert "auto" in capsys.readouterr().err
-
-    def test_serve_device_loop_runs_and_reports_ring(self, tmp_path,
-                                                     capsys):
-        import json as js
-
-        from flowsentryx_tpu import cli
-
-        assert cli.main(["serve", "--scenario", "udp_flood_multi",
-                         "--config", self._small_cfg_file(tmp_path),
-                         "--rate", "1e7", "--packets", str(256 * 20),
-                         "--mega", "4", "--device-loop", "2",
-                         "--no-sink-thread"]) == 0
-        rep = js.loads(capsys.readouterr().out)
-        assert rep["records"] == 256 * 20
-        d = rep["dispatch"]
-        assert d["mode"] == "device_loop"
-        assert d["device_loop"]["ring"] == 2
-        assert d["device_loop"]["rounds"] >= 1
-        assert sum(int(g) * n for g, n in d["group_hist"].items()) \
-            == rep["batches"]
-
-    def test_serve_device_loop_refusals_pre_boot(self, capsys):
-        """The unsafe flag combinations are refused BEFORE the JAX
-        boot, each naming its actual problem."""
-        from flowsentryx_tpu import cli
-
-        assert cli.main(["serve", "--scenario", "syn_benign_mix",
-                         "--packets", "256",
-                         "--device-loop", "2"]) == 1
-        assert "--mega" in capsys.readouterr().err
-        assert cli.main(["serve", "--scenario", "syn_benign_mix",
-                         "--packets", "256", "--mega", "4",
-                         "--verdict-k", "0", "--device-loop", "2"]) == 1
-        assert "verdict" in capsys.readouterr().err
-        assert cli.main(["serve", "--scenario", "syn_benign_mix",
-                         "--packets", "256", "--mega", "4",
-                         "--device-loop", "-1"]) == 1
-        assert ">= 0" in capsys.readouterr().err
-
 
 class TestPallasModelFamily:
     def test_engine_with_pallas_scorer(self):
@@ -2094,36 +1957,3 @@ class TestSloServing:
         assert all(a <= b for a, b in zip(chain, chain[1:]))
         assert chain[0] > 0
 
-    def test_slo_device_loop_parity_and_round_sizer(self):
-        """Ring mode under a budget: an EXISTING full-round backlog
-        still engages the deep scan (the un-capped recovery/
-        throughput path) with byte-identical results; the round
-        SIZER predicate — what the sealed ring loop consults before
-        WAITING for a round to fill — degrades only while headroom is
-        positive and smaller than a round, and is back on once the
-        record is already late."""
-        import time as _t
-
-        recs = self._recs(38)
-        repr_, sinkr, _ = self._run(recs, mega_n="auto", device_loop=2,
-                                    readback_depth=None)
-        reps, sinks, enge = self._run(recs, mega_n="auto",
-                                      device_loop=2, slo_us=2000,
-                                      warm=True, readback_depth=None)
-        assert reps.stats == repr_.stats
-        assert sinks.blocked == sinkr.blocked
-        assert repr_.dispatch["device_loop"]["rounds"] >= 2
-        assert reps.dispatch["device_loop"]["rounds"] >= 2
-        # the sizer predicate (2 ms budget, ring round EWMA 8 ms);
-        # rounds key NEGATED so a depth-1 ring can never alias the
-        # top rung's estimate (the round wall includes uploads+reap)
-        enge._rung_ewma_s[-16] = 0.008
-        now = _t.perf_counter()
-        assert not enge._slo_round_fits(now)          # would breach
-        enge._rung_ewma_s[-16] = 0.0005
-        assert enge._slo_round_fits(now)              # fits fresh
-        assert not enge._slo_round_fits(now - 0.0018)  # headroom gone
-        assert enge._slo_round_fits(now - 0.5)        # late: ring on
-        # warm() seeded the ring round under the negated key, leaving
-        # the top-rung estimate intact (device_loop=1 would alias)
-        assert "round16" in reps.dispatch["slo"]["rung_ewma_ms"]

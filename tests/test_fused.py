@@ -118,8 +118,8 @@ class TestFusedStep:
         assert (np.asarray(out3.verdict)[:5] == int(Verdict.PASS)).all()
 
     def test_ml_detection_votes_then_blacklists(self):
-        """The young-flow vote (ModelConfig.vote_k/vote_m, SERVE_r04
-        fix): a new flow's malicious-scoring records DROP per record
+        """The young-flow vote (ModelConfig.vote_k/vote_m; the fix for
+        the round-4 serve run, whose record PR 23 deleted): a new flow's malicious-scoring records DROP per record
         (fail-closed — a rotating spoofed flood must not sail through)
         but the flow is NOT blacklisted until the vote carries;
         sustained malicious evidence past maturity blacklists."""
@@ -150,9 +150,9 @@ class TestFusedStep:
 
     def test_ml_young_mis_scores_never_block_recovered_flow(self):
         """A benign flow whose ONLY malicious-looking records are its
-        young ones (the exact SERVE_r04 failure) loses those records —
-        per-record fail-closed — but is NEVER blacklisted, and its
-        mature traffic flows untouched."""
+        young ones (the failure of the round-4 serve run) loses those
+        records — per-record fail-closed — but is NEVER blacklisted,
+        and its mature traffic flows untouched."""
         step, table, stats, params = make_env()
         b1 = build_batch([(3101, 3, 100, 0.1, ML_HOT)])   # young mis-scores
         table, stats, o1 = step(table, stats, params, b1)
@@ -408,7 +408,7 @@ class TestFusedStep:
         # an untracked trickle (<= vote_k records) that scores malicious
         # gets its RECORDS dropped — fail-closed per record, so a full
         # table can't shield a slow attack — but is NOT blacklisted
-        # (blocking on unvoted evidence is the SERVE_r04 failure)
+        # (blocking on unvoted evidence is what the round-4 serve run did)
         b2 = build_batch([(998, 2, 100, 0.2, ML_HOT)])
         table, stats, out2 = step(table, stats, params, b2)
         assert (np.asarray(out2.verdict)[:2] == int(Verdict.DROP_ML)).all()

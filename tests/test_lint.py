@@ -91,7 +91,7 @@ class TestLocalImportStage:
 
 
 def _purity_findings(tmp_path, src):
-    p = tmp_path / "device_loop.py"
+    p = tmp_path / "fused.py"
     p.write_text(src)
     old = lint.REPO
     lint.REPO = tmp_path
@@ -101,10 +101,11 @@ def _purity_findings(tmp_path, src):
         lint.REPO = old
 
 
-class TestDeviceLoopPurityStage:
+class TestTracedRegionPurityStage:
     """The traced-region gate: no device_get/callback may appear in
-    fused/ (everything there runs inside jit — fsx audit proves it on
-    the staged graph, this stage catches it at review speed)."""
+    ops/ (the step every engine serves is written there and runs
+    inside jit — fsx audit proves it on the staged graph, this stage
+    catches it at review speed)."""
 
     def test_device_get_flagged(self, tmp_path):
         out = _purity_findings(tmp_path, (
@@ -112,7 +113,7 @@ class TestDeviceLoopPurityStage:
             "def loop(x):\n"
             "    return jax.device_get(x)\n"))
         assert len(out) == 1
-        assert "device_get" in out[0] and "device_loop.py:4" in out[0]
+        assert "device_get" in out[0] and "fused.py:4" in out[0]
 
     def test_callbacks_flagged(self, tmp_path):
         for snippet, name in (
@@ -144,7 +145,14 @@ class TestDeviceLoopPurityStage:
         assert out == []
 
     def test_repo_traced_region_is_clean(self):
-        assert lint.stage_device_loop_purity() == []
+        """The stage reads the tree the step is written in, and the
+        one host-side fetch there (``table_summary``'s, outside any
+        jit) is exempt by ``noqa``, not by being out of sight."""
+        read = {p.name for p in
+                (lint.REPO / lint.TRACED_REGION_TREE).rglob("*.py")}
+        assert {"fused.py", "hashtable.py", "agg.py", "limiters.py",
+                "pallas_kernels.py"} <= read
+        assert lint.stage_traced_region_purity() == []
 
 
 class TestSyncContractsStage:

@@ -320,89 +320,3 @@ class TestShardedMegaStep:
             np.asarray(outs.verdict), np.stack(verdicts))
         # per-chunk route_drop stacks to [N]
         assert np.asarray(outs.route_drop).shape == (4,)
-
-
-class TestShardedDeviceLoop:
-    def test_ring_matches_sequential_sharded_megasteps(self, mesh):
-        """The sharded drain ring (fused/device_loop.py deep scan over
-        the shard-mapped step) must produce byte-identical trajectories
-        to its ring slots dispatched as sequential sharded megasteps —
-        and each slot's wire must equal that megastep's merged wire
-        (the per-slot harvest contract)."""
-        import dataclasses
-
-        from flowsentryx_tpu.core import schema
-        from flowsentryx_tpu.core.config import BatchConfig
-        from flowsentryx_tpu.fused import device_loop as dl
-
-        cfg = dataclasses.replace(
-            CFG, batch=BatchConfig(max_batch=128))
-        spec = get_model(cfg.model.name)
-        params = spec.init()
-        quant = schema.wire_quant_for(params)
-        ring, chunks = 2, 2
-        mega = pstep.make_sharded_compact_megastep(
-            cfg, spec.classify_batch, mesh, n_chunks=chunks,
-            donate=False, **quant)
-        loop = dl.make_sharded_compact_device_loop(
-            cfg, spec.classify_batch, mesh, ring, chunks,
-            donate=False, **quant)
-
-        rng = np.random.default_rng(17)
-        raws = []
-        for i in range(ring * chunks):
-            buf = np.zeros(128, dtype=schema.FLOW_RECORD_DTYPE)
-            buf["saddr"] = rng.integers(1, 200, 128).astype(np.uint32)
-            buf["pkt_len"] = rng.integers(64, 1500, 128)
-            buf["ts_ns"] = (i * 128 + np.arange(128)) * 50_000
-            buf["feat"] = rng.integers(0, 1 << 22, (128, 8))
-            raws.append(schema.encode_compact(buf, 128, t0_ns=0, **quant))
-        slots = [jnp.asarray(np.stack(raws[r * chunks:(r + 1) * chunks]))
-                 for r in range(ring)]
-
-        t1 = pstep.make_sharded_table(cfg, mesh)
-        s1 = make_stats()
-        slot_wires = []
-        for s in slots:
-            t1, s1, o = mega(t1, s1, params, s)
-            slot_wires.append(np.asarray(o.wire))
-        t2, s2, out = loop(pstep.make_sharded_table(cfg, mesh),
-                           make_stats(), params, *slots)
-        np.testing.assert_array_equal(np.asarray(t2.key),
-                                      np.asarray(t1.key))
-        np.testing.assert_array_equal(np.asarray(t2.state),
-                                      np.asarray(t1.state))
-        for a, b in zip(s2, s1):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        # [R, 2K+4]: one merged wire per ring slot, byte-equal to the
-        # sequential megasteps' wires
-        wires = np.asarray(out.wire)
-        assert wires.shape == (ring, 2 * cfg.batch.verdict_k + 4)
-        np.testing.assert_array_equal(wires, np.stack(slot_wires))
-        # overflow fallback arrays stay stacked per slot/chunk
-        assert np.asarray(out.block_key).shape[:2] == (ring, chunks)
-
-    def test_ring_guards_slot_shape(self, mesh):
-        """The compiled ring refuses a wrong slot count or chunk
-        count loudly (anything else would silently recompile)."""
-        import dataclasses
-
-        from flowsentryx_tpu.core import schema
-        from flowsentryx_tpu.core.config import BatchConfig
-        from flowsentryx_tpu.fused import device_loop as dl
-
-        cfg = dataclasses.replace(CFG, batch=BatchConfig(max_batch=128))
-        spec = get_model(cfg.model.name)
-        params = spec.init()
-        quant = schema.wire_quant_for(params)
-        loop = dl.make_sharded_compact_device_loop(
-            cfg, spec.classify_batch, mesh, 2, 2, donate=False, **quant)
-        slot = jnp.zeros((2, 129, schema.COMPACT_RECORD_WORDS),
-                         jnp.uint32)
-        table, stats = pstep.make_sharded_table(cfg, mesh), make_stats()
-        with pytest.raises(ValueError, match="2-slot ring"):
-            loop(table, stats, params, slot)
-        with pytest.raises(ValueError, match="chunk"):
-            loop(table, stats, params, slot[:1], slot[:1])
-        with pytest.raises(ValueError, match="ring_depth"):
-            dl.wrap_device_loop(lambda *a: a, 0, 2, ())

@@ -1,6 +1,5 @@
 """Predictive dispatch governor (ISSUE 18): burst forecasting units,
-actuation-policy units, engine integration parity gates, and the PR 11
-follow-up ring-round EWMA refinement.
+actuation-policy units and engine integration parity gates.
 
 The estimator tests are fully deterministic: they drive
 :class:`BurstPredictor` with the SAME ``traffic.pulse_offsets_ns``
@@ -482,60 +481,6 @@ class TestPredictEngine:
         assert set(eng._rung_ewma_s) == set(before)
         # the warm rung's EWMA moved (that is the point of the warm)
         assert eng._rung_ewma_s[4] != before[4] or True
-
-
-class TestRingRoundRefinement:
-    """PR 11 follow-up (satellite): the ring-round EWMA — seeded by
-    warm() only, until now — is refined online from launch-absorbed
-    round walls, guarded three ways: ready-proven outputs only, never
-    creates the key, never sinks below the warm-seed floor."""
-
-    def _eng(self):
-        recs = TrafficGen(TrafficSpec(seed=5)).next_records(256)
-        return Engine(small_cfg(batch=256), ArraySource(recs),
-                      NullSink(), sink_thread=False, mega_n="auto",
-                      slo_us=10_000)
-
-    def test_refines_only_existing_keys(self):
-        eng = self._eng()
-        assert -16 not in eng._rung_ewma_s
-        eng._note_round_s(-16, 0.02, _ReadyOut())
-        assert -16 not in eng._rung_ewma_s  # warm() owns creation
-
-    def test_launch_absorbed_guard_and_floor(self):
-        eng = self._eng()
-        eng._rung_ewma_s[-16] = 0.010
-        eng._round_floor_s[-16] = 0.010
-        # a not-yet-ready output proves nothing: no refinement
-        eng._note_round_s(-16, 0.030, _ReadyOut(ready=False))
-        assert eng._rung_ewma_s[-16] == 0.010
-        # ready + slower round: EWMA rises toward the sample
-        eng._note_round_s(-16, 0.030, _ReadyOut())
-        risen = eng._rung_ewma_s[-16]
-        assert 0.010 < risen <= 0.030
-        # ready + absurdly fast rounds (launch-absorbed wall under the
-        # timed seed): clamped at the warm floor, never below
-        for _ in range(50):
-            eng._note_round_s(-16, 1e-6, _ReadyOut())
-        assert eng._rung_ewma_s[-16] == 0.010
-
-    def test_no_budget_no_refinement(self):
-        recs = TrafficGen(TrafficSpec(seed=5)).next_records(256)
-        eng = Engine(small_cfg(batch=256), ArraySource(recs),
-                     NullSink(), sink_thread=False, mega_n="auto")
-        eng._rung_ewma_s[-16] = 0.010
-        eng._note_round_s(-16, 0.030, _ReadyOut())
-        assert eng._rung_ewma_s[-16] == 0.010  # slo off: frozen
-
-    def test_warm_seeds_ring_floor(self):
-        recs = TrafficGen(TrafficSpec(seed=5)).next_records(512)
-        eng = Engine(small_cfg(batch=256), ArraySource(recs),
-                     NullSink(), sink_thread=False, mega_n="auto",
-                     device_loop=2, readback_depth=None, slo_us=10_000)
-        eng.warm()
-        key = -(eng.ring * eng._ring_chunks)
-        assert key in eng._rung_ewma_s
-        assert eng._round_floor_s[key] == eng._rung_ewma_s[key] > 0
 
 
 class TestShedDeferral:

@@ -1,7 +1,7 @@
 """``fsx ranges`` — the whole-pipeline integer value-range prover.
 
 Acceptance: every step variant the engine can serve (singles, sharded,
-mega rungs, device-loop rings, eviction epochs) proves clean — no
+mega rungs, eviction epochs) proves clean — no
 equation's exact result interval escapes its dtype — modulo the four
 audited WRAP_OK entries, each of which must both still match and still
 name live code.  Negatives mirror the planted-defect style of
@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from flowsentryx_tpu.core import schema
 from flowsentryx_tpu.core.config import BatchConfig, FsxConfig, TableConfig
 from flowsentryx_tpu.parallel import make_mesh
+from test_audit import BENCH_CONFIGS, bench_cell_cfg
 from flowsentryx_tpu.ranges import (
     interval as iv,
     prover,
@@ -44,8 +45,7 @@ def report():
     """One full range proof over every variant (module-cached; the
     staging is the expensive part, the assertions are reads)."""
     return ranges_runner.run_ranges(
-        CFG, mesh=make_mesh(8), mega_n=2, device_loop=2,
-        artifact=str(ARTIFACT))
+        CFG, mesh=make_mesh(8), mega_n=2, artifact=str(ARTIFACT))
 
 
 def _analyze(fn, *args, seeds_=None, **kw):
@@ -62,12 +62,33 @@ class TestAcceptance:
             str(f) for f in report.registry_findings]
         names = [v.name for v in report.variants]
         assert names == ["raw", "compact", "sharded", "megastep",
-                         "sharded_megastep", "device_loop@2x2",
-                         "sharded_device_loop@2x2"]
+                         "sharded_megastep"]
         for v in report.variants:
             assert v.ok, (v.name, [str(f) for f in v.findings])
             assert v.n_checked > 50, v.name  # the check actually ran
             assert not v.unmodeled, (v.name, v.unmodeled)
+
+    @pytest.mark.parametrize("name", BENCH_CONFIGS)
+    def test_the_programs_a_cell_stages_prove_clean(self, name):
+        """No equation of the graphs the benchmark's cells run —
+        ``jit_step`` and every rung of the ``--mega auto`` ladder at
+        the cell's own limiter, widths and artifact — can wrap
+        silently (tests/test_audit.py holds the same graphs to the
+        audit's contracts)."""
+        from flowsentryx_tpu.ops import fused
+
+        cfg, params, _ = bench_cell_cfg(name)
+        rep = ranges_runner.run_ranges(
+            cfg, params=params, variants=("compact", "megastep"),
+            mega_n=8, mega_sizes=fused.pow2_group_sizes(8),
+            artifact=None, with_negatives=False)
+        # the variants, not ``rep.ok``: the registry's staleness audit
+        # wants every variant family staged (the module's ``report``)
+        assert [v.name for v in rep.variants] == [
+            "compact", "megastep@8", "megastep@4", "megastep@2"]
+        for v in rep.variants:
+            assert v.ok, (v.name, [str(f) for f in v.findings])
+            assert v.n_checked > 50 and not v.unmodeled, v.name
 
     def test_every_wrap_ok_entry_matches(self, report):
         """The registry is exactly the live set: every entry fires in
@@ -92,7 +113,7 @@ class TestAcceptance:
 
         d = json.loads(Path(p).read_text())
         assert d["ok"] is True
-        assert len(d["variants"]) == 7
+        assert len(d["variants"]) == 5
         assert d["negative_controls"]["ok"] is True
         assert d["bridge"]["ok"] is True
         assert {e["name"] for e in d["wrap_ok_registry"]} == {

@@ -663,27 +663,28 @@ class TestProtocolModels:
 
 
 class TestArenaBoundTight:
-    """The headline proof: ring_safe_slots passes ALL interleavings,
+    """The headline proof: safe_slots passes ALL interleavings,
     one slot fewer yields a concrete staged-copy-overwrite schedule."""
 
     def test_shipped_bound_passes_all_interleavings(self):
         from flowsentryx_tpu.engine.arena import DispatchArena
         from flowsentryx_tpu.sync import interleave as il
 
-        depth, ring = il._ARENA_DEPTH, il._ARENA_RING
-        safe = DispatchArena.ring_safe_slots(depth, ring)
-        assert safe == depth + ring + 1
+        depth = il._ARENA_DEPTH
+        safe = DispatchArena.safe_slots(depth)
+        assert safe == depth + 2
+        # the claims wrap the arena at the bound: the proof covers reuse
+        assert il._ARENA_SINGLES > safe
         res = il.explore("safe", il._mk_arena(
-            safe, depth, ring, il._ARENA_SINGLES, il._ARENA_ROUNDS))
+            safe, depth, il._ARENA_SINGLES))
         assert res.ok and res.interleavings > 0 and not res.capped
 
     def test_one_below_yields_staged_copy_overwrite(self):
         from flowsentryx_tpu.sync import interleave as il
 
-        depth, ring = il._ARENA_DEPTH, il._ARENA_RING
+        depth = il._ARENA_DEPTH
         res = il.explore("tight", il._mk_arena(
-            depth + ring, depth, ring,
-            il._ARENA_SINGLES, il._ARENA_ROUNDS),
+            depth + 1, depth, il._ARENA_SINGLES),
             expect_violation=True)
         assert res.ok
         cx = res.counterexample
@@ -698,7 +699,7 @@ class TestArenaBoundTight:
         rep = run_interleave()
         assert rep.ok
         assert rep.bound["safe_slots"] == (
-            rep.bound["readback_depth"] + rep.bound["ring"] + 1)
+            rep.bound["readback_depth"] + 2)
         assert rep.bound["counterexample_found"] is True
         assert rep.bound["interleavings_at_safe"] > 0
         j = rep.to_json()
@@ -732,10 +733,10 @@ class TestSinkChannel:
         assert ch.try_pop() is None
 
     def test_check_raises_named_worker_crash(self):
-        ch = SinkChannel("device-pipeline worker")
+        ch = SinkChannel("ingest worker 3")
         ch.complete(0, exc=ValueError("boom"))
         with pytest.raises(WorkerCrash,
-                           match="device-pipeline worker crashed"):
+                           match="ingest worker 3 crashed"):
             ch.check()
         assert isinstance(ch.crashed(), ValueError)
 
@@ -767,34 +768,10 @@ class TestSinkChannel:
 # ---------------------------------------------------------------------------
 
 class TestCrashPropagationPerWorker:
-    """docs/CONCURRENCY.md §crash: sink thread, device-pipeline worker
-    and strict-mode ingest death all surface as the same loud
-    WorkerCrash on the dispatch side (the sink-thread case is pinned
-    in test_engine.py::test_sink_crash_fails_engine_loudly)."""
-
-    def test_pipeline_worker_crash_is_loud(self):
-        from flowsentryx_tpu.engine import Engine, TrafficSource
-        from flowsentryx_tpu.engine.traffic import Scenario, TrafficSpec
-        from tests.test_engine import small_cfg
-
-        class BoomSink:
-            def apply(self, update):
-                if len(update.key):
-                    raise ValueError("verdict ring gone")
-
-        cfg = small_cfg(batch=256, pps_threshold=200.0,
-                        bps_threshold=1e9)
-        src = TrafficSource(
-            TrafficSpec(scenario=Scenario.UDP_FLOOD_MULTI,
-                        rate_pps=1e7, n_attack_ips=8,
-                        attack_fraction=0.8, seed=7),
-            total=256 * 40)
-        # readback_depth defaults and auto-raises to cover a ring round
-        eng = Engine(cfg, src, BoomSink(), mega_n="auto", device_loop=2)
-        with pytest.raises(WorkerCrash,
-                           match="device-pipeline worker crashed"):
-            eng.run()
-        assert not eng._sink_active  # joined, not wedged
+    """docs/CONCURRENCY.md §crash: sink thread and strict-mode ingest
+    death both surface as the same loud WorkerCrash on the dispatch
+    side (the sink-thread case is pinned in
+    test_engine.py::test_sink_crash_fails_engine_loudly)."""
 
     def test_strict_ingest_crash_is_loud_after_drain(self, tmp_path):
         import time
@@ -867,7 +844,7 @@ class TestSloRegistry:
         assert f["_rung_ewma_s"].discipline == "section:launch"
         # the dispatch-thread policy readers are explicit grants, part
         # of the documented discipline (advisory float reads)
-        for reader in ("_slo_cap", "_slo_pressed", "_slo_round_fits",
+        for reader in ("_slo_cap", "_slo_pressed",
                        "_deadline_flush_due"):
             assert reader in f["_rung_ewma_s"].extra
         assert f["_lat"].discipline == "section:sink"
@@ -948,8 +925,7 @@ class TestSloRegistry:
 
 # ---------------------------------------------------------------------------
 # Predictive-governor registry (ISSUE 18): every new piece of shared
-# state — the governor itself, the ring-round refinement floor, the
-# pre-warm buffer, and the shed-deferral counters on both gossip
+# state — the governor itself, the pre-warm buffer, and the shed-deferral counters on both gossip
 # planes — registered with the correct discipline, and each new
 # discipline surface's planted violation caught.
 # ---------------------------------------------------------------------------
@@ -959,11 +935,10 @@ class TestPredictRegistry:
         f = contracts.ENGINE_PLAN.fields
         assert f["_gov"].discipline == "dispatch"
         assert f["_warm_buf"].discipline == "dispatch"
-        assert f["_round_floor_s"].discipline == "section:launch"
         # the prewarm site reads the EWMA table from the serving loop:
         # an explicit documented grant, like the PR 11 policy readers
         assert "_run_inline" in f["_rung_ewma_s"].extra
-        assert "_note_round_s" in contracts.ENGINE_PLAN.sections["launch"]
+        assert "_note_step_s" in contracts.ENGINE_PLAN.sections["launch"]
         g = contracts.GOSSIP_PLAN.fields
         assert g["_ticks_deferred"].discipline == "section:merge"
         assert g["_defer_streak"].discipline == "section:merge"
@@ -1020,24 +995,24 @@ class TestPredictRegistry:
         assert len(out) == 1
         assert out[0].line == 5 and "'merge' section" in out[0].reason
 
-    def test_planted_round_floor_written_outside_launch(self):
-        # the ring-round floor is launch-section state (written by the
-        # warm seed and read by the refinement): a sink-side write is
-        # a finding
+    def test_planted_rung_ewma_written_outside_launch(self):
+        # the per-rung EWMA is launch-section state (seeded by the warm
+        # pass, refined by the launch tail): a sink-side write is a
+        # finding
         src = (
             "import threading\n"
             "class C:\n"
             "    def run(self):\n"
             "        threading.Thread(target=self._sink_worker).start()\n"
-            "    def _note_round_s(self):\n"
-            "        self._round_floor_s[-16] = 0.1\n"
+            "    def _note_step_s(self):\n"
+            "        self._rung_ewma_s[8] = 0.1\n"
             "    def _sink_worker(self):\n"
-            "        self._round_floor_s[-16] = 0.2\n")
+            "        self._rung_ewma_s[8] = 0.2\n")
         out = check_class(ast.parse(src), "planted.py", ClassPlan(
             module="planted.py", cls="C",
             worker_targets=("_sink_worker",),
-            sections={"launch": ("_note_round_s",)},
-            fields={"_round_floor_s": FieldContract(
-                "section:launch", "warm-seed round floor")}))
+            sections={"launch": ("_note_step_s",)},
+            fields={"_rung_ewma_s": FieldContract(
+                "section:launch", "per-rung step-time EWMA")}))
         assert len(out) == 1
-        assert out[0].line == 8 and "_round_floor_s" in out[0].reason
+        assert out[0].line == 8 and "_rung_ewma_s" in out[0].reason
