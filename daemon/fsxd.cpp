@@ -34,7 +34,6 @@
 #include <random>
 #include <string>
 #include <thread>
-#include <unordered_map>
 #include <vector>
 
 #include <net/if.h>
@@ -593,6 +592,126 @@ Options parse(int argc, char **argv) {
     return o;
 }
 
+// std::mt19937_64, draw for draw (same seeding, twist and tempering:
+// a seed gives the records it always gave), written out because
+// libstdc++'s costs 7.7 ns a draw at -O2 against 2.5 for this, and a
+// sim record takes nine: the generator was the ceiling of the
+// benchmark's closed-loop cells (PERF.md section 6, PR 38).
+class Mt64 {
+public:
+    using result_type = uint64_t;
+    static constexpr result_type min() { return 0; }
+    static constexpr result_type max() { return ~0ULL; }
+
+    explicit Mt64(uint64_t seed) {
+        mt_[0] = seed;
+        for (int i = 1; i < N; i++)
+            mt_[i] = 6364136223846793005ULL *
+                         (mt_[i - 1] ^ (mt_[i - 1] >> 62)) + (uint64_t)i;
+    }
+
+    result_type operator()() {
+        if (next_ >= N)
+            refill();
+        uint64_t x = mt_[next_++];
+        x ^= (x >> 29) & 0x5555555555555555ULL;
+        x ^= (x << 17) & 0x71D67FFFEDA60000ULL;
+        x ^= (x << 37) & 0xFFF7EEE000000000ULL;
+        return x ^ (x >> 43);
+    }
+
+private:
+    static constexpr int N = 312, M = 156;
+
+    static uint64_t twist(uint64_t u, uint64_t v) {
+        uint64_t x = (u & 0xFFFFFFFF80000000ULL) | (v & 0x7FFFFFFFULL);
+        return (x >> 1) ^ ((v & 1) ? 0xB5026F5AA96619E9ULL : 0);
+    }
+
+    void refill() {
+        int i = 0;
+        for (; i < N - M; i++)
+            mt_[i] = mt_[i + M] ^ twist(mt_[i], mt_[i + 1]);
+        for (; i < N - 1; i++)
+            mt_[i] = mt_[i + M - N] ^ twist(mt_[i], mt_[i + 1]);
+        mt_[N - 1] = mt_[M - 1] ^ twist(mt_[N - 1], mt_[0]);
+        next_ = 0;
+    }
+
+    uint64_t mt_[N];
+    int next_ = N;
+};
+
+// The sim path's blocked sources, saddr -> until_ns: open addressing,
+// linear probing, 16-byte slots (a lookup is one cache line; the
+// std::unordered_map this replaces chased nodes for a fifth of the
+// generator's time at half a million entries).  As with that map's
+// lazy erase, an entry counts in size() until a lookup finds it
+// expired.  A slot is never freed: the table holds every source ever
+// blocked, which the sim pools bound.
+class Blacklist {
+public:
+    Blacklist() : slots_(1 << 12) {}
+
+    void block(uint32_t saddr, uint64_t until_ns) {
+        if ((used_ + 1) * 2 > slots_.size())
+            grow();
+        Slot &s = find(saddr);
+        if (!s.used) {
+            s.used = 1;
+            s.saddr = saddr;
+            used_++;
+        }
+        if (!s.live) {
+            s.live = 1;
+            live_++;
+        }
+        s.until_ns = until_ns;
+    }
+
+    // true while `saddr` is blocked at `now_ns`; the lookup that finds
+    // its block expired drops it
+    bool blocked(uint32_t saddr, uint64_t now_ns) {
+        Slot &s = find(saddr);
+        if (!s.used || !s.live)
+            return false;
+        if (now_ns < s.until_ns)
+            return true;
+        s.live = 0;
+        live_--;
+        return false;
+    }
+
+    size_t size() const { return live_; }
+
+private:
+    struct Slot {
+        uint32_t saddr = 0;
+        uint16_t used = 0, live = 0;
+        uint64_t until_ns = 0;
+    };
+
+    Slot &find(uint32_t saddr) {
+        // the shard router's Fibonacci hash (fsx_shard_of), masked
+        size_t mask = slots_.size() - 1;
+        size_t i = (((uint64_t)saddr * 2654435761ULL) >> 16) & mask;
+        while (slots_[i].used && slots_[i].saddr != saddr)
+            i = (i + 1) & mask;
+        return slots_[i];
+    }
+
+    void grow() {
+        std::vector<Slot> old(slots_.size() * 2);
+        old.swap(slots_);
+        for (const Slot &s : old)
+            if (s.used)
+                find(s.saddr) = s;
+    }
+
+    std::vector<Slot> slots_;
+    size_t used_ = 0, live_ = 0;
+};
+
 // Minimal mirror of the Python TrafficGen's statistics so --sim produces
 // model-meaningful features (flowsentryx_tpu/engine/traffic.py is the
 // reference implementation; both emit kernel-estimator-style records).
@@ -666,7 +785,7 @@ public:
 
 private:
     Options o_;
-    std::mt19937_64 rng_;
+    Mt64 rng_;
     std::vector<uint32_t> attack_ips_, benign_ips_;
     uint64_t clock_ns_, dt_ns_;
 };
@@ -698,7 +817,7 @@ int main(int argc, char **argv) {
                  o.verdict_ring.c_str());
 
     uint64_t produced = 0, dropped_ring_full = 0, verdicts = 0, suppressed = 0;
-    std::unordered_map<uint32_t, uint64_t> blacklist;  // saddr -> until_ns
+    Blacklist blacklist;
 
     FILE *replay = nullptr;
     if (o.mode == "replay") {
@@ -758,13 +877,9 @@ int main(int argc, char **argv) {
             uint64_t tnow = batch.empty() ? 0 : batch.back().ts_ns;
             size_t w = 0;
             for (size_t i = 0; i < batch.size(); i++) {
-                auto it = blacklist.find(batch[i].saddr);
-                if (it != blacklist.end()) {
-                    if (tnow < it->second) {
-                        suppressed++;
-                        continue;
-                    }
-                    blacklist.erase(it);  // TTL expired
+                if (blacklist.blocked(batch[i].saddr, tnow)) {
+                    suppressed++;
+                    continue;
                 }
                 if (w != i)
                     batch[w] = batch[i];
@@ -779,7 +894,7 @@ int main(int argc, char **argv) {
         // ---- consume verdicts -------------------------------------------
         uint64_t n = vring.consume(vbatch.data(), vbatch.size());
         for (uint64_t i = 0; i < n; i++)
-            blacklist[vbatch[i].saddr] = vbatch[i].until_ns;
+            blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
         verdicts += n;
 
         // ---- bounds / pacing --------------------------------------------
@@ -791,7 +906,7 @@ int main(int argc, char **argv) {
             if (frings.total_readable() == 0 || t > drain_deadline) {
                 uint64_t extra = vring.consume(vbatch.data(), vbatch.size());
                 for (uint64_t i = 0; i < extra; i++)
-                    blacklist[vbatch[i].saddr] = vbatch[i].until_ns;
+                    blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
                 verdicts += extra;
                 break;
             }
@@ -819,7 +934,7 @@ int main(int argc, char **argv) {
     // to the last verdict the ring holds, not one 4,096-record take.
     while (uint64_t extra = vring.consume(vbatch.data(), vbatch.size())) {
         for (uint64_t i = 0; i < extra; i++)
-            blacklist[vbatch[i].saddr] = vbatch[i].until_ns;
+            blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
         verdicts += extra;
     }
 

@@ -422,9 +422,14 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
     at production capacity, and both are *graph facts* this contract
     pins statically instead of leaving to the bench:
 
-    * a ``lax.cond`` carrying the table copies operands and results
-      through the ``conditional`` every batch, even when the branch
-      never fires;
+    * a ``lax.cond`` the table rides THROUGH (a branch returns it)
+      copies it through the ``conditional`` every batch, even when the
+      branch never fires.  A cond that only READS the table — the
+      probe's, whose branches gather from it and return ``[R, P]`` —
+      is not that cliff: its operand is handed over by reference
+      (XLA:CPU, 2^22 rows, donated: 1.94 ms a step with it, 1.99
+      without; PR 38), and the executable-level census below would
+      show a copy if one appeared;
     * a dynamic-offset ``dynamic_slice``/``dynamic_update_slice``
       touching the table defeats in-place buffer reuse for the whole
       donated chain (a CONSTANT-offset window is fine, and so are the
@@ -436,7 +441,7 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
     the global table shapes AND, given ``n_shards``, the per-shard
     shapes staged inside ``shard_map`` bodies); the HLO half is the
     executable-level census — zero ``copy``/``convert`` ops producing
-    a table-shaped buffer, and no ``conditional`` whose operands carry
+    a table-shaped buffer, and no ``conditional`` whose result carries
     one (shapes are read per-executable, so sharded variants census
     their local shard shapes)."""
     findings: list[Finding] = []
@@ -464,14 +469,14 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
         name = eqn.primitive.name
         if name == "cond":
             carried = sorted({
-                s for v in list(eqn.invars) + list(eqn.outvars)
+                s for v in eqn.outvars
                 if (s := sig_of(getattr(v, "aval", None))) is not None})
             if carried:
                 findings.append(Finding(
                     contract="inplace", where=where, eqn=_eqn_txt(eqn),
                     reason=(f"lax.cond carries the donated table "
                             f"({', '.join(carried)}) — XLA:CPU copies "
-                            "conditional operands and results every "
+                            "what a conditional returns every "
                             "batch even when the branch never fires "
                             "(the PR 8 in-place cliff); hoist the "
                             "table out of the cond or rewrite as a "
@@ -522,35 +527,22 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
                             "variant (each one is a full-table "
                             "materialization per batch)"),
                 ))
-        # operand lists nest parens (tuple-typed operands), so walk to
-        # the balanced close of each call — a single [^)]* scan would
-        # stop at the first inner ')' and miss a table operand sitting
-        # after an earlier tuple operand
+        # the result type sits before the op name on the instruction's
+        # line (a tuple of them where the conditional returns several)
         pat_re = re.compile(pat)
         n_cond = 0
         for mc in re.finditer(r"conditional\(", hlo_text):
-            depth, k = 1, mc.end()
-            while k < len(hlo_text) and depth:
-                c = hlo_text[k]
-                if c == "(":
-                    depth += 1
-                elif c == ")":
-                    depth -= 1
-                k += 1
-            # from the start of the instruction: the result type sits
-            # before the op name, and newer XLA prints operands by name
-            # only, so a carried table shows there and nowhere else
             line_start = hlo_text.rfind("\n", 0, mc.start()) + 1
-            if pat_re.search(hlo_text, line_start, k):
+            if pat_re.search(hlo_text, line_start, mc.start()):
                 n_cond += 1
         census["conditionals"] = n_cond
         if n_cond:
             findings.append(Finding(
                 contract="inplace",
-                reason=(f"{n_cond} conditional op(s) carry a "
-                        f"table-shaped operand ({', '.join(toks)}) in "
+                reason=(f"{n_cond} conditional op(s) return a "
+                        f"table-shaped buffer ({', '.join(toks)}) in "
                         "the compiled executable — XLA:CPU copies "
-                        "conditional operands/results every batch "
+                        "what a conditional returns every batch "
                         "(the PR 8 cond cliff)"),
             ))
     return findings, census

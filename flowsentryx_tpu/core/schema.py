@@ -803,6 +803,14 @@ class GlobalStats(NamedTuple):
     #: passthrough — when eviction is disabled, so pre-eviction graphs
     #: and parity baselines are unchanged.
     evicted: jnp.ndarray            # [2] uint32
+    #: Non-empty batches whose probe read ``last_seen``: some valid key
+    #: had neither a match nor an empty slot among its probes, so
+    #: staleness could decide
+    #: (:func:`flowsentryx_tpu.ops.hashtable.probe_slots`; under a mesh,
+    #: on any shard).  ``stale_reads / batches`` is the share of steps
+    #: that pay the second table gather: near 0 while the table has
+    #: room, toward 1 as it fills.
+    stale_reads: jnp.ndarray        # [2] uint32
 
     @property
     def dropped(self) -> int:

@@ -104,6 +104,7 @@ class FlowDecision(NamedTuple):
     new_blocked_until: jnp.ndarray  # [R] f32
     newly_blocked: jnp.ndarray     # [R] bool
     tracked: jnp.ndarray           # [R] bool
+    read_seen: jnp.ndarray         # [] bool: SlotAssignment.read_seen
 
 
 def flow_step(
@@ -287,6 +288,7 @@ def _flow_core(
         new_blocked_until=new_blocked_until,
         newly_blocked=over_rate | over_ml,
         tracked=asg.tracked,
+        read_seen=asg.read_seen,
     )
 
 
@@ -321,11 +323,13 @@ def count_verdicts(verdict: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
 
 
 def update_stats_from_counts(
-    stats: GlobalStats, counts: jnp.ndarray
+    stats: GlobalStats, counts: jnp.ndarray, read_seen: jnp.ndarray
 ) -> GlobalStats:
     """Fold a ``[4]`` count vector (:data:`STAT_VERDICT_ORDER`) plus one
     batch into the u64 counters — shared by the single-device step
-    (local counts) and the sharded step (psum'd counts).
+    (local counts) and the sharded step (psum'd counts).  ``read_seen``
+    is the batch's ``ProbeResult.read_seen`` (only a valid key can set
+    it, so an empty batch never does).
 
     ``batches`` bumps only for a NON-EMPTY batch: the verdict classes
     partition the valid records, so ``counts.sum()`` is ``n_valid``, and
@@ -347,15 +351,18 @@ def update_stats_from_counts(
         # keeps disabled-eviction graphs — and their donation aliasing —
         # identical to the pre-eviction era
         evicted=stats.evicted,
+        stale_reads=u64_add(stats.stale_reads, read_seen),
     )
 
 
 def update_stats(
-    stats: GlobalStats, verdict: jnp.ndarray, valid: jnp.ndarray
+    stats: GlobalStats, verdict: jnp.ndarray, valid: jnp.ndarray,
+    read_seen: jnp.ndarray,
 ) -> GlobalStats:
     """Per-packet counters (successor of the reference's racy
     allowed/dropped bumps, ``fsx_kern.c:210,332,342``)."""
-    return update_stats_from_counts(stats, count_verdicts(verdict, valid))
+    return update_stats_from_counts(stats, count_verdicts(verdict, valid),
+                                    read_seen)
 
 
 # -- in-step aging: the rolling idle-flow eviction sweep --------------------
@@ -376,7 +383,10 @@ def update_stats(
 # under ``lax.cond``: XLA:CPU materializes a conditional's operands and
 # results as fresh buffers, so a cond carrying a [4M, 12] table COPIES
 # ~400 MB per batch whether or not the sweep branch fires — measured
-# 60x off the no-eviction drain rate.  The window form costs
+# 60x off the no-eviction drain rate.  (What a conditional RETURNS is
+# what is copied: the probe's, whose branches only read the table and
+# return ``[R, P]``, is handed it by reference —
+# ``audit/graph.py::check_inplace``.)  The window form costs
 # ``capacity/evict_every`` rows of gather+scatter per batch, adds no
 # whole-table latency spike on epoch batches, and keeps the exact same
 # guarantee: a row idle past the ttl is freed within one cycle of
@@ -764,6 +774,7 @@ def make_step(
                 found=found_s & rep_winner,
                 inserted=usable_s & ~found_s & rep_winner,
                 tracked=usable_s & rep_winner,
+                read_seen=pr.read_seen,
             )
         with jax.named_scope("fsx.update"):
             all_flows = jnp.ones_like(rep_valid)
@@ -779,7 +790,8 @@ def make_step(
                 spread_from_tails(tail, dec.flow_verdict), mal_s, valid_s)
             verdict = jax.lax.sort(order * 4 + verdict_s,
                                    is_stable=False) & 3
-            new_stats = update_stats(stats, verdict, batch.valid)
+            new_stats = update_stats(stats, verdict, batch.valid,
+                                     dec.read_seen)
             if n_evicted is not None:
                 from flowsentryx_tpu.core.schema import u64_add
 

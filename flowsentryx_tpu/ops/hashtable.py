@@ -7,10 +7,13 @@ in HBM and is updated in place via donated buffers.  Design constraints
 that shaped it (SURVEY.md §7.4.2):
 
 * **Static shapes, bounded probes.**  Open addressing with a
-  compile-time probe count ``P``: lookup is ``[R, P]`` gathers (keys,
-  and ``last_seen`` out of the state matrix) + a reduction — no
-  data-dependent loops and nothing table-wide, so XLA vectorizes it
-  flat and its cost follows the batch, not the capacity.
+  compile-time probe count ``P``: lookup is one ``[R, P]`` gather (the
+  keys) + a reduction — no data-dependent loops and nothing
+  table-wide, so XLA vectorizes it flat and its cost follows the
+  batch, not the capacity.  A second ``[R, P]`` gather (``last_seen``
+  out of the state matrix) is taken only by a batch in which some key
+  has neither a match nor an empty slot among its probes: nothing
+  else can be decided by staleness.
 * **Batch-internal collision resolution.**  Two distinct keys in one
   micro-batch can select the same slot (hash collision on insert); a
   sort-based arbitration picks exactly one winner per slot
@@ -31,6 +34,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,6 +69,7 @@ class SlotAssignment(NamedTuple):
     found: jnp.ndarray     # [R] bool: key already present
     inserted: jnp.ndarray  # [R] bool: claimed an empty/stale slot
     tracked: jnp.ndarray   # [R] bool: found | inserted (and won arbitration)
+    read_seen: jnp.ndarray  # [] bool: ProbeResult.read_seen of the probe
 
 
 class ProbeResult(NamedTuple):
@@ -73,6 +78,8 @@ class ProbeResult(NamedTuple):
     slot: jnp.ndarray    # [R] int32 selected table row
     found: jnp.ndarray   # [R] bool: exact key match at slot
     usable: jnp.ndarray  # [R] bool: match, empty, or stale-reclaimable
+    read_seen: jnp.ndarray  # [] bool: the probe read ``last_seen``
+    #                      (``GlobalStats.stale_reads`` counts these)
 
 
 def probe_slots(
@@ -93,12 +100,20 @@ def probe_slots(
     second salted hash — odd steps generate the full ring for
     power-of-two ``N``, so probes don't clump under adversarial floods.
     Claim priority per key: exact match > first empty > earliest stale
-    reclaimable.  All candidates are examined in two ``[R, P]`` gathers
-    — the keys from ``table.key``, their ``last_seen`` from the state
-    matrix at ``(slot, LAST_SEEN)`` — and selection is ``argmin`` over a
-    priority score, branch-free.  The function takes the TABLE, never a
-    column of it: ``table.last_seen`` is a table-wide copy on the
-    device (:class:`IpTableState`), paid every step whatever the batch
+    reclaimable; selection is ``argmin`` over a priority score.  The
+    candidates' keys come from ``table.key`` in one ``[R, P]`` gather.
+    Their ``last_seen`` — a second one, from the state matrix at
+    ``(slot, LAST_SEEN)`` — is read only where it can decide: a stale
+    candidate scores above every match and every empty slot, so it
+    wins only for a key that has neither among its probes.  A batch
+    with no such valid key (nearly every batch of a table that is not
+    near full) skips the read, and ``found``, ``usable`` and the
+    ``slot`` of every usable row are what the read would have given;
+    ``read_seen`` says which it was.  Both branches return ``[R, P]``
+    and never the table (``ops/fused.py``'s note on conditionals that
+    carry it).  The function takes the TABLE, never a column of it:
+    ``table.last_seen`` is a table-wide copy on the device
+    (:class:`IpTableState`), paid every step whatever the batch
     holds."""
     n = table.key.shape[0]
     mask = jnp.uint32(n - 1)
@@ -112,11 +127,16 @@ def probe_slots(
     slots = slots.astype(jnp.int32)
 
     cand_key = table.key[slots]                             # [R, P] gather
-    cand_seen = table.state[slots, int(TableCol.LAST_SEEN)]  # [R, P] gather
-
     match = cand_key == key[:, None]
     empty = cand_key == EMPTY_KEY
-    stale = (~match) & (~empty) & (now - cand_seen > cfg.stale_s)
+    read_seen = jnp.any(valid & ~jnp.any(match | empty, axis=1))
+
+    def stale_by_last_seen():
+        cand_seen = table.state[slots, int(TableCol.LAST_SEEN)]  # [R, P]
+        return (~match) & (~empty) & (now - cand_seen > cfg.stale_s)
+
+    stale = jax.lax.cond(read_seen, stale_by_last_seen,
+                         lambda: jnp.zeros(slots.shape, bool))
 
     # Priority score per candidate (lower = better):
     #   match  -> 0 + probe index        (prefer earliest probe)
@@ -130,13 +150,17 @@ def probe_slots(
         jnp.where(empty, p + probe_idx,
                   jnp.where(stale, 2 * p + probe_idx, 4 * p)),
     )
-    best = jnp.argmin(score, axis=1)  # [R]
-    best_score = jnp.take_along_axis(score, best[:, None], axis=1)[:, 0]
-    slot = jnp.take_along_axis(slots, best[:, None], axis=1)[:, 0]
+    # the winner's score and slot by a select over the P candidates
+    # (a take_along_axis is a gather of [R] indices: on the chip each
+    # cost a thirtieth of the stage for values it has at hand)
+    best = jnp.argmin(score, axis=1)  # [R], first minimum
+    best_score = jnp.min(score, axis=1)
+    slot = jnp.max(jnp.where(probe_idx == best[:, None], slots, 0), axis=1)
 
     found = valid & (best_score < p)
     usable = valid & (best_score < 4 * p)
-    return ProbeResult(slot=slot, found=found, usable=usable)
+    return ProbeResult(slot=slot, found=found, usable=usable,
+                       read_seen=read_seen)
 
 
 def assign_slots(
@@ -181,4 +205,5 @@ def assign_slots(
     tracked = usable & winner
     inserted = inserted & winner
     found = found & winner
-    return SlotAssignment(slot=slot, found=found, inserted=inserted, tracked=tracked)
+    return SlotAssignment(slot=slot, found=found, inserted=inserted,
+                          tracked=tracked, read_seen=pr.read_seen)

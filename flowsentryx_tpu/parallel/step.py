@@ -215,21 +215,25 @@ def make_sharded_step(
             jnp.where(valid_l, overflow[fa.inv].astype(jnp.uint32),
                       jnp.uint32(0))
         )
+        # each shard's probe decides on its own keys whether it reads
+        # last_seen; a batch counts once in stale_reads if any did.
+        # That and the eviction count join the ONE existing scalar
+        # psum — the audited collective census does not grow
         count_parts = [
             fused.count_verdicts(verdict_l, valid_l),
             route_drop_l[None].astype(jnp.uint32),
+            dec.read_seen[None].astype(jnp.uint32),
         ]
         if n_evict_l is not None:
-            # the eviction count joins the ONE existing scalar psum —
-            # the audited collective census does not grow
             count_parts.append(n_evict_l[None])
         counts = jax.lax.psum(jnp.concatenate(count_parts), axis)
-        new_stats = fused.update_stats_from_counts(stats, counts[:4])
+        new_stats = fused.update_stats_from_counts(stats, counts[:4],
+                                                   counts[5] > 0)
         if n_evict_l is not None:
             from flowsentryx_tpu.core.schema import u64_add
 
             new_stats = new_stats._replace(
-                evicted=u64_add(new_stats.evicted, counts[5]))
+                evicted=u64_add(new_stats.evicted, counts[6]))
 
         blk_key = jnp.where(dec.newly_blocked, m_key,
                             agg.INVALID_KEY)                      # owner-side

@@ -237,8 +237,26 @@ class TestInplaceCensus:
         assert "eqns[" in cond[0].where  # names the source equation
         # the executable-level census sees it too
         assert census["conditionals"] >= 1
-        assert any("conditional op(s) carry a table-shaped operand"
+        assert any("conditional op(s) return a table-shaped buffer"
                    in f.reason for f in finds)
+
+    def test_a_cond_that_only_reads_the_table_is_fine(self):
+        """The probe's form (ISSUE 38): branches that gather from the
+        table and return rows of the batch.  The table is an operand
+        of the conditional and no result of it, and nothing
+        table-shaped is copied."""
+        def step(key, state, x):
+            rows = jnp.arange(8) * 3
+            seen = jax.lax.cond(
+                x > jnp.uint32(0), lambda: state[rows, 0],
+                lambda: jnp.zeros((8,), jnp.float32))
+            state = state.at[x % 64, 0].add(jnp.sum(seen))
+            return key, state, jnp.sum(state[:4])
+
+        finds, census = self._plant(step)
+        assert [f for f in finds if f.contract == "inplace"] == [], [
+            str(f) for f in finds]
+        assert census["copies"] == 0 and census["conditionals"] == 0
 
     def test_planted_dynamic_offset_dus(self):
         def step(key, state, x):

@@ -35,6 +35,21 @@ def _rings(tmp_path):
     return str(tmp_path / "feature_ring"), str(tmp_path / "verdict_ring")
 
 
+def _drain(src, n, chunk=4096, timeout_s=15):
+    """At least `n` records off a feature ring, as one array."""
+    got, have = [], 0
+    deadline = time.monotonic() + timeout_s
+    while have < n:
+        assert time.monotonic() < deadline, "drain timed out"
+        c = src.poll(chunk)
+        if len(c):
+            got.append(c.copy())
+            have += len(c)
+        else:
+            time.sleep(0.001)
+    return np.concatenate(got)
+
+
 class TestShmTransport:
     def test_ring_roundtrip_records(self, fsxd_bin, tmp_path):
         """Daemon produces exactly --packets records; Python drains them."""
@@ -47,17 +62,7 @@ class TestShmTransport:
         try:
             from flowsentryx_tpu.engine.shm import ShmRingSource
 
-            src = ShmRingSource(fring)
-            got = []
-            deadline = time.monotonic() + 15
-            while sum(len(g) for g in got) < 5000:
-                assert time.monotonic() < deadline, "drain timed out"
-                chunk = src.poll(1024)
-                if len(chunk):
-                    got.append(chunk.copy())
-                else:
-                    time.sleep(0.001)
-            rec = np.concatenate(got)
+            rec = _drain(ShmRingSource(fring), 5000, chunk=1024)
             assert len(rec) == 5000
             assert rec.dtype == schema.FLOW_RECORD_DTYPE
             assert (rec["saddr"] > 0).all()
@@ -69,6 +74,35 @@ class TestShmTransport:
         stats = json.loads(out)
         assert stats["produced"] == 5000
         assert stats["dropped_ring_full"] == 0
+
+    @pytest.mark.parametrize("args,digest", [
+        (("--seed", "3"),
+         "c984663ef1674779a50aa08b5fd1e8afb7ec7ac8d130fca1f0543b4f36e5a586"),
+        (("--seed", "2147483999", "--attack-ips", "4096", "--benign-ips",
+          "70000", "--attack-fraction", "0.5"),
+         "5c79f88ea188c13e93c8adcde0e1e7982bdffc496b8ac638670ce2bd9ea8a85d"),
+    ], ids=["defaults", "wide_pools"])
+    def test_a_seed_gives_the_records_it_always_gave(self, fsxd_bin,
+                                                      tmp_path, args, digest):
+        """The sim generator's stream is pinned: its random engine is
+        written out in `fsxd.cpp` (`Mt64`, std::mt19937_64 draw for
+        draw) and the digests are those of the records the daemon made
+        with the library's, before PR 38."""
+        import hashlib
+
+        from flowsentryx_tpu.engine.shm import ShmRingSource
+
+        fring, vring = _rings(tmp_path)
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--packets", "20000", "--rate", "1e8",
+             "--feature-ring", fring, "--verdict-ring", vring, *args],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        )
+        try:
+            rec = _drain(ShmRingSource(fring), 20000)
+        finally:
+            proc.communicate(timeout=15)
+        assert hashlib.sha256(rec.tobytes()).hexdigest() == digest
 
     @pytest.mark.parametrize("ring_args,slots,writer", [
         ((), 1 << 20, "raw"),        # the default: kVerdictRingSlots
@@ -146,6 +180,46 @@ class TestShmTransport:
         assert stats["blacklisted"] == 4
         assert stats["suppressed"] > 0
 
+    def test_a_block_ends_when_the_sim_clock_passes_it(self, fsxd_bin,
+                                                      tmp_path):
+        """A verdict suppresses its source until `until_ns` of the
+        generator's own clock and no longer; the lookup that finds it
+        expired takes it off the count."""
+        from flowsentryx_tpu.engine.shm import ShmRing, ShmRingSource
+
+        fring, vring = _rings(tmp_path)
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--duration", "30", "--rate", "2e5",
+             "--attack-ips", "4", "--attack-fraction", "0.9",
+             "--feature-ring", fring, "--verdict-ring", vring, "--seed", "5"],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        )
+        try:
+            src = ShmRingSource(fring)
+            ring = ShmRing.wait_for(vring, schema.VERDICT_RECORD_DTYPE)
+            rec = _drain(src, 2000)
+            attackers = np.unique(rec["saddr"][rec["saddr"] < (1 << 24)])
+            # two seconds of the generator's clock past what it has
+            # made so far: it is free-running, so that is soon
+            until = int(rec["ts_ns"].max()) + 2_000_000_000
+            v = np.zeros(len(attackers), schema.VERDICT_RECORD_DTYPE)
+            v["saddr"] = attackers
+            v["until_ns"] = np.uint64(until)
+            assert ring.produce(v) == len(v)
+            back = np.zeros(0, rec.dtype)
+            deadline = time.monotonic() + 15
+            while len(np.unique(back["saddr"])) < len(attackers):
+                assert time.monotonic() < deadline, "no attacker came back"
+                late = _drain(src, 1, chunk=1 << 16)
+                late = late[late["ts_ns"] >= until]
+                back = np.concatenate(
+                    [back, late[np.isin(late["saddr"], attackers)]])
+            proc.terminate()
+        finally:
+            out, _ = proc.communicate(timeout=15)
+        stats = json.loads(out)
+        assert stats["verdicts"] == 4 and stats["suppressed"] > 0
+        assert stats["blacklisted"] == 0
 
     def test_a_burst_larger_than_the_old_ring_arrives_whole(self, fsxd_bin,
                                                             tmp_path):

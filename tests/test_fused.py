@@ -76,7 +76,8 @@ def two_stage_step(cfg, spec, params):
                                      ml_count, now)
         verdict = fused.resolve_record_verdicts(
             dec.flow_verdict, fa.inv, mal, batch.valid)
-        return (table, fused.update_stats(stats, verdict, batch.valid),
+        return (table, fused.update_stats(stats, verdict, batch.valid,
+                                          dec.read_seen),
                 verdict,
                 jnp.where(dec.newly_blocked, fa.rep_key, agg.INVALID_KEY),
                 jnp.where(dec.newly_blocked, dec.new_blocked_until, 0.0))
@@ -665,13 +666,20 @@ class TestStepNeverTakesATableColumn:
 
     @classmethod
     def _table_sized_primitives(cls, fn, *args):
-        """Names of the primitives with a table-sized operand or result
-        anywhere in the traced graph (sub-jaxprs walked)."""
-        return {
-            eqn.primitive.name
-            for _, eqn in iter_eqns(jax.make_jaxpr(fn)(*args))
-            if any(cls.CAP in getattr(v.aval, "shape", ())
-                   for v in (*eqn.invars, *eqn.outvars))}
+        """Names of the primitives with a table-sized operand, and of
+        those with a table-sized result, anywhere in the traced graph
+        (sub-jaxprs walked)."""
+        def sized(vs):
+            return any(cls.CAP in getattr(v.aval, "shape", ()) for v in vs)
+
+        eqns = [eqn for _, eqn in iter_eqns(jax.make_jaxpr(fn)(*args))]
+        return ({e.primitive.name for e in eqns if sized(e.invars)},
+                {e.primitive.name for e in eqns if sized(e.outvars)})
+
+    #: the probe's conditional reads the table (its branches gather
+    #: from it) and returns `[R, P]`: only a scatter returns a table
+    READ_BY = {"gather", "scatter", "cond"}
+    WRITTEN_BY = {"scatter"}
 
     def _cfg(self, kind, evict_ttl_s=0.0):
         return FsxConfig(
@@ -691,7 +699,7 @@ class TestStepNeverTakesATableColumn:
             fused.make_step(cfg, spec.classify_batch),
             make_table(self.CAP), make_stats(), spec.init(),
             build_batch([(1001, 5, 100, 0.1, ML_COLD)]))
-        assert used == {"gather", "scatter"}
+        assert used == (self.READ_BY, self.WRITTEN_BY)
 
     @pytest.mark.parametrize("kind", list(LimiterKind),
                              ids=lambda k: k.value)
@@ -709,14 +717,94 @@ class TestStepNeverTakesATableColumn:
                                    jnp.float32(0.1))
 
         assert (self._table_sized_primitives(two_stage, make_table(self.CAP))
-                == {"gather", "scatter"})
+                == (self.READ_BY, self.WRITTEN_BY))
 
     def test_the_guard_sees_a_column_view(self):
         """What the guard is for: the probe's old read."""
-        used = self._table_sized_primitives(
+        read_by, _ = self._table_sized_primitives(
             lambda table, slots: table.last_seen[slots],
             make_table(self.CAP), jnp.zeros((256, 8), jnp.int32))
-        assert used - {"gather"}  # slice/squeeze of the whole column
+        assert read_by - self.READ_BY  # slice/squeeze of the whole column
+
+
+class TestStaleReads:
+    """`GlobalStats.stale_reads` (ISSUE 38): non-empty batches whose
+    probe read `last_seen`, i.e. held a valid key with neither a match
+    nor an empty slot among its probes."""
+
+    TINY = TableConfig(capacity=16, probes=2, stale_s=1e6, salt=0xBEEF)
+
+    @staticmethod
+    def _full(capacity):
+        """A table whose every row holds a live foreign key."""
+        t = make_table(capacity)
+        return t._replace(
+            key=jnp.arange(capacity, dtype=jnp.uint32) + (1 << 30))
+
+    def test_to_dict_names_it(self):
+        assert make_stats().to_dict()["stale_reads"] == 0
+
+    @pytest.mark.parametrize("table,entries,reads", [
+        ("empty", [(1001, 5, 100, 0.1, ML_COLD)], 0),
+        ("full", [(1001, 5, 100, 0.1, ML_COLD)], 1),
+        # the one key that would need it sits in rows the mask hides
+        ("full", [], 0),
+    ], ids=["room", "full-table", "full-table-invalid-rows-only"])
+    def test_one_step_counts_a_read(self, table, entries, reads):
+        cfg = FsxConfig(table=self.TINY,
+                        batch=BatchConfig(max_batch=256, verdict_k=64))
+        step, t, stats, params = make_env(cfg)
+        if table == "full":
+            t = self._full(16)
+        batch = build_batch(entries)
+        if not entries:
+            batch = batch._replace(key=jnp.full((256,), 4242, jnp.uint32))
+        _, stats, _ = step(t, stats, params, batch)
+        assert stats.to_dict()["stale_reads"] == reads
+        assert stats.to_dict()["batches"] == (1 if entries else 0)
+
+    def test_the_megastep_counts_batches_not_groups(self):
+        """Eight chunks against a full table: four hold a new key,
+        two hold nothing, two hold only a resident key."""
+        from flowsentryx_tpu.core import schema
+
+        b = 256
+        cfg = FsxConfig(table=self.TINY,
+                        batch=BatchConfig(max_batch=b, verdict_k=64))
+        spec = get_model(cfg.model.name)
+        params = spec.init()
+        quant = schema.wire_quant_for(params)
+        mega = fused.make_jitted_compact_megastep(
+            cfg, spec.classify_batch, n_chunks=8, donate=False, **quant)
+        from flowsentryx_tpu.ops import hashtable
+
+        # a resident sits on the first probe of its own ring
+        resident = 4242
+        home = hashtable.probe_slots(
+            make_table(16), jnp.array([resident], jnp.uint32),
+            jnp.array([True]), jnp.float32(0.0), self.TINY).slot[0]
+        table = self._full(16)
+        table = table._replace(key=table.key.at[home].set(resident))
+        raws = []
+        for i, keys in enumerate([[7], [], [resident], [8, 9], [],
+                                  [resident], [10], [11]]):
+            buf = np.zeros(len(keys), dtype=schema.FLOW_RECORD_DTYPE)
+            buf["saddr"] = np.asarray(keys, np.uint32)
+            buf["pkt_len"] = 100
+            buf["ts_ns"] = (i + 1) * 1_000_000
+            raws.append(schema.encode_compact(buf, b, t0_ns=0, **quant))
+        _, stats, _ = mega(table, make_stats(), params,
+                           jnp.asarray(np.stack(raws)))
+        d = stats.to_dict()
+        assert (d["stale_reads"], d["batches"]) == (4, 6)
+
+    def test_fsx_serve_report_carries_it(self):
+        from flowsentryx_tpu.engine import ArraySource, CollectSink, Engine
+        from tests.test_spans import flood, small_cfg
+
+        rep = Engine(small_cfg(), ArraySource(flood(256 * 4)), CollectSink(),
+                     sink_thread=False).run()
+        assert rep.stats["stale_reads"] == 0 < rep.stats["batches"]
 
 
 class TestFlowsAtRunTails:
@@ -902,9 +990,17 @@ class TestFlowsAtRunTails:
             assert np.asarray(out.wire)[2 * k + 1] == 1
         if name in ("one_key", "mixed_runs"):
             assert blocks > 0
+        reads = (stat_value(s1.stale_reads), stat_value(s2.stale_reads))
         if name in ("found_and_new_share", "full_table"):
-            # the second batch met a table with no empty row
+            # the second batch met a table with no empty row: its new
+            # keys are the first for which staleness decides
             assert (np.asarray(before.key) != 0).all()
+            assert reads == (1, 1) and stat_value(s1.batches) == 2
+        else:
+            # (at 2,048 records the 4,096-row table is half full, and a
+            # key that lost its row in batch 0 may find its 8 probes
+            # taken in batch 1)
+            assert reads[0] == reads[1] and (b > 256 or reads[0] == 0)
 
     def test_the_shared_row_goes_to_its_owner(self):
         """What ``found_and_new_share`` is there for, spelled out: of a
@@ -1008,3 +1104,34 @@ class TestFlowsAtRunTails:
                     if p.startswith(("gather", "scatter", "dynamic"))}
         assert [stage for stage, p in staged if p == "sort"] \
             == ["aggregate", "emit"]
+
+    @pytest.mark.parametrize("kind", list(LimiterKind),
+                             ids=lambda k: k.value)
+    def test_probe_holds_one_gather_outside_its_reading_branch(self, kind):
+        """ISSUE 38: the stage gathers the candidates' keys, and their
+        `last_seen` only inside the one conditional's reading branch;
+        the winner's score and slot come by select, not by the two
+        `take_along_axis` gathers."""
+        from flowsentryx_tpu.audit.graph import iter_staged_eqns
+
+        cfg = FsxConfig(limiter=LimiterConfig(kind=kind),
+                        table=TableConfig(capacity=1 << 12, probes=8),
+                        batch=BatchConfig(max_batch=256, verdict_k=64))
+        spec = get_model(cfg.model.name)
+        probe = [eqn for stage, eqn in iter_staged_eqns(jax.make_jaxpr(
+            fused.make_step(cfg, spec.classify_batch))(
+                make_table(1 << 12), make_stats(), spec.init(),
+                build_batch([(1001, 5, 100, 0.1, ML_COLD)])))
+                 if stage == "probe"]
+        conds = [e for e in probe if e.primitive.name == "cond"]
+        assert len(conds) == 1
+        gathers = [sorted(e.primitive.name for _, e in iter_eqns(branch)
+                          if e.primitive.name == "gather")
+                   for branch in conds[0].params["branches"]]
+        assert sorted(gathers) == [[], ["gather"]]
+        # `probe` holds the branches' equations too
+        assert [e.primitive.name for e in probe].count("gather") == 2
+        assert not [e for e in probe if e.primitive.name == "pjit"
+                    and "take_along_axis" in e.params["name"]]
+        # every result of the conditional is [B, P]: never the table
+        assert [v.aval.shape for v in conds[0].outvars] == [(256, 8)]

@@ -1,5 +1,8 @@
 """Tests for the ops layer: limiters, batch aggregation, hash table."""
 
+import functools
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -396,7 +399,10 @@ class TestHashTable:
         return hashtable.ProbeResult(
             slot=slots[rows, best],
             found=valid & (score[rows, best] < p),
-            usable=valid & (score[rows, best] < 4 * p))
+            usable=valid & (score[rows, best] < 4 * p),
+            # staleness can decide only for a valid key with neither a
+            # match nor an empty slot among its probes
+            read_seen=(valid & ~(match | empty).any(axis=1)).any())
 
     def test_probe_from_the_matrix_matches_the_column_form(self):
         """One seeded batch whose 4 probes a key meet matches, empties,
@@ -435,6 +441,144 @@ class TestHashTable:
         assert (usable & ~found & (picked == 0)).any()      # empties
         assert (usable & ~found & (picked != 0)).any()      # stale reclaim
         assert (valid & ~usable).any()                      # all live foreign
+
+    #: at NOW an occupied row is stale when last seen before 70 s
+    NOW = 100.0
+    PROBE_CFG = TableConfig(capacity=64, probes=4, stale_s=30.0, salt=0xFEED)
+
+    def _probe_case(self, case):
+        """``(tk, seen, key, valid, lone)`` of one batch against a
+        64-row table.  ``lone`` is the row of the key whose 4 probes all
+        hold live or stale foreign keys (None where the case has no
+        such single key)."""
+        rng = np.random.default_rng(38)
+        cfg = self.PROBE_CFG
+        cap = cfg.capacity
+        tk = np.zeros(cap, np.uint32)
+        seen = np.zeros(cap, np.float32)
+        fresh = iter(rng.permutation(1 << 20)[:4096].astype(np.uint32)
+                     + (1 << 20))
+
+        def occupy(rows, last_seen):
+            for r, t in zip(rows, last_seen):
+                tk[r], seen[r] = next(fresh), t
+
+        if case == "full_table":
+            occupy(range(cap), rng.choice([10.0, 69.5, 70.5, 99.0], cap))
+            key = np.array([next(fresh) for _ in range(40)], np.uint32)
+            return tk, seen, key, rng.random(40) < 0.9, None
+        # 24 residents, each on a probe of its own ring
+        for k in [next(fresh) for _ in range(24)]:
+            ring = self._ring(np.array([k], np.uint32), cfg)[0]
+            free = ring[tk[ring] == 0]
+            if free.size:
+                r = rng.choice(free)
+                tk[r], seen[r] = k, rng.choice([10.0, 99.0])
+        lone_key = next(fresh)
+        ring = self._ring(np.array([lone_key], np.uint32), cfg)[0]
+        if case != "none_needs":
+            # the lone key's ring: foreign keys on all 4 probes.  One
+            # stale candidate, or two that tie in everything but place
+            ages = ([99.0, 10.0, 99.0, 10.0] if case == "stale_tie"
+                    else [99.0, 99.0, 10.0, 99.0])
+            occupy(ring, ages)
+        # everyone else in the batch has a match or an empty probe
+        others = []
+        while len(others) < 24:
+            k = next(fresh)
+            if (tk[self._ring(np.array([k], np.uint32), cfg)[0]] == 0).any():
+                others.append(k)
+        key = np.concatenate([tk[tk != 0][:15], [lone_key],
+                              others]).astype(np.uint32)
+        valid = np.ones(key.shape[0], bool)
+        valid[[3, 20]] = False
+        if case == "none_needs":
+            return tk, seen, key, valid, None
+        if case == "only_an_invalid_row_needs":
+            valid[15] = False
+        return tk, seen, key, valid, 15
+
+    @staticmethod
+    def _same_decisions(got, want, msg):
+        """`found`, `usable` and `read_seen` everywhere, `slot` where
+        `usable` (an unusable row's slot is parked by every caller)."""
+        for name in ("found", "usable", "read_seen"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name)), getattr(want, name),
+                err_msg=f"{msg}: {name}")
+        u = np.asarray(want.usable)
+        np.testing.assert_array_equal(np.asarray(got.slot)[u], want.slot[u],
+                                      err_msg=f"{msg}: slot")
+
+    @pytest.mark.parametrize("how", ["eager", "jit", "scan", "assign_slots"])
+    @pytest.mark.parametrize("case,reads", [
+        ("none_needs", False),
+        ("one_needs", True),
+        ("only_an_invalid_row_needs", False),
+        ("full_table", True),
+        ("stale_tie", True),
+    ])
+    def test_probe_reads_last_seen_only_where_it_decides(self, case, reads,
+                                                         how):
+        """`last_seen` is read by a batch in which some VALID key has
+        neither a match nor an empty slot among its probes, and by no
+        other; either way the decisions are the column form's, which
+        always reads it."""
+        cfg, now = self.PROBE_CFG, jnp.float32(self.NOW)
+        tk, seen, key, valid, lone = self._probe_case(case)
+        table = self._table(jnp.asarray(tk), jnp.asarray(seen))
+        want = self._probe_by_column(tk, seen, key, valid, now, cfg)
+        assert bool(want.read_seen) is reads
+        if case in ("one_needs", "stale_tie"):
+            # the lone key's earliest stale candidate wins: probe 2, or
+            # probe 1 of the tied 1 and 3
+            ring = self._ring(key[lone:lone + 1], cfg)[0]
+            assert want.usable[lone] and not want.found[lone]
+            assert want.slot[lone] == ring[1 if case == "stale_tie" else 2]
+        if case == "full_table":
+            assert not want.found.any()
+            assert want.usable.any() and (valid & ~want.usable).any()
+        probe = functools.partial(hashtable.probe_slots, cfg=cfg)
+        if how == "eager":
+            self._same_decisions(
+                probe(table, jnp.asarray(key), jnp.asarray(valid), now),
+                want, case)
+        elif how == "jit":
+            self._same_decisions(
+                jax.jit(probe)(table, jnp.asarray(key), jnp.asarray(valid),
+                               now), want, case)
+        elif how == "scan":
+            # a second batch: the keys moved on by one row under an
+            # all-true mask, so the conditional is taken batch by batch
+            key2, valid2 = np.roll(key, 1), np.ones_like(valid)
+            want2 = self._probe_by_column(tk, seen, key2, valid2, now, cfg)
+            if case == "only_an_invalid_row_needs":
+                assert want2.read_seen and not want.read_seen
+
+            def body(tbl, kv):
+                return tbl, probe(tbl, kv[0], kv[1], now)
+
+            _, got = jax.lax.scan(
+                body, table, (jnp.asarray(np.stack([key, key2])),
+                              jnp.asarray(np.stack([valid, valid2]))))
+            for i, w in enumerate((want, want2)):
+                self._same_decisions(jax.tree.map(lambda a: a[i], got), w,
+                                     f"{case}, batch {i}")
+        else:
+            a = hashtable.assign_slots(table, jnp.asarray(key),
+                                       jnp.asarray(valid), now, cfg)
+            assert bool(a.read_seen) is reads
+            tracked = np.asarray(a.tracked)
+            # arbitration leaves one winner a claimed slot, a finder
+            # before a claimant
+            assert not (tracked & ~want.usable).any()
+            assert tracked.sum() == np.unique(want.slot[want.usable]).size
+            np.testing.assert_array_equal(np.asarray(a.slot)[tracked],
+                                          want.slot[tracked])
+            np.testing.assert_array_equal(np.asarray(a.found),
+                                          want.found & tracked)
+            np.testing.assert_array_equal(np.asarray(a.inserted),
+                                          ~want.found & tracked)
 
     def test_hash_avalanche(self):
         # sequential keys must not map to sequential slots

@@ -54,6 +54,28 @@ class TestShardedStep:
         for a, b in zip(st_s, st_1):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    def test_stale_reads_counts_a_batch_once_across_shards(self, mesh, env):
+        """Each shard's probe decides on its own keys whether it reads
+        `last_seen`; the psum'd flags count a batch once.  A table full
+        of live foreign keys: 30 new keys spread over the 8 shards
+        read on several, an empty batch on none."""
+        from flowsentryx_tpu.core.schema import stat_value
+
+        sharded, single, params = env
+        cap = CFG.table.capacity
+        full = jnp.arange(cap, dtype=jnp.uint32) + (1 << 30)
+        t_s = pstep.make_sharded_table(CFG, mesh)
+        t_s = t_s._replace(key=jax.device_put(full, t_s.key.sharding))
+        t_1 = make_table(cap)._replace(key=full)
+        st_s, st_1 = make_stats(), make_stats()
+        new = build_batch([(1000 + i, 3, 100, 0.1, ML_COLD)
+                           for i in range(30)], batch_size=256)
+        for batch in (new, build_batch([], batch_size=256), new):
+            t_s, st_s, _ = sharded(t_s, st_s, params, batch)
+            t_1, st_1, _ = single(t_1, st_1, params, batch)
+        assert stat_value(st_s.stale_reads) == 2 == stat_value(st_s.batches)
+        assert stat_value(st_1.stale_reads) == 2
+
     def test_state_persists_and_blacklist_works_sharded(self, mesh, env):
         sharded, _, params = env
         table = pstep.make_sharded_table(CFG, mesh)

@@ -439,27 +439,31 @@ class TestCheckpointV2:
         rep3 = eng3.run()
         assert rep3.stats["dropped_blacklist"] > 0
 
-    def test_missing_stats_counter_tolerated(self, tmp_path):
-        """A pre-eviction-era snapshot (no stats_evicted) restores with
-        the counter at zero, named in missing_stats."""
+    @pytest.mark.parametrize("counter", ["evicted", "stale_reads"])
+    def test_missing_stats_counter_tolerated(self, tmp_path, counter):
+        """A snapshot written before a counter existed (no
+        stats_evicted: pre-eviction era; no stats_stale_reads: before
+        the probe counted its reads of last_seen) restores with the
+        counter at zero, named in missing_stats."""
         from flowsentryx_tpu.engine import checkpoint as ckpt
 
         cfg = evict_cfg()
         eng = self._run_engine(cfg, churn_records(phases=2))
         path = eng.checkpoint(tmp_path / "old.npz")
-        # a faithful pre-eviction-era snapshot predates the integrity
+        # a faithful snapshot of that era predates the integrity
         # CRC as well; a CRC left behind over edited members would
         # (correctly) refuse as corruption
         with np.load(path) as z:
             d = {k: z[k] for k in z.files
-                 if k not in ("stats_evicted", "integrity_crc32")}
+                 if k not in (f"stats_{counter}", "integrity_crc32")}
         np.savez_compressed(path, **d)
         ck = ckpt.load_checkpoint(path)
-        assert ck.missing_stats == ("evicted",)
-        assert (np.asarray(ck.stats.evicted) == 0).all()
+        assert ck.missing_stats == (counter,)
+        assert (np.asarray(getattr(ck.stats, counter)) == 0).all()
         eng2 = Engine(cfg, ArraySource(churn_records(phases=1)),
                       CollectSink(), sink_thread=False)
         eng2.restore(path)  # and the engine accepts it
+        assert stat_value(getattr(eng2.stats, counter)) == 0
 
 
 class TestHotSwap:
