@@ -91,6 +91,7 @@ struct Options {
     uint64_t total_packets = 0;        // 0 = unbounded
     double duration_s = 0;             // 0 = unbounded
     double attack_fraction = 0.8;
+    double spoof_fraction = 0.0;
     uint32_t n_attack_ips = 64;
     uint32_t n_benign_ips = 1024;
     uint64_t seed = 1;
@@ -144,6 +145,10 @@ struct Options {
                  "  --packets N           stop after N packets\n"
                  "  --duration S          stop after S seconds\n"
                  "  --attack-fraction F   sim attack share (default 0.8)\n"
+                 "  --spoof-fraction F    share of the sim attack records that take a\n"
+                 "                        source never seen before and never again\n"
+                 "                        (0x80000000 | a 31-bit permutation of their\n"
+                 "                        count, keyed by --seed; default 0: none)\n"
                  "  --attack-ips N        sim attack pool (default 64, min 1)\n"
                  "  --benign-ips N        sim benign pool (default 1024, min 1)\n"
                  "  --seed N              sim rng seed\n"
@@ -563,6 +568,8 @@ Options parse(int argc, char **argv) {
             o.duration_s = std::stod(next());
         else if (a == "--attack-fraction")
             o.attack_fraction = std::stod(next());
+        else if (a == "--spoof-fraction")
+            o.spoof_fraction = std::stod(next());
         else if (a == "--attack-ips")
             o.n_attack_ips = (uint32_t)std::stoul(next());
         else if (a == "--benign-ips")
@@ -580,6 +587,10 @@ Options parse(int argc, char **argv) {
     }
     if (o.shards < 1 || o.shards > 64) {
         std::fprintf(stderr, "fsxd: --shards must be in [1, 64]\n");
+        std::exit(1);
+    }
+    if (!(o.spoof_fraction >= 0.0 && o.spoof_fraction <= 1.0)) {
+        std::fprintf(stderr, "fsxd: --spoof-fraction must be in [0, 1]\n");
         std::exit(1);
     }
     if (o.n_attack_ips == 0 || o.n_benign_ips == 0) {
@@ -729,7 +740,16 @@ public:
         dt_ns_ = (uint64_t)(1e9 / o.rate_pps);
         if (dt_ns_ == 0)
             dt_ns_ = 1;
+        // perm31's key, from the seed alone (not from rng_: with
+        // --spoof-fraction 0 a seed gives the draws it always gave)
+        uint64_t k = (o.seed + 0x9E3779B97F4A7C15ULL) * 0xBF58476D1CE4E5B9ULL;
+        k ^= k >> 31;
+        spoof_mul_[0] = (uint32_t)k | 1;
+        spoof_mul_[1] = (uint32_t)(k >> 32) | 1;
+        spoof_xor_ = (uint32_t)(k >> 17) & kMask31;
     }
+
+    uint64_t spoofed() const { return spoofed_; }
 
     void fill(std::vector<fsx_flow_record> &out, size_t n) {
         out.resize(n);
@@ -744,7 +764,11 @@ public:
             // are flow_duration_ms / flow_pps_x1000 (the r5 flow-age
             // slots), NOT the pre-r5 variance/avg-size pair.
             if (attack) {
-                r.saddr = attack_ips_[rng_() % attack_ips_.size()];
+                // the extra draw is made only where the option is on
+                if (o_.spoof_fraction > 0 && u01(rng_) < o_.spoof_fraction)
+                    r.saddr = next_spoofed();
+                else
+                    r.saddr = attack_ips_[rng_() % attack_ips_.size()];
                 r.pkt_len = 60 + rng_() % 20;
                 r.ip_proto = 17;  // UDP flood
                 r.feat[0] = 80;
@@ -784,10 +808,40 @@ public:
     }
 
 private:
+    static constexpr uint32_t kMask31 = 0x7FFFFFFFu;
+
+    // A bijection of the 31-bit numbers: multiplying by an odd number
+    // modulo 2^31, xor with a constant and xor with a right shift of
+    // itself are each one.
+    uint32_t perm31(uint32_t x) const {
+        x = (x * spoof_mul_[0]) & kMask31;
+        x ^= x >> 15;
+        x ^= spoof_xor_;
+        x = (x * spoof_mul_[1]) & kMask31;
+        x ^= x >> 13;
+        return x;
+    }
+
+    // The source of the next spoofed record: the top bit (the pools
+    // lie below 2^25) over perm31 of a counter, so none recurs within
+    // 2^31 records, none is a pooled source or key 0, and the one
+    // count that would give 0xFFFFFFFF (the engine's invalid key) is
+    // passed over.
+    uint32_t next_spoofed() {
+        uint32_t x;
+        do
+            x = perm31((uint32_t)(spoof_n_++) & kMask31);
+        while (x == kMask31);
+        spoofed_++;
+        return 0x80000000u | x;
+    }
+
     Options o_;
     Mt64 rng_;
     std::vector<uint32_t> attack_ips_, benign_ips_;
     uint64_t clock_ns_, dt_ns_;
+    uint32_t spoof_mul_[2], spoof_xor_;
+    uint64_t spoof_n_ = 0, spoofed_ = 0;
 };
 
 }  // namespace
@@ -942,8 +996,9 @@ int main(int argc, char **argv) {
         std::fclose(replay);
     std::printf("{\"produced\": %" PRIu64 ", \"verdicts\": %" PRIu64
                 ", \"blacklisted\": %zu, \"suppressed\": %" PRIu64
-                ", \"dropped_ring_full\": %" PRIu64 "}\n",
+                ", \"dropped_ring_full\": %" PRIu64
+                ", \"spoofed\": %" PRIu64 "}\n",
                 produced, verdicts, blacklist.size(), suppressed,
-                dropped_ring_full);
+                dropped_ring_full, sim.spoofed());
     return 0;
 }

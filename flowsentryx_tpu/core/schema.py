@@ -811,6 +811,22 @@ class GlobalStats(NamedTuple):
     #: that pay the second table gather: near 0 while the table has
     #: room, toward 1 as it fills.
     stale_reads: jnp.ndarray        # [2] uint32
+    #: Flows of a batch that ended it with no row: no match, empty or
+    #: reclaimable slot among their probes, or the slot they chose went
+    #: to another new flow of the same batch.  Such a flow carries no
+    #: limiter state (fail-open), is classified record by record and
+    #: votes within its batch (:func:`flowsentryx_tpu.ops.fused._flow_core`).
+    #: ``untracked`` over the flows served is what the table's load
+    #: costs: about ``load ** probes`` of the new flows.  Counted where
+    #: the aging sweep is compiled in (``TableConfig.evict_ttl_s`` > 0)
+    #: and, like ``evicted``, a pure donated passthrough elsewhere: the
+    #: two small operations it adds to a step were enough to tip the
+    #: benchmark's ``c5-l34-1m.saturate`` (no aging, two ring shards
+    #: whose block-and-return waves drift apart) into running one
+    #: shard dry in 5 untraced runs of 11 against 1 of 11 without them
+    #: (PERF.md section 6, PR 39), so a table with no aging keeps the
+    #: graph it had.
+    untracked: jnp.ndarray          # [2] uint32
 
     @property
     def dropped(self) -> int:

@@ -105,6 +105,9 @@ class FlowDecision(NamedTuple):
     newly_blocked: jnp.ndarray     # [R] bool
     tracked: jnp.ndarray           # [R] bool
     read_seen: jnp.ndarray         # [] bool: SlotAssignment.read_seen
+    untracked: Any                 # [] uint32: owned flows left with no
+    #                                row (``GlobalStats.untracked``); None
+    #                                where the table does not age rows out
 
 
 def flow_step(
@@ -289,6 +292,12 @@ def _flow_core(
         newly_blocked=over_rate | over_ml,
         tracked=asg.tracked,
         read_seen=asg.read_seen,
+        # counted where the aging sweep is compiled in, as ``evicted``
+        # is: a table with no aging stages the graph it always staged
+        # (GlobalStats.untracked says what that graph was found to
+        # hold still)
+        untracked=(jnp.sum(eligible & ~asg.tracked).astype(jnp.uint32)
+                   if cfg.table.evict_ttl_s > 0 else None),
     )
 
 
@@ -323,13 +332,16 @@ def count_verdicts(verdict: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
 
 
 def update_stats_from_counts(
-    stats: GlobalStats, counts: jnp.ndarray, read_seen: jnp.ndarray
+    stats: GlobalStats, counts: jnp.ndarray, read_seen: jnp.ndarray,
+    untracked: Any,
 ) -> GlobalStats:
     """Fold a ``[4]`` count vector (:data:`STAT_VERDICT_ORDER`) plus one
     batch into the u64 counters — shared by the single-device step
     (local counts) and the sharded step (psum'd counts).  ``read_seen``
-    is the batch's ``ProbeResult.read_seen`` (only a valid key can set
-    it, so an empty batch never does).
+    is the batch's ``ProbeResult.read_seen`` and ``untracked`` its
+    ``FlowDecision.untracked`` (only a valid key can set either, so an
+    empty batch never does; ``None``, a table with no aging, leaves
+    the counter a donated passthrough, as ``evicted`` is there).
 
     ``batches`` bumps only for a NON-EMPTY batch: the verdict classes
     partition the valid records, so ``counts.sum()`` is ``n_valid``, and
@@ -352,17 +364,20 @@ def update_stats_from_counts(
         # identical to the pre-eviction era
         evicted=stats.evicted,
         stale_reads=u64_add(stats.stale_reads, read_seen),
+        untracked=(stats.untracked if untracked is None
+                   else u64_add(stats.untracked, untracked)),
     )
 
 
 def update_stats(
     stats: GlobalStats, verdict: jnp.ndarray, valid: jnp.ndarray,
-    read_seen: jnp.ndarray,
+    dec: FlowDecision,
 ) -> GlobalStats:
     """Per-packet counters (successor of the reference's racy
-    allowed/dropped bumps, ``fsx_kern.c:210,332,342``)."""
+    allowed/dropped bumps, ``fsx_kern.c:210,332,342``) and the two
+    per-batch ones the flow core reports."""
     return update_stats_from_counts(stats, count_verdicts(verdict, valid),
-                                    read_seen)
+                                    dec.read_seen, dec.untracked)
 
 
 # -- in-step aging: the rolling idle-flow eviction sweep --------------------
@@ -790,8 +805,7 @@ def make_step(
                 spread_from_tails(tail, dec.flow_verdict), mal_s, valid_s)
             verdict = jax.lax.sort(order * 4 + verdict_s,
                                    is_stable=False) & 3
-            new_stats = update_stats(stats, verdict, batch.valid,
-                                     dec.read_seen)
+            new_stats = update_stats(stats, verdict, batch.valid, dec)
             if n_evicted is not None:
                 from flowsentryx_tpu.core.schema import u64_add
 

@@ -217,18 +217,21 @@ def make_sharded_step(
         )
         # each shard's probe decides on its own keys whether it reads
         # last_seen; a batch counts once in stale_reads if any did.
-        # That and the eviction count join the ONE existing scalar
-        # psum — the audited collective census does not grow
+        # That joins the ONE existing scalar psum, and where the table
+        # ages rows out so do the eviction count and the owners' counts
+        # of flows left with no row (a flow has one owner, so they add
+        # up) — the audited collective census does not grow
         count_parts = [
             fused.count_verdicts(verdict_l, valid_l),
             route_drop_l[None].astype(jnp.uint32),
             dec.read_seen[None].astype(jnp.uint32),
         ]
         if n_evict_l is not None:
-            count_parts.append(n_evict_l[None])
+            count_parts += [n_evict_l[None], dec.untracked[None]]
         counts = jax.lax.psum(jnp.concatenate(count_parts), axis)
-        new_stats = fused.update_stats_from_counts(stats, counts[:4],
-                                                   counts[5] > 0)
+        new_stats = fused.update_stats_from_counts(
+            stats, counts[:4], counts[5] > 0,
+            None if n_evict_l is None else counts[7])
         if n_evict_l is not None:
             from flowsentryx_tpu.core.schema import u64_add
 

@@ -116,7 +116,8 @@ def test_new_readers_read_a_number_or_nothing(name):
     entry = next(m for m in bench()["per_layer"] if m["name"] == name)
     assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
         name, entry["unit"], entry["layer"], entry["moves"])
-    assert entry["workloads"] == [NEW_CELL]
+    # (ISSUE 39's cell shares the driver's accounting and joins the list)
+    assert entry["workloads"] == [NEW_CELL, "c6-spoof-churn.saturate"]
     span = {"n": 2, "sum_us": 5e4, "max_us": 4e4,
             "hist": {"scheme": "log2x16us", "buckets": {"240": 2}}}
     # the engine's report, and the ring writer's accounting as the
@@ -247,3 +248,221 @@ class TestNewCellRehearsedOnASmallRing:
         for name in ("records_unaccounted", "batches_gap",
                      "ingest_words_differ"):
             assert c[name]["value"] == 0, name
+
+
+# -- ISSUE 39: c6-spoof-churn ----------------------------------------------
+
+CHURN = "c6-spoof-churn"
+CHURN_CELL = "c6-spoof-churn.saturate"
+CHURN_METRICS = ("step.stage_evict_ms.tput", "evict.hbm_roofline.tput",
+                 "probe.stale_read_share.tput",
+                 "table.untracked_share.tput", "table.load.tput")
+STEADY = ("occupancy_drift", "evicted_gap", "untracked_share")
+
+
+def load(kind: str, name: str) -> dict:
+    return json.loads((ROOT / "benchmark" / kind / f"{name}.json"
+                       ).read_text())
+
+
+def test_the_churn_deployment_differs_from_c5_only_where_it_says():
+    c5, c6 = load("configs", "c5-l34-1m"), load("configs", CHURN)
+    same = set(c5) - {"name", "source", "table", "traffic", "guarantees",
+                      "assumed", "rehearse"}
+    assert set(c6) == set(c5)
+    for group in same:
+        assert c6[group] == c5[group], group
+    assert c6["table"] == dict(c5["table"], evict_ttl_s=12.0,
+                               evict_every=512)
+    assert c6["traffic"] == {"attack_ips": 1 << 17, "benign_ips": 1 << 17,
+                             "attack_fraction": 0.8, "spoof_fraction": 0.75}
+    assert c6["guarantees"][:3] == c5["guarantees"]
+    assert len(c6["guarantees"]) == 4 and "untracked" in c6["guarantees"][3]
+    # a blocked source finds its row when it returns: TTL above the block
+    assert c6["table"]["evict_ttl_s"] > max(c6["limiter"]["block_s"],
+                                            c6["vote"]["ml_block_s"])
+    r = harness().merged(c6, c6["rehearse"])
+    assert r["table"]["evict_ttl_s"] > max(r["limiter"]["block_s"],
+                                           r["vote"]["ml_block_s"])
+    cell, c5cell = load("workloads", CHURN_CELL), load("workloads", NEW_CELL)
+    assert cell["driver"] == "sim_churn" and cell["config"] == CHURN
+    keep = ("pace", "rate", "high_water", "low_water", "ring_capacity",
+            "drain_limit_s")
+    assert {k: cell["traffic"][k] for k in keep} \
+        == {k: c5cell["traffic"][k] for k in keep}
+    assert set(cell["traffic"]) == set(keep) | {"warmup_s"}
+    assert cell["traffic"]["warmup_s"] > c5cell["traffic"]["warmup_s"]
+
+
+def test_benchmark_json_gained_the_cell_and_lost_nothing():
+    b = bench()
+    assert [c["name"] for c in b["configs"]][-1] == CHURN
+    assert b["workloads"][-1] == dict(
+        b["workloads"][-1], name=CHURN_CELL, config=CHURN,
+        traffic="saturate", chips=1)
+    for m in b["end_to_end"] + b["per_layer"]:
+        lst = m.get("workloads")
+        if lst and NEW_CELL in lst:
+            assert CHURN_CELL in lst, m["name"]
+    assert [m["name"] for m in b["per_layer"]][-5:] == list(CHURN_METRICS)
+
+
+def churn_ctx(stats0, stats1, **more):
+    snap = lambda st, b, n: {  # noqa: E731
+        "rep": {"stats": st, "batches": b,
+                "table": {"tracked": 30 << 20}},
+        "gen": {"tap_batches": n}}
+    words = [np.array([[1 << 31 | 7, 0, 0, 0], [5, 0, 0, 0], [5, 0, 0, 0]],
+                      np.uint32)] * 4
+    return SimpleNamespace(
+        snap0=snap(stats0, 10, 1), snap1=snap(stats1, 14, 3),
+        config={"table": {"capacity": 1 << 26, "evict_every": 512},
+                "step_programs": ["jit_step"]},
+        reaps=SimpleNamespace(tap=SimpleNamespace(words=words)),
+        peaks={"hbm_bytes_per_s": 819e9}, trace=None, **more)
+
+
+@pytest.mark.parametrize("name", CHURN_METRICS)
+def test_churn_readers_read_a_number_or_nothing(name, monkeypatch):
+    h = harness()
+    mod = h.load_module("metrics", name)
+    entry = next(m for m in bench()["per_layer"] if m["name"] == name)
+    assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
+        name, entry["unit"], entry["layer"], entry["moves"])
+    assert entry["workloads"] == [CHURN_CELL]
+    s0 = {"batches": 10, "evicted": 100, "stale_reads": 9, "untracked": 1}
+    s1 = {"batches": 14, "evicted": 500, "stale_reads": 12, "untracked": 2}
+    ctx = churn_ctx(s0, s1)
+    # a device trace that holds the scope: 4 batches, 2 ms under fsx.evict
+    from benchmark import trace_scopes
+
+    stage_s = {"evict": 2e-3, "probe": 1e-3}
+    monkeypatch.setattr(trace_scopes, "stages",
+                        lambda c: {"stage_s": stage_s, "scoped": True})
+    ctx.trace = {"snap0": ctx.snap0, "snap1": ctx.snap1}
+    window_rows = (1 << 26) // 512
+    want = {
+        "step.stage_evict_ms.tput": 0.5,
+        "evict.hbm_roofline.tput":
+            100 * (4 * window_rows + 400) * 52 / 819e9 / 2e-3,
+        "probe.stale_read_share.tput": 75.0,
+        # two sealed batches of the window, two keys each
+        "table.untracked_share.tput": 25.0,
+        "table.load.tput": 100 * 30 / 64,
+    }
+    assert mod.read(ctx) == pytest.approx(want[name])
+    # the parent's program: no counter, no scope, no batch count
+    old = {"batches": 14}
+    bare = churn_ctx({"batches": 10}, old)
+    bare.snap0["gen"], bare.snap1["gen"] = {}, {}
+    bare.snap1["rep"]["table"] = None
+    stage_s = {"probe": 1e-3}
+    monkeypatch.setattr(trace_scopes, "stages",
+                        lambda c: {"stage_s": stage_s, "scoped": True})
+    bare.trace = {"snap0": bare.snap0, "snap1": bare.snap1}
+    assert mod.read(bare) is None
+
+
+def churn_driver(tmp_path, fsxd):
+    h = harness()
+    mod = h.load_module("drivers", "sim_churn")
+    assert issubclass(mod.Driver, mod.vring.Driver)
+    ctx = SimpleNamespace(
+        cell=load("workloads", CHURN_CELL), seed=1, workdir=tmp_path,
+        config=h.merged(load("configs", CHURN),
+                        load("configs", CHURN)["rehearse"]), rehearse=True)
+    d = mod.Driver(ctx)
+    d.fsxd, d.shards = fsxd, 2
+    d.fring, d.vring = tmp_path / "fring", tmp_path / "vring"
+    return mod, d
+
+
+def test_a_daemon_without_the_option_fails_the_run_at_once(tmp_path,
+                                                           monkeypatch):
+    """The parent commit's `fsxd` does not name `--spoof-fraction` in
+    its usage: the driver says so in `build`, before the engine is built
+    and compiled, and starts nothing."""
+    old = tmp_path / "fsxd"
+    old.write_text("#!/bin/sh\necho 'usage: fsxd [--sim]' >&2\nexit 2\n")
+    old.chmod(0o755)
+    _, d = churn_driver(tmp_path, old)
+    monkeypatch.setattr(harness(), "build_fsxd", lambda: old)
+    with pytest.raises(SystemExit, match="no --spoof-fraction"):
+        d.build()
+    assert d.proc is None and not hasattr(d, "tap")
+
+
+@pytest.mark.parametrize("case,fails", [
+    ("steady", ()), ("still_filling", ("occupancy_drift", "evicted_gap")),
+    ("aging_off", ("evicted_gap",)),
+    ("no_inserts", ("untracked_share",)),
+])
+def test_the_steady_state_gate(tmp_path, case, fails):
+    """Six sealed batches of 100 flows, 60 spoofed, in the window."""
+    mod, d = churn_driver(tmp_path, tmp_path / "fsxd")
+    key = np.arange(1, 101, dtype=np.uint32)
+    key[:60] |= np.uint32(1 << 31)
+    d.tap = SimpleNamespace(words=[np.stack([key] * 4, axis=1)] * 8)
+    cap = d.ctx.config["table"]["capacity"]
+    rows, evicted, untracked = {
+        "steady": (cap // 2 + 40, 355, 3),
+        "still_filling": (cap // 2 + cap // 10, 0, 3),
+        "aging_off": (cap // 2 + 40, 0, 3),
+        "no_inserts": (cap // 2, 0, 360),
+    }[case]
+    rep = lambda r, e, u: {"table": {"tracked": r, "newest_seen_s": 1.0},  # noqa: E731
+                           "stats": {"evicted": e, "untracked": u}}
+    d.reports = [(rep(cap // 2, 1000, 10), 1),
+                 (rep(rows, 1000 + evicted, 10 + untracked), 7)]
+    got = d.steady_state(d.ctx.config)
+    assert set(got) == set(STEADY)
+    assert got["occupancy_drift"]["detail"]["spoofed_flows"] == 360
+    assert got["occupancy_drift"]["detail"]["flows"] == 600
+    bad = {k for k, c in got.items() if c["value"] > c["limit"]}
+    assert bad == set(fails)
+
+
+@pytest.fixture(scope="module")
+def churn_rehearsed(tmp_path_factory):
+    """One paced run of the churn cell at its rehearse size, as
+    `c5-l34-1m`'s rehearsal: its result line and its `stats` line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(
+                   tmp_path_factory.mktemp("jax_cache")))
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", CHURN_CELL,
+         "--seed", "1", "--seconds", "3", "--trace", "0", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=240)
+    assert p.returncode == 0, p.stderr[-2000:]
+    lines = [json.loads(ln) for ln in p.stdout.strip().splitlines()]
+    return lines[-1], next(ln["stats"] for ln in lines if "stats" in ln)
+
+
+class TestChurnCellRehearsed:
+    def test_correct_against_the_plain_reference(self, churn_rehearsed):
+        r, _ = churn_rehearsed
+        assert r["correct"] is True, r["compared"]
+        assert r["rehearse"] is True and r["failed"] == 0
+        c = r["compared"]
+        for name in EXACT:
+            assert c[name] == {"value": 0, "limit": 0}, name
+        limits = load("configs", CHURN)["correct_limits"]
+        for name in ("blocks_gap", "counters_gap"):
+            assert c[name]["limit"] == limits[name] == 0.001
+            assert c[name]["value"] <= limits[name]
+        assert r["compared_detail"]["blocks"]["ref_blocks"] > 500
+        assert set(r["metrics"]) == {"records_per_s", "setup_s"}
+
+    def test_the_table_aged_rows_out_and_held_steady(self, churn_rehearsed):
+        r, stats = churn_rehearsed
+        c = r["compared"]
+        assert [c[k]["limit"] for k in STEADY] == [0.05, 0.05, 0.03]
+        for k in STEADY:
+            assert c[k]["value"] <= c[k]["limit"], k
+        d = r["compared_detail"]["occupancy_drift"]
+        assert d["evicted"] > 0.9 * d["spoofed_flows"] > 10_000
+        lo, hi = sorted(d["tracked"])
+        assert d["capacity"] / 3 < lo and hi < 0.6 * d["capacity"]
+        assert stats["evicted"] > d["evicted"] and stats["stale_reads"] > 0
+        assert 0 < stats["untracked"] < 0.03 * d["flows"]

@@ -44,8 +44,9 @@ BATCH = 16384
 WORDS = schema.COMPACT_RECORD_WORDS
 
 
-def _cfg(capacity, batch):
-    return FsxConfig(table=TableConfig(capacity=capacity, salt=0x5EED5EED),
+def _cfg(capacity, batch, **aging):
+    return FsxConfig(table=TableConfig(capacity=capacity, salt=0x5EED5EED,
+                                       **aging),
                      batch=BatchConfig(max_batch=batch))
 
 
@@ -146,10 +147,18 @@ def test_score_int8_kernel_compiles_with_mosaic(one_chip, mosaic, served,
 #: one f32 column of it: the temporaries were 272 MB while the probe
 #: took `table.last_seen`, and are 4 MB since it gathers (ISSUE 30);
 #: and the benchmark's `c5-l34-1m` (ISSUE 32): that table under
-#: 16,384-record batches, where the temporaries are the batch's again.
-STEP_SHAPES = [pytest.param(CAPACITY, BATCH, 32 << 20, id="c5-smoke"),
-               pytest.param(1 << 26, 2048, (1 << 26) * 4, id="c4-benchmark"),
-               pytest.param(1 << 26, BATCH, 32 << 20, id="c5-benchmark")]
+#: 16,384-record batches, where the temporaries are the batch's again;
+#: and the benchmark's `c6-spoof-churn` (ISSUE 39): `c5-l34-1m`'s shapes
+#: with the aging sweep compiled in, a 2^17-row window a batch read by
+#: gather and freed by scatter (`ops/fused.py::evict_idle_epoch`), which
+#: has to leave the table in place as the step's own scatters do.
+NO_AGING = {}
+C6_AGING = {"evict_ttl_s": 12.0, "evict_every": 512}
+STEP_SHAPES = [
+    pytest.param(CAPACITY, BATCH, 32 << 20, NO_AGING, id="c5-smoke"),
+    pytest.param(1 << 26, 2048, (1 << 26) * 4, NO_AGING, id="c4-benchmark"),
+    pytest.param(1 << 26, BATCH, 32 << 20, NO_AGING, id="c5-benchmark"),
+    pytest.param(1 << 26, BATCH, 64 << 20, C6_AGING, id="c6-benchmark")]
 
 
 def _foreign_table_sized_results(text, capacity):
@@ -184,12 +193,12 @@ def _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
     assert mem.temp_size_in_bytes < temp_limit
 
 
-@pytest.mark.parametrize("capacity,batch,temp_limit", STEP_SHAPES)
+@pytest.mark.parametrize("capacity,batch,temp_limit,aging", STEP_SHAPES)
 def test_single_compact_step_compiles_and_aliases_the_table(
-        one_chip, served, capacity, batch, temp_limit):
+        one_chip, served, capacity, batch, temp_limit, aging):
     classify, quant, params = served
-    step = fused.make_jitted_compact_step(_cfg(capacity, batch), classify,
-                                          **quant)
+    step = fused.make_jitted_compact_step(_cfg(capacity, batch, **aging),
+                                          classify, **quant)
     compiled = step.lower(
         *_state(params, one_chip, one_chip, one_chip, capacity),
         _wire((batch + 1, WORDS), one_chip)).compile()
@@ -197,13 +206,13 @@ def test_single_compact_step_compiles_and_aliases_the_table(
                                                   temp_limit)
 
 
-@pytest.mark.parametrize("capacity,batch,temp_limit", STEP_SHAPES)
+@pytest.mark.parametrize("capacity,batch,temp_limit,aging", STEP_SHAPES)
 def test_top_mega_rung_compiles_and_aliases_the_table(
-        one_chip, served, capacity, batch, temp_limit):
+        one_chip, served, capacity, batch, temp_limit, aging):
     classify, quant, params = served
     top = max(fused.pow2_group_sizes(8))
     mega = fused.make_compact_megastep_family(
-        _cfg(capacity, batch), classify, (top,), **quant)[top]
+        _cfg(capacity, batch, **aging), classify, (top,), **quant)[top]
     compiled = mega.lower(
         *_state(params, one_chip, one_chip, one_chip, capacity),
         _wire((top, batch + 1, WORDS), one_chip)).compile()

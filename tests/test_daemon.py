@@ -81,13 +81,20 @@ class TestShmTransport:
         (("--seed", "2147483999", "--attack-ips", "4096", "--benign-ips",
           "70000", "--attack-fraction", "0.5"),
          "5c79f88ea188c13e93c8adcde0e1e7982bdffc496b8ac638670ce2bd9ea8a85d"),
-    ], ids=["defaults", "wide_pools"])
+        (("--seed", "3", "--spoof-fraction", "0"),
+         "c984663ef1674779a50aa08b5fd1e8afb7ec7ac8d130fca1f0543b4f36e5a586"),
+        (("--seed", "2147483999", "--attack-ips", "4096", "--benign-ips",
+          "70000", "--attack-fraction", "0.5", "--spoof-fraction", "0.0"),
+         "5c79f88ea188c13e93c8adcde0e1e7982bdffc496b8ac638670ce2bd9ea8a85d"),
+    ], ids=["defaults", "wide_pools", "defaults_no_spoofing",
+            "wide_pools_no_spoofing"])
     def test_a_seed_gives_the_records_it_always_gave(self, fsxd_bin,
                                                       tmp_path, args, digest):
         """The sim generator's stream is pinned: its random engine is
         written out in `fsxd.cpp` (`Mt64`, std::mt19937_64 draw for
         draw) and the digests are those of the records the daemon made
-        with the library's, before PR 38."""
+        with the library's, before PR 38.  `--spoof-fraction 0` (PR 39)
+        is the default and makes no draw of its own."""
         import hashlib
 
         from flowsentryx_tpu.engine.shm import ShmRingSource
@@ -103,6 +110,58 @@ class TestShmTransport:
         finally:
             proc.communicate(timeout=15)
         assert hashlib.sha256(rec.tobytes()).hexdigest() == digest
+
+    @pytest.mark.parametrize("seed", ["3", "2147483999"])
+    def test_spoofed_sources_never_repeat(self, fsxd_bin, tmp_path, seed):
+        """`--spoof-fraction 0.75` (ISSUE 39): three quarters of the
+        attack records, 0.6 of all, take a source of their own —
+        the top bit over a 31-bit permutation of their count — so in
+        2^22 records none repeats, none is a pooled source (below
+        2^25), key 0 or the engine's invalid key, and the daemon's last
+        line counts them."""
+        from flowsentryx_tpu.engine.shm import ShmRingSource
+
+        fring, vring = _rings(tmp_path)
+        want = 1 << 22
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--packets", str(1 << 26),
+             "--rate", "1e8", "--ring-capacity", str(1 << 20),
+             "--spoof-fraction", "0.75", "--attack-ips", "131072",
+             "--benign-ips", "131072", "--feature-ring", fring,
+             "--verdict-ring", vring, "--seed", seed],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+        try:
+            src, got, have = ShmRingSource(fring), [], 0
+            deadline = time.monotonic() + 60
+            while have < want:
+                assert time.monotonic() < deadline, "drain timed out"
+                c = src.poll(1 << 16)
+                got.append(c["saddr"].copy())
+                have += len(c)
+            proc.terminate()
+            out, _ = proc.communicate(timeout=15)
+        finally:
+            if proc.poll() is None:
+                proc.kill()
+                proc.communicate()
+        saddr = np.concatenate(got)
+        spoofed = saddr[saddr >= 1 << 31]
+        assert len(np.unique(spoofed)) == len(spoofed)
+        assert (spoofed != 0xFFFFFFFF).all()
+        pooled = saddr[saddr < 1 << 31]
+        assert (pooled > 0).all() and (pooled < 1 << 25).all()
+        assert abs(len(spoofed) / len(saddr) - 0.6) < 0.01
+        stats = json.loads(out.strip().splitlines()[-1])
+        # it made at least the spoofed records that reached the ring
+        assert len(spoofed) <= stats["spoofed"] <= stats["produced"]
+
+    def test_an_unknown_option_ends_the_daemon_at_once(self, fsxd_bin):
+        """What the churn cell's driver leans on where the daemon is
+        older than `--spoof-fraction`: usage and exit 2, no rings."""
+        r = subprocess.run([str(fsxd_bin), "--sim", "--no-such-option",
+                            "1"], capture_output=True, text=True, timeout=10)
+        assert r.returncode == 2
+        assert "--spoof-fraction F" in r.stderr
 
     @pytest.mark.parametrize("ring_args,slots,writer", [
         ((), 1 << 20, "raw"),        # the default: kVerdictRingSlots

@@ -76,8 +76,7 @@ def two_stage_step(cfg, spec, params):
                                      ml_count, now)
         verdict = fused.resolve_record_verdicts(
             dec.flow_verdict, fa.inv, mal, batch.valid)
-        return (table, fused.update_stats(stats, verdict, batch.valid,
-                                          dec.read_seen),
+        return (table, fused.update_stats(stats, verdict, batch.valid, dec),
                 verdict,
                 jnp.where(dec.newly_blocked, fa.rep_key, agg.INVALID_KEY),
                 jnp.where(dec.newly_blocked, dec.new_blocked_until, 0.0))
@@ -805,6 +804,108 @@ class TestStaleReads:
         rep = Engine(small_cfg(), ArraySource(flood(256 * 4)), CollectSink(),
                      sink_thread=False).run()
         assert rep.stats["stale_reads"] == 0 < rep.stats["batches"]
+
+
+class TestUntracked:
+    """`GlobalStats.untracked` (ISSUE 39): flows of a batch that ended
+    it with no row — none of their probes usable, or their slot taken
+    by another new flow of the same batch."""
+
+    CAP = 1 << 12
+    #: aging on (the counter is compiled in with the sweep), with a
+    #: TTL nothing in these batches reaches
+    TCFG = TableConfig(capacity=CAP, probes=8, stale_s=1e6, salt=0xBEEF,
+                       evict_ttl_s=1e6, evict_every=64)
+
+    def _cfg(self, tcfg=TCFG):
+        return FsxConfig(table=tcfg,
+                         batch=BatchConfig(max_batch=1024, verdict_k=64))
+
+    @staticmethod
+    def _filled(capacity, share, seed=5):
+        """A table of which `share` of the rows hold a live foreign key."""
+        rng = np.random.default_rng(seed)
+        key = np.zeros(capacity, np.uint32)
+        rows = rng.choice(capacity, int(share * capacity), replace=False)
+        key[rows] = (1 << 30) + np.arange(len(rows), dtype=np.uint32)
+        return make_table(capacity)._replace(key=jnp.asarray(key))
+
+    def test_to_dict_names_it(self):
+        assert make_stats().to_dict()["untracked"] == 0
+
+    @pytest.mark.parametrize("share,flows,lo,hi", [
+        (0.0, 24, 0, 0), (0.6, 600, 6, 150), (1.0, 600, 600, 600),
+    ], ids=["empty", "sixty-per-cent", "full"])
+    def test_it_is_the_flows_less_the_rows_they_took(self, share, flows,
+                                                     lo, hi):
+        """New one-record flows: on an empty table every one of a few
+        takes a row, at 60 % about 0.6^8 of them find no slot (and some
+        lose theirs to another new flow), on a full one none finds any."""
+        step, _, stats, params = make_env(self._cfg())
+        t = self._filled(self.CAP, share)
+        batch = build_batch([(5000 + i, 1, 100, 0.1, ML_COLD)
+                             for i in range(flows)], batch_size=1024)
+        rows_before = int(np.count_nonzero(np.asarray(t.key)))
+        t, stats, _ = step(t, stats, params, batch)
+        took = int(np.count_nonzero(np.asarray(t.key))) - rows_before
+        d = stats.to_dict()
+        assert d["untracked"] == flows - took
+        assert lo <= d["untracked"] <= hi
+        # a flow with no row still gets its verdicts
+        assert d["allowed"] == flows
+
+    def test_it_adds_up_over_batches(self):
+        """The same 600 flows twice: the second time those with a row
+        find it, those that lost theirs to another flow take the next
+        empty one, and those whose probes are all taken go without
+        again."""
+        step, _, stats, params = make_env(self._cfg())
+        t = self._filled(self.CAP, 0.6)
+        rows_before = int(np.count_nonzero(np.asarray(t.key)))
+        batch = build_batch([(5000 + i, 1, 100, 0.1, ML_COLD)
+                             for i in range(600)], batch_size=1024)
+        t, stats, _ = step(t, stats, params, batch)
+        first = stats.to_dict()["untracked"]
+        t, stats, _ = step(t, stats, params, batch)
+        took = int(np.count_nonzero(np.asarray(t.key))) - rows_before
+        second = stats.to_dict()["untracked"] - first
+        assert 0 < second == 600 - took < first
+
+    def test_a_table_with_no_aging_stages_no_count(self):
+        """Like `evicted`: with `evict_ttl_s` 0 the step is the one it
+        was before the counter, which stays a passthrough (the
+        benchmark's `c5-l34-1m.saturate` was found to be tipped over by
+        the two small operations it adds: PERF.md section 6, PR 39)."""
+        import dataclasses
+
+        cfg = self._cfg(dataclasses.replace(self.TCFG, evict_ttl_s=0.0))
+        step, _, stats, params = make_env(cfg)
+        batch = build_batch([(5000 + i, 1, 100, 0.1, ML_COLD)
+                             for i in range(600)], batch_size=1024)
+        t, stats, _ = step(self._filled(self.CAP, 1.0), stats, params, batch)
+        assert stats.to_dict()["untracked"] == 0
+        assert stats.to_dict()["allowed"] == 600
+        text = jax.jit(fused.make_step(cfg, get_model(
+            cfg.model.name).classify_batch)).lower(
+                make_table(self.CAP), make_stats(), params, batch).as_text()
+        aged = jax.jit(fused.make_step(self._cfg(), get_model(
+            cfg.model.name).classify_batch)).lower(
+                make_table(self.CAP), make_stats(), params, batch).as_text()
+        assert "fsx.evict" not in text and len(text) < len(aged)
+
+    def test_an_empty_batch_counts_nothing(self):
+        step, _, stats, params = make_env(self._cfg())
+        _, stats, _ = step(self._filled(self.CAP, 1.0), stats, params,
+                           build_batch([], batch_size=1024))
+        assert stats.to_dict()["untracked"] == 0
+
+    def test_fsx_serve_report_carries_it(self):
+        from flowsentryx_tpu.engine import ArraySource, CollectSink, Engine
+        from tests.test_spans import flood, small_cfg
+
+        rep = Engine(small_cfg(), ArraySource(flood(256 * 4)), CollectSink(),
+                     sink_thread=False).run()
+        assert rep.stats["untracked"] == 0 < rep.stats["batches"]
 
 
 class TestFlowsAtRunTails:

@@ -184,6 +184,34 @@ class TestOwnerRouting:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert int(out_s.route_drop) == 0
 
+    def test_untracked_is_summed_once_across_owners(self, mesh):
+        """`GlobalStats.untracked` (ISSUE 39; counted where the table
+        ages rows out): two new flows of one batch picking one row
+        happens more or less often by the layout (one table or eight
+        shards), so the count differs between the two; in each it is
+        the flows less the rows they took — a flow has one owner, and
+        the owners' counts ride the one stats psum."""
+        import dataclasses
+
+        from flowsentryx_tpu.core.schema import stat_value
+
+        cfg = dataclasses.replace(CFG, table=dataclasses.replace(
+            CFG.table, evict_ttl_s=1e6, evict_every=64))
+        spec = get_model(cfg.model.name)
+        params = spec.init()
+        sharded = pstep.make_sharded_step(cfg, spec.classify_batch, mesh,
+                                          donate=False)
+        single = fused.make_jitted_step(cfg, spec.classify_batch,
+                                        donate=False)
+        batch = _random_batch(1024, n_ips=200, seed=7)
+        flows = len(np.unique(np.asarray(batch.key)))
+        for step, table in ((sharded, pstep.make_sharded_table(cfg, mesh)),
+                            (single, make_table(cfg.table.capacity))):
+            table, stats, _ = step(table, make_stats(), params, batch)
+            rows = int(np.count_nonzero(np.asarray(table.key)))
+            assert 0 < stat_value(stats.untracked) == flows - rows
+            assert stat_value(stats.evicted) == 0
+
     def test_adversarial_owner_skew_fails_open(self, mesh):
         """Keys aimed at one owner (ownership is a public hash) overflow
         the per-owner routing capacity: overflowed flows must PASS

@@ -3,9 +3,13 @@ chain, the ``spans`` block that windows by subtraction, the host spans in
 the profiler's trace, the named stages of the fused step, and the
 verdict-ring drop counter (ISSUE 29)."""
 
+import dataclasses
 import json
 import platform
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -301,9 +305,13 @@ class TestHostSpansInTheTrace:
 
 class TestStepScopes:
     @staticmethod
-    def _program(program):
+    def _program(program, aging=False):
         """(engine, one of its step programs, an argument for it)."""
-        eng = Engine(small_cfg(), ArraySource(flood(8)), NullSink(),
+        cfg = small_cfg()
+        if aging:
+            cfg = dataclasses.replace(cfg, table=dataclasses.replace(
+                cfg.table, evict_ttl_s=12.0, evict_every=64))
+        eng = Engine(cfg, ArraySource(flood(8)), NullSink(),
                      mega_n="auto", sink_thread=False)
         raw = np.zeros((257, schema.COMPACT_RECORD_WORDS), np.uint32)
         if program == "compact_step":
@@ -337,6 +345,50 @@ class TestStepScopes:
                 if stage is None] == outside
         assert {stage for stage, _ in staged} - {None} \
             == set(fused.STEP_SCOPES)
+
+    @pytest.mark.parametrize("program,outside", [
+        ("compact_step", ["jit"]), ("mega_rung", ["jit", "scan"])])
+    def test_aging_adds_one_stage_and_nothing_unscoped(self, program,
+                                                       outside):
+        """With ``evict_ttl_s`` on the sweep is an eighth stage,
+        ``fsx.evict`` (the benchmark's ``step.stage_evict_ms.tput``
+        reads it), and still no operation lies outside the scopes."""
+        from flowsentryx_tpu.audit.graph import iter_staged_eqns
+
+        eng, fn, arg = self._program(program, aging=True)
+        args = (eng.table, eng.stats, eng.params, arg)
+        assert "fsx.evict" in fn.lower(*args).as_text(debug_info=True)
+        staged = list(iter_staged_eqns(jax.make_jaxpr(fn)(*args)))
+        assert [eqn.primitive.name for stage, eqn in staged
+                if stage is None] == outside
+        assert {stage for stage, _ in staged} - {None} \
+            == set(fused.STEP_SCOPES) | {"evict"}
+
+    def test_the_table_counters_keep_their_names(self):
+        """``fsx serve``'s report and each benchmark run's ``stats``
+        line carry the three table counters under these names; the
+        benchmark's readers (``probe.stale_read_share.tput``,
+        ``table.untracked_share.tput``, the churn driver's
+        ``evicted_gap``) read them by name."""
+        assert schema.GlobalStats._fields[-3:] == (
+            "evicted", "stale_reads", "untracked")
+        eng, _, _ = self._program("compact_step", aging=True)
+        stats = eng.run().stats
+        assert {"evicted", "stale_reads", "untracked"} <= set(stats)
+
+
+@pytest.mark.parametrize("script", [
+    "check_trace_reduce.py", "check_trace_scopes.py",
+    "check_trace_scopes_evict.py"])
+def test_the_benchmarks_trace_checks_pass(script):
+    """The arithmetic behind the device-trace metrics, on traces whose
+    answers are known: run by hand until ISSUE 39, which added the
+    third (the aging sweep as an eighth stage, ``fsx.evict``)."""
+    root = Path(__file__).resolve().parents[1]
+    p = subprocess.run([sys.executable, f"benchmark/{script}"], cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stdout[-800:] + p.stderr[-800:]
+    assert p.stdout.strip().splitlines()[-1] == "ok"
 
 
 class TestVerdictRingDropped:

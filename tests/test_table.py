@@ -164,7 +164,8 @@ class TestEvictionStep:
             np.testing.assert_array_equal(np.asarray(out_e.verdict),
                                           np.asarray(out_r.verdict))
             for f in schema.GlobalStats._fields:
-                if f != "evicted":
+                # (the step without aging counts neither)
+                if f not in ("evicted", "untracked"):
                     np.testing.assert_array_equal(
                         np.asarray(getattr(s_e, f)),
                         np.asarray(getattr(s_r, f)), err_msg=f)
@@ -304,15 +305,23 @@ class TestEngineEviction:
             tables.append(eng.table)
         # verdict counters are layout-independent; ``evicted`` counts
         # TABLE ROWS, which differ by a few batch-internal arbitration
-        # losses between the global and per-shard layouts — so it is
-        # compared for presence and closeness, not equality (the exact
-        # per-layout parity pin is the reference-sweep test above)
+        # losses between the global and per-shard layouts, and
+        # ``untracked`` counts those losses — so they are compared for
+        # presence and closeness, not equality (the exact per-layout
+        # parity pin is the reference-sweep test above)
         for f, v0 in reps[0].stats.items():
             if f == "evicted":
                 assert v0 > 0 and reps[1].stats[f] > 0
+            if f in ("evicted", "untracked"):
                 assert abs(v0 - reps[1].stats[f]) <= 8
             else:
                 assert v0 == reps[1].stats[f], f
+        # every source sends one record: a flow was left with no row,
+        # or its row was freed, or it is still there — in each layout,
+        # so the mesh adds its shards' untracked flows up once
+        for rep in reps:
+            assert (rep.stats["untracked"] + rep.stats["evicted"]
+                    + rep.table["tracked"]) == len(recs)
         assert sinks[0].blocked == sinks[1].blocked
 
     def test_mega_auto_parity_with_eviction(self):
@@ -439,11 +448,13 @@ class TestCheckpointV2:
         rep3 = eng3.run()
         assert rep3.stats["dropped_blacklist"] > 0
 
-    @pytest.mark.parametrize("counter", ["evicted", "stale_reads"])
+    @pytest.mark.parametrize("counter", ["evicted", "stale_reads",
+                                         "untracked"])
     def test_missing_stats_counter_tolerated(self, tmp_path, counter):
         """A snapshot written before a counter existed (no
         stats_evicted: pre-eviction era; no stats_stale_reads: before
-        the probe counted its reads of last_seen) restores with the
+        the probe counted its reads of last_seen; no stats_untracked:
+        before flows left with no row were counted) restores with the
         counter at zero, named in missing_stats."""
         from flowsentryx_tpu.engine import checkpoint as ckpt
 
