@@ -125,14 +125,50 @@ def iter_eqns(jaxpr: Any, path: str = "") -> Iterator[tuple[str, Any]]:
     """Depth-first ``(path, eqn)`` walk over a (possibly closed) jaxpr,
     descending into every nested sub-jaxpr (pjit bodies, scan bodies,
     shard_map bodies, cond branches)."""
+    for where, eqn, _ in iter_platform_eqns(jaxpr, path):
+        yield where, eqn
+
+
+#: what :func:`iter_platform_eqns` calls the ``default=`` branch of a
+#: ``jax.lax.platform_dependent`` (JAX itself stores ``None``)
+DEFAULT_PLATFORM = "default"
+
+
+def platform_branches(eqn: Any) -> tuple[tuple[str, ...], ...] | None:
+    """The platforms of each branch of a ``cond`` that
+    ``jax.lax.platform_dependent`` staged, ``None`` for any other
+    equation.  Such a cond's index is a ``platform_index`` and is
+    resolved where the program is LOWERED: the compiler sees one
+    branch inlined and never a conditional, so the rules that hold a
+    runtime ``lax.cond`` do not apply to it, and each branch answers
+    only to the rules of the platforms it is lowered for."""
+    if eqn.primitive.name != "cond":
+        return None
+    platforms = eqn.params.get("branches_platforms")
+    if platforms is None:
+        return None
+    return tuple((DEFAULT_PLATFORM,) if ps is None else tuple(ps)
+                 for ps in platforms)
+
+
+def iter_platform_eqns(jaxpr: Any, path: str = "",
+                       platforms: tuple[str, ...] | None = None,
+                       ) -> Iterator[tuple[str, Any, tuple[str, ...] | None]]:
+    """:func:`iter_eqns` with, for each equation, the platforms of the
+    innermost ``platform_dependent`` branch it lies in (``None``:
+    outside any — lowered for every platform)."""
     if hasattr(jaxpr, "jaxpr"):          # ClosedJaxpr -> Jaxpr
         jaxpr = jaxpr.jaxpr
     for i, eqn in enumerate(jaxpr.eqns):
         where = f"{path}eqns[{i}]:{eqn.primitive.name}"
-        yield where, eqn
+        yield where, eqn, platforms
+        per_branch = platform_branches(eqn)
         for pname, pval in eqn.params.items():
-            for sub in _sub_jaxprs(pval):
-                yield from iter_eqns(sub, f"{where}/{pname}/")
+            for k, sub in enumerate(_sub_jaxprs(pval)):
+                inner = (per_branch[k] if per_branch is not None
+                         and pname == "branches" else platforms)
+                yield from iter_platform_eqns(sub, f"{where}/{pname}/",
+                                              inner)
 
 
 def iter_staged_eqns(jaxpr: Any, stage: str | None = None
@@ -406,6 +442,15 @@ _HLO_DTYPE = {
 }
 
 
+#: ``platform_dependent`` branches whose dynamic-offset slices of the
+#: donated table are the in-place form: the TPU's compiler updates the
+#: aliased table through a ``dynamic-update-slice`` and walks every
+#: index of a scatter (tests/test_chip_compile.py holds the compiled
+#: program to that).  A branch lowered for anything else as well — the
+#: default's, or one shared with the CPU — answers to XLA:CPU's rules.
+_SLICES_IN_PLACE = frozenset({("tpu",)})
+
+
 def _is_literal(var: Any) -> bool:
     # test the POSITIVE property (Literal carries .val) so a jax
     # upgrade reshaping Var internals fails closed, not open
@@ -437,6 +482,18 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
       property is table-shaped jaxpr-level DUS with computed starts,
       which the fast gather + victim-only-scatter form never emits).
 
+    Both are XLA:CPU's rules, and ``jax.lax.platform_dependent`` is how
+    a graph gives another backend its own form (the aging sweep's
+    window, ``ops/fused.py::evict_idle_epoch``: a slice on the TPU,
+    where a scatter is the slow form).  The ``cond`` it stages is
+    resolved at lowering (:func:`platform_branches`) — no compiled
+    program holds a conditional for it — so it is not the first cliff,
+    and each of its branches is walked under its own platforms' rules:
+    a branch lowered for the TPU alone may slice and update the donated
+    table at a computed start; the ``default`` branch, and everything
+    outside such a cond, is held to the rules above in full.  A
+    ``lax.cond`` on a traced predicate stays a finding wherever it is.
+
     The jaxpr half catches both at their source equation (matching
     the global table shapes AND, given ``n_shards``, the per-shard
     shapes staged inside ``shard_map`` bodies); the HLO half is the
@@ -465,9 +522,9 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
                                                        ()) or ()),
                          str(aval.dtype)))
 
-    for where, eqn in iter_eqns(closed_jaxpr):
+    for where, eqn, platforms in iter_platform_eqns(closed_jaxpr):
         name = eqn.primitive.name
-        if name == "cond":
+        if name == "cond" and platform_branches(eqn) is None:
             carried = sorted({
                 s for v in eqn.outvars
                 if (s := sig_of(getattr(v, "aval", None))) is not None})
@@ -482,7 +539,8 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
                             "table out of the cond or rewrite as a "
                             "lax.select/where on the rows"),
                 ))
-        elif name in ("dynamic_slice", "dynamic_update_slice"):
+        elif (name in ("dynamic_slice", "dynamic_update_slice")
+              and platforms not in _SLICES_IN_PLACE):
             operand = sig_of(getattr(eqn.invars[0], "aval", None))
             idx_start = 2 if name == "dynamic_update_slice" else 1
             dynamic = any(not _is_literal(v)
@@ -496,7 +554,9 @@ def check_inplace(closed_jaxpr: Any, hlo_text: str | None,
                             "the whole donated chain (the PR 8 DUS "
                             "cliff); use gather reads + victim-only "
                             "scatter writes (the eviction sweep's "
-                            "proven form)"),
+                            "form off the TPU), or keep the slice to "
+                            "the tpu= branch of a "
+                            "lax.platform_dependent"),
                 ))
 
     census = {"checked": hlo_text is not None,

@@ -324,8 +324,9 @@ def _cmd_audit(args: argparse.Namespace) -> int:
         return 1
     if args.evict_ttl:
         # stage the EVICTION-EPOCH variants: the in-step aging sweep
-        # changes every staged graph (a rolling gather + victim-only-
-        # scatter window at step start), so its donation/transfer/
+        # changes every staged graph (a rolling window at step start:
+        # gather + victim-only scatter here, a slice in the branch
+        # lowered for a TPU), so its donation/transfer/
         # collective contracts must be proved on the graphs an
         # eviction-enabled engine actually serves — and the boot cache
         # keys on the config, so these stage (and cache) as their own

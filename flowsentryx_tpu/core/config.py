@@ -193,7 +193,10 @@ class TableConfig:
     #: the window base advancing with the batch counter, so every row
     #: is re-examined once per ``evict_every`` batches
     #: (``ops/fused.evict_idle_epoch``; shard-local on a mesh, no new
-    #: collectives or D2H, constant per-batch cost).  0 disables the
+    #: collectives or D2H, constant per-batch cost: the window is a
+    #: slice of the table where the step is lowered for a TPU, a
+    #: gather and a victim-only scatter elsewhere — chosen at
+    #: lowering, not here).  0 disables the
     #: sweep entirely: the staged step graphs are then unchanged from
     #: the pre-eviction era (stale-slot reclamation on insert still
     #: works as before), which is what keeps parity baselines
@@ -204,7 +207,10 @@ class TableConfig:
     evict_ttl_s: float = 0.0
     #: Batches per full sweep cycle: each batch sweeps
     #: ``ceil(capacity / evict_every)`` rows, and a row idle past the
-    #: ttl is freed within one cycle of crossing it.
+    #: ttl is freed within one cycle of crossing it.  What a window
+    #: costs is the backend's (``ops/fused.evict_window``): on XLA:CPU
+    #: keep it to hundreds of rows; on a TPU a 2^17-row window is
+    #: under a tenth of a millisecond.
     evict_every: int = 64
 
     def __post_init__(self) -> None:

@@ -799,7 +799,8 @@ class GlobalStats(NamedTuple):
     batches: jnp.ndarray            # [2] uint32
     #: Idle flows freed by the in-step aging epoch
     #: (:func:`flowsentryx_tpu.ops.fused.evict_idle_epoch`;
-    #: ``TableConfig.evict_ttl_s``).  Stays zero — a pure donated
+    #: ``TableConfig.evict_ttl_s``), whichever of its two forms the
+    #: backend was given.  Stays zero — a pure donated
     #: passthrough — when eviction is disabled, so pre-eviction graphs
     #: and parity baselines are unchanged.
     evicted: jnp.ndarray            # [2] uint32

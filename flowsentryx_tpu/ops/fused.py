@@ -24,6 +24,7 @@ Design notes (why this shape is the TPU-fast shape):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -402,20 +403,46 @@ def update_stats(
 # what is copied: the probe's, whose branches only read the table and
 # return ``[R, P]``, is handed it by reference —
 # ``audit/graph.py::check_inplace``.)  The window form costs
-# ``capacity/evict_every`` rows of gather+scatter per batch, adds no
+# ``capacity/evict_every`` rows read and written per batch, adds no
 # whole-table latency spike on epoch batches, and keeps the exact same
 # guarantee: a row idle past the ttl is freed within one cycle of
 # crossing it.
 #
-# The window is read with a GATHER and written with a victim-only
-# SCATTER — not ``dynamic_slice``/``dynamic_update_slice``: a
-# dynamic-OFFSET slice touching the donated table defeats XLA:CPU's
-# in-place buffer reuse for the whole donated chain, and the step
-# falls off the in-place cliff (measured ~250 ms/step at 4M rows —
-# the full-table-copy signature — regardless of window size, even at
-# a 1-row window).  Scatters on the donated buffers are the hot
-# path's own proven-in-place mechanism; with drop-mode parking for
-# the non-victim lanes the write volume is the evicted rows only.
+# HOW the window is read and written is the one thing here that the
+# backend decides, and the two backends' needs are opposite, so there
+# are two forms and ``jax.lax.platform_dependent`` picks one where the
+# program is LOWERED (no configuration field, no environment variable,
+# no device name: a compile for a described v5e from a CPU host takes
+# the TPU's form, which ``jax.default_backend()`` would get wrong):
+#
+# * XLA:CPU, and anything not named: a GATHER over ``off + arange``
+#   and a victim-only drop-mode SCATTER (``_sweep_by_scatter``).  A
+#   dynamic-OFFSET slice touching the donated table defeats XLA:CPU's
+#   in-place buffer reuse for the whole donated chain, and the step
+#   falls off the in-place cliff: the full-table-copy signature,
+#   whatever the window (~250 ms/step at 4M rows even at a 1-row
+#   window when this was written; on JAX 0.9.0 the compact step at
+#   2^22 rows, batch 2,048, costs 1.5 ms with this form and 29.9 ms
+#   with the slice form, at a 128-row and an 8,192-row window alike:
+#   ISSUE 40).  Scatters on the donated buffers are the hot path's own
+#   proven-in-place mechanism; with drop-mode parking for the
+#   non-victim lanes the write volume is the evicted rows only.
+# * the TPU: ``dynamic_slice`` the window, ``where`` the victims to
+#   EMPTY_KEY / 0.0, ``dynamic_update_slice`` it back
+#   (``_sweep_by_slice``).  There the reverse holds: a drop-mode
+#   scatter walks every index of the window whatever it frees, at the
+#   ~0.09 us an index the step's other scatters cost, twice (key
+#   column, row matrix), behind a sort of the (index, key) pairs the
+#   compiler puts in front of a scatter it cannot prove unique: 23.4
+#   ms a batch for a 2^17-row window of a 2^26-row table, 76 % of the
+#   step (PERF_LEDGER, PR 39), where the window is 12 contiguous column
+#   runs of the chip's layout and the slices update the donated table
+#   in place.
+#
+# The victim rule is written once (``_sweep_victims``) and both forms
+# are held to the numpy reference bit for bit (tests/test_fused.py);
+# ``audit/graph.py::check_inplace`` walks each branch under its own
+# platform's rules.
 #
 # Everything stays inside the staged graph: no new D2H (the verdict
 # wire is unchanged), no new collectives (each shard sweeps its own
@@ -430,15 +457,81 @@ def evict_window(capacity: int, evict_every: int) -> int:
     """Rows swept per batch: one full pass every ``evict_every``
     batches.  When the division is ragged the last window re-sweeps a
     few tail rows (the base is clamped to keep the window in bounds) —
-    idempotent, so merely redundant.  Sizing rule: the sweep costs
-    ~0.2 µs/row single-device and ~1 µs/row under shard_map on CPU, so
-    size by CYCLE TIME, not window size — pick ``evict_every`` so one
-    full pass (``evict_every`` batches) takes about ``ttl/4`` at your
-    batch rate; the window lands in the tens-to-hundreds of rows and
-    the per-batch overhead vanishes.  At the 10 Mpps design rate a 4M
-    table with ``evict_every=32768`` cycles in ~7 s with a 128-row
-    window (the TABLESCALE_r12 bench setting)."""
+    idempotent, so merely redundant.  Sizing rule: size by CYCLE TIME —
+    pick ``evict_every`` so one full pass (``evict_every`` batches)
+    takes about ``ttl/4`` at your batch rate — and then look at what
+    the window costs on YOUR backend.  On XLA:CPU the sweep costs ~0.2
+    µs/row single-device and ~1 µs/row under shard_map, so the window
+    should land in the tens-to-hundreds of rows, where the per-batch
+    overhead vanishes: at the 10 Mpps design rate a 4M table with
+    ``evict_every=32768`` cycles in ~7 s with a 128-row window (the
+    TABLESCALE_r12 bench setting).  On the TPU the window is a slice
+    and costs what its bytes cost: a 2^17-row window (2^26 rows,
+    ``evict_every=512``) is measured in PERF.md §5
+    (``step.stage_evict_ms.tput``), not derived from the CPU's rule."""
     return -(-capacity // evict_every)
+
+
+def _sweep_offset(tcfg, cap: int, stats: GlobalStats) -> jnp.ndarray:
+    """``[] uint32`` first row of this batch's window.  Unsigned, and
+    clamped only where the clamp can bite, for the TPU's sake: a slice
+    at a signed start is lowered behind a ``start < 0`` select, and
+    that select or a ``minimum`` hides from the chip's compiler that
+    the start is a multiple of the window.  Knowing it, it blanks the
+    victims inside the in-place ``dynamic-update-slice``; not knowing,
+    it slices the window into a temporary and copies it back (0.084
+    against 0.049 ms a batch at a 2^17-row window: PERF.md §6, PR 40)."""
+    chunk = evict_window(cap, tcfg.evict_every)
+    off = (stats.batches[0] % np.uint32(tcfg.evict_every)) * np.uint32(chunk)
+    if chunk * tcfg.evict_every != cap:
+        # clamp so a ragged last window re-sweeps tail rows instead of
+        # parking out of bounds (which would leave them unswept forever)
+        off = jnp.minimum(off, np.uint32(cap - chunk))
+    return off
+
+
+def _sweep_victims(tcfg, keys: jnp.ndarray, rows: jnp.ndarray,
+                   now: jnp.ndarray) -> jnp.ndarray:
+    """``[chunk] bool``: occupied, idle past the ttl, block not live."""
+    C = TableCol
+    idle = now - rows[:, C.LAST_SEEN] > tcfg.evict_ttl_s
+    live_block = rows[:, C.BLOCKED_UNTIL] > now
+    return (keys != hashtable.EMPTY_KEY) & idle & ~live_block
+
+
+def _sweep_by_scatter(tcfg, table: IpTableState, off: jnp.ndarray,
+                      now: jnp.ndarray) -> tuple[IpTableState, jnp.ndarray]:
+    """The sweep where a dynamic-offset slice would copy the table
+    (XLA:CPU): gather the window, scatter the victims."""
+    cap = table.key.shape[0]
+    idx = off.astype(jnp.int32) + jnp.arange(
+        evict_window(cap, tcfg.evict_every), dtype=jnp.int32)
+    victim = _sweep_victims(tcfg, table.key[idx], table.state[idx], now)
+    # victim-only scatter: non-victim lanes park at row `cap` and drop
+    vidx = jnp.where(victim, idx, jnp.int32(cap))
+    return IpTableState(
+        key=table.key.at[vidx].set(jnp.uint32(hashtable.EMPTY_KEY),
+                                   mode="drop"),
+        state=table.state.at[vidx].set(0.0, mode="drop"),
+    ), jnp.sum(victim).astype(jnp.uint32)
+
+
+def _sweep_by_slice(tcfg, table: IpTableState, off: jnp.ndarray,
+                    now: jnp.ndarray) -> tuple[IpTableState, jnp.ndarray]:
+    """The sweep where a scatter walks every index (the TPU): slice the
+    window out, blank the victims, slice it back in."""
+    chunk = evict_window(table.key.shape[0], tcfg.evict_every)
+    keys = jax.lax.dynamic_slice_in_dim(table.key, off, chunk)
+    rows = jax.lax.dynamic_slice_in_dim(table.state, off, chunk)
+    victim = _sweep_victims(tcfg, keys, rows, now)
+    return IpTableState(
+        key=jax.lax.dynamic_update_slice_in_dim(
+            table.key,
+            jnp.where(victim, jnp.uint32(hashtable.EMPTY_KEY), keys),
+            off, 0),
+        state=jax.lax.dynamic_update_slice_in_dim(
+            table.state, jnp.where(victim[:, None], 0.0, rows), off, 0),
+    ), jnp.sum(victim).astype(jnp.uint32)
 
 
 def evict_idle_epoch(
@@ -457,27 +550,10 @@ def evict_idle_epoch(
     construction (``0 - last_seen`` can never exceed a positive ttl),
     so ``warm()``'s state-preservation contract holds without a
     valid-count input here."""
-    C = TableCol
-    cap = table.key.shape[0]
-    chunk = evict_window(cap, tcfg.evict_every)
-    off = ((stats.batches[0] % np.uint32(tcfg.evict_every))
-           * np.uint32(chunk)).astype(jnp.int32)
-    # clamp so a ragged last window re-sweeps tail rows instead of
-    # parking out of bounds (which would leave them unswept forever)
-    off = jnp.minimum(off, np.int32(cap - chunk))
-    idx = off + jnp.arange(chunk, dtype=jnp.int32)
-    keys = table.key[idx]
-    rows = table.state[idx]
-    idle = now - rows[:, C.LAST_SEEN] > tcfg.evict_ttl_s
-    live_block = rows[:, C.BLOCKED_UNTIL] > now
-    victim = (keys != hashtable.EMPTY_KEY) & idle & ~live_block
-    # victim-only scatter: non-victim lanes park at row `cap` and drop
-    vidx = jnp.where(victim, idx, jnp.int32(cap))
-    return IpTableState(
-        key=table.key.at[vidx].set(jnp.uint32(hashtable.EMPTY_KEY),
-                                   mode="drop"),
-        state=table.state.at[vidx].set(0.0, mode="drop"),
-    ), jnp.sum(victim).astype(jnp.uint32)
+    return jax.lax.platform_dependent(
+        table, _sweep_offset(tcfg, table.key.shape[0], stats), now,
+        tpu=functools.partial(_sweep_by_slice, tcfg),
+        default=functools.partial(_sweep_by_scatter, tcfg))
 
 
 # -- compact verdict wire ---------------------------------------------------

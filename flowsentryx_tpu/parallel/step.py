@@ -125,8 +125,9 @@ def make_sharded_step(
         # stats psum below.  Statically absent when disabled.
         n_evict_l = None
         if cfg.table.evict_ttl_s > 0:
-            table_shard, n_evict_l = fused.evict_idle_epoch(
-                cfg.table, table_shard, stats, now)
+            with jax.named_scope("fsx.evict"):
+                table_shard, n_evict_l = fused.evict_idle_epoch(
+                    cfg.table, table_shard, stats, now)
 
         # --- route local flow partials to their owner ----------------------
         h1 = hashtable.hash_u32(fa.rep_key, cfg.table.salt)

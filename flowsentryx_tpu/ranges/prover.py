@@ -221,6 +221,11 @@ class _Prover:
             return self._scan(where, eqn, ins, axis_env, record)
         if name == "while":
             return self._while(where, eqn, ins, axis_env, record)
+        if name == "platform_index":
+            # jax.lax.platform_dependent's branch index, resolved at
+            # lowering: which branch depends on the target, so the
+            # cond below joins all of them
+            return [iv.scalar(0, len(p["platforms"]) - 1)]
         if name == "cond":
             outs = None
             for bi, br in enumerate(p["branches"]):

@@ -162,7 +162,10 @@ def bench_cell_cfg(name: str):
     return cfg, params, config["mega"]
 
 
-BENCH_CONFIGS = ("c3-offline-ml", "c4-syn-mix", "c5-l34-1m")
+#: `c6-spoof-churn` ages its table: its step holds the sweep's switch on
+#: the lowering platform (`TestInplaceCensus`'s aging cases)
+BENCH_CONFIGS = ("c3-offline-ml", "c4-syn-mix", "c5-l34-1m",
+                 "c6-spoof-churn")
 
 
 class TestBenchmarkConfigs:
@@ -271,6 +274,99 @@ class TestInplaceCensus:
         assert dus and dus[0].contract == "inplace"
         assert "table.state" in dus[0].reason
         assert "gather reads + victim-only scatter" in dus[0].reason
+
+    def test_the_aging_step_is_clean_on_every_variant(self):
+        """The aging sweep hands the donated table through a
+        `platform_dependent` switch whose `tpu` branch slices it at a
+        computed start (ISSUE 40).  Neither is a cliff: the switch is
+        resolved at lowering, and the slices are lowered only for the
+        TPU.  On XLA:CPU the compiled program is the `default`
+        branch's: no copy, no conditional."""
+        cfg = FsxConfig(
+            table=TableConfig(capacity=1 << 12, evict_ttl_s=12.0,
+                              evict_every=16),
+            batch=CFG.batch)
+        (compact,), _, _ = runner.stage_variants(cfg,
+                                                 variants=("compact",))
+        closed = compact.jitted.trace(*compact.make_args()).jaxpr
+        switches = [graph.platform_branches(e)
+                    for _, e in graph.iter_eqns(closed)
+                    if graph.platform_branches(e)]
+        assert switches == [(("tpu",), ("default",))]
+        sliced = {p for _, e, p in graph.iter_platform_eqns(closed)
+                  if e.primitive.name == "dynamic_update_slice"
+                  and e.invars[0].aval.shape[0] == cfg.table.capacity}
+        assert sliced == {("tpu",)}  # what the audit walks past
+
+        rep = runner.run_audit(cfg, mesh=make_mesh(8), mega_n=2)
+        assert rep.ok, [str(f) for v in rep.variants for f in v.findings]
+        assert len(rep.variants) == 5
+        for v in rep.variants:
+            assert v.inplace["checked"], v.name
+            assert (v.inplace["copies"], v.inplace["converts"],
+                    v.inplace["conditionals"]) == (0, 0, 0), v.name
+
+    @staticmethod
+    def _sweepish(default):
+        """A step that hands the table to a `platform_dependent` as
+        the sweep does: the TPU's branch slices, `default` is given."""
+        def on_tpu(key, state, x):
+            start = (x.astype(jnp.int32), jnp.int32(0))
+            rows = jax.lax.dynamic_slice(state, start, (8, 4))
+            return key, jax.lax.dynamic_update_slice(state, rows * 0.0,
+                                                     start)
+
+        def step(key, state, x):
+            key, state = jax.lax.platform_dependent(
+                key, state, x, tpu=on_tpu, default=default)
+            return key, state, jnp.sum(state[:4])
+        return step
+
+    def test_a_slice_in_the_default_branch_is_still_a_finding(self):
+        """The `default` branch is what XLA:CPU lowers: held to its
+        rules in full, a computed start there is PR 8's cliff.  The
+        same two equations in the `tpu` branch are not reported."""
+        def by_slice(key, state, x):
+            return key, jax.lax.dynamic_update_slice(
+                state, jnp.ones((8, 4), jnp.float32),
+                (x.astype(jnp.int32), jnp.int32(0)))
+
+        finds, _ = self._plant(self._sweepish(by_slice))
+        jaxpr_finds = [f for f in finds if f.where]
+        assert len(jaxpr_finds) == 1, [str(f) for f in finds]
+        assert "dynamic-offset dynamic_update_slice" in jaxpr_finds[0].reason
+        assert "tpu= branch" in jaxpr_finds[0].reason
+        # ... and in the default branch it is: branches/ holds both
+        assert "cond/branches/" in jaxpr_finds[0].where
+
+        def by_scatter(key, state, x):
+            return key, state.at[x % 64].set(0.0)
+
+        finds, census = self._plant(self._sweepish(by_scatter))
+        assert [f for f in finds if f.contract == "inplace"] == [], [
+            str(f) for f in finds]
+        assert census["copies"] == 0 and census["conditionals"] == 0
+
+    def test_a_traced_cond_is_a_finding_inside_a_platform_branch_too(self):
+        """Only the switch on `platform_index` is resolved at
+        lowering.  A `lax.cond` on a traced predicate that returns the
+        table is a conditional in every compiled program, whichever
+        branch of the switch it sits in."""
+        def default(key, state, x):
+            return jax.lax.cond(
+                x > jnp.uint32(0),
+                lambda k, s: (k.at[x % 64].set(x), s),
+                lambda k, s: (k, s), key, state)
+
+        finds, census = self._plant(self._sweepish(default))
+        cond = [f for f in finds
+                if "lax.cond carries the donated table" in f.reason]
+        assert len(cond) == 1 and cond[0].contract == "inplace"
+        # the runtime one, inside the switch's second branch; the
+        # switch itself (an outer `cond` that carries the table too)
+        # is not reported
+        assert cond[0].where.count(":cond") == 2
+        assert census["conditionals"] >= 1
 
     def test_planted_shard_local_dus(self):
         # shard_map bodies stage SHARD-LOCAL avals — the census must

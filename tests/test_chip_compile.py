@@ -149,9 +149,12 @@ def test_score_int8_kernel_compiles_with_mosaic(one_chip, mosaic, served,
 #: and the benchmark's `c5-l34-1m` (ISSUE 32): that table under
 #: 16,384-record batches, where the temporaries are the batch's again;
 #: and the benchmark's `c6-spoof-churn` (ISSUE 39): `c5-l34-1m`'s shapes
-#: with the aging sweep compiled in, a 2^17-row window a batch read by
-#: gather and freed by scatter (`ops/fused.py::evict_idle_epoch`), which
-#: has to leave the table in place as the step's own scatters do.
+#: with the aging sweep compiled in, a 2^17-row window a batch.  Lowered
+#: for the chip the window is sliced out of the table and sliced back in
+#: (`ops/fused.py::evict_idle_epoch`'s `tpu` form, ISSUE 40: read by
+#: gather and freed by scatter it was 23.4 ms of a 30.8 ms step), which
+#: has to leave the table in place as the step's own scatters do:
+#: `_assert_the_sweep_is_two_slices`.
 NO_AGING = {}
 C6_AGING = {"evict_ttl_s": 12.0, "evict_every": 512}
 STEP_SHAPES = [
@@ -193,6 +196,36 @@ def _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
     assert mem.temp_size_in_bytes < temp_limit
 
 
+def _assert_the_sweep_is_two_slices(compiled, capacity):
+    """Every instruction of the aging sweep (`op_name` under the
+    `fsx.evict` scope): none is a scatter, a sort or a fusion over
+    either, and the window goes back into the donated row matrix by a
+    `dynamic-update-slice` whose result is the table itself."""
+    text = compiled.as_text()
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+    sweep = [ln for ln in text.splitlines()
+             if re.search(r'op_name="[^"]*fsx\.evict', ln)]
+    assert sweep
+    state = f"f32[{capacity},{schema.NUM_TABLE_COLS}]"
+    back_in_place = False
+    for ln in sweep:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", ln)
+        if not m:
+            continue
+        result, opcode = m.groups()
+        body = ln
+        called = re.search(r"calls=%([\w.\-]+)", ln)
+        if opcode == "fusion" and called:
+            body += computations.get(called.group(1), "")
+        assert not re.search(r"\b(scatter|sort)\(", body), ln[:200]
+        if result.startswith(state) and "dynamic-update-slice(" in body:
+            back_in_place = True
+    # (that the result is the donated buffer and not a copy of it is
+    # the callers' alias and temporary limits)
+    assert back_in_place
+
+
 @pytest.mark.parametrize("capacity,batch,temp_limit,aging", STEP_SHAPES)
 def test_single_compact_step_compiles_and_aliases_the_table(
         one_chip, served, capacity, batch, temp_limit, aging):
@@ -204,6 +237,8 @@ def test_single_compact_step_compiles_and_aliases_the_table(
         _wire((batch + 1, WORDS), one_chip)).compile()
     _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
                                                   temp_limit)
+    if aging:
+        _assert_the_sweep_is_two_slices(compiled, capacity)
 
 
 @pytest.mark.parametrize("capacity,batch,temp_limit,aging", STEP_SHAPES)
@@ -218,15 +253,22 @@ def test_top_mega_rung_compiles_and_aliases_the_table(
         _wire((top, batch + 1, WORDS), one_chip)).compile()
     _assert_in_place_and_no_table_sized_temporary(compiled, capacity,
                                                   temp_limit)
+    if aging:
+        _assert_the_sweep_is_two_slices(compiled, capacity)
 
 
-def test_sharded_step_compiles_for_four_chips(mesh4, served):
+@pytest.mark.parametrize("aging", [NO_AGING, C6_AGING],
+                         ids=["no-aging", "c6-aging"])
+def test_sharded_step_compiles_for_four_chips(mesh4, served, aging):
     """`fsx serve --mesh 4`: rows sharded by parallel/layout.py, the
     wire replicated; the compiler puts in the designed collectives and
-    each chip holds a quarter of the table."""
+    each chip holds a quarter of the table.  With aging each chip
+    sweeps a window of its own quarter, by the `tpu` form under
+    `shard_map`: no collective more, the shard still in place."""
     classify, quant, params = served
     rep = NamedSharding(mesh4, P())
-    step = par.make_sharded_compact_step(CFG, classify, mesh4, **quant)
+    step = par.make_sharded_compact_step(_cfg(CAPACITY, BATCH, **aging),
+                                         classify, mesh4, **quant)
     compiled = step.lower(
         *_state(params, layout.sharding_for(mesh4, "table.key"),
                 layout.sharding_for(mesh4, "table.state"), rep),
@@ -237,6 +279,8 @@ def test_sharded_step_compiles_for_four_chips(mesh4, served):
     assert _foreign_table_sized_results(text, CAPACITY // 4) == set()
     mem = compiled.memory_analysis()  # bytes on EACH device
     assert TABLE_BYTES // 4 <= mem.alias_size_in_bytes < TABLE_BYTES // 2
+    if aging:
+        _assert_the_sweep_is_two_slices(compiled, CAPACITY // 4)
 
 
 def test_sharded_table_summary_needs_the_xla_twin(mesh4, mosaic):

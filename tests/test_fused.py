@@ -11,7 +11,9 @@ from flowsentryx_tpu.core.config import (
 from flowsentryx_tpu.core.schema import (
     FeatureBatch, Verdict, make_stats, make_table, stat_value,
 )
-from flowsentryx_tpu.audit.graph import iter_eqns
+from flowsentryx_tpu.audit.graph import (
+    iter_eqns, iter_platform_eqns, platform_branches,
+)
 from flowsentryx_tpu.models import get_model
 from flowsentryx_tpu.ops import fused
 
@@ -652,12 +654,111 @@ class TestBatchesWrapEviction:
         assert int(n) == fused.evict_window(self.CAP, self.EVERY) - 1
 
 
+class TestSweepFormsAgree:
+    """The aging sweep has two forms, and `fused.evict_idle_epoch`
+    lets `jax.lax.platform_dependent` choose one where the program is
+    lowered (ISSUE 40): the TPU's slices the window out and back in,
+    the other gathers it and scatters the victims.  Both are called
+    directly here, on XLA:CPU, and held to a numpy sweep of the same
+    window bit for bit."""
+
+    CAP, TTL, NOW = 256, 5.0, 60.0
+
+    def _table(self, seed=7):
+        """Every kind of row in every window: empty, fresh, idle, and
+        idle with a block that has run out; every column non-zero."""
+        from flowsentryx_tpu.core import schema
+
+        rng = np.random.default_rng(seed)
+        state = rng.uniform(1.0, 9.0, (self.CAP, schema.NUM_TABLE_COLS)
+                            ).astype(np.float32)
+        state[:, schema.TableCol.LAST_SEEN] = rng.uniform(
+            self.NOW - 4 * self.TTL, self.NOW, self.CAP)
+        state[:, schema.TableCol.BLOCKED_UNTIL] = rng.uniform(
+            0.0, self.NOW - 1.0, self.CAP)
+        key = rng.integers(1, 1 << 32, self.CAP, dtype=np.uint32)
+        key[rng.random(self.CAP) < 0.25] = 0
+        return key, state
+
+    @staticmethod
+    def _numpy_sweep(key, state, window, now, ttl):
+        from flowsentryx_tpu.core.schema import TableCol
+
+        idle = (np.float32(now) - state[window, TableCol.LAST_SEEN]
+                > np.float32(ttl))
+        live = state[window, TableCol.BLOCKED_UNTIL] > np.float32(now)
+        victim = (key[window] != 0) & idle & ~live
+        key, state = key.copy(), state.copy()
+        key[window] = np.where(victim, 0, key[window])
+        state[window] = np.where(victim[:, None], np.float32(0.0),
+                                 state[window])
+        return key, state, victim
+
+    @pytest.mark.parametrize("case,every,batches,now", [
+        ("middle", 8, 3, NOW),
+        # 256 rows in 7 windows of 37: the last would start at 222
+        ("ragged-last", 7, 6, NOW),
+        ("live-block", 8, 5, NOW),
+        ("all-empty", 8, 2, NOW),
+        ("now-zero", 8, 3, 0.0),
+    ], ids=lambda v: v if isinstance(v, str) else None)
+    def test_both_forms_equal_the_numpy_sweep(self, case, every, batches,
+                                              now):
+        from flowsentryx_tpu.core import schema
+
+        tcfg = TableConfig(capacity=self.CAP, evict_ttl_s=self.TTL,
+                           evict_every=every)
+        chunk = fused.evict_window(self.CAP, every)
+        unclamped = (batches % every) * chunk
+        base = min(unclamped, self.CAP - chunk)
+        blocked = base + np.arange(3, chunk, 5)
+        key, state = self._table()
+        if case == "live-block":
+            # idle for four TTLs, blocked for another minute: stays
+            key[blocked] |= 1
+            state[blocked, schema.TableCol.LAST_SEEN] = now - 4 * self.TTL
+            state[blocked, schema.TableCol.BLOCKED_UNTIL] = now + 60.0
+        if case == "all-empty":
+            key[base:base + chunk] = 0
+        want_key, want_state, victim = self._numpy_sweep(
+            key, state, slice(base, base + chunk), now, self.TTL)
+        # each case is what its name says
+        assert (base < unclamped) == (case == "ragged-last")
+        if case in ("all-empty", "now-zero"):
+            assert not victim.any()
+        else:
+            assert 0 < victim.sum() < chunk
+        if case == "live-block":
+            assert not victim[blocked - base].any()
+
+        table = schema.IpTableState(key=jnp.asarray(key),
+                                    state=jnp.asarray(state))
+        stats = make_stats()._replace(
+            batches=jnp.asarray([batches, 0], jnp.uint32))
+        for form in (fused._sweep_by_slice, fused._sweep_by_scatter):
+            got, n = jax.jit(
+                lambda t, s, clock, form=form: form(
+                    tcfg, t, fused._sweep_offset(tcfg, self.CAP, s), clock)
+            )(table, stats, jnp.float32(now))
+            assert int(n) == victim.sum(), form.__name__
+            np.testing.assert_array_equal(np.asarray(got.key), want_key,
+                                          err_msg=form.__name__)
+            # bit for bit: -0.0 and 0.0 are told apart
+            np.testing.assert_array_equal(
+                np.asarray(got.state).view(np.uint32),
+                want_state.view(np.uint32), err_msg=form.__name__)
+
+
 class TestStepNeverTakesATableColumn:
     """The step may touch the table only by gather and scatter (ISSUE
     30).  `table.last_seen` is `state[:, LAST_SEEN]`: handed to the
     probe it made every step copy a whole column out of the state
     matrix, 256 MB at 2^26 rows, 73 % of the step on the chip.  Read
-    from the jaxpr, so it holds whatever the backend would fuse away."""
+    from the jaxpr, so it holds whatever the backend would fuse away.
+    The one exception is the aging sweep's window where the program is
+    lowered for a TPU (ISSUE 40): a `dynamic_slice` and a
+    `dynamic_update_slice` of the table inside the `tpu` branch of a
+    `jax.lax.platform_dependent`, and nowhere else."""
 
     #: unlike every other dimension of the traced step (batch 256,
     #: 8 probes, 12 columns, a 256-row eviction window)
@@ -668,17 +769,47 @@ class TestStepNeverTakesATableColumn:
         """Names of the primitives with a table-sized operand, and of
         those with a table-sized result, anywhere in the traced graph
         (sub-jaxprs walked)."""
-        def sized(vs):
-            return any(cls.CAP in getattr(v.aval, "shape", ()) for v in vs)
-
         eqns = [eqn for _, eqn in iter_eqns(jax.make_jaxpr(fn)(*args))]
-        return ({e.primitive.name for e in eqns if sized(e.invars)},
-                {e.primitive.name for e in eqns if sized(e.outvars)})
+        return ({e.primitive.name for e in eqns if cls._sized(e.invars)},
+                {e.primitive.name for e in eqns if cls._sized(e.outvars)})
+
+    @classmethod
+    def _sized(cls, variables):
+        return any(cls.CAP in getattr(v.aval, "shape", ())
+                   for v in variables)
+
+    @classmethod
+    def _table_sized_primitives_by_platform(cls, fn, *args):
+        """The same two sets, apart for the equations inside a `tpu`
+        branch of a `platform_dependent` and for all the others (what
+        every backend lowers, and the `default` branch); and the
+        platforms of every conditional that returns something
+        table-sized."""
+        used = {True: (set(), set()), False: (set(), set())}
+        writers = []
+        for _, e, platforms in iter_platform_eqns(jax.make_jaxpr(fn)(*args)):
+            read_by, written_by = used[platforms == ("tpu",)]
+            if cls._sized(e.invars):
+                read_by.add(e.primitive.name)
+            if cls._sized(e.outvars):
+                written_by.add(e.primitive.name)
+                if e.primitive.name == "cond":
+                    writers.append(platform_branches(e))
+        return used[False], used[True], writers
 
     #: the probe's conditional reads the table (its branches gather
     #: from it) and returns `[R, P]`: only a scatter returns a table
     READ_BY = {"gather", "scatter", "cond"}
     WRITTEN_BY = {"scatter"}
+    #: under aging, one more writer: the switch on `platform_index`
+    #: that hands the table to the backend's form of the sweep.  It is
+    #: resolved at lowering, so no program holds a conditional for it
+    #: (`audit/graph.py::check_inplace`).  The slices stay in its
+    #: `tpu` branch.
+    WRITTEN_BY_AGING = WRITTEN_BY | {"cond"}
+    TPU_BRANCH = ({"dynamic_slice", "dynamic_update_slice"},
+                  {"dynamic_update_slice"})
+    SWEEP_SWITCH = (("tpu",), ("default",))
 
     def _cfg(self, kind, evict_ttl_s=0.0):
         return FsxConfig(
@@ -694,11 +825,20 @@ class TestStepNeverTakesATableColumn:
     def test_make_step(self, kind, evict_ttl_s):
         cfg = self._cfg(kind, evict_ttl_s)
         spec = get_model(cfg.model.name)
-        used = self._table_sized_primitives(
-            fused.make_step(cfg, spec.classify_batch),
-            make_table(self.CAP), make_stats(), spec.init(),
-            build_batch([(1001, 5, 100, 0.1, ML_COLD)]))
-        assert used == (self.READ_BY, self.WRITTEN_BY)
+        args = (fused.make_step(cfg, spec.classify_batch),
+                make_table(self.CAP), make_stats(), spec.init(),
+                build_batch([(1001, 5, 100, 0.1, ML_COLD)]))
+        if not evict_ttl_s:
+            assert (self._table_sized_primitives(*args)
+                    == (self.READ_BY, self.WRITTEN_BY))
+            return
+        anywhere, tpu_only, writers = (
+            self._table_sized_primitives_by_platform(*args))
+        assert anywhere == (self.READ_BY, self.WRITTEN_BY_AGING)
+        assert tpu_only == self.TPU_BRANCH
+        # key column and state matrix leave through the one switch; a
+        # `lax.cond` on a traced predicate would read None here
+        assert writers == [self.SWEEP_SWITCH]
 
     @pytest.mark.parametrize("kind", list(LimiterKind),
                              ids=lambda k: k.value)
