@@ -1,0 +1,15 @@
+"""Median, over the records sunk in the window, of publish in the feature
+ring -> verdict sunk (``spans["latency.arrival_to_verdict"]``: the ring
+wait plus first record -> verdict, entry by entry; the window's counts:
+later report less earlier)."""
+
+from benchmark import span_window
+
+NAME = "path.arrival_win_p50_ms.lat"
+UNIT = "ms"
+LAYER = "whole path (first record in its batch to verdict sunk)"
+MOVES = "verdict_p50_ms"
+
+
+def read(ctx):
+    return span_window.p_ms(ctx, "latency.arrival_to_verdict", 50)

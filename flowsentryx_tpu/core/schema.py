@@ -408,7 +408,9 @@ SHM_TAIL_OFFSET = 128           # u64: consumer cursor (records read)
 #                          and e2e accounting; same clock as
 #                          time.perf_counter on Linux).
 #   word 6    fill_dur_us  first-record-arrival -> seal duration.
-#   word 7    reserved (0)
+#   word 7    ring_wait_us the batch's first record's wait in the feature
+#                          ring: publish (as the worker's cursor log
+#                          saw it) -> first-record-arrival; saturating.
 
 SHM_BATCHQ_MAGIC = 0x4653584241545131  # "FSXBATQ1"
 SHM_HBEAT_OFFSET = 24
@@ -423,6 +425,14 @@ SHM_WSTATE_OFFSET = 56
 #: corruption/restart signal and this counter is the ONLY place such a
 #: loss shows up.
 SHM_EMIT_DROP_OFFSET = 72
+#: u64s, worker-written beside EMIT_DROP (ingest/worker.py
+#: ``CursorLog``): batches whose first record's cursor was older than
+#: the worker's log of the ring's producer cursor, so that their ring
+#: wait is clamped to the log's oldest entry (an understatement), and
+#: how many times the worker has looked at that cursor (the log's
+#: observation interval is the worker's wall over this count).
+SHM_RING_WAIT_CLAMPED_OFFSET = 80
+SHM_RING_OBSERVES_OFFSET = 88
 #: u64 pair, creator-written BEFORE the worker spawns (read-only
 #: thereafter, so the one-writer rule holds trivially): the worker's
 #: idle backoff policy.  SPIN_US is the budget of busy-spin polling
@@ -446,9 +456,10 @@ BATCHQ_SLOT_HDR_WORDS = 8
 #: (ISSUE 11): every record of a sealed batch is timestamped at shm
 #: seal by its worker (words 4/5, CLOCK_MONOTONIC ns — the same clock
 #: as ``time.perf_counter`` on Linux), with word 6 recovering the
-#: batch's first-record arrival; ``SealedBatchQueue.peek_batches``
-#: surfaces the header and the engine's sink section closes the
-#: seal→verdict interval against it.
+#: batch's first-record arrival and word 7 that record's wait in the
+#: feature ring before it; ``SealedBatchQueue.peek_batches`` surfaces
+#: the header and the engine's sink section closes the seal→verdict
+#: and arrival→verdict intervals against it.
 BATCHQ_SEQ_LO_WORD = 0
 BATCHQ_SEQ_HI_WORD = 1
 BATCHQ_N_RECORDS_WORD = 2
@@ -456,7 +467,7 @@ BATCHQ_WIRE_ID_WORD = 3
 BATCHQ_SEAL_NS_LO_WORD = 4
 BATCHQ_SEAL_NS_HI_WORD = 5
 BATCHQ_FILL_DUR_US_WORD = 6
-BATCHQ_RESERVED_WORD = 7
+BATCHQ_RING_WAIT_US_WORD = 7
 WIRE_ID_RAW48 = 0
 WIRE_ID_COMPACT16 = 1
 

@@ -187,12 +187,15 @@ class EngineReport(NamedTuple):
 class _Stamps(NamedTuple):
     """The host stamps of one sealed batch (perf_counter domain): the
     latency plane charges an in-flight entry from its OLDEST member
-    batch, so that member's three stamps ride with the entry."""
+    batch, so that member's stamps ride with the entry."""
 
     t_enqueue: float    # when the batch's first record arrived
     t_seal: float       # when its batcher (worker or inline) sealed it
     t_dequeue: float    # when the engine took it off the sealed queue
     #                     (the inline path has no queue: == t_seal)
+    #: the first record's wait in the feature ring before ``t_enqueue``
+    #: (seconds; None: no ring, the inline path)
+    ring_s: float | None = None
 
 
 def _inline_stamps(t_first: float) -> _Stamps:
@@ -206,7 +209,7 @@ def _sealed_stamps(sb) -> _Stamps:
     """Stamps of a batch taken off the sealed queue; a source that does
     not stamp the dequeue (a stand-in fleet) dequeued it just now."""
     return _Stamps(sb.t_enqueue, sb.t_seal,
-                   sb.t_dequeue or time.perf_counter())
+                   sb.t_dequeue or time.perf_counter(), sb.ring_s)
 
 
 class _InFlight(NamedTuple):
@@ -603,8 +606,8 @@ class Engine:
         #: :class:`~flowsentryx_tpu.engine.sources.PacedSource` can pop
         #: ``n_records`` scheduled arrival times per call and obtain
         #: exact per-record arrival→verdict-sunk latencies (the latency
-        #: bench's measurement; batch-level ``metrics.e2e`` conflates
-        #: queueing with readback-group policy, which is fine for
+        #: bench's measurement; the latency plane charges a group's
+        #: records their oldest batch's path, which is fine for
         #: throughput mode but not for judging the 1 ms budget).
         self.on_reap = None
         self._inflight: list[_InFlight] = []
@@ -1441,13 +1444,12 @@ class Engine:
             self._last_sink_t = t_done
             for g in group:
                 st = g.stamps
-                self.metrics.e2e.add(t_done - st.t_enqueue)
                 # per-record accounting: every record of the entry is
                 # charged the entry's OLDEST-record path (a
                 # conservative upper bound — earlier members waited
-                # for the group, the same anchoring e2e has always
-                # used).  hold ends where the step call began, less the
-                # upload made ahead of it, so the chain closes exactly.
+                # for the group).  hold ends where the step call began,
+                # less the upload made ahead of it, so the chain closes
+                # exactly; the ring wait lies ahead of it.
                 self._lat.record(
                     total_s=t_done - st.t_enqueue,
                     staged_s=(g.t_launch - st.t_enqueue
@@ -1463,6 +1465,7 @@ class Engine:
                             - st.t_dequeue),
                     device_s=t_wire - g.t_launched,
                     sink_host_s=t_done - t_wire,
+                    ring_s=st.ring_s,
                 )
                 if self.on_reap is not None:
                     self.on_reap(g.n_records, t_done)
@@ -2133,136 +2136,138 @@ class Engine:
                 return True
             return False
 
-        while not bounded():
-            with self.metrics.poll:
-                # Mega mode polls up to the remaining GROUP capacity so
-                # a deep source backlog can seal several batches in
-                # one drain; otherwise exactly one batch's worth.
-                group_room = max(self.mega_n - len(self._pending), 1)
-                requested = group_room * cfg_b.max_batch - self.batcher.fill
-                records = self.source.poll(requested)
-                if self._t0_auto and len(records):
-                    if self.precompact:
-                        t0 = int(schema.unwrap_kernel_ts16(
-                            records["w3"][:1],
-                            time.clock_gettime_ns(time.CLOCK_MONOTONIC),
-                        )[0])
+        with self.metrics.serve:
+            while not bounded():
+                with self.metrics.poll:
+                    # Mega mode polls up to the remaining GROUP capacity so
+                    # a deep source backlog can seal several batches in
+                    # one drain; otherwise exactly one batch's worth.
+                    group_room = max(self.mega_n - len(self._pending), 1)
+                    requested = (group_room * cfg_b.max_batch
+                                 - self.batcher.fill)
+                    records = self.source.poll(requested)
+                    if self._t0_auto and len(records):
+                        if self.precompact:
+                            t0 = int(schema.unwrap_kernel_ts16(
+                                records["w3"][:1],
+                                time.clock_gettime_ns(time.CLOCK_MONOTONIC),
+                            )[0])
+                        else:
+                            t0 = int(records["ts_ns"][0])
+                        self.batcher.t0_ns = t0
+                        if hasattr(self.sink, "t0_ns"):
+                            self.sink.t0_ns = t0  # sinks translate s -> abs ns
+                        self._t0_auto = False
+                    # the (simulated) kernel tier splits records exactly
+                    # where XDP would: after the drain, before the batcher.
+                    # n_polled drives the idle backoff below — a hot source
+                    # whose records all drop in-kernel is not an idle link.
+                    n_polled = len(records)
+                    if self._gov is not None and n_polled:
+                        # the governor observes the PRE-filter arrival
+                        # process (like the idle backoff): the burst shape
+                        # it forecasts is the link's, not the survivors'
+                        self._gov.note_arrivals(time.perf_counter(),
+                                                n_polled)
+                    if self.kernel_tier is not None and n_polled:
+                        records = self.kernel_tier.filter(records)
+                    if not len(records):
+                        sealed = []
+                        if self.precompact:
+                            # A drain opportunity with no records: note it so
+                            # the wrap-risk heuristic keys on drain cadence,
+                            # not traffic cadence (a lull is not a stall).
+                            self.batcher.note_poll()
+                    elif self.precompact:
+                        sealed = self.batcher.add_precompact(records)
                     else:
-                        t0 = int(records["ts_ns"][0])
-                    self.batcher.t0_ns = t0
-                    if hasattr(self.sink, "t0_ns"):
-                        self.sink.t0_ns = t0  # sinks translate s -> abs ns
-                    self._t0_auto = False
-                # the (simulated) kernel tier splits records exactly
-                # where XDP would: after the drain, before the batcher.
-                # n_polled drives the idle backoff below — a hot source
-                # whose records all drop in-kernel is not an idle link.
-                n_polled = len(records)
-                if self._gov is not None and n_polled:
-                    # the governor observes the PRE-filter arrival
-                    # process (like the idle backoff): the burst shape
-                    # it forecasts is the link's, not the survivors'
-                    self._gov.note_arrivals(time.perf_counter(),
-                                            n_polled)
-                if self.kernel_tier is not None and n_polled:
-                    records = self.kernel_tier.filter(records)
-                if not len(records):
-                    sealed = []
-                    if self.precompact:
-                        # A drain opportunity with no records: note it so
-                        # the wrap-risk heuristic keys on drain cadence,
-                        # not traffic cadence (a lull is not a stall).
-                        self.batcher.note_poll()
-                elif self.precompact:
-                    sealed = self.batcher.add_precompact(records)
+                        sealed = self.batcher.add(records)
+                    # The idle-pipe deadline-flush rule lives in
+                    # _deadline_flush_due (flush ONLY when the pipe is
+                    # fully drained, never mid-flight; SLO mode adds the
+                    # budget bound on batcher residency) — extracted so
+                    # the rule is tested directly, not just documented.
+                    if not sealed and self._deadline_flush_due():
+                        took = self.batcher.take()
+                        sealed = [took] if took is not None else []
+                if self.mega_n > 0:
+                    # Backlog-triggered grouping: full top-rung groups go
+                    # as one dispatch; the moment the source comes back
+                    # short (no deep backlog) the stragglers flush through
+                    # the largest staged rung they still fill (adaptive),
+                    # then singly — so grouping only ever ADDS latency to
+                    # batches that were queueing behind a backlog anyway.
+                    # Shortness is judged PRE-filter (n_polled): a flood
+                    # the kernel tier mostly drops still means a deep
+                    # source backlog, exactly when coalescing pays most.
+                    for raw in sealed:
+                        self._pending.append((raw, self._pop_stamps()))
+                    self._drain_pending(short=n_polled < requested)
                 else:
-                    sealed = self.batcher.add(records)
-                # The idle-pipe deadline-flush rule lives in
-                # _deadline_flush_due (flush ONLY when the pipe is
-                # fully drained, never mid-flight; SLO mode adds the
-                # budget bound on batcher residency) — extracted so
-                # the rule is tested directly, not just documented.
-                if not sealed and self._deadline_flush_due():
-                    took = self.batcher.take()
-                    sealed = [took] if took is not None else []
-            if self.mega_n > 0:
-                # Backlog-triggered grouping: full top-rung groups go
-                # as one dispatch; the moment the source comes back
-                # short (no deep backlog) the stragglers flush through
-                # the largest staged rung they still fill (adaptive),
-                # then singly — so grouping only ever ADDS latency to
-                # batches that were queueing behind a backlog anyway.
-                # Shortness is judged PRE-filter (n_polled): a flood
-                # the kernel tier mostly drops still means a deep
-                # source backlog, exactly when coalescing pays most.
-                for raw in sealed:
-                    self._pending.append((raw, self._pop_stamps()))
-                self._drain_pending(short=n_polled < requested)
-            else:
-                for raw in sealed:
-                    self._dispatch(raw, self._pop_stamps())
-                    self._reap(self.readback_depth)
-            # Latency path: sink whatever the device has finished, every
-            # iteration — including iterations that sealed nothing (the
-            # depth cap above only bounds the pipe; waiting for it to
-            # fill would defer verdicts by depth × batch-fill time).
-            self._reap_ready()
-            if not sealed and self.source.exhausted():
-                if self.batcher.fill:
-                    self._dispatch(self.batcher.take(), self._pop_stamps())
-                break
-            if not sealed and not n_polled:
-                if self._busy_depth() == 0:
-                    # Proactive rung pre-sizing (engine/predict.py):
-                    # inside the pre-warm lead window before a
-                    # forecast burst onset, spend this otherwise-idle
-                    # iteration re-dispatching the predicted rung with
-                    # a masked zero-valid batch — results untouched
-                    # (warm()'s masking argument), but the rung's
-                    # step-time EWMA refreshes launch-absorbed, so
-                    # _slo_cap prices the incoming burst with a HOT
-                    # measurement instead of a stale one and the XLA
-                    # executable/arena path is warm when the burst
-                    # lands.  Idle iterations only: a pre-warm must
-                    # never queue ahead of real traffic.
-                    if self._gov is not None:
-                        rung = self._gov.prewarm_rung(
-                            time.perf_counter(),
-                            self._rung_ewma_s.get(1, 0.0))
-                        if rung:
-                            # clamp the forecast rung to the READY set
-                            # (a tiered warm may still be filling):
-                            # pre-warming an uninstalled rung would
-                            # spend the idle window on an inline
-                            # compile instead of a hot re-dispatch
-                            self._prewarm_dispatch(
-                                self._rung_for(rung) if rung > 1
-                                else rung)
-                            continue
-                    # Idle link: back off instead of spinning poll() at
-                    # 100% CPU (sync/tuning.py IDLE_SLEEP_S, the
-                    # daemon-matched cadence).  A fraction of the batch
-                    # deadline keeps added latency under the flush
-                    # budget.
-                    with self.metrics.idle:
-                        time.sleep(tuning.idle_sleep_s(cfg_b.deadline_us))
-                elif self._sink_active:
-                    # Pipe busy, nothing new to dispatch: YIELD the GIL
-                    # (sync/tuning.py GIL_YIELD_S — a spinning dispatch
-                    # loop starved the sink thread's pure-Python
-                    # decode/writeback, measured 10-25 ms sinks).
-                    with self.metrics.idle:
-                        time.sleep(tuning.GIL_YIELD_S)
+                    for raw in sealed:
+                        self._dispatch(raw, self._pop_stamps())
+                        self._reap(self.readback_depth)
+                # Latency path: sink whatever the device has finished, every
+                # iteration — including iterations that sealed nothing (the
+                # depth cap above only bounds the pipe; waiting for it to
+                # fill would defer verdicts by depth × batch-fill time).
+                self._reap_ready()
+                if not sealed and self.source.exhausted():
+                    if self.batcher.fill:
+                        self._dispatch(self.batcher.take(), self._pop_stamps())
+                    break
+                if not sealed and not n_polled:
+                    if self._busy_depth() == 0:
+                        # Proactive rung pre-sizing (engine/predict.py):
+                        # inside the pre-warm lead window before a
+                        # forecast burst onset, spend this otherwise-idle
+                        # iteration re-dispatching the predicted rung with
+                        # a masked zero-valid batch — results untouched
+                        # (warm()'s masking argument), but the rung's
+                        # step-time EWMA refreshes launch-absorbed, so
+                        # _slo_cap prices the incoming burst with a HOT
+                        # measurement instead of a stale one and the XLA
+                        # executable/arena path is warm when the burst
+                        # lands.  Idle iterations only: a pre-warm must
+                        # never queue ahead of real traffic.
+                        if self._gov is not None:
+                            rung = self._gov.prewarm_rung(
+                                time.perf_counter(),
+                                self._rung_ewma_s.get(1, 0.0))
+                            if rung:
+                                # clamp the forecast rung to the READY set
+                                # (a tiered warm may still be filling):
+                                # pre-warming an uninstalled rung would
+                                # spend the idle window on an inline
+                                # compile instead of a hot re-dispatch
+                                self._prewarm_dispatch(
+                                    self._rung_for(rung) if rung > 1
+                                    else rung)
+                                continue
+                        # Idle link: back off instead of spinning poll() at
+                        # 100% CPU (sync/tuning.py IDLE_SLEEP_S, the
+                        # daemon-matched cadence).  A fraction of the batch
+                        # deadline keeps added latency under the flush
+                        # budget.
+                        with self.metrics.idle:
+                            time.sleep(tuning.idle_sleep_s(cfg_b.deadline_us))
+                    elif self._sink_active:
+                        # Pipe busy, nothing new to dispatch: YIELD the GIL
+                        # (sync/tuning.py GIL_YIELD_S — a spinning dispatch
+                        # loop starved the sink thread's pure-Python
+                        # decode/writeback, measured 10-25 ms sinks).
+                        with self.metrics.idle:
+                            time.sleep(tuning.GIL_YIELD_S)
 
-        # A bounded exit (max_batches/max_seconds) can in principle trip
-        # with sealed group candidates still pending (span-boundary
-        # partial seals make the per-iteration invariants fragile):
-        # dispatch them singly — their records are already counted in
-        # records_emitted, and leaving them would also wedge a later
-        # reset_stream on a genuinely idle engine.
-        for raw, stamps in self._pending:
-            self._dispatch(raw, stamps)
-        self._pending.clear()
+            # A bounded exit (max_batches/max_seconds) can in principle trip
+            # with sealed group candidates still pending (span-boundary
+            # partial seals make the per-iteration invariants fragile):
+            # dispatch them singly — their records are already counted in
+            # records_emitted, and leaving them would also wedge a later
+            # reset_stream on a genuinely idle engine.
+            for raw, stamps in self._pending:
+                self._dispatch(raw, stamps)
+            self._pending.clear()
         self._reap(0)
         with self.metrics.report:
             return self._build_report(time.perf_counter() - t_start)
@@ -2305,7 +2310,8 @@ class Engine:
                 return True
             return False
 
-        self._sealed_loop_arena(src, bounded)
+        with self.metrics.serve:
+            self._sealed_loop_arena(src, bounded)
         self._reap(0)
         with self.metrics.report:
             return self._build_report(time.perf_counter() - t_start)

@@ -15,8 +15,9 @@ stage boundary of the serving path is a :class:`Span`:
   the histogram alone, for durations measured elsewhere (the ingest
   workers' seal stamps, the staging memcpy inside a poll).
 
-:class:`LatencyRecorder` is the per-RECORD seal→verdict plane (ISSUE
-11) on the same histograms, with the closed stage chain of ISSUE 29.
+:class:`LatencyRecorder` is the per-RECORD seal→verdict plane on the
+same histograms, with its closed stage chain and, where records come
+through a feature ring, the ring wait ahead of it.
 Everything is cumulative since boot (or ``reset_stream``): the report's
 ``spans`` block carries every histogram's mergeable counts, so a
 reader gets any window by subtracting two reports, and the per-rank
@@ -73,7 +74,7 @@ class LatencyHist:
     ``add(seconds, n)`` charges ``n`` records one latency value (the
     engine's per-record accounting anchors every record of a batch at
     the batch's OLDEST-record stamp — a conservative per-record upper
-    bound, matching how ``e2e`` has always been anchored); ``merge``
+    bound); ``merge``
     sums another histogram in; ``percentile_us`` walks the cumulative
     counts.  ``to_counts()``/``from_counts()`` round-trip the nonzero
     buckets through JSON for the cluster per-rank merge."""
@@ -288,6 +289,12 @@ class LatencyRecorder:
     histograms weight by the entry's record count; an entry with zero
     valid records (warm) records nothing.
 
+    Ahead of the chain, where the batch came through a feature ring:
+    ``ring`` (the first record published in the ring → the worker took
+    it: the ingest worker's cursor log, a lower bound) and
+    ``arrival_to_verdict`` = ``ring`` + seal→verdict, entry by entry.
+    The inline path has no ring and records neither.
+
     ``negatives`` counts stage deltas that arrived negative (clock
     inversion between the seal and sink stamps) — the smoke gate pins
     it at 0 every run."""
@@ -300,6 +307,8 @@ class LatencyRecorder:
     def __init__(self) -> None:
         self.total = LatencyHist()
         self.stages = {s: LatencyHist() for s in self.STAGES}
+        self.ring = LatencyHist()
+        self.arrival = LatencyHist()
         self.negatives = 0
         self.slo_miss_records = 0
 
@@ -307,7 +316,8 @@ class LatencyRecorder:
                compute_s: float, sink_s: float, n: int,
                budget_s: float = 0.0, *, fill_s: float = 0.0,
                queue_s: float = 0.0, hold_s: float = 0.0,
-               device_s: float = 0.0, sink_host_s: float = 0.0) -> None:
+               device_s: float = 0.0, sink_host_s: float = 0.0,
+               ring_s: float | None = None) -> None:
         if n <= 0:
             return
         stages = (staged_s, upload_s, compute_s, sink_s,
@@ -316,18 +326,26 @@ class LatencyRecorder:
         self.total.add(max(total_s, 0.0), n)
         for name, v in zip(self.STAGES, stages):
             self.stages[name].add(max(v, 0.0), n)
+        if ring_s is not None:
+            ring_s = max(ring_s, 0.0)
+            self.ring.add(ring_s, n)
+            self.arrival.add(ring_s + max(total_s, 0.0), n)
         if budget_s and total_s > budget_s:
             self.slo_miss_records += n
 
     def hists(self) -> dict[str, LatencyHist]:
         """The plane's histograms under their ``spans`` names."""
         return {"latency.seal_to_verdict": self.total,
-                **{f"latency.{s}": h for s, h in self.stages.items()}}
+                **{f"latency.{s}": h for s, h in self.stages.items()},
+                "latency.ring": self.ring,
+                "latency.arrival_to_verdict": self.arrival}
 
     def merge(self, other: "LatencyRecorder") -> "LatencyRecorder":
         self.total.merge(other.total)
         for s in self.STAGES:
             self.stages[s].merge(other.stages[s])
+        self.ring.merge(other.ring)
+        self.arrival.merge(other.arrival)
         self.negatives += other.negatives
         self.slo_miss_records += other.slo_miss_records
         return self
@@ -339,6 +357,8 @@ class LatencyRecorder:
             "seal_to_verdict": self.total.to_dict(),
             "stages": {s: self.stages[s].to_dict()
                        for s in self.STAGES},
+            "ring": self.ring.to_dict(),
+            "arrival_to_verdict": self.arrival.to_dict(),
             "compute_is_wall": bool(compute_is_wall),
             "negatives": int(self.negatives),
             "hist": self.total.to_counts(),
@@ -355,21 +375,25 @@ class LatencyRecorder:
 
 class WorkerIngestMetrics:
     """Per-drain-worker spans of the sharded ingest subsystem
-    (flowsentryx_tpu/ingest/): ``fill`` is first-record-arrival → seal
-    inside the worker (the parallelized decode/assembly stage), ``queue``
-    is seal → engine dequeue (sealed-batch queue residency — the
-    pipelining debt the engine's dispatch loop imposes).  One sample a
-    sealed batch, unweighted (the latency plane's ``fill``/``queue``
+    (flowsentryx_tpu/ingest/): ``ring`` is the batch's first record's
+    wait in the feature ring before the worker took it (its cursor log,
+    a lower bound), ``fill`` is first-record-arrival → seal inside the
+    worker (the parallelized decode/assembly stage), ``queue`` is seal
+    → engine dequeue (sealed-batch queue residency — the pipelining
+    debt the engine's dispatch loop imposes).  One sample a sealed
+    batch, unweighted (the latency plane's ``ring``/``fill``/``queue``
     weigh by records and charge an entry's oldest batch).  Surfaced per
     worker in the engine report's ``ingest`` block, and in ``spans``."""
 
     def __init__(self, worker: int):
         self.worker = worker
+        self.ring = Span(f"fsx.ingest.w{worker}.ring")
         self.fill = Span(f"fsx.ingest.w{worker}.fill")
         self.queue = Span(f"fsx.ingest.w{worker}.queue")
 
     def to_dict(self) -> dict:
         return {
+            "ring_ms": self.ring.percentiles_ms(),
             "fill_ms": self.fill.percentiles_ms(),
             "queue_ms": self.queue.percentiles_ms(),
         }
@@ -381,7 +405,11 @@ class PipelineMetrics:
     parts, ``pop`` and ``stage`` — so a thread's time is the sum of its
     spans, and the rest of its wall is what it did unnamed.
 
-    Dispatch thread: ``poll`` (the sealed loops' ``poll_batches_into``;
+    Dispatch thread: ``serve`` (one a ``run()`` call, from its first
+    poll until its loop stops launching: before the drain and the
+    report; it holds the dispatch thread's other spans and marks the
+    serving window on the trace's clock), ``poll`` (the sealed loops'
+    ``poll_batches_into``;
     the inline loop's source poll + batcher pack), of which the sealed
     path counts ``pop`` (queue peek + header decode + seq bookkeeping)
     and ``stage`` (the ONE shm-slot-view → dispatch-arena memcpy of
@@ -396,12 +424,13 @@ class PipelineMetrics:
     Sink section (the sink thread; the dispatch thread in
     single-thread mode): ``wait`` (nothing queued), ``fetch`` (the
     D2H: on an async backend the wait on the device), ``decode``,
-    ``apply`` (writeback, gossip publish, book-keeping, ``on_reap``);
-    ``e2e`` is first record in → sunk, one sample an in-flight entry.
+    ``apply`` (writeback, gossip publish, book-keeping, the latency
+    plane, ``on_reap``).
 
     ``stages_ms`` is the older face of the same histograms."""
 
     def __init__(self) -> None:
+        self.serve = Span("fsx.serve")
         self.poll = Span("fsx.dispatch.poll")
         self.pop = Span("fsx.dispatch.pop")
         self.stage = Span("fsx.dispatch.stage")
@@ -414,7 +443,6 @@ class PipelineMetrics:
         self.fetch = Span("fsx.sink.fetch")
         self.decode = Span("fsx.sink.decode")
         self.apply = Span("fsx.sink.apply")
-        self.e2e = Span("fsx.e2e")
 
     def spans(self) -> tuple[Span, ...]:
         """Every span: the attributes are the spans and nothing else."""
@@ -427,6 +455,5 @@ class PipelineMetrics:
             for name, span in (("fill", self.poll), ("pop", self.pop),
                                ("stage", self.stage),
                                ("dispatch", self.launch),
-                               ("readback", self.fetch),
-                               ("e2e", self.e2e))
+                               ("readback", self.fetch))
         }

@@ -146,16 +146,19 @@ class ShmRing:
         self._tail[0] = t + n     # publish after the copy
         return out
 
-    def peek(self, max_records: int) -> tuple[list[np.ndarray], int]:
+    def peek(self, max_records: int,
+             head: int | None = None) -> tuple[list[np.ndarray], int]:
         """Zero-copy drain half: up to two contiguous VIEWS of the
         oldest readable records, without releasing them.  SPSC makes
         this safe — the producer cannot overwrite a slot until
         :meth:`advance` moves the tail — so a consumer that transforms
         records anyway (the ingest drain workers packing compact16) can
         skip the :meth:`consume` copy entirely.  Views die at
-        ``advance``; copy anything that must outlive it."""
+        ``advance``; copy anything that must outlive it.  ``head``, a
+        producer cursor the caller already read (:meth:`head`), bounds
+        the records to those published by then."""
         t = int(self._tail[0])
-        h = int(self._head[0])
+        h = int(self._head[0]) if head is None else head
         n = min(h - t, max_records)
         if n <= 0:
             return [], 0
@@ -193,6 +196,10 @@ class ShmRing:
         """The reader's cursor (a producer watches it for progress)."""
         return int(self._tail[0])
 
+    def head(self) -> int:
+        """The writer's cursor (a reader logs when it moves)."""
+        return int(self._head[0])
+
 
 class SealedBatchQueue:
     """SPSC shared-memory queue of SEALED wire buffers — the ingest
@@ -201,7 +208,8 @@ class SealedBatchQueue:
 
     Same header geometry and x86-TSO plain-store cursor protocol as
     :class:`ShmRing`, but each "record" is one batch SLOT: an 8-word
-    header (seq / n_records / wire_id / seal time / fill duration — the
+    header (seq / n_records / wire_id / seal time / fill duration /
+    ring wait — the
     cross-process batch contract, documented at
     ``schema.SHM_BATCHQ_MAGIC``) followed by a ``[max_batch+1, words]``
     wire buffer.  The meta cache line additionally carries the worker
@@ -243,6 +251,8 @@ class SealedBatchQueue:
                 ("stop", schema.SHM_STOP_OFFSET),
                 ("wstate", schema.SHM_WSTATE_OFFSET),
                 ("emit_drop", schema.SHM_EMIT_DROP_OFFSET),
+                ("ring_wait_clamped", schema.SHM_RING_WAIT_CLAMPED_OFFSET),
+                ("ring_observes", schema.SHM_RING_OBSERVES_OFFSET),
                 ("spin_us", schema.SHM_SPIN_US_OFFSET),
                 ("idle_us", schema.SHM_IDLE_US_OFFSET),
             )
@@ -311,6 +321,7 @@ class SealedBatchQueue:
         wire_id: int,
         seal_ns: int,
         fill_dur_us: int,
+        ring_wait_us: int = 0,
     ) -> bool:
         """Copy one sealed wire buffer in; False when the queue is full
         (the worker retries — backpressure propagates to the shard ring
@@ -331,7 +342,8 @@ class SealedBatchQueue:
         cell[schema.BATCHQ_SEAL_NS_HI_WORD] = (seal_ns >> 32) & 0xFFFFFFFF
         cell[schema.BATCHQ_FILL_DUR_US_WORD] = min(int(fill_dur_us),
                                                    0xFFFFFFFF)
-        cell[schema.BATCHQ_RESERVED_WORD] = 0
+        cell[schema.BATCHQ_RING_WAIT_US_WORD] = min(int(ring_wait_us),
+                                                    0xFFFFFFFF)
         cell[schema.BATCHQ_SLOT_HDR_WORDS:] = payload.reshape(-1)
         self._head[0] = h + 1  # publish after the copy
         return True

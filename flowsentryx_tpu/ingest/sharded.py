@@ -26,7 +26,7 @@ Responsibilities here:
   ignored; the remaining shards keep serving (the kernel limiter stands
   alone for the dead shard's flows, the same posture as every other
   degradation in this system).
-* **metrics** — per-worker fill and queue-residency timers
+* **metrics** — per-worker ring-wait, fill and queue-residency timers
   (:class:`~flowsentryx_tpu.engine.metrics.WorkerIngestMetrics`)
   surfaced through the engine report.
 """
@@ -71,6 +71,10 @@ class SealedBatch(NamedTuple):
     #: latency plane's ``queue`` stage ends and ``hold`` begins.  0.0 =
     #: not stamped (a stand-in source); the engine then stamps its own.
     t_dequeue: float = 0.0
+    #: the first record's wait in the feature ring before ``t_enqueue``
+    #: (seconds, the slot header's word 7).  None = not stamped (a
+    #: stand-in source): the latency plane then records no ring stage.
+    ring_s: float | None = None
 
 
 class SeqTracker:
@@ -457,7 +461,8 @@ class ShardedIngest:
 
     def _note_batch(self, wid: int, hdr: np.ndarray) -> tuple:
         """Header decode + per-worker bookkeeping shared by both
-        dequeue paths: ``(seq, n_records, t_seal, fill_s, t_dequeue)``."""
+        dequeue paths: ``(seq, n_records, t_seal, fill_s, t_dequeue,
+        ring_s)``."""
         seq = (int(hdr[schema.BATCHQ_SEQ_LO_WORD])
                | (int(hdr[schema.BATCHQ_SEQ_HI_WORD]) << 32))
         n = int(hdr[schema.BATCHQ_N_RECORDS_WORD])
@@ -467,15 +472,17 @@ class ShardedIngest:
         seal_ns = (int(hdr[schema.BATCHQ_SEAL_NS_LO_WORD])
                    | (int(hdr[schema.BATCHQ_SEAL_NS_HI_WORD]) << 32))
         fill_s = int(hdr[schema.BATCHQ_FILL_DUR_US_WORD]) * 1e-6
+        ring_s = int(hdr[schema.BATCHQ_RING_WAIT_US_WORD]) * 1e-6
         t_seal = seal_ns * 1e-9
         self._seqs.note(wid, seq)
         self._batches[wid] += 1
         self._records[wid] += n
         t_dequeue = time.perf_counter()
         m = self._metrics[wid]
+        m.ring.add(ring_s)
         m.fill.add(fill_s)
         m.queue.add(t_dequeue - t_seal)
-        return seq, n, t_seal, fill_s, t_dequeue
+        return seq, n, t_seal, fill_s, t_dequeue, ring_s
 
     def _slot_problem(self, hdr: np.ndarray,
                       meta: np.ndarray) -> tuple[str, str] | None:
@@ -582,7 +589,8 @@ class ShardedIngest:
                     self._discard_slot(wid, hdr, payload, *prob)
                     wid = (wid + 1) % n_q
                     continue
-                seq, n, t_seal, fill_s, t_deq = self._note_batch(wid, hdr)
+                (seq, n, t_seal, fill_s, t_deq,
+                 ring_s) = self._note_batch(wid, hdr)
                 out.append(SealedBatch(
                     raw=rows,
                     n_records=n,
@@ -591,6 +599,7 @@ class ShardedIngest:
                     worker=wid,
                     seq=seq,
                     t_dequeue=t_deq,
+                    ring_s=ring_s,
                 ))
             wid = (wid + 1) % n_q
         self._rr = wid
@@ -658,7 +667,8 @@ class ShardedIngest:
                     self._discard_slot(wid, hdr, row, *prob)
                     wid = (wid + 1) % n_q
                     continue
-                seq, n, t_seal, fill_s, t_deq = self._note_batch(wid, hdr)
+                (seq, n, t_seal, fill_s, t_deq,
+                 ring_s) = self._note_batch(wid, hdr)
                 out.append(SealedBatch(
                     raw=row,
                     n_records=n,
@@ -667,6 +677,7 @@ class ShardedIngest:
                     worker=wid,
                     seq=seq,
                     t_dequeue=t_deq,
+                    ring_s=ring_s,
                 ))
             wid = (wid + 1) % n_q
         self._rr = wid
@@ -694,9 +705,10 @@ class ShardedIngest:
     # -- reporting ----------------------------------------------------------
 
     def ingest_spans(self) -> list:
-        """Every worker's ``fill`` and ``queue`` span, for the engine
-        report's ``spans`` block."""
-        return [sp for m in self._metrics for sp in (m.fill, m.queue)]
+        """Every worker's ``ring``, ``fill`` and ``queue`` span, for the
+        engine report's ``spans`` block."""
+        return [sp for m in self._metrics
+                for sp in (m.ring, m.fill, m.queue)]
 
     def ingest_stats(self) -> dict:
         assert self._seqs is not None
@@ -708,6 +720,9 @@ class ShardedIngest:
                 "seq_gaps": self._seqs.gaps[k],
                 "seq_missing": self._seqs.missing[k],
                 "dropped_emit_batches": self._queues[k].ctl_get("emit_drop"),
+                "ring_wait_clamped": self._queues[k].ctl_get(
+                    "ring_wait_clamped"),
+                "ring_observes": self._queues[k].ctl_get("ring_observes"),
                 "bad_wire_slots": self._bad_slots[k],
                 "quarantined_batches": self._quarantined[k],
                 "dead": k in self._dead,

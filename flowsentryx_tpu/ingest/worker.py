@@ -17,7 +17,8 @@ control block):
 3. **RUNNING** — drain → decode/quantize → seal → enqueue.  A full
    queue is backpressure: the worker retries, the ring fills, the
    daemon's drop counters account the loss (fail-open, same policy as
-   the kernel ringbuf).
+   the kernel ringbuf).  Each sealed batch carries its first record's
+   wait in the ring (:class:`CursorLog`).
 4. ``STOP`` observed — drain the ring to empty, flush the partial
    batch, publish **DONE**, exit.  Crashes publish **FAILED** (best
    effort) and leave the traceback on stderr; the engine fails open.
@@ -96,14 +97,85 @@ def _monotonic_ns() -> int:
     return time.clock_gettime_ns(time.CLOCK_MONOTONIC)
 
 
-class _Emitter:
-    """Seal-side bookkeeping: batch header fields + queue backpressure."""
+#: Entries of a worker's :class:`CursorLog`: one a look that found the
+#: producer cursor moved, so seconds of history at the rate a worker
+#: looks; no per-record storage.
+CURSOR_LOG_ENTRIES = 1 << 16
 
-    def __init__(self, queue, batcher, wire_id: int, max_batch: int):
+
+class CursorLog:
+    """When the ring's producer cursor was seen to move: a fixed ring of
+    ``(head, t_ns)`` pairs on CLOCK_MONOTONIC (the clock of the seal
+    stamps), appended by :meth:`observe` whenever ``head`` has moved
+    since the last look.  The worker looks at every poll, in the emit
+    loop's wait on a full queue and after each idle sleep.
+
+    :meth:`published_ns` dates a record as the first entry whose head
+    passed its cursor: never before the record was published, and after
+    it by at most one observation interval, so a wait computed from it
+    is a lower bound.  A cursor older than the log (its entry
+    overwritten, or the record already there at the first look) is
+    dated by the oldest entry and counted in ``clamped``: the wait is
+    then understated by an unknown amount."""
+
+    def __init__(self, ring, entries: int = CURSOR_LOG_ENTRIES):
+        if entries < 2 or entries & (entries - 1):
+            raise ValueError(f"entries must be a power of two >= 2, "
+                             f"got {entries}")
+        self._ring = ring
+        self._heads = np.zeros(entries, np.int64)
+        self._t_ns = np.zeros(entries, np.int64)
+        self._mask = entries - 1
+        self.n = 0          # entries ever appended
+        self.last = -1      # head at the newest entry
+        self.observes = 0   # looks at the cursor
+        self.clamped = 0
+
+    def observe(self) -> int:
+        """Look at the producer cursor, log it if it moved, return it."""
+        self.observes += 1
+        h = self._ring.head()
+        if h != self.last:
+            i = self.n & self._mask
+            self._heads[i] = h
+            self._t_ns[i] = _monotonic_ns()
+            self.n += 1
+            self.last = h
+        return h
+
+    def published_ns(self, cursor: int) -> int:
+        """When the record at ``cursor`` was seen published (ns).  A
+        cursor no entry has passed yet was published after the last
+        look: it is dated now."""
+        oldest = max(self.n - self._mask - 1, 0)
+        lo, hi = oldest, self.n
+        if lo == hi or self.last <= cursor:
+            return _monotonic_ns()
+        while lo < hi:  # heads rise strictly along the log
+            mid = (lo + hi) // 2
+            if self._heads[mid & self._mask] > cursor:
+                hi = mid
+            else:
+                lo = mid + 1
+        if lo == oldest:
+            self.clamped += 1
+        return int(self._t_ns[lo & self._mask])
+
+
+class _Emitter:
+    """Seal-side bookkeeping: batch header fields + queue backpressure.
+    ``cursor0`` is the ring cursor of the first record the worker took:
+    records reach the batcher in ring order, so a batch's first record
+    lies at ``cursor0`` + the records sealed before it."""
+
+    def __init__(self, queue, batcher, wire_id: int, max_batch: int,
+                 log: CursorLog, cursor0: int):
         self.q = queue
         self.batcher = batcher
         self.wire_id = wire_id
         self.max_batch = max_batch
+        self.log = log
+        self.cursor0 = cursor0
         self.seq = 0
 
     def emit(self, buf: np.ndarray, stopping: bool) -> None:
@@ -111,6 +183,11 @@ class _Emitter:
         first_add_t = self.batcher.pop_seal_time()
         seal_ns = _monotonic_ns()
         fill_dur_us = max(0, int(seal_ns / 1e3 - first_add_t * 1e6))
+        published_ns = self.log.published_ns(
+            self.cursor0 + self.batcher.records_emitted - n)
+        ring_wait_us = max(0, int(first_add_t * 1e6 - published_ns / 1e3))
+        self.q.ctl_set("ring_wait_clamped", self.log.clamped)
+        self.q.ctl_set("ring_observes", self.log.observes)
         self.seq += 1
         deadline = (time.monotonic() + EMIT_STOP_TIMEOUT_S
                     if stopping else None)
@@ -121,6 +198,7 @@ class _Emitter:
             wire_id=self.wire_id,
             seal_ns=seal_ns,
             fill_dur_us=fill_dur_us,
+            ring_wait_us=ring_wait_us,
         ):
             # Queue full: backpressure.  While stopping the consumer may
             # already be gone — bound the wait so shutdown can't hang.
@@ -135,6 +213,9 @@ class _Emitter:
                 return
             self.q.ctl_set("hbeat", _monotonic_ns())
             time.sleep(IDLE_SLEEP_S)
+            # records published while the queue is full are dated here,
+            # not when the wait ends
+            self.log.observe()
 
 
 def worker_main(spec: dict) -> None:
@@ -178,6 +259,8 @@ def worker_main(spec: dict) -> None:
             verdict_k=0,
         )
         poll_chunk = 2 * cfg.max_batch
+        log = CursorLog(src.ring)
+        cursor0 = src.ring.tail()
         emitter = None
         pending: list[np.ndarray] = []
         pending_n = 0
@@ -204,7 +287,7 @@ def worker_main(spec: dict) -> None:
             # Zero-copy drain: pack straight out of the ring slots and
             # release them afterwards — at Mpps rates the consume()
             # memcpy was a fifth of the whole worker budget.
-            chunks, n_polled = src.ring.peek(poll_chunk)
+            chunks, n_polled = src.ring.peek(poll_chunk, log.observe())
             if n_polled and q.ctl_get("first_ts") == 0:
                 head = chunks[0]
                 if src.precompact:
@@ -246,7 +329,8 @@ def worker_main(spec: dict) -> None:
                     quant=spec.get("quant") or None,
                 )
                 emitter = _Emitter(
-                    q, batcher, schema.wire_id_of(wire), cfg.max_batch
+                    q, batcher, schema.wire_id_of(wire), cfg.max_batch,
+                    log, cursor0,
                 )
                 for r in pending:
                     add(batcher, r)
@@ -281,7 +365,8 @@ def worker_main(spec: dict) -> None:
                 # was the dominant empty-ring wakeup latency at high
                 # rates — a burst landing just after the sleep started
                 # waited the whole 200 µs plus reschedule).
-                backoff.idle()
+                if backoff.idle():
+                    log.observe()
             else:
                 backoff.reset()
     except Exception:

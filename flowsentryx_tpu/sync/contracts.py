@@ -336,9 +336,9 @@ ENGINE_PLAN = ClassPlan(
         "metrics": FieldContract(
             "documented",
             "the spans (metrics.PipelineMetrics), one writing thread "
-            "each: poll/pop/stage/backpressure/idle/report on the "
-            "dispatch thread, upload/launch in the launch section, "
-            "sink_wait/fetch/decode/apply/e2e in the sink section"),
+            "each: serve/poll/pop/stage/backpressure/idle/report on "
+            "the dispatch thread, upload/launch in the launch section, "
+            "sink_wait/fetch/decode/apply in the sink section"),
         "sink": FieldContract(
             "documented",
             "t0_ns written on the dispatch thread only before the "
@@ -828,7 +828,8 @@ CURSORS: tuple[CursorPlan, ...] = (
 #: writer side" — this is that claim, checkable).
 CTL_WRITERS: dict[str, str] = {
     "hbeat": "worker", "first_ts": "worker", "wstate": "worker",
-    "emit_drop": "worker",
+    "emit_drop": "worker", "ring_wait_clamped": "worker",
+    "ring_observes": "worker",
     "t0": "engine", "stop": "engine", "spin_us": "engine",
     "idle_us": "engine",
     # cluster status block (cluster/mailbox.py StatusBlock): the

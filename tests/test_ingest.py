@@ -108,11 +108,15 @@ class TestSealedBatchQueue:
         q = SealedBatchQueue.create(tmp_path / "q", 2, payload_words)
 
         class _StubBatcher:
+            records_emitted = 0
+
             def pop_seal_time(self):
                 return time.perf_counter()
 
+        ring = ShmRing.create(tmp_path / "r", 16, schema.FLOW_RECORD_DTYPE)
         em = worker_mod._Emitter(
-            q, _StubBatcher(), schema.WIRE_ID_RAW48, max_batch)
+            q, _StubBatcher(), schema.WIRE_ID_RAW48, max_batch,
+            worker_mod.CursorLog(ring), 0)
         buf = np.zeros((max_batch + 1, words), np.uint32)
         buf[max_batch, 0] = 2
         em.emit(buf, stopping=False)  # seq 1

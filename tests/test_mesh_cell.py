@@ -343,7 +343,7 @@ def test_benchmark_json_gained_one_configuration_one_cell_four_readers():
     assert b["workloads"][-1] == dict(b["workloads"][-1], name=CELL,
                                       config=MESH, chips=4)
     assert sum(w["chips"] == 4 for w in b["workloads"]) == 1
-    new = b["per_layer"][-len(READERS):]
+    new = [m for m in b["per_layer"] if m["name"] in READERS]
     assert [m["name"] for m in new] == list(READERS)
     assert all(m["workloads"] == [CELL] for m in new)
     joined = {m["name"] for m in b["end_to_end"] + b["per_layer"]
@@ -351,6 +351,7 @@ def test_benchmark_json_gained_one_configuration_one_cell_four_readers():
     assert joined == {
         "records_per_s", "gen.blocked.tput", "dispatch.occupancy.tput",
         "dispatch.host_busy.tput", "device.idle.tput",
+        "device.idle_serving.tput",
         "step.device_ms_per_batch.tput", "sink.host_busy.tput",
         "sink.fallback_share.tput"} | {
         f"step.stage_{s}_ms.tput" for s in fused.STEP_SCOPES + ("unscoped",)}

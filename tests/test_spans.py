@@ -212,9 +212,10 @@ class TestSpanStore:
         assert {f"latency.{s}" for s in LatencyRecorder.STAGES} <= set(sp)
         for face, name in (("fill", "fsx.dispatch.poll"),
                            ("dispatch", "fsx.dispatch.launch"),
-                           ("readback", "fsx.sink.fetch"),
-                           ("e2e", "fsx.e2e")):
+                           ("readback", "fsx.sink.fetch")):
             assert rep.stages_ms[face]["n"] == sp[name]["n"] > 0
+        # every sunk record is charged once by the latency plane
+        assert sp["latency.seal_to_verdict"]["n"] == rep.records
         for entry in sp.values():
             assert set(entry) == {"n", "sum_us", "max_us", "hist"}
             assert entry["hist"]["scheme"] == "log2x16us"

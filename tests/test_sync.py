@@ -48,6 +48,8 @@ class TestContractsOnRepo:
         declared = set(contracts.CTL_WRITERS)
         assert declared == {"hbeat", "first_ts", "t0", "stop",
                             "wstate", "emit_drop", "spin_us", "idle_us",
+                            # the worker's cursor log (its ring wait)
+                            "ring_wait_clamped", "ring_observes",
                             # cluster status block (PR 10): engine line
                             "c_hbeat", "c_state", "c_batches", "c_records",
                             # supervisor line (c_t0_wall: ISSUE 15,
