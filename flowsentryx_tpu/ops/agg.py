@@ -45,10 +45,13 @@ class FlowAgg(NamedTuple):
     gives ``inv``, ``[B]`` mapping each input packet position to its
     flow's entry, so per-flow decisions broadcast back to packets as
     ``decision[inv]``.  The fused step
-    (:func:`flowsentryx_tpu.ops.fused.make_step`) leaves each flow at
-    the last position of its run in ITS sort's order, padding between,
-    and hands verdicts back along the runs itself: its ``inv`` is
-    ``None``.  Padding entries read ``INVALID_KEY`` and zeros.
+    (:func:`flowsentryx_tpu.ops.fused.make_step`) and the sharded
+    step's slice aggregation
+    (:func:`flowsentryx_tpu.parallel.step.aggregate_slice`) leave each
+    flow at the last position of its run in their own sort's order,
+    padding between, and hand verdicts back along the runs themselves:
+    their ``inv`` is ``None``.  Padding entries read ``INVALID_KEY``
+    and zeros.
     """
 
     rep_key: jnp.ndarray    # [B] uint32, INVALID_KEY padded

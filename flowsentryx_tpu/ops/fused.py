@@ -782,9 +782,9 @@ def make_step(
         # back the same way: spread over the run in sorted order
         # (:func:`spread_from_tails`), then one sort keyed on the
         # first sort's permutation puts it in the batch's order.  The
-        # sharded path keeps the two-stage composition (it aggregates
-        # before any table exists on the owner side); parity is pinned
-        # by tests/test_fused.py.
+        # sharded step aggregates its slice the same way, with no slot
+        # key (parallel/step.py::aggregate_slice); parity with the
+        # two-stage composition is pinned by tests/test_fused.py.
         # The ``fsx.<stage>`` scopes (STEP_SCOPES) are metadata only:
         # the compiled program is the same with or without them.
         b = batch.key.shape[0]

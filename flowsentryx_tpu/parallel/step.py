@@ -8,8 +8,9 @@ This is the scale-out analog of SURVEY.md §2.3's parallelism table:
   the *low* bits — ownership and probing use disjoint bits, and a key's
   owner never changes, so limiter state never migrates between devices.
 * **Data parallelism** — each device parses, scores, and locally
-  aggregates its ``B/n`` slice of the packet batch (sort, classifier
-  matmul, and segment ops all shrink with the mesh).
+  aggregates its ``B/n`` slice of the packet batch (classifier matmul,
+  and the one-device step's one sort and segmented scan,
+  :func:`aggregate_slice`, all shrink with the mesh).
 * **Flow routing** — local per-flow partial aggregates are routed to
   their owner device with one ``all_to_all`` (ICI); the owner merges
   partials (a flow's packets may land on several devices' slices),
@@ -52,7 +53,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,82 @@ def empty_table_fn(capacity: int, mesh: Mesh):
 def make_sharded_table(cfg: FsxConfig, mesh: Mesh) -> IpTableState:
     """Fresh empty table of ``cfg.table.capacity`` rows, row-sharded."""
     return empty_table_fn(cfg.table.capacity, mesh)()
+
+
+class SliceRuns(NamedTuple):
+    """A device's slice in its aggregation sort's order: what hands each
+    flow's verdict back to its records (:func:`slice_verdicts`)."""
+
+    order: jnp.ndarray  # [b] int32: slice position of each sorted record
+    tail: jnp.ndarray   # [b] bool: last position of each key's run
+    mal: jnp.ndarray    # [b] bool: the record scored malicious
+    valid: jnp.ndarray  # [b] bool
+
+
+def aggregate_slice(key, pkt_len, ts, valid, mal
+                    ) -> tuple[agg.FlowAgg, jnp.ndarray, SliceRuns]:
+    """The one-device step's aggregation (``fused.make_step``'s
+    ``fsx.aggregate``) on a device's ``[b]`` slice, where no table
+    decides a slot: one stable sort by the sanitised key (0 →
+    ``0xFFFFFFFE``, an invalid row → ``INVALID_KEY``, as
+    :func:`agg.aggregate`) carrying the record's length, time, and one
+    word of its place and two flags, then one :func:`fused.scan_runs`
+    over the runs.  No gather and no scatter.
+
+    Returns ``(flows, [b] f32 malicious records of each flow, runs)``.
+    The flows lie at their runs' last positions, in key order, under
+    ``rep_valid``, padding between (``inv`` is ``None``); they, their
+    counts and their order among themselves are :func:`agg.aggregate`'s
+    bit for bit while a run's bytes stay under 2^24 (whole numbers)."""
+    b = key.shape[0]
+    key = jnp.where(key == 0, jnp.uint32(0xFFFFFFFE), key)
+    key = jnp.where(valid, key, agg.INVALID_KEY)
+    tag = (jnp.arange(b, dtype=jnp.int32) * 4
+           + 2 * mal.astype(jnp.int32) + valid.astype(jnp.int32))
+    key_s, tag_s, len_s, ts_s = jax.lax.sort((key, tag, pkt_len, ts),
+                                             num_keys=1)
+    valid_s = (tag_s & 1) > 0
+    mal_s = (tag_s & 2) > 0
+    one = jnp.ones((1,), bool)
+    head = jnp.concatenate([one, key_s[1:] != key_s[:-1]])
+    tail = jnp.concatenate([head[1:], one])
+    runs = fused.scan_runs(
+        head,
+        jnp.stack([valid_s.astype(jnp.float32),
+                   jnp.where(valid_s, len_s, 0.0),
+                   mal_s.astype(jnp.float32),
+                   jnp.where(valid_s, ts_s, -jnp.inf)]),
+        maxed=(False, False, False, True))
+    rep_valid = tail & (runs[0] > 0)
+    pkts, bytes_, ml_count, ts_max = jnp.where(rep_valid, runs, 0.0)
+    fa = agg.FlowAgg(rep_key=jnp.where(rep_valid, key_s, agg.INVALID_KEY),
+                     rep_pkts=pkts, rep_bytes=bytes_, rep_ts=ts_max,
+                     rep_valid=rep_valid, inv=None)
+    return fa, ml_count, SliceRuns(order=tag_s >> 2, tail=tail, mal=mal_s,
+                                   valid=valid_s)
+
+
+def slice_verdicts(runs: SliceRuns, rep_verdict: jnp.ndarray) -> jnp.ndarray:
+    """Each record's verdict, in the slice's order, from its flow's
+    verdict at the run's tail (``[b]`` int32, codes under 128,
+    ``ML_RECORD_GATE`` among them): spread over the run, gated record
+    by record, then one sort keyed on the aggregation sort's
+    permutation puts it back, the code in the two bits under the
+    place."""
+    verdict_s = fused.gate_record_verdicts(
+        fused.spread_from_tails(runs.tail, rep_verdict), runs.mal,
+        runs.valid)
+    return jax.lax.sort(runs.order * 4 + verdict_s, is_stable=False) & 3
+
+
+def fail_open_records(fa: agg.FlowAgg, overflow: jnp.ndarray) -> jnp.ndarray:
+    """``[]`` uint32: the records of the flows that ``overflow`` (``[b]``
+    bool, at the flows' places) marks, counted at each flow.  A flow
+    holds at most the slice's ``b`` records: the clip states the bound
+    that ``fsx ranges`` cannot read through the scan's float sums."""
+    b = overflow.shape[0]
+    pkts = jnp.clip(fa.rep_pkts, 0, b).astype(jnp.uint32)
+    return jnp.sum(jnp.where(overflow, pkts, 0))
 
 
 def merge_partials(r: jnp.ndarray) -> tuple[agg.FlowAgg, jnp.ndarray]:
@@ -266,12 +343,11 @@ def make_sharded_step(
             score_l = classify_batch(params, feat_l)             # [local_b]
             mal_l = (score_l > cfg.model.threshold) & valid_l
         with jax.named_scope("fsx.aggregate"):
-            fa = agg.aggregate(key_l, len_l, ts_l, valid_l)
-            # per-local-flow COUNT of malicious records (vote evidence;
-            # owner-side merge SUMS partials so a flow spanning slices
-            # votes with its full record count)
-            ml_l = (jnp.zeros((local_b,), jnp.float32)
-                    .at[fa.inv].add(mal_l.astype(jnp.float32)))
+            # ml_l: per-local-flow COUNT of malicious records (vote
+            # evidence; owner-side merge SUMS partials so a flow
+            # spanning slices votes with its full record count)
+            fa, ml_l, runs_l = aggregate_slice(key_l, len_l, ts_l, valid_l,
+                                               mal_l)
 
         # --- route local flow partials to their owner ----------------------
         bits = jax.lax.bitcast_convert_type
@@ -334,14 +410,10 @@ def make_sharded_step(
             # the ML_RECORD_GATE sentinel rides the verdict all_to_all
             # and resolves per record HERE, where the local slice's
             # scores live
-            verdict_l = fused.resolve_record_verdicts(rep_verdict, fa.inv,
-                                                      mal_l, valid_l)
+            verdict_l = slice_verdicts(runs_l, rep_verdict)
 
             # --- stats: local counts, one psum -----------------------------
-            route_drop_l = jnp.sum(
-                jnp.where(valid_l, overflow[fa.inv].astype(jnp.uint32),
-                          jnp.uint32(0))
-            )
+            route_drop_l = fail_open_records(fa, overflow)
             # each shard's probe decides on its own keys whether it reads
             # last_seen; a batch counts once in stale_reads if any did.
             # That joins the ONE existing scalar psum, and where the table

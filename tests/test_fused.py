@@ -58,9 +58,9 @@ def make_env(cfg=CFG):
 
 
 def two_stage_step(cfg, spec, params):
-    """The aggregate → assign_slots → core composition the sharded path
-    still uses, as ``step(table, stats, batch) -> (table, stats,
-    verdict, block_key, block_until)``: the reference twin of
+    """The aggregate → assign_slots → core composition that both steps
+    ran before their one sort, as ``step(table, stats, batch) -> (table,
+    stats, verdict, block_key, block_until)``: the reference twin of
     ``make_step``.  Its flows lie at the front in KEY order, so its
     block arrays agree with the fused step's as sets, not by place."""
     from flowsentryx_tpu.ops import agg

@@ -531,3 +531,110 @@ class TestOwnerRungs:
                 if a is not None:
                     np.testing.assert_array_equal(np.asarray(a),
                                                   np.asarray(b), f)
+
+
+def _slice_case(name: str, b: int, rng):
+    """``(key, pkt_len, ts, valid, mal)`` of one device's slice."""
+    key = rng.integers(1, b // 4, b).astype(np.uint32)
+    valid = rng.random(b) < 0.9
+    if name == "key_zero":
+        # 0 is sanitised to 0xFFFFFFFE and so shares its flow; a valid
+        # 0xFFFFFFFF shares its sort run with the invalid rows
+        key = rng.choice(np.array([0, 0xFFFFFFFE, 0xFFFFFFFF, 5, 9],
+                                  np.uint32), b)
+    elif name == "all_invalid":
+        valid[:] = False
+    elif name == "one_source":
+        key[:], valid[:] = 4242, True
+    return (key, rng.integers(64, 1501, b).astype(np.float32),
+            rng.uniform(0, 1, b).astype(np.float32), valid,
+            (rng.random(b) < 0.3) & valid)
+
+
+class TestSliceAggregation:
+    """The sharded step's local aggregation (`aggregate_slice`, one sort
+    and a segmented scan) and its way back (`slice_verdicts`,
+    `fail_open_records`) against the two-stage form: `agg.aggregate`,
+    the malicious counts scattered through `inv`, and
+    `resolve_record_verdicts`."""
+
+    B = 256
+
+    @pytest.mark.parametrize(
+        "name", ["key_zero", "all_invalid", "one_source", "random"])
+    def test_matches_the_two_stage_form_bit_for_bit(self, name):
+        from flowsentryx_tpu.ops import agg
+
+        rng = np.random.default_rng(sum(map(ord, name)))
+        cols = _slice_case(name, self.B, rng)
+        key, plen, ts, valid, mal = map(jnp.asarray, cols)
+        fa, ml, runs = jax.jit(pstep.aggregate_slice)(key, plen, ts, valid,
+                                                      mal)
+        old = agg.aggregate(key, plen, ts, valid)
+        ml_old = (jnp.zeros((self.B,), jnp.float32)
+                  .at[old.inv].add(mal.astype(jnp.float32)))
+
+        at = np.flatnonzero(np.asarray(fa.rep_valid))
+        at_old = np.flatnonzero(np.asarray(old.rep_valid))
+        n = len(np.unique(np.where(cols[0] == 0, 0xFFFFFFFE,
+                                   cols[0])[cols[3]]))
+        assert len(at) == len(at_old) == n
+        for new_col, old_col in ((fa.rep_key, old.rep_key),
+                                 (fa.rep_pkts, old.rep_pkts),
+                                 (fa.rep_bytes, old.rep_bytes),
+                                 (fa.rep_ts, old.rep_ts), (ml, ml_old)):
+            np.testing.assert_array_equal(
+                np.asarray(new_col)[at].view(np.uint32),
+                np.asarray(old_col)[at_old].view(np.uint32))
+        assert fa.inv is None
+
+        # a verdict a flow, ML_RECORD_GATE among the codes, at each
+        # form's places of the flows; padding reads PASS
+        codes = rng.choice([int(v) for v in Verdict]
+                           + [fused.ML_RECORD_GATE], n)
+        codes[: min(n, 2)] = fused.ML_RECORD_GATE
+        flow_v = np.zeros(self.B, np.int32)
+        flow_v[at] = codes
+        flow_v_old = np.zeros(self.B, np.int32)
+        flow_v_old[at_old] = codes
+        got = jax.jit(pstep.slice_verdicts)(runs, jnp.asarray(flow_v))
+        want = fused.resolve_record_verdicts(jnp.asarray(flow_v_old),
+                                             old.inv, mal, valid)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+        # route_drop: the records of the flows that overflowed
+        over = rng.random(n) < 0.5
+        overflow = np.zeros(self.B, bool)
+        overflow[at[over]] = True
+        overflow_old = np.zeros(self.B, bool)
+        overflow_old[at_old[over]] = True
+        drop = jax.jit(pstep.fail_open_records)(fa, jnp.asarray(overflow))
+        want_drop = jnp.sum(jnp.where(
+            valid, jnp.asarray(overflow_old)[old.inv].astype(jnp.uint32),
+            jnp.uint32(0)))
+        assert drop.dtype == jnp.uint32
+        assert int(drop) == int(want_drop)
+        assert (int(drop) > 0) == bool(over.any())
+
+    def test_the_stage_holds_one_sort_and_no_gather_or_scatter(self, mesh,
+                                                               env):
+        """What the stage was (an argsort, a key gather, the `inv`
+        scatter, three payload gathers, four segment reductions and the
+        malicious counts' scatter-add) cannot come back unseen, nor the
+        two gathers through `inv` that took verdicts and overflow back
+        to the records: `fsx.aggregate` holds one four-operand sort and
+        `fsx.emit` no gather."""
+        from flowsentryx_tpu.audit.graph import iter_staged_eqns
+
+        sharded, _, params = env
+        staged = list(iter_staged_eqns(jax.make_jaxpr(sharded)(
+            pstep.make_sharded_table(CFG, mesh), make_stats(), params,
+            build_batch([(1001, 5, 100, 0.1, ML_COLD)], batch_size=256))))
+        aggregate = [e for stage, e in staged if stage == "aggregate"]
+        emit = [e.primitive.name for stage, e in staged if stage == "emit"]
+        sorts = [e for e in aggregate if e.primitive.name == "sort"]
+        assert [len(e.invars) for e in sorts] == [4]
+        assert not [e for e in aggregate if e.primitive.name.startswith(
+            ("gather", "scatter", "dynamic"))]
+        assert "sort" in emit
+        assert not [p for p in emit if p.startswith("gather")]
