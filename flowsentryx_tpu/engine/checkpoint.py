@@ -53,13 +53,14 @@ Integrity + retention (PR 13, the chaos campaign's forcing function):
 Streamed save (the 3.5 GB snapshot of a 2^26-row table):
 
 * The table leaves the device a column at a time (one program splits
-  the ``[N, 12]`` matrix into its columns on the device; a column's
-  transfer starts ``AHEAD`` sections before it is written, so the first
-  is not queued behind the rest, which arrive while the ones before
-  them are written),
-  so every column is a contiguous run of host memory.  Nothing else of the
-  table's size is made on the host: it holds the fetched columns and,
-  for a host-side table, one column's copy at a time.
+  the ``[N, 12]`` matrix into its columns on the device; each column is
+  cut into pieces of ``PIECE_BYTES`` on the device as it is sent, and a
+  piece's transfer starts ``AHEAD`` pieces before it is written, so the
+  first is not queued behind the rest, which arrive while the ones
+  before them are written), so every piece is a contiguous run of host
+  memory.  Nothing else of the table's size is made on the host: it
+  holds the pieces in flight and, for a host-side table, one column's
+  copy at a time.
 * The ``.npz`` is uncompressed and written section by section into the
   publish's same-directory temp file (:func:`durable.publish_stream`),
   ``integrity_crc32`` last.  Each section's bytes pass through one
@@ -69,6 +70,25 @@ Streamed save (the 3.5 GB snapshot of a 2^26-row table):
   are both derived from it.  The fold's value is what it always
   was, so every older snapshot, compressed or not, still loads and
   verifies.
+
+Overlapped save (the periodic policy's; ``Engine._checkpoint_due``):
+
+* Only the boundary's state is taken on the dispatch thread, the
+  *hold*: every launched step sunk, then one device program
+  (:func:`snapshot`) that writes the key column and the twelve state
+  columns into buffers of their own (the key copied too: the next step
+  donates the live one), and the small stats fetched.  The dispatch
+  thread then returns to serving.
+* A thread of its own (``fsx-ckpt``) runs :func:`write_sections` over
+  those device arrays — the fetch, the CRC, the write and the publish —
+  while the engine serves on.  At most one save is in flight.  A device
+  column leaves in pieces of ``PIECE_BYTES``: a transfer's host buffer
+  is made ready with the interpreter lock held, so a small piece holds
+  the serving threads up for a millisecond or two, where a column held
+  them for its whole transfer.  The CRC pool keeps to ``CRC_THREADS``,
+  leaving the serving path's threads and processes their cores.
+* An explicit save (``Engine.checkpoint``) runs the same code on the
+  calling thread and blocks.
 
 (Plain npz rather than orbax: the state is a flat dict of arrays;
 zero-dependency and byte-inspectable wins here.)
@@ -208,25 +228,27 @@ class _StoredZip:
         self.pos = 0
         self.members: list[tuple[bytes, int, int, int]] = []
 
-    def _put(self, data) -> None:
+    def put(self, data) -> None:
+        """Bytes of the member begun last."""
         self.f.write(data)
         self.pos += len(data)
 
-    def add(self, name: str, header: bytes, body: memoryview,
-            body_crc: int | None = None) -> None:
-        """One ``.npy`` member: its header, then its body; without
+    def begin(self, name: str, header: bytes, body_size: int,
+              body_crc: int | None = None) -> None:
+        """One ``.npy`` member's local header and ``.npy`` header; its
+        ``body_size`` bytes of body follow by :meth:`put`.  Without
         ``body_crc``, :meth:`crc` gives it."""
         raw = f"{name}.npy".encode()
-        size = len(header) + len(body)
+        size = len(header) + body_size
         crc = (0 if body_crc is None else
-               crc32_combine(zlib.crc32(header), body_crc, len(body)))
+               crc32_combine(zlib.crc32(header), body_crc, body_size))
         extra = struct.pack("<2H2Q", 1, 16, size, size)
         self.members.append((raw, crc, size, self.pos))
         self._header = header
-        self._put(_LOCAL.pack(b"PK\x03\x04", _ZIP64_VERSION, 0, 0, 0, 0,
+        self.put(_LOCAL.pack(b"PK\x03\x04", _ZIP64_VERSION, 0, 0, 0, 0,
                               _DOS_DATE, crc, _FF, _FF, len(raw),
                               len(extra)) + raw + extra + header)
-        self._put(body)
+
 
     def crc(self, body_crc: int) -> None:
         """The last member's CRC, from its body's."""
@@ -244,23 +266,26 @@ class _StoredZip:
         start = self.pos
         for raw, crc, size, offset in self.members:
             extra = struct.pack("<2H3Q", 1, 24, size, size, offset)
-            self._put(_CENTRAL.pack(
+            self.put(_CENTRAL.pack(
                 b"PK\x01\x02", _ZIP64_VERSION, 3, _ZIP64_VERSION, 0, 0, 0,
                 0, _DOS_DATE, crc, _FF, _FF, len(raw), len(extra), 0, 0,
                 0, 0o600 << 16, _FF) + raw + extra)
         end64, n = self.pos, len(self.members)
-        self._put(_END64.pack(b"PK\x06\x06", _END64.size - 12,
+        self.put(_END64.pack(b"PK\x06\x06", _END64.size - 12,
                               _ZIP64_VERSION, _ZIP64_VERSION, 0, 0, n, n,
                               end64 - start, start))
-        self._put(_END64_LOCATOR.pack(b"PK\x06\x07", 0, end64, 1))
-        self._put(_END.pack(b"PK\x05\x06", 0, 0, n, n, _FF, _FF, 0))
+        self.put(_END64_LOCATOR.pack(b"PK\x06\x07", 0, end64, 1))
+        self.put(_END.pack(b"PK\x05\x06", 0, 0, n, n, _FF, _FF, 0))
         return self.pos
 
 
-def _npy_header(arr: np.ndarray) -> bytes:
+def _npy_header(dtype: np.dtype, shape: tuple) -> bytes:
+    """The ``.npy`` header of a C-ordered array of ``dtype`` and
+    ``shape``: what ``np.save`` writes for it."""
     buf = io.BytesIO()
-    np.lib.format.write_array_header_1_0(
-        buf, np.lib.format.header_data_from_array_1_0(arr))
+    np.lib.format.write_array_header_1_0(buf, {
+        "descr": np.lib.format.dtype_to_descr(np.dtype(dtype)),
+        "fortran_order": False, "shape": tuple(shape)})
     return buf.getvalue()
 
 
@@ -294,11 +319,12 @@ class Checkpoint(NamedTuple):
 
 
 class SaveSpans(NamedTuple):
-    """Where :func:`save_state` charges its time (each a
-    :class:`~flowsentryx_tpu.engine.metrics.Span`, in turn on the
-    calling thread): the device table's fetch (the wait for each
-    column), the CRC (the wait for each section's, which runs beside
-    its write, and the folds), the writes of the sections into the temp
+    """Where :func:`write_sections` charges its time (each a
+    :class:`~flowsentryx_tpu.engine.metrics.Span`, in turn on the thread
+    that writes: the save thread for the policy's saves, the caller for
+    :func:`save_state`): the device table's fetch (the wait for each
+    piece), the CRC (the wait for each piece's, which runs beside its
+    write, and the folds), the writes of the sections into the temp
     file, and the publish around them (fsync, ``.prev`` rotation,
     rename, fsync of the directory)."""
 
@@ -311,15 +337,35 @@ class SaveSpans(NamedTuple):
 _NO_SPANS = SaveSpans(*(contextlib.nullcontext(),) * 3, None)
 
 
+def _columns(state) -> tuple:
+    return tuple(state[:, i] for i in range(state.shape[1]))
+
+
 @functools.cache
 def _split_columns():
     import jax
 
-    return jax.jit(lambda s: tuple(s[:, i] for i in range(s.shape[1])))
+    return jax.jit(_columns)
 
 
-#: device sections whose transfers run ahead of the one being written
-AHEAD = 2
+#: bytes of a device section a transfer moves at a time.  A transfer's
+#: host buffer is made ready with the interpreter lock held, at the rate
+#: the host backs fresh pages (1.3 GB/s on a TPU v5e host), so a piece
+#: this small holds the lock for a millisecond or two and stays in the
+#: allocator's reused memory: with 2 MiB pieces the engine served on at
+#: 95 % of its rate during a save of 2^26 rows, with 32 MiB pieces at
+#: 37 %, with whole columns at 4 %
+PIECE_BYTES = 2 << 20
+#: device pieces cut and sent ahead of the one being written: a piece is
+#: cut on the device behind the serving steps already queued there (up
+#: to ``readback_depth`` groups, tens of ms), so 128 MiB in flight keep
+#: the write fed
+AHEAD = 64
+#: threads that CRC a save's pieces.  Four keep ahead of the write (one
+#: reads 1.75 GB/s, the write runs at 1.3), and leave the serving path's
+#: own threads and processes (dispatch, sink, ingest workers, ``fsxd``,
+#: the save's writer) the rest of a 13-core host
+CRC_THREADS = 4
 
 
 def device_columns(state) -> tuple:
@@ -334,30 +380,89 @@ def device_columns(state) -> tuple:
     return cols
 
 
-def warm_fetch(state) -> None:
-    """Compile the column fetch of a device table of this shape, so that
-    the first save compiles nothing."""
-    if not isinstance(state, np.ndarray):
+@functools.cache
+def _snapshot_program():
+    """The key column copied (a buffer of its own, which the next step's
+    donation of the live key leaves alone) and the state split into its
+    columns: one program per shape."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda key, state: (jnp.copy(key), _columns(state)))
+
+
+@functools.cache
+def _piece_program(rows: int):
+    """``rows`` rows of a device section from a row given at run time:
+    one program per piece size, whichever piece it cuts."""
+    import jax
+    from jax import lax
+
+    return jax.jit(lambda a, start: lax.dynamic_slice_in_dim(a, start, rows))
+
+
+def _parts(arr) -> list:
+    """A device column as arrays of one device each, in row order: its
+    shards (one of each row range, where a range is replicated), so that
+    a piece is cut by a program of one device, which the save thread
+    may launch beside the serving steps: a program over several devices
+    launched from two threads could order their collectives differently
+    on each device."""
+    shards = {(s.index[0].start or 0): s.data for s in arr.addressable_shards}
+    return [shards[start] for start in sorted(shards)]
+
+
+def _cut(arr) -> list:
+    """A section as the pieces it is sent in: a device column as
+    ``(array, first row, rows)`` of each piece of ``PIECE_BYTES`` of each
+    of its :func:`_parts`, cut on the device as the piece is sent
+    (:func:`_make`); anything else whole."""
+    if not hasattr(arr, "copy_to_host_async") or arr.ndim != 1:
+        return [arr]
+    step = max(PIECE_BYTES // arr.dtype.itemsize, 1)
+    return [(part, a, min(step, part.shape[0] - a))
+            for part in _parts(arr) for a in range(0, part.shape[0], step)]
+
+
+def _make(piece):
+    """A piece of :func:`_cut` as an array: a device piece cut (a piece
+    that is a whole part is the part itself)."""
+    if isinstance(piece, tuple):
+        arr, start, rows = piece
+        return (arr if rows == arr.shape[0]
+                else _piece_program(rows)(arr, start))
+    return piece
+
+
+def warm_snapshot(table: schema.IpTableState) -> None:
+    """Compile the snapshot of a device table of this shape and the cut
+    of its pieces, so that the first save compiles nothing."""
+    if not isinstance(table.state, np.ndarray):
         import jax
 
-        jax.block_until_ready(_split_columns()(state))
+        key, columns = _fetch(table)
+        # each device's first piece, and the last, if it is shorter
+        pieces = [p for a in (key, columns[0]) for p in _cut(a)]
+        jax.block_until_ready([
+            _make(p) for i, p in enumerate(pieces)
+            if i + 1 == len(pieces) or p[1] == 0])
 
 
 def _fetch(table: schema.IpTableState) -> tuple[Any, Any]:
-    """The key column and the state's columns: a device table's as
-    device arrays, each sent to the host as the write comes near it
-    (``save_state``); a host table's as strided views, each copied as
-    it is written."""
+    """The key column and the state's columns: a device table's taken by
+    one device program into buffers of their own, each sent to the host
+    in pieces as the write comes near it (:func:`write_sections`); a
+    host table's as strided views, each copied as it is written."""
     if isinstance(table.state, np.ndarray):
         return np.asarray(table.key), table.state.T
-    return table.key, _split_columns()(table.state)
+    return _snapshot_program()(table.key, table.state)
 
 
 def _sections(key, columns, stats, t0_ns, hash_salt, n_shards) -> dict:
     """The snapshot's sections by name.  One array per column (not the
     in-memory [N, 12] matrix): the column-per-key format predates the
-    matrix layout, keeps old snapshots loadable, and lets future
-    columns default cleanly."""
+    matrix layout, keeps old snapshots loadable, and lets future columns
+    default cleanly."""
     return {
         "table_key": key,
         **{f"table_{name}": columns[i]
@@ -371,6 +476,16 @@ def _sections(key, columns, stats, t0_ns, hash_salt, n_shards) -> dict:
     }
 
 
+def snapshot(table: schema.IpTableState, stats: schema.GlobalStats,
+             t0_ns: int, hash_salt: int = 0, n_shards: int = 1) -> dict:
+    """The state as it stands now, as :func:`write_sections` takes it:
+    the table in buffers of its own (a device table's by one program,
+    dispatched and not waited for), the stats on the host.  What the
+    engine does with the live table after this call does not reach the
+    snapshot."""
+    return _sections(*_fetch(table), stats, t0_ns, hash_salt, n_shards)
+
+
 def save_state(
     path: str | Path,
     table: schema.IpTableState,
@@ -380,12 +495,25 @@ def save_state(
     n_shards: int = 1,
     spans: SaveSpans | None = None,
 ) -> Path:
-    """Snapshot serving state ATOMICALLY (module docstring).  Arrays
-    are fetched from device (the one deliberate D2H of the engine's
-    lifetime); ``hash_salt``/``n_shards`` record the geometry the slot
-    layout was built under, so a restore can detect and reshard a
-    geometry change instead of mislocating keys.  The file is streamed
-    (module docstring); ``spans`` times its phases."""
+    """Snapshot serving state ATOMICALLY (module docstring), on the
+    calling thread.  Arrays are fetched from device (the one deliberate
+    D2H of the engine's lifetime); ``hash_salt``/``n_shards`` record the
+    geometry the slot layout was built under, so a restore can detect
+    and reshard a geometry change instead of mislocating keys.  The file
+    is streamed (module docstring); ``spans`` times its phases."""
+    spans = spans or _NO_SPANS
+    with spans.fetch:
+        sections = snapshot(table, stats, t0_ns, hash_salt, n_shards)
+    return write_sections(path, sections, spans)
+
+
+def write_sections(path: str | Path, sections: dict,
+                   spans: SaveSpans | None = None) -> Path:
+    """Write :func:`snapshot`'s sections to ``path`` through the
+    five-step publish, streamed (module docstring), and return the path
+    written.  ``sections`` is emptied as it is taken: each piece is let
+    go once it is written, so a device table's pieces free their memory
+    one by one."""
     path = Path(path)
     # np.savez silently appends .npz to a suffix-less path; normalize so
     # the returned path is the file actually written (same contract as
@@ -393,54 +521,70 @@ def save_state(
     if path.suffix != ".npz":
         path = path.with_suffix(path.suffix + ".npz")
     spans = spans or _NO_SPANS
-    with spans.fetch:
-        sections = _sections(*_fetch(table), stats, t0_ns, hash_salt,
-                             n_shards)
+    # the integrity fold runs over the sections in sorted-name order
+    # (_fold_crc), so they are written in that order; `flat` holds the
+    # only references to the pieces
+    meta, flat = [], []
+    for name, arr in sorted(sections.items()):
+        pieces = _cut(arr)
+        meta.append((name, len(pieces), np.dtype(arr.dtype),
+                     tuple(arr.shape)))
+        flat.extend(pieces)
+    sections.clear()
+    del arr, pieces
     in_write = 0.0
 
-    # the integrity fold runs over the sections in sorted-name order
-    # (_fold_crc), so they are written in that order
-    items = sorted(sections.items())
-    del sections
-
     def send(i: int) -> None:
-        # a device section's transfer, started AHEAD sections before it
-        # is written: the first is not queued behind the rest, which
-        # arrive while the sections before them are written
-        if i < len(items) and hasattr(items[i][1], "copy_to_host_async"):
-            items[i][1].copy_to_host_async()
+        # a device piece cut and its transfer started AHEAD pieces
+        # before it is written: the first is not queued behind the rest,
+        # which arrive while the pieces before them are written
+        if i < len(flat):
+            flat[i] = _make(flat[i])
+            if hasattr(flat[i], "copy_to_host_async"):
+                flat[i].copy_to_host_async()
 
     def write(f) -> None:
         nonlocal in_write
         t0 = time.perf_counter()
         out = _StoredZip(f)
-        fold = 0
-        for i in range(AHEAD):
-            send(i)
-        with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
-            for i, (name, _) in enumerate(items):
-                send(i + AHEAD)
-                # a device column waits here for its transfer, a host
-                # table's column is copied: one at a time, each let go
-                # once it is written
-                with spans.fetch:
-                    arr = np.asarray(items[i][1], order="C")
-                items[i] = None
-                body = memoryview(arr).cast("B")
-                # the CRC reads the body on the pool while it is written
-                pieces = _crc32_start(body, pool)
+        fold = i = 0
+        for j in range(AHEAD):
+            send(j)
+        with ThreadPoolExecutor(
+                min(CRC_THREADS, os.cpu_count() or 1)) as pool:
+            for name, n, dtype, shape in meta:
+                size = dtype.itemsize * int(np.prod(shape, dtype=np.int64))
                 with spans.write:
-                    out.add(name, _npy_header(arr), body)
+                    out.begin(name, _npy_header(dtype, shape), size)
+                crc = 0
+                for _ in range(n):
+                    send(i + AHEAD)
+                    # a device piece waits here for its transfer, a host
+                    # table's column is copied: one at a time, each let
+                    # go once it is written
+                    with spans.fetch:
+                        arr = np.asarray(flat[i], order="C")
+                    flat[i] = None
+                    i += 1
+                    body = memoryview(arr).cast("B")
+                    # the CRC reads the body on the pool while it is
+                    # written
+                    crcs = _crc32_start(body, pool)
+                    with spans.write:
+                        out.put(body)
+                    with spans.crc:
+                        crc = crc32_combine(crc, _crc32_join(crcs),
+                                            len(body))
+                    del arr, body
                 with spans.crc:
-                    crc = _crc32_join(pieces)
                     out.crc(crc)
                     fold = crc32_combine(zlib.crc32(name.encode(), fold),
-                                         crc, len(body))
-                del arr, body
+                                         crc, size)
         with spans.write:
-            tail = np.uint32(fold & 0xFFFFFFFF)
-            out.add("integrity_crc32", _npy_header(tail),
-                    memoryview(tail.tobytes()), zlib.crc32(tail.tobytes()))
+            tail = np.uint32(fold & 0xFFFFFFFF).tobytes()
+            out.begin("integrity_crc32", _npy_header(np.uint32, ()),
+                      len(tail), zlib.crc32(tail))
+            out.put(tail)
             out.close()
         in_write = time.perf_counter() - t0
 
@@ -464,11 +608,16 @@ class CheckpointPolicy:
     The saves fall due on a grid of the host clock (``time.perf_counter``)
     that the engine starts when it takes the policy: the first
     ``first_s`` later (default ``every_s``), then one every ``every_s``,
-    across ``run()`` calls.  The engine saves at the first group boundary
-    after a slot falls due; a slot that falls due while a save runs, or
+    across ``run()`` calls.  The engine takes the save's state at the
+    first group boundary after a slot falls due, on the dispatch thread,
+    and writes it on the save thread while it serves on (module
+    docstring); a slot that falls due while a save is in flight, or
     passes while nothing serves, is skipped and counted, not made up.
     The counters live here and are the engine report's ``checkpoint``
-    block."""
+    block.  The save thread counts a save (:meth:`saved`) when its
+    publish returns; the dispatch thread reads :attr:`next_at` only
+    while no save is in flight, and the report only once the engine
+    has joined the save."""
 
     def __init__(self, path: str | Path, every_s: float,
                  first_s: float | None = None):
@@ -480,8 +629,11 @@ class CheckpointPolicy:
         self.first_at = float("inf")
         self.next_slot = 0
         self.saves = self.skipped = self.bytes = 0
-        #: the last save: when it began (host clock) and took, the bytes
-        #: written, and the point of the stream it holds
+        #: the last save: when it began (host clock, the boundary) and
+        #: took to its publish, the bytes written, and the point of the
+        #: stream it holds; the policy's saves add how long they held the
+        #: dispatch thread (``hold_s``) and the records it emitted
+        #: between the boundary and the publish (``records_during``)
         self.last: dict | None = None
 
     def start(self, now: float) -> None:

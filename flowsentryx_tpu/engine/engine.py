@@ -191,6 +191,28 @@ class EngineReport(NamedTuple):
     checkpoint: dict | None = None
 
 
+class _SaveJob:
+    """One periodic save, handed from the hold on the dispatch thread to
+    the save thread (``Engine._checkpoint_due``): the policy it counts
+    in, the snapshot's sections (device arrays of its own, emptied as
+    they are written), when the hold began and how long it took, and
+    the point of the stream the snapshot holds.  The save thread sets
+    ``exc`` if it fails; the dispatch thread reads it after the join."""
+
+    __slots__ = ("pol", "sections", "at", "hold_s", "point", "thread",
+                 "exc")
+
+    def __init__(self, pol, sections: dict, at: float, hold_s: float,
+                 point: dict):
+        self.pol = pol
+        self.sections = sections
+        self.at = at
+        self.hold_s = hold_s
+        self.point = point
+        self.thread: threading.Thread | None = None
+        self.exc: BaseException | None = None
+
+
 class _Stamps(NamedTuple):
     """The host stamps of one sealed batch (perf_counter domain): the
     latency plane charges an in-flight entry from its OLDEST member
@@ -662,6 +684,9 @@ class Engine:
         #: CheckpointPolicy), None = no saves while serving: the serving
         #: loops test it once a group boundary.  Dispatch-thread-only.
         self._ckpt = None
+        #: The policy's save in flight on the save thread (``_SaveJob``),
+        #: None once joined: at most one.  Dispatch-thread-only.
+        self._save: _SaveJob | None = None
         #: Live-rebalance audit counters (cluster/rebalance.py drives
         #: the quiescent span methods below; engine/health.py folds
         #: the loss-shaped ones — adopt_dropped, staged_discarded,
@@ -1787,53 +1812,116 @@ class Engine:
     def _n_shards(self) -> int:
         return int(self.mesh.devices.size) if self.mesh is not None else 1
 
+    def _save_spans(self):
+        from flowsentryx_tpu.engine import checkpoint as ckpt
+
+        m = self.metrics
+        return ckpt.SaveSpans(m.ckpt_fetch, m.ckpt_crc, m.ckpt_write,
+                              m.ckpt_publish)
+
     def checkpoint(self, path) -> str:
         """Snapshot table+stats+clock so a restarted engine resumes with
         every tracked flow and blacklist expiry intact.  The write is
         atomic and the header records the table GEOMETRY (salt, shard
         count, capacity) so a restore under a different mesh reshards
         instead of mislocating keys (engine/checkpoint.py docstring).
-        Its phases are the ``fsx.ckpt.*`` spans."""
+        Synchronous: it waits for the policy's save in flight, if any,
+        then saves on the calling thread.  Its phases are the
+        ``fsx.ckpt.*`` spans."""
         from flowsentryx_tpu.engine import checkpoint as ckpt
 
-        m = self.metrics
+        self._save_join()
         return str(ckpt.save_state(
             path, self.table, self.stats, self.batcher.t0_ns,
             hash_salt=self.cfg.table.salt, n_shards=self._n_shards(),
-            spans=ckpt.SaveSpans(m.ckpt_fetch, m.ckpt_crc, m.ckpt_write,
-                                 m.ckpt_publish)))
+            spans=self._save_spans()))
 
     def set_checkpoint_policy(self, policy) -> None:
         """Save on ``policy``'s grid while serving
         (:class:`~flowsentryx_tpu.engine.checkpoint.CheckpointPolicy`),
         the grid laid from now; None stops.  Quiescent, between
-        ``run()`` calls: the fetch's device program compiles here, not
-        in the first save."""
+        ``run()`` calls: it waits for a save in flight, so that the
+        policy it leaves holds every save made, and the snapshot's
+        device program compiles here, not in the first save."""
+        self._save_join()
         if policy is not None:
             from flowsentryx_tpu.engine import checkpoint as ckpt
 
-            ckpt.warm_fetch(self.table.state)
+            ckpt.warm_snapshot(self.table)
             policy.start(time.perf_counter())
         self._ckpt = policy
 
     def _checkpoint_due(self) -> None:
-        """At a group boundary: save if the policy's slot has come.
-        Every launched step is sunk first, so that the table, the stats,
-        the clock and the verdicts the sink has written all stand at one
-        point of the stream.  Serving stops for the save; the watchdog
-        counts its end as progress, since nothing sinks while it runs."""
+        """At a group boundary: if the policy's slot has come, take the
+        save's state and hand it to the save thread (the *hold*, span
+        ``fsx.ckpt.hold``).  Every launched step is sunk first, so that
+        the table, the stats, the clock and the verdicts the sink has
+        written all stand at one point of the stream; then one device
+        program copies the table into buffers of its own
+        (``checkpoint.snapshot``), dispatched ahead of the next step,
+        which donates the live table, and the small stats are fetched.
+        The fetch, CRC, write and publish run on the save thread
+        (:meth:`_save_worker`) while this thread serves on.  While a
+        save is in flight no other is taken: its slot is skipped and
+        counted once that save publishes.  A save that failed raises
+        here, at the first boundary after."""
         pol = self._ckpt
+        if self._save is not None:
+            if self._save.thread.is_alive():
+                return
+            self._save_join()
         if time.perf_counter() < pol.next_at:
             return
-        t0 = time.perf_counter()
-        self._reap(0)
-        path = self.checkpoint(pol.path)
-        took = time.perf_counter() - t0
-        pol.saved(t0, took, durable.get_fs().size(path), {
-            "batches": self.batcher.batches_emitted,
-            "records": self.batcher.records_emitted,
-            "clock_s": self._device_now})
-        self._watchdog.note_progress()
+        from flowsentryx_tpu.engine import checkpoint as ckpt
+
+        with self.metrics.ckpt_hold as hold:
+            self._reap(0)
+            point = {"batches": self.batcher.batches_emitted,
+                     "records": self.batcher.records_emitted,
+                     "clock_s": self._device_now}
+            sections = ckpt.snapshot(
+                self.table, jax.device_get(self.stats),
+                self.batcher.t0_ns, hash_salt=self.cfg.table.salt,
+                n_shards=self._n_shards())
+        job = _SaveJob(pol, sections, hold.t0, hold.seconds, point)
+        job.thread = threading.Thread(target=self._save_worker,
+                                      args=(job,), name="fsx-ckpt",
+                                      daemon=True)
+        self._save = job
+        job.thread.start()
+
+    def _save_worker(self, job: "_SaveJob") -> None:
+        """The save thread's main: write the hold's snapshot through the
+        five-step publish (``checkpoint.write_sections``, the
+        ``fsx.ckpt.fetch`` / ``.crc`` / ``.write`` / ``.publish``
+        spans), then count the save in the policy.  Its one look at the
+        serving side is the records emitted so far, once, at the
+        publish.  An exception is kept for :meth:`_save_join`."""
+        from flowsentryx_tpu.engine import checkpoint as ckpt
+
+        try:
+            path = ckpt.write_sections(job.pol.path, job.sections,
+                                       self._save_spans())
+            took = time.perf_counter() - job.at
+            during = self.batcher.records_emitted - job.point["records"]
+            job.pol.saved(job.at, took, durable.get_fs().size(path), {
+                **job.point, "hold_s": job.hold_s,
+                "records_during": during})
+        except BaseException as e:  # noqa: BLE001 — raised by _save_join
+            job.exc = e
+
+    def _save_join(self) -> None:
+        """Wait for the policy's save in flight, if any, and raise what
+        it raised.  Called on the dispatch thread: at a boundary once
+        the save has ended, at the end of each serving loop, and before
+        every quiescent method that saves, restores or changes the
+        policy, so that no two publishes ever race on one path."""
+        job, self._save = self._save, None
+        if job is None:
+            return
+        job.thread.join()
+        if job.exc is not None:
+            raise job.exc
 
     def restore(self, path) -> dict:
         """Resume from a snapshot.  Same geometry → bit-identical
@@ -1861,6 +1949,7 @@ class Engine:
         from flowsentryx_tpu.engine import checkpoint as ckpt
         from flowsentryx_tpu.engine import table as tbl
 
+        self._save_join()
         fallback_from = None
         try:
             ck = ckpt.load_checkpoint(path)
@@ -2149,6 +2238,11 @@ class Engine:
                    else self._run_inline(max_batches, max_seconds))
         finally:
             self._stop_sink_thread()
+            if self._save is not None:
+                # serving raised with a save in flight: let it publish
+                # before the caller goes on (what it raised waits for
+                # the next _save_join)
+                self._save.thread.join()
             # Serving is over; do not hand a daemon fill thread to
             # interpreter teardown mid-XLA-compile (measured segfault
             # in short-lived `fsx serve --batches N --tiered-warm`
@@ -2317,6 +2411,7 @@ class Engine:
                 self._dispatch(raw, stamps)
             self._pending.clear()
         self._reap(0)
+        self._save_join()
         with self.metrics.report:
             return self._build_report(time.perf_counter() - t_start)
 
@@ -2361,6 +2456,7 @@ class Engine:
         with self.metrics.serve:
             self._sealed_loop_arena(src, bounded)
         self._reap(0)
+        self._save_join()
         with self.metrics.report:
             return self._build_report(time.perf_counter() - t_start)
 

@@ -427,12 +427,16 @@ class PipelineMetrics:
     ``apply`` (writeback, gossip publish, book-keeping, the latency
     plane, ``on_reap``).
 
-    A periodic save (``Engine.set_checkpoint_policy``), on the dispatch
-    thread at a group boundary: ``ckpt_fetch`` (the wait for the
-    table's D2H), ``ckpt_write`` and ``ckpt_crc`` (per section: the
-    write, then the wait for the CRC that ran beside it),
+    A periodic save (``Engine.set_checkpoint_policy``): ``ckpt_hold``
+    on the dispatch thread at a group boundary (every launched step
+    sunk, the table's device-side snapshot dispatched, the stats
+    fetched: until the save is handed to the save thread); then, on the
+    save thread while serving goes on, ``ckpt_fetch`` (the wait for
+    each piece of the table's D2H), ``ckpt_write`` and ``ckpt_crc`` (per
+    piece: the write, then the wait for the CRC that ran beside it),
     ``ckpt_publish`` (fsync, ``.prev`` rotation, rename, fsync of the
-    directory).
+    directory).  An explicit ``Engine.checkpoint`` writes the last four
+    on its caller's thread, once no save is in flight.
 
     ``stages_ms`` is the older face of the same histograms."""
 
@@ -454,6 +458,7 @@ class PipelineMetrics:
         self.ckpt_crc = Span("fsx.ckpt.crc")
         self.ckpt_write = Span("fsx.ckpt.write")
         self.ckpt_publish = Span("fsx.ckpt.publish")
+        self.ckpt_hold = Span("fsx.ckpt.hold")
 
     def spans(self) -> tuple[Span, ...]:
         """Every span: the attributes are the spans and nothing else."""

@@ -184,12 +184,20 @@ _DISP = FieldContract(
 ENGINE_PLAN = ClassPlan(
     module="flowsentryx_tpu/engine/engine.py",
     cls="Engine",
-    worker_targets=("_sink_worker", "_warm_worker"),
+    worker_targets=("_sink_worker", "_warm_worker", "_save_worker"),
     sections={"launch": _ENGINE_LAUNCH, "sink": _ENGINE_SINK},
     quiescent=_ENGINE_QUIESCENT,
     fields={
         # -- launch section -------------------------------------------
-        "table": _LAUNCH, "stats": _LAUNCH,
+        # the periodic save's hold reads the carry on the dispatch
+        # thread, the one launcher, after _reap(0): its snapshot program
+        # is dispatched ahead of the next step, which donates the table
+        "table": FieldContract(
+            "section:launch", _LAUNCH.rationale,
+            extra=("_checkpoint_due",)),
+        "stats": FieldContract(
+            "section:launch", _LAUNCH.rationale,
+            extra=("_checkpoint_due",)),
         "_dispatch_calls": _LAUNCH, "_dispatched_chunks": _LAUNCH,
         "_group_hist": _LAUNCH,
         # -- sink section ---------------------------------------------
@@ -236,7 +244,19 @@ ENGINE_PLAN = ClassPlan(
             "the budget in seconds, same lifecycle as slo_us"),
         # -- dispatch-thread-owned ------------------------------------
         "_inflight": _DISP, "_pending": _DISP, "_arena": _DISP,
-        "batcher": _DISP, "_staged_batches": _DISP,
+        "batcher": FieldContract(
+            "dispatch", _DISP.rationale,
+            # the save thread reads batcher.records_emitted once, at
+            # its publish (the report's records_during): one int load,
+            # a count the dispatch thread only ever increases
+            extra=("_save_worker",)),
+        "_save": FieldContract(
+            "dispatch",
+            "the policy's save in flight (_SaveJob): started by the "
+            "hold in _checkpoint_due, joined by _save_join on the "
+            "dispatch thread or in a quiescent method; the save "
+            "thread is handed its job and never reads this field"),
+        "_staged_batches": _DISP,
         "_staged_bytes": _DISP, "_t0_auto": _DISP,
         "_watch_path": _DISP, "_watch_mtime": _DISP,
         "_watch_next": _DISP, "_hot_swaps": _DISP, "_ckpt": _DISP,

@@ -480,6 +480,11 @@ CKPT_METRICS = ("ckpt.stall_share.tput", "ckpt.fetch_ms.tput",
                 "ckpt.persist_ms.tput")
 CKPT_EXACT = ("ckpt_failed", "ckpt_corrupt", "ckpt_rows_differ",
               "ckpt_stats_differ")
+#: the overlapped save's readers, after the three above: the hold's
+#: share of the window (a span) and serving's rate while the save was in
+#: flight (the report's counters)
+CKPT_OVERLAP = {"ckpt.hold_share.tput": "program_span",
+                "ckpt.overlap_serve_share.tput": "program_counter"}
 
 
 def test_the_saving_deployment_is_c4s_with_a_checkpoint_group():
@@ -517,13 +522,15 @@ def test_benchmark_json_gained_the_saving_cell_and_lost_nothing():
     for m in b["per_layer"]:
         if m["name"] == "boot.compile_s":
             assert m["workloads"] == accepted
-        elif m["name"] not in CKPT_METRICS:
+        elif m["name"] not in (*CKPT_METRICS, *CKPT_OVERLAP):
             assert CKPT_CELL not in m["workloads"], m["name"]
-    assert [m["name"] for m in b["per_layer"][-3:]] == list(CKPT_METRICS)
-    for m in b["per_layer"][-3:]:
+    assert [m["name"] for m in b["per_layer"][-5:-2]] == list(CKPT_METRICS)
+    assert [m["name"] for m in b["per_layer"][-2:]] == list(CKPT_OVERLAP)
+    for m in b["per_layer"][-5:]:
         assert m["workloads"] == [CKPT_CELL]
         assert (m["layer"], m["moves"], m["source"]) == (
-            "checkpoint", "records_per_s", "program_span")
+            "checkpoint", "records_per_s",
+            CKPT_OVERLAP.get(m["name"], "program_span"))
 
 
 def ckpt_snap(saves: int, sums_ms: dict) -> dict:
@@ -557,6 +564,54 @@ def test_ckpt_readers_read_a_number_or_nothing(name):
     bare = {"rep": {"spans": {"fsx.serve": {"n": 1, "sum_us": 1.0}}}}
     assert mod.read(SimpleNamespace(snap0=bare, snap1=bare,
                                     window_s=20.0)) is None
+
+
+def overlap_snap(records: int, saves: int, hold_ms: float,
+                 last: dict | None) -> dict:
+    snap = ckpt_snap(saves, {"hold": hold_ms})
+    snap["rep"]["records"] = records
+    snap["rep"]["checkpoint"]["last"] = last
+    return snap
+
+
+@pytest.mark.parametrize("name", list(CKPT_OVERLAP))
+def test_overlap_readers_read_a_number_or_nothing(name):
+    """The hold's share of the window, and serving's rate while the save
+    was in flight over the window's: a number where the program has the
+    span and the counters, nothing where it has not (the parent's
+    program: a save that holds the dispatch thread for its length)."""
+    h = harness()
+    mod = h.load_module("metrics", name)
+    entry = next(m for m in bench()["per_layer"] if m["name"] == name)
+    assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
+        name, entry["unit"], entry["layer"], entry["moves"])
+    # 20 s at 2 M records/s, the save 3.5 s of it with 20 ms of hold,
+    # serving meanwhile at 1.8 M/s
+    last = {"at": 1.0, "seconds": 3.5, "bytes": 1, "batches": 9,
+            "records": 7, "clock_s": 1.0, "hold_s": 0.02,
+            "records_during": int(1.8e6 * 3.48)}
+    ctx = SimpleNamespace(
+        snap0=overlap_snap(1_000_000, 0, 0.0, None), window_s=20.0,
+        snap1=overlap_snap(41_000_000, 1, 20.0, last))
+    want = {"ckpt.hold_share.tput": 100 * 0.02 / 20.0,
+            "ckpt.overlap_serve_share.tput": 90.0}
+    assert mod.read(ctx) == pytest.approx(want[name])
+    # the parent's program: a save with no hold and no such counters
+    parent = dict(last)
+    del parent["hold_s"], parent["records_during"]
+    snap0 = ckpt_snap(0, dict.fromkeys(("fetch", "crc"), 0.0))
+    snap0["rep"]["records"] = 1_000_000
+    snap1 = ckpt_snap(1, {"fetch": 500.0, "crc": 100.0})
+    snap1["rep"].update(records=36_000_000)
+    snap1["rep"]["checkpoint"]["last"] = parent
+    assert mod.read(SimpleNamespace(snap0=snap0, snap1=snap1,
+                                    window_s=20.0)) is None
+    # no save in the window: no time held, no rate during a save
+    idle = SimpleNamespace(snap0=overlap_snap(1, 1, 20.0, last),
+                           snap1=overlap_snap(2, 1, 20.0, last),
+                           window_s=20.0)
+    assert mod.read(idle) == (0.0 if name == "ckpt.hold_share.tput"
+                              else None)
 
 
 def test_an_engine_without_the_policy_fails_the_run_at_once(tmp_path,

@@ -7,6 +7,7 @@ exploring the streamed publish; and the engine's save policy
 
 import io
 import json
+import threading
 import time
 import tracemalloc
 import zipfile
@@ -279,6 +280,208 @@ class TestEngineSavesOnItsGrid:
         assert c["saves"] >= 2 and c["skipped"] >= 2 * c["saves"] - 1
         assert rep.records < 128 * 20000  # it served on after each save
         assert pol.last["seconds"] >= 0.3
+
+
+class HeldFS(durable.RealFS):
+    """A file system whose streamed publish waits for ``go`` before it
+    writes a byte: a save held in flight.  It logs each publish's start
+    and end by thread, and the most publishes it ever had open at once."""
+
+    def __init__(self):
+        self.go = threading.Event()
+        self.entered = threading.Event()
+        self.log: list[tuple[str, str]] = []
+        self.open = self.most_open = 0
+        self._lock = threading.Lock()
+
+    def write_stream(self, path, write, **kw):
+        me = threading.current_thread().name
+        with self._lock:
+            self.open += 1
+            self.most_open = max(self.most_open, self.open)
+            self.log.append(("start", me))
+        self.entered.set()
+        try:
+            assert self.go.wait(120), "the held save was never let go"
+            super().write_stream(path, write, **kw)
+        finally:
+            with self._lock:
+                self.open -= 1
+                self.log.append(("end", me))
+
+
+def boundary_spy(monkeypatch) -> dict:
+    """``checkpoint.snapshot`` wrapped: the host copy of the table, the
+    stats and the clock anchor it is handed, at the boundary."""
+    seen = {}
+    real = ckpt.snapshot
+
+    def spy(table, stats, t0_ns, **kw):
+        seen.update(key=np.array(table.key), state=np.array(table.state),
+                    stats=[np.array(v) for v in stats], t0_ns=t0_ns)
+        return real(table, stats, t0_ns, **kw)
+
+    monkeypatch.setattr(ckpt, "snapshot", spy)
+    return seen
+
+
+class TestTheSaveOverlapsServing:
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_the_held_save_is_the_boundarys_state_bit_for_bit(
+            self, tmp_path, monkeypatch, shards):
+        """The save is held in flight while the engine serves on, each
+        step donating and changing the table; what it publishes is the
+        table, stats and clock of its boundary to the bit, taken by one
+        device program into buffers of their own (the key copied too),
+        sent in pieces."""
+        from flowsentryx_tpu.parallel import make_mesh
+
+        monkeypatch.setattr(ckpt, "PIECE_BYTES", 4000)  # 5 a column
+        eng = engine(128 * 4000, mesh=make_mesh(shards) if shards > 1
+                     else None)
+        seen = boundary_spy(monkeypatch)
+        # the save thread cuts its pieces with programs of one device
+        # each: one over several, launched beside the serving steps,
+        # could order its collectives differently on each device
+        cut_on = []
+        piece_program = ckpt._piece_program
+
+        def one_device(rows):
+            def cut(a, start):
+                cut_on.append(len(a.sharding.device_set))
+                return piece_program(rows)(a, start)
+            return cut
+
+        monkeypatch.setattr(ckpt, "_piece_program", one_device)
+        fs = HeldFS()
+        after = []
+
+        def on_reap(n, t):
+            if fs.entered.is_set():
+                after.append(n)
+                if len(after) == 16:
+                    fs.go.set()
+
+        eng.on_reap = on_reap
+        pol = ckpt.CheckpointPolicy(tmp_path / "h.npz", 3600.0, first_s=0.0)
+        eng.set_checkpoint_policy(pol)
+        with durable.use_fs(fs):
+            rep = eng.run()
+        assert len(after) >= 16 and fs.go.is_set()
+        assert fs.log == [("start", "fsx-ckpt"), ("end", "fsx-ckpt")]
+        # serving changed the table after the boundary
+        assert not np.array_equal(np.asarray(eng.table.state),
+                                  seen["state"])
+        loaded = ckpt.load_checkpoint(tmp_path / "h.npz")
+        assert loaded.crc_checked and loaded.n_shards == shards
+        np.testing.assert_array_equal(loaded.table.key, seen["key"])
+        assert loaded.table.state.view(np.uint32).tobytes() == \
+            seen["state"].view(np.uint32).tobytes()
+        for got, want in zip(loaded.stats, seen["stats"]):
+            np.testing.assert_array_equal(got, want)
+        assert loaded.t0_ns == seen["t0_ns"] == eng.batcher.t0_ns
+        last = rep.checkpoint["last"]
+        assert last["clock_s"] == pytest.approx(float(
+            seen["state"][:, schema.TableCol.LAST_SEEN].max()))
+        assert schema.stat_value(loaded.stats.batches) == last["batches"]
+        # the hold is a sliver of the save, and serving went on beside it
+        assert 0 < last["hold_s"] < last["seconds"]
+        assert last["records_during"] >= 128 * 16
+        assert rep.spans["fsx.ckpt.hold"]["n"] == 1
+        assert rep.spans["fsx.ckpt.fetch"]["n"] >= 4 * 13
+        assert len(cut_on) >= 4 * 13 and set(cut_on) == {1}
+
+    def test_the_snapshots_key_outlives_the_next_donated_step(
+            self, monkeypatch):
+        import jax
+
+        table = host_table(1 << 12)
+        dev = schema.IpTableState(key=jax.device_put(table.key),
+                                  state=jax.device_put(table.state))
+        sections = ckpt.snapshot(dev, stats_of(), 5)
+        key = sections["table_key"]
+        assert key.unsafe_buffer_pointer() != dev.key.unsafe_buffer_pointer()
+        step = jax.jit(lambda k, s: (k + 1, s * 2), donate_argnums=(0, 1))
+        step(dev.key, dev.state)
+        assert dev.key.is_deleted() and not key.is_deleted()
+        np.testing.assert_array_equal(np.asarray(key), table.key)
+        np.testing.assert_array_equal(
+            np.asarray(sections["table_ml_votes"]),
+            table.state[:, schema.TableCol.ML_VOTES])
+        # sent in pieces: 1,000 rows, then the 96 left
+        monkeypatch.setattr(ckpt, "PIECE_BYTES", 4000)
+        pieces = [ckpt._make(p) for p in ckpt._cut(key)]
+        assert [len(p) for p in pieces] == [1000] * 4 + [96]
+        np.testing.assert_array_equal(np.concatenate(pieces), table.key)
+
+    def test_policy_off_and_an_explicit_save_wait_for_the_save_in_flight(
+            self, tmp_path):
+        """``set_checkpoint_policy(None)`` and ``checkpoint()`` each wait
+        for the policy's save in flight: the explicit save publishes
+        after it, so ``.prev`` holds the policy's generation."""
+        eng = engine(128 * 8)
+        eng.run()
+        path = tmp_path / "p.npz"
+        for stop in (lambda: eng.set_checkpoint_policy(None),
+                     lambda: eng.checkpoint(path)):
+            pol = ckpt.CheckpointPolicy(path, 3600.0, first_s=0.0)
+            eng.set_checkpoint_policy(pol)
+            fs = HeldFS()
+            with durable.use_fs(fs):
+                eng._checkpoint_due()  # the boundary: the hold, then go
+                assert fs.entered.wait(60) and pol.saves == 0
+                waiter = threading.Thread(target=stop)
+                waiter.start()
+                waiter.join(0.3)
+                assert waiter.is_alive()  # held behind the save in flight
+                fs.go.set()
+                waiter.join(60)
+            assert not waiter.is_alive() and pol.saves == 1
+            assert fs.most_open == 1
+        # the explicit save ran after the policy's had published
+        assert fs.log == [("start", "fsx-ckpt"), ("end", "fsx-ckpt"),
+                          ("start", waiter.name), ("end", waiter.name)]
+        prev = ckpt.prev_path(path)
+        assert prev.exists()
+        assert ckpt.load_checkpoint(prev).crc_checked
+        assert ckpt.load_checkpoint(path).crc_checked
+        assert eng._save is None
+
+    def test_a_fault_in_the_save_thread_raises_on_the_dispatch_thread(
+            self, tmp_path):
+        class FullFS(durable.RealFS):
+            def write_stream(self, path, write, **kw):
+                raise OSError("disk full")
+
+        total = 128 * 20000
+        eng = engine(total)
+        pol = ckpt.CheckpointPolicy(tmp_path / "f.npz", 3600.0, first_s=0.0)
+        eng.set_checkpoint_policy(pol)
+        with durable.use_fs(FullFS()), pytest.raises(OSError,
+                                                     match="disk full"):
+            eng.run()
+        # raised at a boundary soon after, not at the end of the stream
+        assert eng.batcher.records_emitted < total
+        assert pol.saves == 0 and eng._save is None
+        assert list(tmp_path.iterdir()) == []
+        eng.set_checkpoint_policy(None)  # nothing left to raise
+
+    def test_a_slot_due_while_a_save_is_in_flight_is_skipped_and_counted(
+            self, tmp_path):
+        eng = engine(128 * 20000)
+        fs = HeldFS()
+        pol = ckpt.CheckpointPolicy(tmp_path / "s.npz", 0.05, first_s=0.0)
+        eng.set_checkpoint_policy(pol)
+        threading.Timer(0.4, fs.go.set).start()
+        with durable.use_fs(fs):
+            rep = eng.run(max_seconds=1.0)
+        c = rep.checkpoint
+        # one save in flight at a time, the first held for 0.4 s: the
+        # seven or more slots that fell due meanwhile were skipped
+        assert fs.most_open == 1
+        assert c["saves"] >= 1 and c["skipped"] >= 7
+        assert c["saves"] == rep.spans["fsx.ckpt.hold"]["n"]
+        assert rep.spans["fsx.ckpt.publish"]["n"] == c["saves"]
 
 
 def test_serve_checkpoint_every_goes_through_the_policy(tmp_path, capsys,

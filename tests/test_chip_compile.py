@@ -369,3 +369,31 @@ def test_sharded_table_summary_needs_the_xla_twin(mesh4, mosaic):
     with pytest.raises(NotImplementedError, match="partitioned"):
         lowered(True).compile()
     assert "all-reduce(" in lowered(False).compile().as_text()
+
+
+def test_the_saves_snapshot_takes_no_temporary_at_2_26_rows(one_chip):
+    """The periodic save's snapshot (``checkpoint.snapshot``) at the
+    benchmark's 2^26 rows: the key copied and the twelve columns split
+    out, so that the chip holds the table and one copy of its bytes and
+    no third table's worth; then each section cut into ``PIECE_BYTES``
+    pieces as it is sent, by one small program a piece size."""
+    from flowsentryx_tpu.engine import checkpoint as ckpt
+
+    rows = 1 << 26
+    key = jax.ShapeDtypeStruct((rows,), jnp.uint32, sharding=one_chip)
+    state = jax.ShapeDtypeStruct((rows, schema.NUM_TABLE_COLS),
+                                 jnp.float32, sharding=one_chip)
+    mem = ckpt._snapshot_program().lower(
+        key, state).compile().memory_analysis()
+    # the sections, and the output tuple's table of their addresses
+    table_bytes = rows * (schema.NUM_TABLE_COLS + 1) * 4
+    assert 0 <= mem.output_size_in_bytes - table_bytes < 1 << 12
+    assert mem.temp_size_in_bytes == 0
+    piece_rows = ckpt.PIECE_BYTES // 4
+    assert rows % piece_rows == 0 and rows // piece_rows > 1
+    column = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    mem = ckpt._piece_program(piece_rows).lower(
+        column, start).compile().memory_analysis()
+    assert mem.output_size_in_bytes == ckpt.PIECE_BYTES
+    assert mem.temp_size_in_bytes == 0

@@ -386,14 +386,14 @@ class TestCheckpointV2:
         # a crash mid-snapshot must leave the previous snapshot intact
         # (tmp + os.replace) and no temp litter behind: the disk fills
         # once the streamed write has put sections into the temp file
-        add = ckpt._StoredZip.add
+        begin = ckpt._StoredZip.begin
 
         def boom(self, *a, **k):
             if len(self.members) == 3:
                 raise OSError("disk full")
-            add(self, *a, **k)
+            begin(self, *a, **k)
 
-        monkeypatch.setattr(ckpt._StoredZip, "add", boom)
+        monkeypatch.setattr(ckpt._StoredZip, "begin", boom)
         with pytest.raises(OSError):
             eng.checkpoint(path)
         monkeypatch.undo()
