@@ -22,6 +22,12 @@
 //             kernel feature ringbuf into the shm ring and apply
 //             engine verdicts to the blacklist map.
 //
+// Key width (--key-bits): 32 writes 48 B fsx_flow_record rings and
+// reads 16 B verdicts keyed by the u32 source (an IPv6 source as its
+// XOR fold); 128 writes 64 B fsx_flow_record6 rings and reads 32 B
+// verdicts keyed by the full source, IPv4 as ::ffff:a.b.c.d (upstream
+// src/fsx_struct.h:9 __u128) — the engine's table.key_bits 128.
+//
 // Output: one JSON line on stdout at exit with counters; progress on
 // stderr.  The Python integration test asserts on the JSON.
 
@@ -34,6 +40,8 @@
 #include <random>
 #include <string>
 #include <thread>
+#include <unordered_set>
+#include <utility>
 #include <vector>
 
 #include <net/if.h>
@@ -117,6 +125,13 @@ struct Options {
     // --rule proto:dport (key = (proto << 16) | dport, 0 = wildcard)
     std::vector<std::pair<uint32_t, uint64_t>> rules;
     bool compact = false;              // 16 B kernel-quantized records
+    // --key-bits 32|128: the width of a source key on both rings
+    uint32_t key_bits = 32;
+    // --sim at --key-bits 128: the share of each pool drawn as IPv6
+    // /128s inside 2000::/3 (the rest ::ffff:a.b.c.d), and how many
+    // IPv6 attackers take the XOR fold of a distinct benign IPv4 source
+    double v6_fraction = 0.0;
+    uint32_t fold_collide = 0;
     // --pace: sim produces at --rate in REAL time (sleeps when ahead)
     // instead of free-running against ring backpressure.  A real data
     // plane delivers records at line rate, not at memcpy speed; paced
@@ -152,6 +167,15 @@ struct Options {
                  "  --attack-ips N        sim attack pool (default 64, min 1)\n"
                  "  --benign-ips N        sim benign pool (default 1024, min 1)\n"
                  "  --seed N              sim rng seed\n"
+                 "  --key-bits 32|128     source key width on both rings (default 32:\n"
+                 "                        48 B records, 16 B verdicts, an IPv6 source\n"
+                 "                        as its XOR fold; 128: 64 B records, 32 B\n"
+                 "                        verdicts, the full source, IPv4 as ::ffff:a.b.c.d)\n"
+                 "  --v6-fraction F       --key-bits 128: share of each sim pool drawn\n"
+                 "                        as IPv6 /128s in 2000::/3 (default 0)\n"
+                 "  --fold-collide N      --key-bits 128: N IPv6 sim attackers whose XOR\n"
+                 "                        fold is the address of a distinct benign IPv4\n"
+                 "                        source (default 0)\n"
                  "bpf mode (--bpf IFACE, or --bpf none to load without attach):\n"
                  "  --prog-image PATH     FSXPROG image (default kern/build/fsx_prog.img;\n"
                  "                        emit: python -m flowsentryx_tpu.bpf.image)\n"
@@ -177,6 +201,36 @@ uint32_t fsx_shard_of(uint32_t saddr, uint32_t n) {
     return (uint32_t)((((uint64_t)saddr * 2654435761ULL) >> 16) % n);
 }
 
+// A full source, as four u32 loads of its 16 wire bytes (the layout of
+// fsx_flow_record6.saddr6 and fsx_verdict_record6.saddr6).
+struct Key128 {
+    uint32_t w[4];
+    bool operator==(const Key128 &o) const {
+        return w[0] == o.w[0] && w[1] == o.w[1] && w[2] == o.w[2] &&
+               w[3] == o.w[3];
+    }
+};
+
+// One u32 of a full source for the shard and table hashes — MUST
+// mirror flowsentryx_tpu.core.schema.shard_word6: each word times its
+// own odd constant, so that no plain XOR fold decides the shard.
+uint32_t fsx_shard_word6(const uint32_t w[4]) {
+    return (w[0] * 0xC2B2AE3Du) ^ (w[1] * 0x85EBCA77u) ^
+           (w[2] * 0x9E3779B1u) ^ w[3];
+}
+
+uint32_t key_hash_word(uint32_t k) { return k; }
+uint32_t key_hash_word(const Key128 &k) { return fsx_shard_word6(k.w); }
+
+// ::ffff:0:0/96 (RFC 4291 s2.5.5.2) in wire words: an IPv4 source.
+constexpr uint32_t kV4MappedWord2 = 0xFFFF0000u;
+
+Key128 v4_mapped(uint32_t saddr) { return Key128{{0, 0, kV4MappedWord2, saddr}}; }
+
+bool is_v4_mapped(const uint32_t w[4]) {
+    return w[0] == 0 && w[1] == 0 && w[2] == kV4MappedWord2;
+}
+
 std::string shard_path(const std::string &base, uint32_t k, uint32_t n) {
     return n <= 1 ? base : base + "." + std::to_string(k);
 }
@@ -186,9 +240,11 @@ std::string shard_path(const std::string &base, uint32_t k, uint32_t n) {
 // every ring sees one contiguous produce() per chunk, not per record.
 class ShardedRings {
 public:
+    // key128: the source at saddr_off is 16 bytes (fsx_flow_record6)
     ShardedRings(const std::string &base, uint32_t n, uint64_t capacity,
-                 size_t rec_size, size_t saddr_off)
-        : rec_size_(rec_size), saddr_off_(saddr_off), lanes_(n) {
+                 size_t rec_size, size_t saddr_off, bool key128 = false)
+        : rec_size_(rec_size), saddr_off_(saddr_off), key128_(key128),
+          lanes_(n) {
         rings_.reserve(n);
         for (uint32_t k = 0; k < n; k++)
             rings_.push_back(
@@ -206,9 +262,11 @@ public:
             l.clear();
         const char *p = (const char *)records;
         for (uint64_t i = 0; i < n; i++) {
-            uint32_t saddr;
-            std::memcpy(&saddr, p + i * rec_size_ + saddr_off_, 4);
-            auto &lane = lanes_[fsx_shard_of(saddr, ns)];
+            uint32_t saddr[4];
+            std::memcpy(saddr, p + i * rec_size_ + saddr_off_,
+                        key128_ ? 16 : 4);
+            auto &lane = lanes_[fsx_shard_of(
+                key128_ ? fsx_shard_word6(saddr) : saddr[0], ns)];
             lane.insert(lane.end(), p + i * rec_size_,
                         p + (i + 1) * rec_size_);
         }
@@ -239,6 +297,7 @@ public:
 
 private:
     size_t rec_size_, saddr_off_;
+    bool key128_;
     std::vector<std::vector<char>> lanes_;
     std::vector<fsx::ShmRing> rings_;
 };
@@ -290,7 +349,31 @@ fsx_stats read_stats(int stats_fd) {
     return total;
 }
 
+// A verdict into the kernel's blacklist.  A 32-bit key (an IPv4 source,
+// or the XOR fold of an IPv6 one) goes to blacklist_map.  A full
+// source goes by its kind: v4-mapped to blacklist_map under its IPv4
+// address, IPv6 to blacklist_v6 under all 16 bytes (upstream
+// src/fsx_kern.c:66-72,159-176) — never under its fold, which a host
+// choosing its /64's low bits can make equal an innocent IPv4 address.
+void write_block(int bl_fd, int, const fsx_verdict_record &v) {
+    uint32_t saddr = v.saddr;
+    uint64_t until = v.until_ns;
+    fsxbpf::map_update(bl_fd, &saddr, &until);
+}
+
+void write_block(int bl_fd, int bl6_fd, const fsx_verdict_record6 &v) {
+    uint32_t w[4];
+    std::memcpy(w, v.saddr6, sizeof(w));
+    uint64_t until = v.until_ns;
+    if (is_v4_mapped(w))
+        fsxbpf::map_update(bl_fd, &w[3], &until);
+    else
+        fsxbpf::map_update(bl6_fd, w, &until);
+}
+
 // --bpf backend: the real kernel seam (jobs 1+2 of the header comment).
+// VRec is the verdict record of --key-bits (16 B or 32 B).
+template <class VRec>
 int run_bpf(const Options &o) {
     auto lp = fsxbpf::load_image(o.prog_image);
     if (!lp.error.empty()) {
@@ -359,12 +442,19 @@ int run_bpf(const Options &o) {
         std::fprintf(stderr, "fsxd: pinned under %s\n", o.pin_dir.c_str());
     }
 
+    // --key-bits 128 takes 64 B records from the kernel ring: an image
+    // that emits 48 B ones trips the record-size check below
+    const bool key128 = o.key_bits == 128;
     const size_t rec_size = o.compact ? sizeof(fsx_compact_record)
+                            : key128  ? sizeof(fsx_flow_record6)
                                       : sizeof(fsx_flow_record);
     ShardedRings frings(o.feature_ring, o.shards, o.ring_capacity, rec_size,
-                        o.compact ? 0 : offsetof(fsx_flow_record, saddr));
+                        o.compact ? 0
+                        : key128  ? offsetof(fsx_flow_record6, saddr6)
+                                  : offsetof(fsx_flow_record, saddr),
+                        key128);
     auto vring = fsx::ShmRing::create(o.verdict_ring, o.verdict_ring_capacity,
-                                      sizeof(fsx_verdict_record));
+                                      sizeof(VRec));
 
     const fsxbpf::ImageMapSpec *rspec = lp.spec("feature_ring");
     fsxbpf::RingbufConsumer rb;
@@ -374,11 +464,12 @@ int run_bpf(const Options &o) {
     }
 
     int blacklist_fd = lp.map_fd("blacklist_map");
+    int blacklist_v6_fd = lp.map_fd("blacklist_v6");
     int stats_fd = lp.map_fd("stats_map");
     uint64_t forwarded = 0, dropped_ring_full = 0, verdicts = 0;
     bool size_warned = false, first_interval_done = false;
     std::vector<uint8_t> buf;
-    std::vector<fsx_verdict_record> vbatch(4096);
+    std::vector<VRec> vbatch(4096);
     uint64_t t_start = now_ns(), next_report = t_start + 1'000'000'000ULL;
 
     while (!g_stop) {
@@ -397,13 +488,14 @@ int run_bpf(const Options &o) {
                          "the configured %zu-byte size — the loaded image's "
                          "emit format disagrees with %s (records are being "
                          "dropped)\n",
-                         rec_size, o.compact ? "--compact" : "48 B default");
+                         rec_size, o.compact ? "--compact"
+                                   : key128  ? "--key-bits 128"
+                                             : "48 B default");
         }
         // 2. verdict ingress: shm ring → blacklist map
         uint64_t nv = vring.consume(vbatch.data(), vbatch.size());
         for (uint64_t i = 0; i < nv; i++)
-            fsxbpf::map_update(blacklist_fd, &vbatch[i].saddr,
-                               &vbatch[i].until_ns);
+            write_block(blacklist_fd, blacklist_v6_fd, vbatch[i]);
         verdicts += nv;
 
         uint64_t t = now_ns();
@@ -437,7 +529,9 @@ int run_bpf(const Options &o) {
                                  "fsxd: FATAL: 100%% of kernel records "
                                  "skipped (record-size mismatch between "
                                  "the loaded image and %s); exiting\n",
-                                 o.compact ? "--compact" : "the 48 B default");
+                                 o.compact ? "--compact"
+                                 : key128  ? "--key-bits 128"
+                                           : "the 48 B default");
                     return 2;
                 }
             }
@@ -451,8 +545,7 @@ int run_bpf(const Options &o) {
     // the sim path's exit contract)
     while (uint64_t extra = vring.consume(vbatch.data(), vbatch.size())) {
         for (uint64_t i = 0; i < extra; i++)
-            fsxbpf::map_update(blacklist_fd, &vbatch[i].saddr,
-                               &vbatch[i].until_ns);
+            write_block(blacklist_fd, blacklist_v6_fd, vbatch[i]);
         verdicts += extra;
     }
 
@@ -576,8 +669,32 @@ Options parse(int argc, char **argv) {
             o.n_benign_ips = (uint32_t)std::stoul(next());
         else if (a == "--seed")
             o.seed = std::stoull(next());
+        else if (a == "--key-bits")
+            o.key_bits = (uint32_t)std::stoul(next());
+        else if (a == "--v6-fraction")
+            o.v6_fraction = std::stod(next());
+        else if (a == "--fold-collide")
+            o.fold_collide = (uint32_t)std::stoul(next());
         else
             usage(argv[0]);
+    }
+    if (o.key_bits != 32 && o.key_bits != 128) {
+        std::fprintf(stderr, "fsxd: --key-bits must be 32 or 128\n");
+        std::exit(1);
+    }
+    if (!(o.v6_fraction >= 0.0 && o.v6_fraction <= 1.0)) {
+        std::fprintf(stderr, "fsxd: --v6-fraction must be in [0, 1]\n");
+        std::exit(1);
+    }
+    if (o.key_bits == 32 && (o.v6_fraction > 0 || o.fold_collide > 0)) {
+        std::fprintf(stderr, "fsxd: --v6-fraction and --fold-collide need "
+                     "--key-bits 128 (a 32-bit key holds no IPv6 source)\n");
+        std::exit(1);
+    }
+    if (o.key_bits == 128 && o.compact) {
+        std::fprintf(stderr, "fsxd: --compact records carry a 32-bit "
+                     "source: not with --key-bits 128\n");
+        std::exit(1);
     }
     if ((o.bucket_rate_bps == 0) != (o.bucket_burst_bytes == 0)) {
         std::fprintf(stderr, "fsxd: --bucket-rate-bytes and "
@@ -653,24 +770,27 @@ private:
     int next_ = N;
 };
 
-// The sim path's blocked sources, saddr -> until_ns: open addressing,
-// linear probing, 16-byte slots (a lookup is one cache line; the
-// std::unordered_map this replaces chased nodes for a fifth of the
-// generator's time at half a million entries).  As with that map's
-// lazy erase, an entry counts in size() until a lookup finds it
-// expired.  A slot is never freed: the table holds every source ever
-// blocked, which the sim pools bound.
+// The sim path's blocked sources, key -> until_ns: open addressing,
+// linear probing, 16-byte slots for a u32 key and 32-byte ones for a
+// Key128 (a lookup is one cache line; the std::unordered_map this
+// replaces chased nodes for a fifth of the generator's time at half a
+// million entries).  As with that map's lazy erase, an entry counts in
+// size() until a lookup finds it expired.  A slot is never freed: the
+// table holds every source ever blocked, which the sim pools bound.
+// A Key128 table keys by all 128 bits, so two sources that share an
+// XOR fold are suppressed apart.
+template <class K>
 class Blacklist {
 public:
     Blacklist() : slots_(1 << 12) {}
 
-    void block(uint32_t saddr, uint64_t until_ns) {
+    void block(const K &key, uint64_t until_ns) {
         if ((used_ + 1) * 2 > slots_.size())
             grow();
-        Slot &s = find(saddr);
+        Slot &s = find(key);
         if (!s.used) {
             s.used = 1;
-            s.saddr = saddr;
+            s.key = key;
             used_++;
         }
         if (!s.live) {
@@ -680,10 +800,10 @@ public:
         s.until_ns = until_ns;
     }
 
-    // true while `saddr` is blocked at `now_ns`; the lookup that finds
+    // true while `key` is blocked at `now_ns`; the lookup that finds
     // its block expired drops it
-    bool blocked(uint32_t saddr, uint64_t now_ns) {
-        Slot &s = find(saddr);
+    bool blocked(const K &key, uint64_t now_ns) {
+        Slot &s = find(key);
         if (!s.used || !s.live)
             return false;
         if (now_ns < s.until_ns)
@@ -697,16 +817,17 @@ public:
 
 private:
     struct Slot {
-        uint32_t saddr = 0;
+        K key{};
         uint16_t used = 0, live = 0;
         uint64_t until_ns = 0;
     };
 
-    Slot &find(uint32_t saddr) {
+    Slot &find(const K &key) {
         // the shard router's Fibonacci hash (fsx_shard_of), masked
         size_t mask = slots_.size() - 1;
-        size_t i = (((uint64_t)saddr * 2654435761ULL) >> 16) & mask;
-        while (slots_[i].used && slots_[i].saddr != saddr)
+        size_t i = (((uint64_t)key_hash_word(key) * 2654435761ULL) >> 16) &
+                   mask;
+        while (slots_[i].used && !(slots_[i].key == key))
             i = (i + 1) & mask;
         return slots_[i];
     }
@@ -716,7 +837,7 @@ private:
         old.swap(slots_);
         for (const Slot &s : old)
             if (s.used)
-                find(s.saddr) = s;
+                find(s.key) = s;
     }
 
     std::vector<Slot> slots_;
@@ -747,12 +868,42 @@ public:
         spoof_mul_[0] = (uint32_t)k | 1;
         spoof_mul_[1] = (uint32_t)(k >> 32) | 1;
         spoof_xor_ = (uint32_t)(k >> 17) & kMask31;
+        if (o.key_bits == 128)
+            draw_keys128();
     }
 
     uint64_t spoofed() const { return spoofed_; }
+    uint64_t v6_sources() const { return v6_sources_; }
+    uint64_t planted() const { return planted_; }
+
+    // 64 B records: the 48 B ones the same seed gives, each source
+    // widened to its pool entry's full address (a spoofed one to its
+    // v4-mapped form) and an IPv6 one flagged FSX_FLAG_IPV6.
+    void fill(std::vector<fsx_flow_record6> &out, size_t n) {
+        fill(narrow_, n);
+        out.resize(n);
+        const size_t na = attack_keys_.size();
+        for (size_t i = 0; i < n; i++) {
+            const fsx_flow_record &r = narrow_[i];
+            fsx_flow_record6 &w = out[i];
+            std::memset(&w, 0, sizeof(w));
+            uint32_t k = pool_idx_[i];
+            Key128 key = k == kNoPool ? v4_mapped(r.saddr)
+                         : k < na     ? attack_keys_[k]
+                                      : benign_keys_[k - na];
+            w.ts_ns = r.ts_ns;
+            std::memcpy(w.saddr6, key.w, sizeof(key.w));
+            w.pkt_len = r.pkt_len;
+            w.ip_proto = r.ip_proto;
+            w.flags = r.flags | (is_v4_mapped(key.w) ? 0 : FSX_FLAG_IPV6);
+            std::memcpy(w.feat, r.feat, sizeof(w.feat));
+        }
+    }
 
     void fill(std::vector<fsx_flow_record> &out, size_t n) {
         out.resize(n);
+        if (key128_)
+            pool_idx_.resize(n);
         std::uniform_real_distribution<double> u01(0.0, 1.0);
         for (size_t i = 0; i < n; i++) {
             fsx_flow_record &r = out[i];
@@ -765,10 +916,17 @@ public:
             // slots), NOT the pre-r5 variance/avg-size pair.
             if (attack) {
                 // the extra draw is made only where the option is on
-                if (o_.spoof_fraction > 0 && u01(rng_) < o_.spoof_fraction)
+                if (o_.spoof_fraction > 0 &&
+                    u01(rng_) < o_.spoof_fraction) {
                     r.saddr = next_spoofed();
-                else
-                    r.saddr = attack_ips_[rng_() % attack_ips_.size()];
+                    if (key128_)
+                        pool_idx_[i] = kNoPool;
+                } else {
+                    uint32_t k = (uint32_t)(rng_() % attack_ips_.size());
+                    r.saddr = attack_ips_[k];
+                    if (key128_)
+                        pool_idx_[i] = k;
+                }
                 r.pkt_len = 60 + rng_() % 20;
                 r.ip_proto = 17;  // UDP flood
                 r.feat[0] = 80;
@@ -785,7 +943,10 @@ public:
                 r.feat[6] = rng_() % 20;
                 r.feat[7] = (uint32_t)(iat * (1 + rng_() % 3));
             } else {
-                r.saddr = benign_ips_[rng_() % benign_ips_.size()];
+                uint32_t k = (uint32_t)(rng_() % benign_ips_.size());
+                r.saddr = benign_ips_[k];
+                if (key128_)
+                    pool_idx_[i] = (uint32_t)attack_ips_.size() + k;
                 r.pkt_len = 100 + rng_() % 1400;
                 r.ip_proto = 6;
                 r.flags = FSX_FLAG_TCP;
@@ -809,6 +970,57 @@ public:
 
 private:
     static constexpr uint32_t kMask31 = 0x7FFFFFFFu;
+    static constexpr uint32_t kNoPool = 0xFFFFFFFFu;  // a spoofed source
+
+    // --key-bits 128: each pool entry's full source.  The first
+    // round(--v6-fraction x size) entries of a pool are IPv6 /128s in
+    // 2000::/3 (first byte 0x20-0x3F), the rest ::ffff:a.b.c.d of the
+    // entry's IPv4 address.  Then the plant: IPv6 attacker i <
+    // --fold-collide takes as its last word the one that makes its
+    // XOR fold (kern/parsing.h fsx_fold_ip6) the address of the i-th
+    // distinct benign IPv4 source.  Drawn from the seed by a generator
+    // of their own, so the pools' draws stay those of --key-bits 32.
+    void draw_keys128() {
+        key128_ = true;
+        Mt64 r(o_.seed ^ 0xD1B54A32D192ED03ULL);
+        auto widen = [&](const std::vector<uint32_t> &ips,
+                         std::vector<Key128> &keys) {
+            size_t n6 = (size_t)std::llround(o_.v6_fraction * ips.size());
+            keys.resize(ips.size());
+            for (size_t i = 0; i < ips.size(); i++) {
+                if (i >= n6) {
+                    keys[i] = v4_mapped(ips[i]);
+                    continue;
+                }
+                uint64_t a = r(), b = r();
+                keys[i] = Key128{{((uint32_t)a & ~0xE0u) | 0x20u,
+                                  (uint32_t)(a >> 32), (uint32_t)b,
+                                  (uint32_t)(b >> 32)}};
+            }
+            v6_sources_ += n6;
+            return n6;
+        };
+        size_t a6 = widen(attack_ips_, attack_keys_);
+        widen(benign_ips_, benign_keys_);
+        std::unordered_set<uint32_t> taken;
+        size_t j = 0;
+        for (uint32_t i = 0; i < o_.fold_collide; i++) {
+            while (j < benign_keys_.size() &&
+                   (!is_v4_mapped(benign_keys_[j].w) ||
+                    !taken.insert(benign_ips_[j]).second))
+                j++;
+            if (i >= a6 || j == benign_keys_.size()) {
+                std::fprintf(stderr,
+                             "fsxd: --fold-collide %u: only %u IPv6 "
+                             "attackers with a distinct benign IPv4 fold\n",
+                             o_.fold_collide, i);
+                std::exit(1);
+            }
+            uint32_t *w = attack_keys_[i].w;
+            w[3] = benign_ips_[j++] ^ w[0] ^ w[1] ^ w[2];
+            planted_++;
+        }
+    }
 
     // A bijection of the 31-bit numbers: multiplying by an odd number
     // modulo 2^31, xor with a constant and xor with a right shift of
@@ -839,31 +1051,44 @@ private:
     Options o_;
     Mt64 rng_;
     std::vector<uint32_t> attack_ips_, benign_ips_;
+    bool key128_ = false;
+    std::vector<Key128> attack_keys_, benign_keys_;
+    std::vector<uint32_t> pool_idx_;     // per record of the last fill
+    std::vector<fsx_flow_record> narrow_;
+    uint64_t v6_sources_ = 0, planted_ = 0;
     uint64_t clock_ns_, dt_ns_;
     uint32_t spoof_mul_[2], spoof_xor_;
     uint64_t spoof_n_ = 0, spoofed_ = 0;
 };
 
-}  // namespace
+uint32_t key_of(const fsx_flow_record &r) { return r.saddr; }
+uint32_t key_of(const fsx_verdict_record &v) { return v.saddr; }
 
-int main(int argc, char **argv) {
-    Options o = parse(argc, argv);
-    std::signal(SIGINT, on_signal);
-    std::signal(SIGTERM, on_signal);
+Key128 key_of(const fsx_flow_record6 &r) {
+    Key128 k;
+    std::memcpy(k.w, r.saddr6, sizeof(k.w));
+    return k;
+}
 
-    if (o.mode == "bpf")
-        return run_bpf(o);
-    if (o.compact) {
-        std::fprintf(stderr, "fsxd: --compact requires --bpf (the sim/"
-                             "replay generators emit 48 B records)\n");
-        return 2;
-    }
+Key128 key_of(const fsx_verdict_record6 &v) {
+    Key128 k;
+    std::memcpy(k.w, v.saddr6, sizeof(k.w));
+    return k;
+}
 
+// --sim / --replay: Rec and VRec are the feature and verdict records
+// of --key-bits (48 B and 16 B, or 64 B and 32 B).
+template <class Rec, class VRec>
+int run_stream(const Options &o) {
+    using Key = decltype(key_of(std::declval<Rec>()));
+    constexpr bool key128 = sizeof(Rec) == sizeof(fsx_flow_record6);
     ShardedRings frings(o.feature_ring, o.shards, o.ring_capacity,
-                        sizeof(fsx_flow_record),
-                        offsetof(fsx_flow_record, saddr));
+                        sizeof(Rec),
+                        key128 ? offsetof(fsx_flow_record6, saddr6)
+                               : offsetof(fsx_flow_record, saddr),
+                        key128);
     auto vring = fsx::ShmRing::create(o.verdict_ring, o.verdict_ring_capacity,
-                                      sizeof(fsx_verdict_record));
+                                      sizeof(VRec));
 
     std::fprintf(stderr,
                  "fsxd: mode=%s feature_ring=%s shards=%u verdict_ring=%s\n",
@@ -871,7 +1096,7 @@ int main(int argc, char **argv) {
                  o.verdict_ring.c_str());
 
     uint64_t produced = 0, dropped_ring_full = 0, verdicts = 0, suppressed = 0;
-    Blacklist blacklist;
+    Blacklist<Key> blacklist;
 
     FILE *replay = nullptr;
     if (o.mode == "replay") {
@@ -883,8 +1108,8 @@ int main(int argc, char **argv) {
     }
 
     SimSource sim(o);
-    std::vector<fsx_flow_record> batch;
-    std::vector<fsx_verdict_record> vbatch(4096);
+    std::vector<Rec> batch;
+    std::vector<VRec> vbatch(4096);
     const size_t CHUNK = 2048;
     uint64_t t_start = now_ns();
     uint64_t next_report = t_start + 1'000'000'000ULL;
@@ -917,8 +1142,8 @@ int main(int argc, char **argv) {
         if (want > 0) {
             if (replay) {
                 batch.resize(want);
-                size_t got = std::fread(batch.data(), sizeof(fsx_flow_record),
-                                        want, replay);
+                size_t got = std::fread(batch.data(), sizeof(Rec), want,
+                                        replay);
                 batch.resize(got);
                 if (got == 0)
                     g_stop = 1;
@@ -931,7 +1156,7 @@ int main(int argc, char **argv) {
             uint64_t tnow = batch.empty() ? 0 : batch.back().ts_ns;
             size_t w = 0;
             for (size_t i = 0; i < batch.size(); i++) {
-                if (blacklist.blocked(batch[i].saddr, tnow)) {
+                if (blacklist.blocked(key_of(batch[i]), tnow)) {
                     suppressed++;
                     continue;
                 }
@@ -948,7 +1173,7 @@ int main(int argc, char **argv) {
         // ---- consume verdicts -------------------------------------------
         uint64_t n = vring.consume(vbatch.data(), vbatch.size());
         for (uint64_t i = 0; i < n; i++)
-            blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
+            blacklist.block(key_of(vbatch[i]), vbatch[i].until_ns);
         verdicts += n;
 
         // ---- bounds / pacing --------------------------------------------
@@ -960,7 +1185,7 @@ int main(int argc, char **argv) {
             if (frings.total_readable() == 0 || t > drain_deadline) {
                 uint64_t extra = vring.consume(vbatch.data(), vbatch.size());
                 for (uint64_t i = 0; i < extra; i++)
-                    blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
+                    blacklist.block(key_of(vbatch[i]), vbatch[i].until_ns);
                 verdicts += extra;
                 break;
             }
@@ -988,7 +1213,7 @@ int main(int argc, char **argv) {
     // to the last verdict the ring holds, not one 4,096-record take.
     while (uint64_t extra = vring.consume(vbatch.data(), vbatch.size())) {
         for (uint64_t i = 0; i < extra; i++)
-            blacklist.block(vbatch[i].saddr, vbatch[i].until_ns);
+            blacklist.block(key_of(vbatch[i]), vbatch[i].until_ns);
         verdicts += extra;
     }
 
@@ -997,8 +1222,32 @@ int main(int argc, char **argv) {
     std::printf("{\"produced\": %" PRIu64 ", \"verdicts\": %" PRIu64
                 ", \"blacklisted\": %zu, \"suppressed\": %" PRIu64
                 ", \"dropped_ring_full\": %" PRIu64
-                ", \"spoofed\": %" PRIu64 "}\n",
+                ", \"spoofed\": %" PRIu64,
                 produced, verdicts, blacklist.size(), suppressed,
                 dropped_ring_full, sim.spoofed());
+    if (key128)
+        std::printf(", \"v6_sources\": %" PRIu64 ", \"planted\": %" PRIu64,
+                    sim.v6_sources(), sim.planted());
+    std::printf("}\n");
     return 0;
+}
+
+}  // namespace
+
+int main(int argc, char **argv) {
+    Options o = parse(argc, argv);
+    std::signal(SIGINT, on_signal);
+    std::signal(SIGTERM, on_signal);
+
+    if (o.mode == "bpf")
+        return o.key_bits == 128 ? run_bpf<fsx_verdict_record6>(o)
+                                 : run_bpf<fsx_verdict_record>(o);
+    if (o.compact) {
+        std::fprintf(stderr, "fsxd: --compact requires --bpf (the sim/"
+                             "replay generators emit 48 B records)\n");
+        return 2;
+    }
+    return o.key_bits == 128
+               ? run_stream<fsx_flow_record6, fsx_verdict_record6>(o)
+               : run_stream<fsx_flow_record, fsx_verdict_record>(o);
 }

@@ -12,14 +12,20 @@ packet from that source.
 Key space: TWO maps (kern/fsx_kern.c:48-86, mirrored by bpf/progs.py):
 
 * ``blacklist_map`` — u32 keys: IPv4 wire bytes verbatim (little-endian
-  load, kern/parsing.h:83-86), or the XOR-fold of a v6 address.  This
-  is where the TPU plane's ML verdicts land (its whole data plane keys
-  on the fold) — for v6 the fold is approximate by construction.
+  load, kern/parsing.h:83-86), or the XOR-fold of a v6 address.  The
+  TPU plane's ML verdicts land here where its table keys by 32 bits
+  (``TableConfig.key_bits`` 32, every configuration but a 128-bit one):
+  its data plane then keys a v6 source on the fold, which is
+  approximate, and a host that picks its /64's low bits can make its
+  fold equal an innocent IPv4 address.
 * ``blacklist_v6`` — EXACT 16-byte v6 source keys (reference
   parity: src/fsx_struct.h:9 ``__u128``).  ``fsx block <v6addr>`` and
   the kernel's own v6 rate-limit blocks write here, so a manual or
   limiter block can never hit an innocent source that merely shares a
-  32-bit fold with an attacker.
+  32-bit fold with an attacker.  So does ``fsxd --bpf --key-bits 128``
+  for the TPU plane's verdicts on a table keyed by 128 bits: an IPv6
+  source's block under all 16 bytes, a v4-mapped one's in
+  ``blacklist_map`` under its IPv4 address, never a fold.
 """
 
 from __future__ import annotations

@@ -1106,6 +1106,22 @@ def _boot_salt(cache_dir: str | None, label: str) -> int:
     return salt
 
 
+def _key128_serve_problem(args: argparse.Namespace) -> str | None:
+    """What ``fsx serve`` was asked for that a table keyed by 128 bits
+    (``table.key_bits``) cannot do yet, or None."""
+    if args.checkpoint or args.restore:
+        return ("no checkpoint save or restore yet (the snapshot's key "
+                "column carries 32-bit keys)")
+    if args.mesh and args.mesh > 1:
+        return ("one chip only (the sharded step's owner hash and "
+                "routing carry 32-bit keys)")
+    if not (args.feature_ring and args.ingest_workers):
+        return ("serve the sealed ring path (--feature-ring with "
+                "--ingest-workers N, fsxd --key-bits 128): the inline / "
+                "record-file path seals 48 B records with a 32-bit source")
+    return None
+
+
 def _cmd_serve(args: argparse.Namespace) -> int:
     """Run the serving engine over a record source.
 
@@ -1253,6 +1269,12 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     import dataclasses as _dck
 
     cfg = _load_cfg(args)
+    if cfg.table.key_bits == 128:
+        problem = _key128_serve_problem(args)
+        if problem:
+            print(f"fsx serve: table.key_bits 128: {problem}",
+                  file=sys.stderr)
+            return 1
     if args.verdict_k is not None:
         cfg = _dck.replace(cfg, batch=_dck.replace(
             cfg.batch, verdict_k=args.verdict_k))
@@ -1783,6 +1805,11 @@ def _cmd_cluster(args: argparse.Namespace) -> int:
     import dataclasses as _dc
 
     cfg = _load_cfg(args)
+    if cfg.table.key_bits == 128:
+        print("fsx cluster: table.key_bits 128 is not built for the "
+              "cluster: its gossip, rebalance, hand-off and saves carry "
+              "32-bit keys (serve it with fsx serve)", file=sys.stderr)
+        return 1
     if args.verdict_k is not None:
         cfg = _dc.replace(cfg, batch=_dc.replace(
             cfg.batch, verdict_k=args.verdict_k))

@@ -60,6 +60,8 @@ _RECORD_FIELD_COMMENTS = {
     "ip_proto": "IPPROTO_*",
     "flags": "FSX_FLAG_* bits",
     "feat": "integer feature estimates (no FPU in eBPF; host casts to f32)",
+    "saddr6": "full source, wire words; IPv4 as ::ffff:a.b.c.d",
+    "_pad": "to 64 B",
 }
 
 _NP_TO_C = {
@@ -71,17 +73,21 @@ _NP_TO_C = {
 }
 
 
-def _flow_record_struct() -> str:
-    """Emit ``struct fsx_flow_record`` directly from FLOW_RECORD_DTYPE
-    so the C wire format cannot drift from the Python decoder."""
+def _flow_record_struct(cname: str = "fsx_flow_record",
+                        dtype_name: str = "FLOW_RECORD_DTYPE",
+                        what: str = "kernel -> user wire format") -> str:
+    """Emit ``struct fsx_flow_record`` (or its 64 B twin) directly from
+    its schema dtype so the C wire format cannot drift from the Python
+    decoder."""
+    dtype = getattr(schema, dtype_name)
     lines = [
-        "/* One feature-ring entry: kernel -> user wire format.",
-        " * GENERATED from flowsentryx_tpu.core.schema.FLOW_RECORD_DTYPE"
-        " (packed, %d bytes). */" % schema.FLOW_RECORD_SIZE,
-        "struct fsx_flow_record {",
+        "/* One feature-ring entry: %s." % what,
+        " * GENERATED from flowsentryx_tpu.core.schema.%s"
+        " (packed, %d bytes). */" % (dtype_name, dtype.itemsize),
+        "struct %s {" % cname,
     ]
-    for name in schema.FLOW_RECORD_DTYPE.names:
-        dt, _ = schema.FLOW_RECORD_DTYPE.fields[name][:2]
+    for name in dtype.names:
+        dt, _ = dtype.fields[name][:2]
         if dt.subdtype is not None:
             base, shape = dt.subdtype
             decl = f"{_NP_TO_C[base]} {name}[{shape[0]}]"
@@ -160,6 +166,10 @@ def generate() -> str:
 
     parts.append(_flow_record_struct())
     parts.append("")
+    parts.append(_flow_record_struct(
+        "fsx_flow_record6", "FLOW_RECORD6_DTYPE",
+        "the source keyed by all 128 bits (TableConfig.key_bits 128)"))
+    parts.append("")
     parts.append("""\
 /* One COMPACT feature-ring entry (16 B): the kernel-quantized twin of
  * fsx_flow_record for deployments built with FSX_EMIT_COMPACT — 3x
@@ -234,8 +244,17 @@ struct fsx_verdict_record {
 	__u32 _pad;
 	__u64 until_ns;      /* blacklist expiry, kernel clock ns */
 } __attribute__((packed));
+
+/* The verdict-ring entry of a 128-bit-keyed deployment: the full
+ * source.  GENERATED from schema.VERDICT_RECORD6_DTYPE (%d bytes). */
+struct fsx_verdict_record6 {
+	__u32 saddr6[4];     /* full source, wire words; IPv4 as ::ffff:a.b.c.d */
+	__u64 until_ns;      /* blacklist expiry, kernel clock ns */
+	__u64 _pad;
+} __attribute__((packed));
 """
-        % (schema.SHM_MAGIC, schema.VERDICT_RECORD_SIZE)
+        % (schema.SHM_MAGIC, schema.VERDICT_RECORD_SIZE,
+           schema.VERDICT_RECORD6_SIZE)
     )
 
     parts.append(_fields_struct(

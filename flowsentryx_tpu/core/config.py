@@ -220,10 +220,33 @@ class TableConfig:
     #: programs.  A checkpoint records it as ``n_shards``; ``fsx serve
     #: --mesh N`` sets it.
     shards: int = 1
+    #: Width of a flow key: 32 (an IPv4 source, or the XOR fold of an
+    #: IPv6 one: ``kern/parsing.h`` ``fsx_fold_ip6``) or 128 (the full
+    #: source, IPv4 as ``::ffff:a.b.c.d``; upstream ``src/fsx_struct.h:9``
+    #: ``__u128``).  At 128 the table's key is ``[capacity, 4]`` u32,
+    #: the probe hashes and compares all four words, the aggregate's
+    #: sort orders by the whole key and a block names the whole source,
+    #: so two sources that share a fold never share a row, a vote or a
+    #: block.  Resolved when a step is traced; 128 runs the one-chip
+    #: sealed path (``fsxd --key-bits 128`` rings, the ``compact28``
+    #: wire) and is refused where that width is not built yet.
+    key_bits: int = 32
 
     def __post_init__(self) -> None:
         if self.capacity & (self.capacity - 1) or self.capacity <= 0:
             raise ValueError("capacity must be a power of two")
+        if self.key_bits not in (32, 128):
+            raise ValueError(f"key_bits must be 32 or 128, got "
+                             f"{self.key_bits!r}")
+        if self.key_bits == 128 and self.shards > 1:
+            raise ValueError(
+                "key_bits 128 needs shards 1: the sharded step's owner "
+                "hash and routing (parallel/step.py) carry 32-bit keys")
+        if self.key_bits == 128 and self.evict_ttl_s > 0:
+            raise ValueError(
+                "key_bits 128 needs evict_ttl_s 0: the in-step aging "
+                "sweep (ops/fused.py evict_idle_epoch) frees 32-bit "
+                "keys")
         if self.shards < 1 or self.shards & (self.shards - 1):
             raise ValueError("shards must be a power of two >= 1 (the "
                              "owner is read off the hash's top bits)")

@@ -116,6 +116,35 @@ FLOW_RECORD_DTYPE = np.dtype(
 FLOW_RECORD_SIZE = FLOW_RECORD_DTYPE.itemsize  # 48
 assert FLOW_RECORD_SIZE == 48
 
+#: The ring record of a deployment that keys sources by all 128 bits
+#: (``TableConfig.key_bits`` 128; upstream ``src/fsx_struct.h:9``
+#: ``__u128``): the 48 B record with the full source in place of the
+#: u32 one, padded to 64 B.  ``saddr6`` is the address's 16 wire bytes
+#: read as four little-endian u32 words, as the kernel loads them; an
+#: IPv4 source is carried as ``::ffff:a.b.c.d`` (RFC 4291 §2.5.5.2), so
+#: its word 3 is the 48 B record's ``saddr``.
+FLOW_RECORD6_DTYPE = np.dtype(
+    [
+        ("ts_ns", "<u8"),
+        ("saddr6", "<u4", (4,)),  # full source address, wire words
+        ("pkt_len", "<u2"),
+        ("ip_proto", "u1"),
+        ("flags", "u1"),
+        ("feat", "<u4", (NUM_FEATURES,)),
+        ("_pad", "<u4"),
+    ]
+)
+FLOW_RECORD6_SIZE = FLOW_RECORD6_DTYPE.itemsize  # 64
+assert FLOW_RECORD6_SIZE == 64
+
+#: Words 0-2 of a v4-mapped source ``::ffff:0:0/96`` (wire words).
+V4_MAPPED_PREFIX = (0, 0, 0xFFFF0000)
+
+
+def key_words(key_bits: int) -> int:
+    """u32 words of one flow key: 1 for 32-bit keys, 4 for 128-bit."""
+    return key_bits // 32
+
 
 #: Streaming per-flow statistics the kernel keeps (one entry per tracked
 #: flow) to derive the 8 features online.  Welford-free: we keep sums and
@@ -329,6 +358,10 @@ def struct_layouts() -> dict[str, StructLayout]:
                                                  COMPACT_RECORD_DTYPE),
         "fsx_verdict_record": _layout_from_dtype("fsx_verdict_record",
                                                  VERDICT_RECORD_DTYPE),
+        "fsx_flow_record6": _layout_from_dtype("fsx_flow_record6",
+                                               FLOW_RECORD6_DTYPE),
+        "fsx_verdict_record6": _layout_from_dtype("fsx_verdict_record6",
+                                                  VERDICT_RECORD6_DTYPE),
         "fsx_shm_ring_hdr": shm_hdr,
     }
 
@@ -470,6 +503,7 @@ BATCHQ_FILL_DUR_US_WORD = 6
 BATCHQ_RING_WAIT_US_WORD = 7
 WIRE_ID_RAW48 = 0
 WIRE_ID_COMPACT16 = 1
+WIRE_ID_COMPACT28 = 2
 
 # -- cluster gossip/status shm layout (flowsentryx_tpu/cluster/) ------------
 # Same 192 B header geometry and x86-TSO plain-store cursor protocol as
@@ -594,7 +628,8 @@ CSTATE_DRAINING = 5
 
 
 def wire_id_of(wire: str) -> int:
-    return WIRE_ID_COMPACT16 if wire == WIRE_COMPACT16 else WIRE_ID_RAW48
+    return {WIRE_COMPACT16: WIRE_ID_COMPACT16,
+            WIRE_COMPACT28: WIRE_ID_COMPACT28}.get(wire, WIRE_ID_RAW48)
 
 
 def shard_ring_path(base: str, shard: int, n_shards: int) -> str:
@@ -609,9 +644,25 @@ def shard_of(saddr, n_shards: int):
     both producers use (Fibonacci hash; mirrors ``fsx_shard_of`` in the
     daemon).  Keeping a flow's records on ONE shard preserves their
     relative order through the parallel ingest stage, matching the
-    kernel's per-CPU production semantics."""
-    h = (np.asarray(saddr, np.uint64) * np.uint64(2654435761)) >> np.uint64(16)
+    kernel's per-CPU production semantics.  ``[n, 4]`` keys are first
+    mixed to one word (:func:`shard_word6`)."""
+    saddr = np.asarray(saddr)
+    if saddr.ndim == 2:
+        saddr = shard_word6(saddr)
+    h = (saddr.astype(np.uint64) * np.uint64(2654435761)) >> np.uint64(16)
     return (h % np.uint64(n_shards)).astype(np.uint32)
+
+
+def shard_word6(key) -> np.ndarray:
+    """One u32 of a ``[n, 4]`` key for the shard hash (mirrors
+    ``fsx_shard_word6`` in the daemon): every word multiplied by its
+    own odd constant, so that no plain XOR fold of the address decides
+    the shard."""
+    k = np.asarray(key, np.uint32)
+    with np.errstate(over="ignore"):
+        return (k[:, 0] * np.uint32(0xC2B2AE3D)
+                ^ k[:, 1] * np.uint32(0x85EBCA77)
+                ^ k[:, 2] * np.uint32(0x9E3779B1) ^ k[:, 3])
 
 
 #: One verdict-ring entry (engine -> daemon): newly blacklisted source.
@@ -623,6 +674,17 @@ VERDICT_RECORD_DTYPE = np.dtype(
     ]
 )
 VERDICT_RECORD_SIZE = VERDICT_RECORD_DTYPE.itemsize  # 16
+
+#: The verdict-ring entry of a 128-bit-keyed deployment: the full
+#: source (wire words, as ``FLOW_RECORD6_DTYPE``), padded to 32 B.
+VERDICT_RECORD6_DTYPE = np.dtype(
+    [
+        ("saddr6", "<u4", (4,)),  # full source address, wire words
+        ("until_ns", "<u8"),      # blacklist expiry, kernel clock ns
+        ("_pad", "<u8"),
+    ]
+)
+VERDICT_RECORD6_SIZE = VERDICT_RECORD6_DTYPE.itemsize  # 32
 
 
 # ---------------------------------------------------------------------------
@@ -701,12 +763,15 @@ class IpTableState(NamedTuple):
     packet count).
     """
 
-    key: jnp.ndarray    # [capacity] uint32; 0 = empty slot sentinel
+    #: [capacity] uint32, or [capacity, 4] for 128-bit keys
+    #: (``TableConfig.key_bits``: one 16 B row a slot, so one gather
+    #: index fetches a whole key); 0 (all words) = empty slot sentinel
+    key: jnp.ndarray
     state: jnp.ndarray  # [capacity, NUM_TABLE_COLS] f32 (TableCol columns)
 
     @property
     def capacity(self) -> int:
-        return self.key.shape[-1]
+        return self.key.shape[0]
 
     # -- read-only column views: reporting and tests ONLY.  Each is a
     #    table-wide copy on the device; the step gathers from the
@@ -777,14 +842,17 @@ class IpTableState(NamedTuple):
 TABLE_COLUMN_NAMES = tuple(c.name.lower() for c in TableCol)
 
 
-def make_table(capacity: int) -> IpTableState:
-    """Fresh, empty state table with ``capacity`` slots (power of two)."""
+def make_table(capacity: int, key_bits: int = 32) -> IpTableState:
+    """Fresh, empty state table with ``capacity`` slots (power of two)
+    and keys of ``key_bits`` (32 or 128)."""
     if capacity & (capacity - 1):
         raise ValueError(f"capacity must be a power of two, got {capacity}")
     import jax.numpy as jnp
 
+    kw = key_words(key_bits)
     return IpTableState(
-        key=jnp.zeros((capacity,), jnp.uint32),
+        key=jnp.zeros((capacity,) if kw == 1 else (capacity, kw),
+                      jnp.uint32),
         state=jnp.zeros((capacity, NUM_TABLE_COLS), jnp.float32),
     )
 
@@ -815,6 +883,13 @@ class GlobalStats(NamedTuple):
     #: ``batches × n × n·C`` it is the share of the worst-case owner
     #: buffer the core ran on.  A pure donated passthrough on one device.
     owner_rows: jnp.ndarray         # [2] uint32
+    #: Valid records whose 128-bit source lies outside
+    #: ``::ffff:0:0/96`` (an IPv6 source), and flows newly blocked with
+    #: such a source.  Counted where the table keys by 128 bits
+    #: (``TableConfig.key_bits``); a pure donated passthrough at 32, so
+    #: the 32-bit step keeps the graph it had.
+    v6_records: jnp.ndarray         # [2] uint32
+    v6_blocks: jnp.ndarray          # [2] uint32
     #: Idle flows freed by the in-step aging epoch
     #: (:func:`flowsentryx_tpu.ops.fused.evict_idle_epoch`;
     #: ``TableConfig.evict_ttl_s``), whichever of its two forms the
@@ -895,7 +970,7 @@ class FeatureBatch(NamedTuple):
     every shape under ``jit`` stays static).
     """
 
-    key: jnp.ndarray      # [B] uint32 source address / fold
+    key: jnp.ndarray      # [B] uint32 source address / fold, or [B, 4]
     feat: jnp.ndarray     # [B, 8] f32
     pkt_len: jnp.ndarray  # [B] f32 bytes
     ts: jnp.ndarray       # [B] f32 seconds (process-relative)
@@ -1027,8 +1102,23 @@ def raw_proto_flags(raw) -> tuple:
 COMPACT_RECORD_WORDS = 4
 COMPACT_RECORD_SIZE = COMPACT_RECORD_WORDS * 4  # 16
 
+#: The sealed row of a 128-bit-keyed deployment (``compact28``): the
+#: source's 4 wire words, then compact16's words 1-3 unchanged.  The
+#: metadata row is compact16's.  Its own ``WIRE_ID_COMPACT28``, so a
+#: sealed batch states its layout.
+COMPACT28_RECORD_WORDS = 7
+
 WIRE_RAW48 = "raw48"
 WIRE_COMPACT16 = "compact16"
+WIRE_COMPACT28 = "compact28"
+#: The quantised wires: a µs delta from the batch base in the last word
+COMPACT_WIRES = (WIRE_COMPACT16, WIRE_COMPACT28)
+
+
+def wire_words(wire: str) -> int:
+    """u32 words in one row of ``wire``."""
+    return {WIRE_RAW48: RECORD_WORDS, WIRE_COMPACT16: COMPACT_RECORD_WORDS,
+            WIRE_COMPACT28: COMPACT28_RECORD_WORDS}[wire]
 
 # -- declared field-width / value-range constants ---------------------------
 #
@@ -1212,9 +1302,12 @@ def compact_pack(
     log1p: bool = False,
 ) -> np.ndarray:
     """Vectorized pack of flow records → ``[n, 4]`` compact words
-    (shared by :func:`encode_compact` and the incremental batcher)."""
+    (shared by :func:`encode_compact` and the incremental batcher);
+    ``FLOW_RECORD6_DTYPE`` records → ``[n, 7]`` compact28 words, the
+    four key words first."""
     n = len(rec)
-    out = np.empty((n, COMPACT_RECORD_WORDS), np.uint32)
+    kw = 4 if "saddr6" in rec.dtype.names else 1
+    out = np.empty((n, kw + 3), np.uint32)
     if feat_mode == "model":
         q8 = quantize_feat_model(
             rec["feat"], in_scale, in_zp, log1p).astype(np.uint8)
@@ -1222,21 +1315,25 @@ def compact_pack(
         q8 = _minifloat_q8(rec["feat"])
     else:
         raise ValueError(f"unknown feat_mode {feat_mode!r}")
-    out[:, 0] = rec["saddr"]
+    if kw == 1:
+        out[:, 0] = rec["saddr"]
+    else:
+        out[:, :kw] = rec["saddr6"]
     # [n, 8] u8 reinterpreted as [n, 2] u32 IS the little-endian byte
     # pack q0|q1<<8|…  (the shm seam already requires x86-TSO, so LE is
     # given) — one view instead of six shift/or passes per seal.
     qw = np.ascontiguousarray(q8).view(np.uint32)
-    out[:, 1] = qw[:, 0]
-    out[:, 2] = qw[:, 1]
+    out[:, kw] = qw[:, 0]
+    out[:, kw + 1] = qw[:, 1]
     len8 = np.minimum((rec["pkt_len"].astype(np.uint32) + 4) >> 3,
                       RANGE_LEN8_MAX)
     # records can arrive slightly out of order; clamp below base to 0
     dt = rec["ts_ns"].astype(np.int64) - np.int64(base_ns)
     dt_us = np.clip(dt // 1000, 0, RANGE_DT_US_MAX).astype(np.uint32)
-    out[:, 3] = (len8
-                 | (rec["flags"].astype(np.uint32) & RANGE_FLAGS_MAX) << 11
-                 | dt_us << 16)
+    out[:, kw + 2] = (len8
+                      | (rec["flags"].astype(np.uint32) & RANGE_FLAGS_MAX)
+                      << 11
+                      | dt_us << 16)
     return out
 
 
@@ -1292,7 +1389,9 @@ def decode_compact(
     in_zp: int = 0,
     log1p: bool = False,
 ) -> "FeatureBatch":
-    """Device-side decode of :func:`encode_compact` (jit-inlined).
+    """Device-side decode of :func:`encode_compact` (jit-inlined), and
+    of the compact28 wire: a row of ``kw + 3`` words holds a ``kw``-word
+    key (``[B]`` for compact16, ``[B, 4]`` for compact28).
 
     ``base_rel_us`` splits across two u32 words; the f32 recombination
     ``hi·2^32·1e-6 + lo·1e-6 + dt·1e-6`` keeps every term small enough
@@ -1306,7 +1405,8 @@ def decode_compact(
     n = meta[0].astype(jnp.int32)
     base = (meta[2].astype(jnp.float32) * np.float32(4294.967296)
             + meta[1].astype(jnp.float32) * np.float32(1e-6))
-    w1, w2, w3 = words[:, 1], words[:, 2], words[:, 3]
+    kw = raw.shape[-1] - 3
+    w1, w2, w3 = words[:, kw], words[:, kw + 1], words[:, kw + 2]
     q8 = RANGE_FEAT_Q8_MAX  # the byte lanes carry u8 quantized features
     q = jnp.stack(
         [
@@ -1322,7 +1422,7 @@ def decode_compact(
     else:
         raise ValueError(f"unknown feat_mode {feat_mode!r}")
     return FeatureBatch(
-        key=words[:, 0],
+        key=words[:, 0] if kw == 1 else words[:, :kw],
         feat=feat,
         pkt_len=((w3 & np.uint32(RANGE_LEN8_MAX))
                  << np.uint32(3)).astype(jnp.float32),
@@ -1333,7 +1433,7 @@ def decode_compact(
 
 def compact_flags(raw):
     """FLAG_* bits vector from the compact wire format."""
-    return (raw[:-1, 3] >> np.uint32(11)) & np.uint32(RANGE_FLAGS_MAX)
+    return (raw[:-1, -1] >> np.uint32(11)) & np.uint32(RANGE_FLAGS_MAX)
 
 
 #: One KERNEL-emitted compact record (struct fsx_compact_record): the

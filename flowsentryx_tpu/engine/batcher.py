@@ -18,6 +18,9 @@ Two wire formats (core/schema.py):
   quantization).  The compact ts field is a µs delta from the batch
   base, so ``deadline_us`` must stay under its 65 ms range — enforced
   here rather than silently saturating.
+* ``compact28`` — compact16 with the source's four words in place of
+  its one, ``[B+1, 7]`` u32, sealed from 64 B ring records
+  (``schema.FLOW_RECORD6_DTYPE``) for a table keyed by 128 bits.
 """
 
 from __future__ import annotations
@@ -59,17 +62,15 @@ class MicroBatcher:
         self.n_buffers = max(2, n_buffers)
         self.wire = wire
         self.quant = dict(quant or {})
-        if wire == schema.WIRE_COMPACT16:
+        if wire in schema.COMPACT_WIRES:
             if cfg.deadline_us > 60_000:
                 raise ValueError(
-                    "compact16 ts field spans 65 ms; deadline_us "
+                    f"{wire} ts field spans 65 ms; deadline_us "
                     f"{cfg.deadline_us} would saturate record deltas"
                 )
-            words = schema.COMPACT_RECORD_WORDS
-        elif wire == schema.WIRE_RAW48:
-            words = schema.RECORD_WORDS
-        else:
+        elif wire != schema.WIRE_RAW48:
             raise ValueError(f"unknown wire format {wire!r}")
+        words = schema.wire_words(wire)
         b = cfg.max_batch
         self._bufs = [
             np.zeros((b + 1, words), np.uint32)
@@ -166,7 +167,7 @@ class MicroBatcher:
         sealed = on_seal or out.append
         pos = 0
         b = self.cfg.max_batch
-        compact = self.wire == schema.WIRE_COMPACT16
+        compact = self.wire in schema.COMPACT_WIRES
         while pos < len(records):
             if self.fill == 0:
                 self._first_add_t = time.perf_counter()
@@ -248,7 +249,7 @@ class MicroBatcher:
         b = self.cfg.max_batch
         meta = buf[b]
         meta[0] = self.fill
-        if self.wire == schema.WIRE_COMPACT16:
+        if self.wire in schema.COMPACT_WIRES:
             base_rel_us = max(0, self._base_ns - self.t0_ns) // 1000
             meta[1] = base_rel_us & 0xFFFFFFFF
             meta[2] = (base_rel_us >> 32) & 0xFFFFFFFF

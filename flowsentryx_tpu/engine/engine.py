@@ -260,6 +260,39 @@ class _InFlight(NamedTuple):
     seq: int = 0
 
 
+def _refuse_key128(source, gossip, wire) -> None:
+    """A table keyed by 128 bits (``TableConfig.key_bits``) is served
+    by the sealed ring path alone: refuse, at construction, every path
+    that still carries 32-bit keys, naming what it lacks."""
+    if not getattr(source, "provides_sealed", False):
+        raise ValueError(
+            "table.key_bits 128 needs the sealed ring path (fsxd "
+            "--key-bits 128 rings, ingest workers): the inline / "
+            "record-file path seals 48 B records with a 32-bit source")
+    if getattr(source, "precompact", False):
+        raise ValueError(
+            "table.key_bits 128 cannot serve a compact-emit ring: its "
+            "16 B kernel records carry a 32-bit source")
+    if gossip is not None:
+        raise ValueError(
+            "table.key_bits 128 is not built for fsx cluster: its "
+            "gossip, rebalance and hand-off carry 32-bit keys")
+    if wire not in (None, schema.WIRE_COMPACT28):
+        raise ValueError(
+            f"table.key_bits 128 is served on the compact28 wire, not "
+            f"{wire!r}")
+
+
+def _refuse_key128_checkpoint(cfg) -> None:
+    """The checkpoint's key column and the restore's re-placement
+    (``engine/checkpoint.py``, ``engine/table.py``) carry 32-bit keys."""
+    if cfg.table.key_bits == 128:
+        raise ValueError(
+            "table.key_bits 128 has no checkpoint save or restore yet: "
+            "the snapshot's key column (engine/checkpoint.py) and the "
+            "restore's re-placement (engine/table.py) carry 32-bit keys")
+
+
 class Engine:
     """Owns the device state (table/stats/params) and runs the loop.
 
@@ -417,7 +450,12 @@ class Engine:
         # engine must speak compact16/minifloat end to end, whatever
         # was requested.
         self.precompact = bool(getattr(source, "precompact", False))
-        if self.precompact:
+        if cfg.table.key_bits == 128:
+            # the one path built for 128-bit keys: 64 B rings sealed to
+            # compact28 by the ingest workers (TableConfig.key_bits)
+            _refuse_key128(source, gossip, wire)
+            wire = schema.WIRE_COMPACT28
+        elif self.precompact:
             wire = schema.WIRE_COMPACT16
         elif wire is None:
             # Default wire: compact16 only when it is bit-exact (the
@@ -449,7 +487,7 @@ class Engine:
                     "model-mode quantization.",
                     file=sys.stderr,
                 )
-        elif wire == schema.WIRE_COMPACT16:
+        elif wire in schema.COMPACT_WIRES:
             quant = schema.wire_quant_for(self.params)
         else:
             quant = None
@@ -476,11 +514,12 @@ class Engine:
                     cfg, spec.classify_batch, self.mesh, donate=donate
                 )
             self.table = par.make_sharded_table(cfg, self.mesh)
-        elif wire == schema.WIRE_COMPACT16:
+        elif wire in schema.COMPACT_WIRES:
             self.step = fused.make_jitted_compact_step(
                 cfg, spec.classify_batch, donate=donate, **quant
             )
-            self.table = jax.device_put(schema.make_table(cfg.table.capacity))
+            self.table = jax.device_put(schema.make_table(
+                cfg.table.capacity, cfg.table.key_bits))
         else:
             self.step = fused.make_jitted_raw_step(
                 cfg, spec.classify_batch, donate=donate
@@ -547,8 +586,9 @@ class Engine:
         self.megasteps: dict[int, Any] = {}
         self.megastep = None
         if mega_sizes:
-            if wire != schema.WIRE_COMPACT16:
-                raise ValueError("mega_n requires the compact16 wire")
+            if wire not in schema.COMPACT_WIRES:
+                raise ValueError("mega_n requires the compact16 wire "
+                                 "(compact28 at 128-bit keys)")
             if self.mesh is not None:
                 from flowsentryx_tpu import parallel as par
 
@@ -572,6 +612,10 @@ class Engine:
 
             audit = _os.environ.get("FSX_AUDIT", "").lower() in (
                 "1", "true", "on")
+        if audit and cfg.table.key_bits == 128:
+            raise ValueError(
+                "table.key_bits 128 has no boot audit yet: the graph "
+                "contracts (flowsentryx_tpu/audit/) stage 32-bit keys")
         if audit:
             from flowsentryx_tpu.audit import boot_audit
 
@@ -596,9 +640,9 @@ class Engine:
             source.start(cfg.batch, self.wire, quant)
         #: u32 words in one wire row (the last axis of every staged
         #: buffer).
-        self._words = (schema.COMPACT_RECORD_WORDS
-                       if self.wire == schema.WIRE_COMPACT16
-                       else schema.RECORD_WORDS)
+        self._words = schema.wire_words(self.wire)
+        #: u32 words of one flow key in the step's outputs
+        self._key_words = schema.key_words(cfg.table.key_bits)
         self._arena = self._make_arena()
         # dispatch-block accounting (EngineReport.dispatch)
         self._group_hist: dict[int, int] = {}
@@ -1354,10 +1398,11 @@ class Engine:
             # EXPLICIT (class docstring / transfer_guard contract)
             if len(group) <= 2:
                 keys = np.concatenate(
-                    [jax.device_get(g.out.block_key).reshape(-1)
+                    [self._flat_keys(jax.device_get(g.out.block_key))
                      for g in group]) \
                     if len(group) > 1 \
-                    else jax.device_get(group[0].out.block_key).reshape(-1)
+                    else self._flat_keys(
+                        jax.device_get(group[0].out.block_key))
                 untils = np.concatenate(
                     [jax.device_get(g.out.block_until).reshape(-1)
                      for g in group]) \
@@ -1365,7 +1410,7 @@ class Engine:
                     else jax.device_get(group[0].out.block_until).reshape(-1)
             else:
                 keys = jax.device_get(jnp.concatenate(
-                    [g.out.block_key.reshape(-1) for g in group]))
+                    [self._flat_keys(g.out.block_key) for g in group]))
                 untils = jax.device_get(jnp.concatenate(
                     [g.out.block_until.reshape(-1) for g in group]))
             now = float(np.max(jax.device_get(group[-1].out.now)))
@@ -1393,6 +1438,12 @@ class Engine:
             upd = extract_updates(keys, untils)
         self._apply_updates(upd, now, group, fetch.t0, fetch.t1)
 
+    def _flat_keys(self, key):
+        """A step's (or a mega entry's stacked) block keys as ``[R]``,
+        or ``[R, 4]`` for 128-bit keys."""
+        return (key.reshape(-1) if self._key_words == 1
+                else key.reshape(-1, self._key_words))
+
     def _sink_group_wire(self, group: list[_InFlight]) -> None:
         """The compact-wire sink (see :meth:`_sink_group`).
 
@@ -1417,7 +1468,7 @@ class Engine:
             for i, (g, w) in enumerate(zip(group, wires)):
                 self._d2h_bytes += w.nbytes
                 if wire_overflowed(w):
-                    fk = jax.device_get(g.out.block_key).reshape(-1)
+                    fk = self._flat_keys(jax.device_get(g.out.block_key))
                     fu = jax.device_get(g.out.block_until).reshape(-1)
                     self._d2h_bytes += fk.nbytes + fu.nbytes
                     full[i] = (fk, fu)
@@ -1426,7 +1477,7 @@ class Engine:
             parts_u: list[np.ndarray] = []
             now = 0.0
             for i, w in enumerate(wires):
-                vw = decode_verdict_wire(w)
+                vw = decode_verdict_wire(w, self._key_words)
                 self._route_drop += vw.route_drop
                 now = max(now, vw.now)
                 # both counters in batches (an entry's n_chunks), so
@@ -1466,7 +1517,8 @@ class Engine:
                 # producer is this sink section, whichever thread owns
                 # it
                 self.gossip.publish(upd, now)
-            self._blocked.update(upd.key.tolist())
+            self._blocked.update(upd.key.tolist() if upd.key.ndim == 1
+                                 else map(tuple, upd.key.tolist()))
             self._device_now = max(self._device_now, now)
             self._sunk_batches += sum(g.n_chunks for g in group)
             t_done = time.perf_counter()
@@ -1828,6 +1880,7 @@ class Engine:
         Synchronous: it waits for the policy's save in flight, if any,
         then saves on the calling thread.  Its phases are the
         ``fsx.ckpt.*`` spans."""
+        _refuse_key128_checkpoint(self.cfg)
         from flowsentryx_tpu.engine import checkpoint as ckpt
 
         self._save_join()
@@ -1843,6 +1896,8 @@ class Engine:
         ``run()`` calls: it waits for a save in flight, so that the
         policy it leaves holds every save made, and the snapshot's
         device program compiles here, not in the first save."""
+        if policy is not None:
+            _refuse_key128_checkpoint(self.cfg)
         self._save_join()
         if policy is not None:
             from flowsentryx_tpu.engine import checkpoint as ckpt
@@ -1944,6 +1999,7 @@ class Engine:
         ``.prev`` that is itself corrupt) re-raises: there is nothing
         safe to resume from, and inventing an empty table silently
         would unblock every previously-blocked source."""
+        _refuse_key128_checkpoint(self.cfg)
         import sys
 
         from flowsentryx_tpu.engine import checkpoint as ckpt

@@ -402,28 +402,34 @@ class SealedBatchQueue:
         return int(self._head[0]) - int(self._tail[0])
 
 
+def _open_sized(path: str | Path, dtypes: tuple, timeout_s: float
+                ) -> ShmRing:
+    """Open a ring the daemon creates as whichever of ``dtypes`` its
+    header's record size names (the first while it waits)."""
+    deadline = time.monotonic() + timeout_s
+    for i, dtype in enumerate(dtypes):
+        try:
+            return ShmRing.wait_for(path, dtype,
+                                    max(0.01, deadline - time.monotonic()))
+        except ValueError:
+            if i == len(dtypes) - 1:
+                raise  # a size none of them has
+
+
 class ShmRingSource:
     """RecordSource over the daemon's feature ring.
 
     The record format is read off the ring header: 48 B rings carry
     full-fidelity ``FLOW_RECORD_DTYPE`` records, 16 B rings carry
     KERNEL-quantized ``COMPACT_RECORD_DTYPE`` records (a compact-emit
-    data plane / ``fsxd --compact``); ``precompact`` tells the engine
-    which batcher path to use."""
+    data plane / ``fsxd --compact``), 64 B rings the full 128-bit
+    source (``FLOW_RECORD6_DTYPE``, ``fsxd --key-bits 128``);
+    ``precompact`` tells the engine which batcher path to use."""
 
     def __init__(self, path: str | Path, timeout_s: float = 10.0):
-        deadline = time.monotonic() + timeout_s
-        try:
-            self.ring = ShmRing.wait_for(
-                path, schema.FLOW_RECORD_DTYPE,
-                max(0.01, deadline - time.monotonic()),
-            )
-        except ValueError:
-            # size mismatch: re-open expecting the compact record
-            self.ring = ShmRing.wait_for(
-                path, schema.COMPACT_RECORD_DTYPE,
-                max(0.01, deadline - time.monotonic()),
-            )
+        self.ring = _open_sized(
+            path, (schema.FLOW_RECORD_DTYPE, schema.COMPACT_RECORD_DTYPE,
+                   schema.FLOW_RECORD6_DTYPE), timeout_s)
         self.precompact = (
             self.ring.record_size == schema.COMPACT_RECORD_SIZE
         )
@@ -455,7 +461,11 @@ class ShmVerdictSink:
     (fail-open)."""
 
     def __init__(self, path: str | Path, t0_ns: int = 0, timeout_s: float = 10.0):
-        self.ring = ShmRing.wait_for(path, schema.VERDICT_RECORD_DTYPE, timeout_s)
+        # 16 B records carry a 32-bit key; 32 B ones (fsxd --key-bits
+        # 128) the full source, and take ``[K, 4]`` update keys
+        self.ring = _open_sized(
+            path, (schema.VERDICT_RECORD_DTYPE, schema.VERDICT_RECORD6_DTYPE),
+            timeout_s)
         self.t0_ns = t0_ns
         self.dropped = 0           # blocks given up on a reader that stood still
         self.waits = 0             # applies that had to wait for room
@@ -484,8 +494,8 @@ class ShmVerdictSink:
         n = len(update.key)
         if not n:
             return
-        rec = np.zeros(n, schema.VERDICT_RECORD_DTYPE)
-        rec["saddr"] = update.key
+        rec = np.zeros(n, self.ring.dtype)
+        rec["saddr6" if update.key.ndim == 2 else "saddr"] = update.key
         rec["until_ns"] = (
             update.until_s.astype(np.float64) * 1e9
         ).astype(np.uint64) + np.uint64(self.t0_ns)

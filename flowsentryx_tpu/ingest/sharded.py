@@ -280,8 +280,7 @@ class ShardedIngest:
             raise RuntimeError("ShardedIngest already started")
         self._started = True
         self.wire = wire
-        words = (schema.COMPACT_RECORD_WORDS
-                 if wire == schema.WIRE_COMPACT16 else schema.RECORD_WORDS)
+        words = schema.wire_words(wire)
         payload_words = (batch_cfg.max_batch + 1) * words
         self._payload_shape = (batch_cfg.max_batch + 1, words)
         self._max_batch = batch_cfg.max_batch
@@ -293,7 +292,7 @@ class ShardedIngest:
         # carries base_rel_us (µs since t0), raw48 carries t0_ns; both
         # HI words are bounded by the declared deployment horizon.
         horizon = schema.RANGE_DEPLOY_HORIZON_S * (
-            10 ** 6 if wire == schema.WIRE_COMPACT16 else 10 ** 9)
+            10 ** 6 if wire in schema.COMPACT_WIRES else 10 ** 9)
         self._meta_ts_hi_max = horizon >> 32
         ctx = mp.get_context("spawn")  # never fork a jax/XLA process
         from flowsentryx_tpu.ingest.worker import worker_main

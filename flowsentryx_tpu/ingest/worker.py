@@ -250,6 +250,12 @@ def worker_main(spec: dict) -> None:
             raise ValueError(
                 "compact-emit ring shard requires the compact16 wire"
             )
+        if (src.ring.dtype == schema.FLOW_RECORD6_DTYPE) != (
+                wire == schema.WIRE_COMPACT28):
+            raise ValueError(
+                f"a {src.ring.record_size} B ring shard cannot be sealed "
+                f"to the {wire} wire: 64 B records (fsxd --key-bits 128) "
+                f"and compact28 (table.key_bits 128) go together")
         # verdict_k=0: the worker-side config only drives the
         # micro-batcher (fill/deadline); the verdict wire is an
         # engine-side device concern, and the default K could exceed a

@@ -33,9 +33,24 @@ import numpy as np
 
 from flowsentryx_tpu.core.config import FsxConfig
 from flowsentryx_tpu.core.schema import (
-    GlobalStats, IpTableState, TableCol, Verdict,
+    V4_MAPPED_PREFIX, GlobalStats, IpTableState, TableCol, Verdict,
+    key_words, u64_add,
 )
 from flowsentryx_tpu.ops import agg, hashtable, limiters
+
+
+#: What a 128-bit key of all zeros (``::``, the empty-slot sentinel) is
+#: served as: the 32-bit step's 0 -> 0xFFFFFFFE, widened.
+ZERO_KEY128_SUB = np.array([0xFFFFFFFE, 0xFFFFFFFF, 0xFFFFFFFF,
+                            0xFFFFFFFF], np.uint32)
+
+
+def is_v4_mapped_key(key: jnp.ndarray) -> jnp.ndarray:
+    """Whether each ``[R, 4]`` key lies in ``::ffff:0:0/96`` (an IPv4
+    source; ``core/schema.py`` ``V4_MAPPED_PREFIX``)."""
+    return ((key[:, 0] == np.uint32(V4_MAPPED_PREFIX[0]))
+            & (key[:, 1] == np.uint32(V4_MAPPED_PREFIX[1]))
+            & (key[:, 2] == np.uint32(V4_MAPPED_PREFIX[2])))
 
 
 class StepOutput(NamedTuple):
@@ -354,8 +369,6 @@ def update_stats_from_counts(
     trigger — must leave every counter untouched (warm's documented
     contract; unconditional bumping skewed ``fsx serve --mega`` reports
     by 1 + mega_n device batches vs the report's own batch count)."""
-    from flowsentryx_tpu.core.schema import u64_add
-
     return GlobalStats(
         allowed=u64_add(stats.allowed, counts[0]),
         dropped_blacklist=u64_add(stats.dropped_blacklist, counts[1]),
@@ -374,6 +387,11 @@ def update_stats_from_counts(
         stale_reads=u64_add(stats.stale_reads, read_seen),
         untracked=(stats.untracked if untracked is None
                    else u64_add(stats.untracked, untracked)),
+        # the 128-bit step's counts, added by make_step where the table
+        # keys by 128 bits; a passthrough here, so the 32-bit graph is
+        # the one it was
+        v6_records=stats.v6_records,
+        v6_blocks=stats.v6_blocks,
     )
 
 
@@ -581,6 +599,12 @@ def evict_idle_epoch(
 #     [2K + 2]         route_drop (sharded fail-opens; 0 single-device)
 #     [2K + 3]         batch device clock "now" (f32 bitcast)
 #
+# With 128-bit keys (``TableConfig.key_bits``) a key takes 4 words: the
+# keys fill ``[0 : 4K]`` (key i at ``4i .. 4i+3``), the expiries
+# ``[4K : 5K]``, and the four scalars follow at ``5K``: ``[5K + 4]``
+# words in all.  The width is the configuration's; the wire does not
+# state it.
+#
 # Host-side decode lives in engine/writeback.py (numpy, no jax needed at
 # decode time).
 
@@ -589,9 +613,16 @@ def evict_idle_epoch(
 VERDICT_WIRE_SCALARS = 4
 
 
-def verdict_wire_words(k_max: int) -> int:
-    """uint32 words in a verdict wire built for ``k_max`` slots."""
-    return 2 * k_max + VERDICT_WIRE_SCALARS
+def verdict_wire_words(k_max: int, key_words: int = 1) -> int:
+    """uint32 words in a verdict wire built for ``k_max`` slots of
+    ``key_words``-word keys."""
+    return (key_words + 1) * k_max + VERDICT_WIRE_SCALARS
+
+
+def _real_row(key: jnp.ndarray) -> jnp.ndarray:
+    """Where a key (``[R]`` or ``[R, 4]``) is not the INVALID_KEY pad."""
+    return (key != agg.INVALID_KEY if key.ndim == 1
+            else jnp.any(key != agg.INVALID_KEY, axis=1))
 
 
 def compact_blocklist(
@@ -605,11 +636,13 @@ def compact_blocklist(
     Entries past ``k_max`` are parked out of the buffer (the count still
     reflects them, which is how callers detect overflow).  Order
     preservation matters: duplicate keys across merged buffers resolve
-    last-wins downstream, exactly like the kernel blacklist map."""
-    nb = block_key != agg.INVALID_KEY
+    last-wins downstream, exactly like the kernel blacklist map.
+    ``[R, 4]`` keys compact to ``[k_max, 4]``."""
+    nb = _real_row(block_key)
     pos = jnp.cumsum(nb.astype(jnp.int32)) - 1
     idx = jnp.where(nb & (pos < k_max), pos, k_max)  # park tail + invalid
-    ck = (jnp.full((k_max + 1,), agg.INVALID_KEY, jnp.uint32)
+    ck = (jnp.full((k_max + 1,) + block_key.shape[1:], agg.INVALID_KEY,
+                   jnp.uint32)
           .at[idx].set(block_key)[:k_max])
     cu = (jnp.zeros((k_max + 1,), jnp.float32)
           .at[idx].set(block_until)[:k_max])
@@ -623,7 +656,8 @@ def pack_verdict_wire(
     route_drop: Any,
     k_max: int,
 ) -> jnp.ndarray:
-    """Build the ``[2*k_max + 4]`` uint32 compact verdict wire."""
+    """Build the ``[2*k_max + 4]`` uint32 compact verdict wire
+    (``[5*k_max + 4]`` for ``[R, 4]`` keys)."""
     bits = jax.lax.bitcast_convert_type
     ck, cu, count = compact_blocklist(block_key, block_until, k_max)
     scalars = jnp.stack([
@@ -632,10 +666,13 @@ def pack_verdict_wire(
         jnp.asarray(route_drop).astype(jnp.uint32),
         bits(jnp.asarray(now, jnp.float32), jnp.uint32),
     ])
+    if ck.ndim == 2:
+        ck = ck.reshape(-1)
     return jnp.concatenate([ck, bits(cu, jnp.uint32), scalars])
 
 
-def merge_verdict_wires(wires: jnp.ndarray) -> jnp.ndarray:
+def merge_verdict_wires(wires: jnp.ndarray, key_words: int = 1
+                        ) -> jnp.ndarray:
     """Fold a ``[N, 2K+4]`` stack of per-chunk verdict wires (a megastep
     scan's outputs) into ONE wire, so a mega dispatch still costs a
     single O(K) readback.
@@ -644,18 +681,25 @@ def merge_verdict_wires(wires: jnp.ndarray) -> jnp.ndarray:
     re-compact in chunk order (last-wins per key downstream).  The
     merged overflow derives from the summed TRUE counts: any lost entry
     — a chunk's own overflow or more than K total across chunks —
-    implies total > K, so the flag is exact."""
+    implies total > K, so the flag is exact.  ``key_words`` is the
+    width of a key in the wires (4 for 128-bit keys)."""
     bits = jax.lax.bitcast_convert_type
-    k = (wires.shape[1] - VERDICT_WIRE_SCALARS) // 2
-    keys = wires[:, :k].reshape(-1)
-    untils = bits(wires[:, k:2 * k], jnp.float32).reshape(-1)
-    count = jnp.sum(wires[:, 2 * k]).astype(jnp.uint32)
-    rd = jnp.sum(wires[:, 2 * k + 2]).astype(jnp.uint32)
-    now = jnp.max(bits(wires[:, 2 * k + 3], jnp.float32))
+    kw = key_words
+    k = (wires.shape[1] - VERDICT_WIRE_SCALARS) // (kw + 1)
+    keys = wires[:, :kw * k].reshape(-1)
+    if kw > 1:
+        keys = keys.reshape(-1, kw)
+    untils = bits(wires[:, kw * k:(kw + 1) * k], jnp.float32).reshape(-1)
+    s0 = (kw + 1) * k
+    count = jnp.sum(wires[:, s0]).astype(jnp.uint32)
+    rd = jnp.sum(wires[:, s0 + 2]).astype(jnp.uint32)
+    now = jnp.max(bits(wires[:, s0 + 3], jnp.float32))
     ck, cu, _ = compact_blocklist(keys, untils, k)
     scalars = jnp.stack([
         count, (count > k).astype(jnp.uint32), rd, bits(now, jnp.uint32),
     ])
+    if ck.ndim == 2:
+        ck = ck.reshape(-1)
     return jnp.concatenate([ck, bits(cu, jnp.uint32), scalars])
 
 
@@ -787,7 +831,13 @@ def make_step(
         # two-stage composition is pinned by tests/test_fused.py.
         # The ``fsx.<stage>`` scopes (STEP_SCOPES) are metadata only:
         # the compiled program is the same with or without them.
+        # A 128-bit key (``TableConfig.key_bits``, ``[B, 4]``, ``kw``
+        # 4) takes the same path word for word: the probe hashes and
+        # compares all four words, the sort orders by all four after
+        # the slot key and a run breaks where any word does, so equal
+        # 32-bit hashes or folds of two sources never merge their flows.
         b = batch.key.shape[0]
+        kw = key_words(cfg.table.key_bits)
         with jax.named_scope("fsx.decode"):
             now = jnp.max(jnp.where(batch.valid, batch.ts, 0.0))
         # In-step aging epoch (evict_idle_epoch): sweep BEFORE probing
@@ -810,9 +860,14 @@ def make_step(
             # key sanitization (agg.aggregate's contract): 0 must not
             # masquerade as the empty-slot sentinel; invalid rows park
             # at INVALID_KEY, which sorts past every real key
-            key = jnp.where(batch.key == 0, jnp.uint32(0xFFFFFFFE),
-                            batch.key)
-            key = jnp.where(batch.valid, key, agg.INVALID_KEY)
+            if kw == 1:
+                key = jnp.where(batch.key == 0, jnp.uint32(0xFFFFFFFE),
+                                batch.key)
+                key = jnp.where(batch.valid, key, agg.INVALID_KEY)
+            else:
+                zero = jnp.all(batch.key == 0, axis=1, keepdims=True)
+                key = jnp.where(zero, ZERO_KEY128_SUB, batch.key)
+                key = jnp.where(batch.valid[:, None], key, agg.INVALID_KEY)
             pr = hashtable.probe_slots(table, key, batch.valid, now,
                                        cfg.table)
             slot, found, usable = pr.slot, pr.found, pr.usable
@@ -829,8 +884,15 @@ def make_step(
             tag = (jnp.arange(b, dtype=jnp.int32) * 4
                    + 2 * mal.astype(jnp.int32)
                    + batch.valid.astype(jnp.int32))
-            sp_s, key_s, tag_s, len_s, ts_s = jax.lax.sort(
-                (slot_pri, key, tag, batch.pkt_len, batch.ts), num_keys=2)
+            if kw == 1:
+                sp_s, key_s, tag_s, len_s, ts_s = jax.lax.sort(
+                    (slot_pri, key, tag, batch.pkt_len, batch.ts),
+                    num_keys=2)
+            else:
+                sp_s, *key_cols, tag_s, len_s, ts_s = jax.lax.sort(
+                    (slot_pri, *(key[:, i] for i in range(kw)), tag,
+                     batch.pkt_len, batch.ts), num_keys=1 + kw)
+                key_s = jnp.stack(key_cols, axis=1)
             order = tag_s >> 2
             valid_s = (tag_s & 1) > 0
             mal_s = (tag_s & 2) > 0
@@ -841,7 +903,10 @@ def make_step(
             slot_s = jnp.minimum(sp_s >> 1, n - 1)
 
             one = jnp.ones((1,), bool)
-            key_head = jnp.concatenate([one, key_s[1:] != key_s[:-1]])
+            differs = key_s[1:] != key_s[:-1]
+            if kw > 1:
+                differs = jnp.any(differs, axis=1)
+            key_head = jnp.concatenate([one, differs])
             tail = jnp.concatenate([key_head[1:], one])
             # arbitration: a flow wins iff its first packet opens its
             # slot group (the found-first bit in slot_pri already
@@ -864,7 +929,9 @@ def make_step(
             rep_winner = won > 0
 
             fa = agg.FlowAgg(
-                rep_key=jnp.where(rep_valid, key_s, agg.INVALID_KEY),
+                rep_key=jnp.where(rep_valid if kw == 1
+                                  else rep_valid[:, None],
+                                  key_s, agg.INVALID_KEY),
                 rep_pkts=pkts, rep_bytes=bytes_, rep_ts=ts_max,
                 rep_valid=rep_valid, inv=None)
             asg = hashtable.SlotAssignment(
@@ -890,13 +957,23 @@ def make_step(
                                    is_stable=False) & 3
             new_stats = update_stats(stats, verdict, batch.valid, dec)
             if n_evicted is not None:
-                from flowsentryx_tpu.core.schema import u64_add
-
                 new_stats = new_stats._replace(
                     evicted=u64_add(new_stats.evicted, n_evicted))
 
-            block_key = jnp.where(dec.newly_blocked, fa.rep_key,
-                                  agg.INVALID_KEY)
+            if kw > 1:
+                # the v6 counters: records and new blocks whose source
+                # lies outside ::ffff:0:0/96
+                v6_s = valid_s & ~is_v4_mapped_key(key_s)
+                new_stats = new_stats._replace(
+                    v6_records=u64_add(new_stats.v6_records,
+                                       jnp.sum(v6_s).astype(jnp.uint32)),
+                    v6_blocks=u64_add(
+                        new_stats.v6_blocks,
+                        jnp.sum(dec.newly_blocked & v6_s
+                                ).astype(jnp.uint32)))
+            block_key = jnp.where(dec.newly_blocked if kw == 1
+                                  else dec.newly_blocked[:, None],
+                                  fa.rep_key, agg.INVALID_KEY)
             block_until = jnp.where(dec.newly_blocked,
                                     dec.new_blocked_until, 0.0)
             k_max = cfg.batch.verdict_k
@@ -1053,7 +1130,8 @@ def make_jitted_compact_megastep(
     mega dispatch still costs a single O(verdict_k) readback.
     """
     base = make_compact_step(cfg, classify_batch, **quant)
-    return wrap_megastep(base, n_chunks, (0, 1) if donate else ())
+    return wrap_megastep(base, n_chunks, (0, 1) if donate else (),
+                         key_words(cfg.table.key_bits))
 
 
 def make_compact_megastep_family(
@@ -1069,20 +1147,23 @@ def make_compact_megastep_family(
     sharing the base step keeps the N traces from re-staging the whole
     fused pipeline per size."""
     base = make_compact_step(cfg, classify_batch, **quant)
+    kw = key_words(cfg.table.key_bits)
     return {
-        n: wrap_megastep(base, n, (0, 1) if donate else ())
+        n: wrap_megastep(base, n, (0, 1) if donate else (), kw)
         for n in sorted(sizes, reverse=True)
     }
 
 
-def wrap_megastep(base, n_chunks: int, donate_argnums: tuple):
+def wrap_megastep(base, n_chunks: int, donate_argnums: tuple,
+                  key_words: int = 1):
     """Shared mega-dispatch wrapper: ``lax.scan`` of ``base`` over a
     ``[N, ...]`` stacked wire group, carrying (table, stats).  Both the
     single-device and the sharded mega factories build on this, so the
     chunk-count guard and scan-carry logic cannot drift.  The N per-chunk
     compact verdict wires merge into ONE after the scan (the engine's
     group sink fetches one O(verdict_k) buffer per mega entry, not
-    ``[N, 2K+4]`` stacks)."""
+    ``[N, 2K+4]`` stacks).  ``key_words`` is the width of the wires'
+    keys (:func:`merge_verdict_wires`)."""
 
     def mega(table, stats, params, raws):
         if raws.shape[0] != n_chunks:
@@ -1099,7 +1180,8 @@ def wrap_megastep(base, n_chunks: int, donate_argnums: tuple):
         (table, stats), outs = jax.lax.scan(body, (table, stats), raws)
         if outs.wire is not None:
             with jax.named_scope("fsx.emit"):
-                outs = outs._replace(wire=merge_verdict_wires(outs.wire))
+                outs = outs._replace(
+                    wire=merge_verdict_wires(outs.wire, key_words))
         return table, stats, outs
 
     return jax.jit(mega, donate_argnums=donate_argnums)

@@ -27,7 +27,10 @@ that shaped it (SURVEY.md §7.4.2):
 
 Keys are uint32 (IPv4 address or 32-bit fold of IPv6); 0 and
 0xFFFFFFFF are reserved (empty slot / invalid sentinel) — neither is a
-routable unicast source.
+routable unicast source.  A table with ``TableConfig.key_bits`` 128
+keys by the full source instead: ``[capacity, 4]`` u32, all-zero empty
+and all-ones invalid (``::`` and ``ffff:...:ffff`` are no unicast
+source either); the probe hashes and compares all four words.
 """
 
 from __future__ import annotations
@@ -60,6 +63,21 @@ def hash_u32(k: jnp.ndarray, salt: int = 0) -> jnp.ndarray:
     k *= jnp.uint32(0xC2B2AE35)
     k ^= k >> 16
     return k
+
+
+def hash_key(key: jnp.ndarray, salt: int = 0, xor0: int = 0
+             ) -> jnp.ndarray:
+    """:func:`hash_u32` of a ``[R]`` key xor ``xor0``, or a chain of it
+    over the four words of a ``[R, 4]`` one (``xor0`` on the first):
+    each word is mixed into the hash of the words before it, so every
+    bit of the source moves the result and sources whose XOR folds
+    agree hash apart."""
+    if key.ndim == 1:
+        return hash_u32(key ^ jnp.uint32(xor0) if xor0 else key, salt)
+    h = hash_u32(key[:, 0] ^ jnp.uint32(xor0), salt)
+    for i in range(1, key.shape[1]):
+        h = hash_u32(h ^ key[:, i], salt)
+    return h
 
 
 class SlotAssignment(NamedTuple):
@@ -119,16 +137,22 @@ def probe_slots(
     mask = jnp.uint32(n - 1)
     p = cfg.probes
 
-    h1 = hash_u32(key, cfg.salt)
-    step = (hash_u32(key ^ jnp.uint32(0x9E3779B9), cfg.salt)
-            | jnp.uint32(1))
+    # a [R, 4] key (TableConfig.key_bits 128): the step hash's chain
+    # starts from the first word's golden-ratio xor, as the 32-bit one
+    # does from the key's
+    h1 = hash_key(key, cfg.salt)
+    step = hash_key(key, cfg.salt, xor0=0x9E3779B9) | jnp.uint32(1)
     offs = jnp.arange(p, dtype=jnp.uint32)  # [P]
     slots = (h1[:, None] + offs[None, :] * step[:, None]) & mask  # [R, P]
     slots = slots.astype(jnp.int32)
 
-    cand_key = table.key[slots]                             # [R, P] gather
-    match = cand_key == key[:, None]
-    empty = cand_key == EMPTY_KEY
+    cand_key = table.key[slots]               # [R, P] (or [R, P, 4]) gather
+    if key.ndim == 2:
+        match = jnp.all(cand_key == key[:, None, :], axis=2)
+        empty = jnp.all(cand_key == EMPTY_KEY, axis=2)
+    else:
+        match = cand_key == key[:, None]
+        empty = cand_key == EMPTY_KEY
     read_seen = jnp.any(valid & ~jnp.any(match | empty, axis=1))
 
     def stale_by_last_seen():

@@ -195,7 +195,10 @@ def _table_summary_device(
 def _table_summary(key, state, now, stale_s, use_pallas):
     """Column extraction + dispatch under ONE jit, so the host-side
     caller never materializes slice constants eagerly (the engine's
-    transfer-guard contract)."""
+    transfer-guard contract).  A ``[capacity, 4]`` key (a table keyed
+    by 128 bits) is tracked where any word is set: its words' max."""
+    if key.ndim == 2:
+        key = jnp.max(key, axis=1)
     blocked_until = state[..., int(TableCol.BLOCKED_UNTIL)]
     last_seen = state[..., int(TableCol.LAST_SEEN)]
     fn = _table_summary_device if use_pallas else _table_summary_xla

@@ -68,8 +68,10 @@ struct {
 
 /* Blacklist: key = folded source addr, value = blocked-until (ktime ns).
  * Serves v4 exactly and v6 approximately via the 32-bit fold; written by
- * this program (v4 rate limit) AND by the daemon (TPU verdict ingress,
- * whose whole data plane keys on the fold) — the north star's plugin
+ * this program (v4 rate limit) AND by the daemon (TPU verdict ingress:
+ * every verdict of a table keyed by 32 bits, TableConfig.key_bits 32,
+ * whose data plane keys a v6 source on the fold; only the IPv4 sources'
+ * of one keyed by 128, fsxd --key-bits 128) — the north star's plugin
  * seam. */
 struct {
 	__uint(type, BPF_MAP_TYPE_LRU_HASH);
@@ -82,10 +84,12 @@ struct {
  * src/fsx_struct.h:9 __u128 + blacklist_v6, src/fsx_kern.c:66-72,
  * 159-176).  The kernel rate limiter and `fsx block <v6addr>` write
  * HERE for v6 sources, so a block can never hit an innocent source
- * that merely shares a 32-bit fold with an attacker.  The folded map
- * is still consulted for v6 (it carries the TPU plane's ML verdicts,
- * which live in the folded key space by design — approximate, and
- * documented as such in bpf/blacklist.py). */
+ * that merely shares a 32-bit fold with an attacker.  So do the TPU
+ * plane's verdicts on v6 sources where its table keys by 128 bits
+ * (fsxd --bpf --key-bits 128 writes them here, whole).  The folded map
+ * is still consulted for v6: it carries the ML verdicts of a table
+ * keyed by 32 bits (TableConfig.key_bits 32), which live in the folded
+ * key space — approximate, and documented as such in bpf/blacklist.py. */
 struct fsx_v6key {
 	__u32 addr[4];
 };

@@ -63,6 +63,18 @@ struct fsx_flow_record {
 	__u32 feat[8]; /* integer feature estimates (no FPU in eBPF; host casts to f32) */
 } __attribute__((packed));
 
+/* One feature-ring entry: the source keyed by all 128 bits (TableConfig.key_bits 128).
+ * GENERATED from flowsentryx_tpu.core.schema.FLOW_RECORD6_DTYPE (packed, 64 bytes). */
+struct fsx_flow_record6 {
+	__u64 ts_ns; /* bpf_ktime_get_ns() at packet arrival */
+	__u32 saddr6[4]; /* full source, wire words; IPv4 as ::ffff:a.b.c.d */
+	__u16 pkt_len; /* wire length of this packet */
+	__u8 ip_proto; /* IPPROTO_* */
+	__u8 flags; /* FSX_FLAG_* bits */
+	__u32 feat[8]; /* integer feature estimates (no FPU in eBPF; host casts to f32) */
+	__u32 _pad; /* to 64 B */
+} __attribute__((packed));
+
 /* One COMPACT feature-ring entry (16 B): the kernel-quantized twin of
  * fsx_flow_record for deployments built with FSX_EMIT_COMPACT — 3x
  * less ring/wire traffic.  GENERATED from the layout in
@@ -157,6 +169,14 @@ struct fsx_verdict_record {
 	__u32 saddr;         /* folded source address */
 	__u32 _pad;
 	__u64 until_ns;      /* blacklist expiry, kernel clock ns */
+} __attribute__((packed));
+
+/* The verdict-ring entry of a 128-bit-keyed deployment: the full
+ * source.  GENERATED from schema.VERDICT_RECORD6_DTYPE (32 bytes). */
+struct fsx_verdict_record6 {
+	__u32 saddr6[4];     /* full source, wire words; IPv4 as ::ffff:a.b.c.d */
+	__u64 until_ns;      /* blacklist expiry, kernel clock ns */
+	__u64 _pad;
 } __attribute__((packed));
 
 /* Global counters (successor of struct stats, fsx_struct.h:11-15),

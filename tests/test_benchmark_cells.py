@@ -508,25 +508,26 @@ def test_the_saving_deployment_is_c4s_with_a_checkpoint_group():
 
 def test_benchmark_json_gained_the_saving_cell_and_lost_nothing():
     b = bench()
-    assert [c["name"] for c in b["configs"]][-1] == CKPT
-    assert [w["name"] for w in b["workloads"]] == [
+    assert CKPT in [c["name"] for c in b["configs"]]
+    cells = [w["name"] for w in b["workloads"]]
+    assert cells[:7] == [
         "c4-syn-mix.saturate", "c4-syn-mix.steady", "c3-offline-ml.file",
         "c5-l34-1m.saturate", "c6-spoof-churn.saturate",
         "c5-l34-mesh4.saturate", CKPT_CELL]
-    cell = b["workloads"][-1]
+    cell = b["workloads"][6]
     assert cell == dict(cell, config=CKPT, traffic="saturate", chips=1)
     e2e = {m["name"]: m for m in b["end_to_end"]}
-    assert e2e["records_per_s"]["workloads"][-1] == CKPT_CELL
+    assert CKPT_CELL in e2e["records_per_s"]["workloads"]
     assert "workloads" not in e2e["setup_s"]
-    accepted = [w["name"] for w in b["workloads"][:-1]]
+    ckpt = (*CKPT_METRICS, *CKPT_OVERLAP)
     for m in b["per_layer"]:
         if m["name"] == "boot.compile_s":
-            assert m["workloads"] == accepted
-        elif m["name"] not in (*CKPT_METRICS, *CKPT_OVERLAP):
+            assert m["workloads"] == [c for c in cells if c != CKPT_CELL]
+        elif m["name"] not in ckpt:
             assert CKPT_CELL not in m["workloads"], m["name"]
-    assert [m["name"] for m in b["per_layer"][-5:-2]] == list(CKPT_METRICS)
-    assert [m["name"] for m in b["per_layer"][-2:]] == list(CKPT_OVERLAP)
-    for m in b["per_layer"][-5:]:
+    mine = [m for m in b["per_layer"] if m["name"] in ckpt]
+    assert [m["name"] for m in mine] == list(ckpt)
+    for m in mine:
         assert m["workloads"] == [CKPT_CELL]
         assert (m["layer"], m["moves"], m["source"]) == (
             "checkpoint", "records_per_s",

@@ -271,6 +271,53 @@ def test_top_mega_rung_compiles_and_aliases_the_table(
         _assert_the_sweep_is_two_slices(compiled, capacity)
 
 
+@pytest.mark.parametrize("top", [1, 8], ids=["single", "mega-8"])
+def test_the_128_bit_step_compiles_and_aliases_the_table(one_chip, served,
+                                                         top):
+    """`c8-dual6-mix`'s shapes (`benchmark/configs/`): 2^26 rows keyed
+    by 16 B (`TableConfig.key_bits` 128), 2,048-record batches of the
+    7-word wire, a single step and the top mega rung.  The key
+    `u32[capacity, 4]` and the rows update in place, and nothing else
+    as long as the table is made."""
+    classify, quant, params = served
+    cap, b = 1 << 26, 2048
+    cfg = dataclasses.replace(_cfg(cap, b), table=TableConfig(
+        capacity=cap, salt=0x5EED5EED, key_bits=128))
+    table = schema.IpTableState(
+        key=jax.ShapeDtypeStruct((cap, 4), jnp.uint32, sharding=one_chip),
+        state=jax.ShapeDtypeStruct((cap, schema.NUM_TABLE_COLS),
+                                   jnp.float32, sharding=one_chip))
+    stats = _abstract(jax.eval_shape(schema.make_stats), one_chip)
+    words = schema.COMPACT28_RECORD_WORDS
+    if top == 1:
+        step = fused.make_jitted_compact_step(cfg, classify, **quant)
+        wire = _wire((b + 1, words), one_chip)
+    else:
+        step = fused.make_compact_megastep_family(
+            cfg, classify, (top,), **quant)[top]
+        wire = _wire((top, b + 1, words), one_chip)
+    compiled = step.lower(table, stats, _abstract(params, one_chip),
+                          wire).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cap * (16 + schema.NUM_TABLE_COLS * 4)
+    found = {f for f in _foreign_table_sized_results(compiled.as_text(), cap)
+             if f[1:] != ("u32", (cap, 4))}
+    assert found == set()
+    assert mem.temp_size_in_bytes < cap * 4
+
+
+def test_a_32_bit_step_passes_the_v6_counters_through(one_chip, served):
+    """`GlobalStats.v6_records` and `.v6_blocks` are counted where the
+    table keys by 128 bits; a 32-bit program hands them back as they
+    came, so its graph is the one it was."""
+    classify, quant, params = served
+    step = fused.make_jitted_compact_step(CFG, classify, **quant)
+    text = step.lower(*_state(params, one_chip, one_chip, one_chip),
+                      _wire((BATCH + 1, WORDS), one_chip)).compile().as_text()
+    assert _passed_through(text, "v6_records")
+    assert _passed_through(text, "v6_blocks")
+
+
 @pytest.mark.parametrize("aging", [NO_AGING, C6_AGING],
                          ids=["no-aging", "c6-aging"])
 def test_sharded_step_compiles_for_four_chips(mesh4, served, aging):

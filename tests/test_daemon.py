@@ -15,6 +15,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from benchmark import key128
 from flowsentryx_tpu.core import schema
 
 REPO = Path(__file__).resolve().parents[1]
@@ -86,15 +87,18 @@ class TestShmTransport:
         (("--seed", "2147483999", "--attack-ips", "4096", "--benign-ips",
           "70000", "--attack-fraction", "0.5", "--spoof-fraction", "0.0"),
          "5c79f88ea188c13e93c8adcde0e1e7982bdffc496b8ac638670ce2bd9ea8a85d"),
+        (("--seed", "3", "--key-bits", "32"),
+         "c984663ef1674779a50aa08b5fd1e8afb7ec7ac8d130fca1f0543b4f36e5a586"),
     ], ids=["defaults", "wide_pools", "defaults_no_spoofing",
-            "wide_pools_no_spoofing"])
+            "wide_pools_no_spoofing", "defaults_32_bit_keys"])
     def test_a_seed_gives_the_records_it_always_gave(self, fsxd_bin,
                                                       tmp_path, args, digest):
         """The sim generator's stream is pinned: its random engine is
         written out in `fsxd.cpp` (`Mt64`, std::mt19937_64 draw for
         draw) and the digests are those of the records the daemon made
         with the library's, before PR 38.  `--spoof-fraction 0` (PR 39)
-        is the default and makes no draw of its own."""
+        is the default and makes no draw of its own, as is `--key-bits
+        32`."""
         import hashlib
 
         from flowsentryx_tpu.engine.shm import ShmRingSource
@@ -154,6 +158,138 @@ class TestShmTransport:
         stats = json.loads(out.strip().splitlines()[-1])
         # it made at least the spoofed records that reached the ring
         assert len(spoofed) <= stats["spoofed"] <= stats["produced"]
+
+    def _records6(self, fsxd_bin, tmp_path, seed, n=40000):
+        """``n`` 64 B records of ``fsxd --sim --key-bits 128 --v6-fraction
+        0.5 --fold-collide 8`` over two shards, and its last line."""
+        from flowsentryx_tpu.engine.shm import ShmRingSource
+
+        tmp_path.mkdir()
+        fring, vring = _rings(tmp_path)
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--packets", str(n), "--rate", "1e8",
+             "--shards", "2", "--attack-ips", "64", "--benign-ips", "256",
+             "--attack-fraction", "0.5", "--key-bits", "128",
+             "--v6-fraction", "0.5", "--fold-collide", "8",
+             "--ring-capacity", str(1 << 16), "--feature-ring", fring,
+             "--verdict-ring", vring, "--seed", seed],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+        try:
+            srcs = [ShmRingSource(schema.shard_ring_path(fring, k, 2))
+                    for k in range(2)]
+            got, have = [[], []], 0
+            deadline = time.monotonic() + 15
+            while have < n:
+                assert time.monotonic() < deadline, "drain timed out"
+                for k, src in enumerate(srcs):
+                    c = src.poll(4096)
+                    got[k].append(c.copy())
+                    have += len(c)
+        finally:
+            out, _ = proc.communicate(timeout=15)
+        shards = [np.concatenate(g) for g in got]
+        return shards, json.loads(out.strip().splitlines()[-1])
+
+    @pytest.mark.parametrize("seed", ["3", "2147483999"])
+    def test_128_bit_sources_mix_and_plant(self, fsxd_bin, tmp_path, seed):
+        """`--key-bits 128`: 64 B records keyed by the full source, half
+        of each pool IPv6 inside 2000::/3 and flagged so, the rest
+        ::ffff:a.b.c.d of the pool's IPv4 address; eight IPv6
+        attackers whose XOR fold is a distinct benign IPv4 source of the
+        stream; a shard a source by `schema.shard_of` of all 128 bits;
+        and one seed, one stream."""
+        shards, stats = self._records6(fsxd_bin, tmp_path / "a", seed)
+        for k, rec in enumerate(shards):
+            assert rec.dtype == schema.FLOW_RECORD6_DTYPE
+            assert (schema.shard_of(rec["saddr6"], 2) == k).all()
+        rec = np.concatenate(shards)
+        key, first = np.unique(rec["saddr6"], axis=0, return_index=True)
+        v4 = key128.is_v4_mapped(key)
+        assert len(key) == 64 + 256 and (~v4).sum() == 32 + 128
+        assert stats["v6_sources"] == 160 and stats["planted"] == 8
+        first_byte = key[~v4, 0] & 0xFF
+        assert ((first_byte >= 0x20) & (first_byte < 0x40)).all()
+        assert ((rec["flags"][first] & schema.FLAG_IPV6 > 0) == ~v4).all()
+        attack = np.concatenate([
+            key[v4, 3] < (1 << 24), np.zeros((~v4).sum(), bool)])
+        assert attack.sum() == 32  # the v4 half of the 64 attackers
+        folds = key128.fold(key[~v4])
+        benign_v4 = key[v4 & (key[:, 3] >= (1 << 24)), 3]
+        planted = np.isin(folds, benign_v4)
+        assert planted.sum() == 8
+        assert len(np.unique(folds[planted])) == 8
+        again, _ = self._records6(fsxd_bin, tmp_path / "b", seed)
+        for x, y in zip(shards, again):
+            assert x.tobytes() == y.tobytes()
+
+    def test_128_bit_verdicts_suppress_the_full_source(self, fsxd_bin,
+                                                      tmp_path):
+        """A 32 B verdict for a planted IPv6 attacker stops its records
+        and not those of the benign IPv4 source its fold names."""
+        from flowsentryx_tpu.engine.shm import (
+            ShmRingSource, ShmVerdictSink,
+        )
+        from flowsentryx_tpu.engine.writeback import BlacklistUpdate
+
+        fring, vring = _rings(tmp_path)
+        proc = subprocess.Popen(
+            [str(fsxd_bin), "--sim", "--duration", "30", "--rate", "2e5",
+             "--attack-ips", "2", "--benign-ips", "4",
+             "--attack-fraction", "0.5", "--key-bits", "128",
+             "--v6-fraction", "0.5", "--fold-collide", "1",
+             "--feature-ring", fring, "--verdict-ring", vring,
+             "--seed", "5"],
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+        try:
+            src = ShmRingSource(fring)
+            seen = []
+            deadline = time.monotonic() + 10
+            while sum(len(g) for g in seen) < 4000:
+                assert time.monotonic() < deadline
+                seen.append(src.poll(1024).copy())
+                time.sleep(0.002)
+            key = np.unique(np.concatenate(seen)["saddr6"], axis=0)
+            v4 = key128.is_v4_mapped(key)
+            folds = key128.fold(key)
+            victim = key[v4 & np.isin(key[:, 3], folds[~v4])]
+            attacker = key[~v4 & np.isin(folds, victim[:, 3])]
+            assert len(victim) == len(attacker) == 1
+            sink = ShmVerdictSink(vring)
+            assert sink.ring.dtype == schema.VERDICT_RECORD6_DTYPE
+            sink.apply(BlacklistUpdate(key=attacker,
+                                       until_s=np.full(1, 4e9, np.float32)))
+            clean = 0
+            deadline = time.monotonic() + 15
+            while clean < 3:
+                assert time.monotonic() < deadline, \
+                    "the attacker's records kept arriving"
+                time.sleep(0.05)
+                tail = src.poll(1 << 16)["saddr6"]
+                if len(tail):
+                    hit = (tail == attacker).all(axis=1).any()
+                    clean = 0 if hit else clean + 1
+                    victim_flows = (tail == victim).all(axis=1).any()
+            assert victim_flows
+            proc.terminate()
+        finally:
+            out, _ = proc.communicate(timeout=15)
+        stats = json.loads(out.strip().splitlines()[-1])
+        assert stats["verdicts"] == 1 and stats["blacklisted"] == 1
+
+    @pytest.mark.parametrize("args,why", [
+        (("--key-bits", "64"), "--key-bits must be 32 or 128"),
+        (("--v6-fraction", "0.5"), "need --key-bits 128"),
+        (("--key-bits", "128", "--fold-collide", "40", "--attack-ips",
+          "64", "--v6-fraction", "0.5"), "--fold-collide 40"),
+    ], ids=["width", "v6_at_32_bits", "more_plants_than_v6_attackers"])
+    def test_128_bit_options_are_checked(self, fsxd_bin, tmp_path, args,
+                                         why):
+        fring, vring = _rings(tmp_path)
+        r = subprocess.run([str(fsxd_bin), "--sim", "--packets", "10",
+                            "--feature-ring", fring, "--verdict-ring",
+                            vring, *args],
+                           capture_output=True, text=True, timeout=10)
+        assert r.returncode == 1 and why in r.stderr
 
     def test_an_unknown_option_ends_the_daemon_at_once(self, fsxd_bin):
         """What the churn cell's driver leans on where the daemon is
@@ -457,3 +593,34 @@ class TestEndToEnd:
         assert rep.records_per_s >= 0.8 * rate, rep.records_per_s
         assert rep.blocked_sources == 0
         assert rep.stats["dropped_ml"] == 0
+
+
+def test_the_64_byte_record_matches_the_schema(tmp_path):
+    """`struct fsx_flow_record6` and `struct fsx_verdict_record6` as
+    gcc lays out the generated kern/fsx_schema.h, against the numpy
+    dtypes they are generated from."""
+    src = tmp_path / "offs6.c"
+    fields = [("fsx_flow_record6", f)
+              for f in schema.FLOW_RECORD6_DTYPE.names] + [
+        ("fsx_verdict_record6", f)
+        for f in schema.VERDICT_RECORD6_DTYPE.names]
+    src.write_text(
+        "#include <stdio.h>\n#include <stddef.h>\n"
+        "#define FSX_HOST_BUILD 1\n#include \"fsx_schema.h\"\n"
+        "int main(void){\n"
+        'printf("%zu %zu\\n", sizeof(struct fsx_flow_record6),'
+        " sizeof(struct fsx_verdict_record6));\n"
+        + "".join(f'printf("%zu\\n", offsetof(struct {s}, {f}));\n'
+                  for s, f in fields)
+        + "return 0;}\n")
+    exe = tmp_path / "offs6"
+    subprocess.run(["gcc", "-I", str(REPO / "kern"), str(src), "-o",
+                    str(exe)], check=True)
+    out = [int(x) for x in subprocess.run(
+        [str(exe)], capture_output=True, text=True, check=True).stdout.split()]
+    assert out[:2] == [64, 32]
+    want = [schema.FLOW_RECORD6_DTYPE.fields[f][1] for f in
+            schema.FLOW_RECORD6_DTYPE.names] + [
+        schema.VERDICT_RECORD6_DTYPE.fields[f][1]
+        for f in schema.VERDICT_RECORD6_DTYPE.names]
+    assert out[2:] == want
